@@ -22,7 +22,7 @@ Public surface mirrors the reference's (``com.intel.analytics.bigdl``):
     opt.set_end_when(bt.optim.Trigger.max_epoch(10)).optimize()
 """
 
-from bigdl_tpu.utils.engine import Engine
+from bigdl_tpu.utils.engine import Engine, compile_cache_dir
 from bigdl_tpu.utils.table import Table, T
 from bigdl_tpu.tensor import Tensor
 from bigdl_tpu import nn
@@ -37,8 +37,10 @@ from bigdl_tpu import telemetry
 
 __version__ = "0.1.0"
 
+compile_cache_dir()  # before anything compiles: see utils/engine.py
+
 __all__ = [
-    "Engine", "Table", "T", "Tensor",
+    "Engine", "compile_cache_dir", "Table", "T", "Tensor",
     "nn", "optim", "dataset", "parallel", "utils", "visualization", "interop",
     "ml", "telemetry",
     "__version__",

@@ -443,38 +443,31 @@ _DTYPE_BYTES = {"pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2,
                 "f16": 2, "bf16": 2, "s32": 4, "u32": 4, "f32": 4,
                 "s64": 8, "u64": 8, "f64": 8}
 _SHAPE_RE = re.compile(r"\b(pred|[suf]\d+|bf16)\[([\d,]*)\]")
+# the result type is everything between "= " and the op name: TPU layouts
+# nest parentheses inside a tuple type ("(f32[64]{0:T(128)S(1)}, ...)")
 _OP_RE = re.compile(
-    r"=\s+(\([^)]*\)|\S+)\s+"
+    r"=\s+(.+?)\s+"
     r"(all-reduce|all-gather|reduce-scatter|collective-permute|all-to-all)"
     r"(-start)?\(")
 _GROUPS_BRACE_RE = re.compile(r"replica_groups=\{\{([\d,]+)\}")
 _GROUPS_IOTA_RE = re.compile(r"replica_groups=\[(\d+),(\d+)\]<=")
 
 
-def _shape_bytes(token: str) -> int:
-    m = _SHAPE_RE.search(token)
-    if not m:
+def _result_bytes(result_type: str, is_start: bool) -> int:
+    """Output bytes of an HLO result type. For async-start tuples
+    ``(operand, result)`` the LAST element is the op's true output; a
+    plain op with a tuple result is variadic (one all-reduce over every
+    gradient), so its output is the SUM of the elements."""
+    sizes = []
+    for dtype, dims in _SHAPE_RE.findall(result_type):
+        n = 1
+        if dims:
+            for d in dims.split(","):
+                n *= int(d)
+        sizes.append(n * _DTYPE_BYTES.get(dtype, 4))
+    if not sizes:
         return 0
-    n = 1
-    dims = m.group(2)
-    if dims:
-        for d in dims.split(","):
-            n *= int(d)
-    return n * _DTYPE_BYTES.get(m.group(1), 4)
-
-
-def _result_bytes(result_type: str) -> int:
-    """Output bytes of an HLO result type; for async-start tuples
-    ``(operand, result)`` the LAST element is the op's true output."""
-    shapes = _SHAPE_RE.findall(result_type)
-    if not shapes:
-        return 0
-    dtype, dims = shapes[-1]
-    n = 1
-    if dims:
-        for d in dims.split(","):
-            n *= int(d)
-    return n * _DTYPE_BYTES.get(dtype, 4)
+    return sizes[-1] if is_start else sum(sizes)
 
 
 def _group_size(line: str) -> Optional[int]:
@@ -500,7 +493,7 @@ def collective_bytes_from_hlo(txt: str,
         if not m:
             continue
         result_type, op = m.group(1), m.group(2)
-        out_bytes = _result_bytes(result_type)
+        out_bytes = _result_bytes(result_type, bool(m.group(3)))
         s = _group_size(line) or default_group
         payload = out_bytes * s if op == "reduce-scatter" else out_bytes
         d = per_op.setdefault(op, {"count": 0, "payload_bytes": 0.0,

@@ -13,9 +13,6 @@ from bigdl_tpu.optim import (Optimizer, SGD, Top1Accuracy, Top5Accuracy,
 from bigdl_tpu.utils.logger_filter import redirect_logs
 
 
-from bigdl_tpu.utils.platform import ensure_platform  # noqa: F401 (re-export)
-
-
 def train_parser(prog: str, default_batch: int = 128,
                  default_epochs: int = 5,
                  default_lr: float = 0.01) -> argparse.ArgumentParser:

@@ -197,7 +197,7 @@ def _train_context_parallel(model, criterion, ds, args):
 
     import jax
     import jax.numpy as jnp
-    from bigdl_tpu.utils.jax_compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from bigdl_tpu.nn.module import functional_apply

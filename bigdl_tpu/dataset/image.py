@@ -373,7 +373,7 @@ class NativeBGRBatchDecoder(Transformer[ByteRecord, MiniBatch]):
         # device_normalize: emit RAW uint8 batches (4x fewer host->device
         # bytes) and let ``nn.InputNormalize`` cast+normalize ON DEVICE —
         # the TPU-first split when the host->chip link is the ingest
-        # bottleneck (tunneled/PCIe feeds). The native kernel then has
+        # bottleneck. The native kernel then has
         # nothing to do; the host path reduces to framing + collation.
         self.device_normalize = device_normalize
         n = 1 if channels == 1 else channels

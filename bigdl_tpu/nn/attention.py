@@ -22,11 +22,11 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.lax import axis_size
 
 from bigdl_tpu.nn import initialization as init
 from bigdl_tpu.nn.module import Module, TensorModule
 from bigdl_tpu.ops.precision import match_compute
-from bigdl_tpu.utils.jax_compat import axis_size
 
 
 class LayerNorm(TensorModule):

@@ -91,8 +91,8 @@ class InputNormalize(TensorModule):
     per-channel ``(x - mean) / std``.
 
     The TPU-first half of the ingest pipeline (round 5): the host ships
-    RAW uint8 batches — 4x fewer host->device bytes than f32, which on a
-    tunneled/PCIe-fed chip is the binding ingest constraint — and XLA
+    RAW uint8 batches — 4x fewer host->device bytes than f32, which is
+    the binding ingest constraint when the host link is — and XLA
     fuses the cast+normalize into the first convolution's input read.
     Pairs with ``dataset.image.NativeBGRBatchDecoder(device_normalize=
     True)``. No parameters; gradients pass through the affine map.

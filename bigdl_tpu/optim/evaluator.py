@@ -61,8 +61,8 @@ def _evaluate_batches(fwd, params, buffers, batches, v_methods, cache):
     sliceable: Optional[bool] = None  # learned from the first (full) batch
     # Device-side accumulation (steady state): one jitted dispatch per
     # batch carries a donated (M, 2) [value, count] accumulator — the
-    # per-batch ``float(v)`` host syncs otherwise dominate eval on
-    # dispatch-latency-bound backends (each sync ~a full RPC round trip).
+    # per-batch ``float(v)`` host syncs otherwise dominate eval where
+    # dispatch latency binds (each sync is a full device round trip).
     # Callers that evaluate repeatedly (the training loop's validation
     # trigger) pass a persistent ``cache`` dict so the scorer jit is traced
     # ONCE, not per validation (a per-call retrace costs seconds and undoes

@@ -45,10 +45,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.lax import axis_size, pcast
 
 from bigdl_tpu.ops.attention_core import (
     attention_partial, finalize_partial, online_softmax_combine)
-from bigdl_tpu.utils.jax_compat import axis_size, pcast
 
 _NEG = float(jnp.finfo(jnp.float32).min)
 
@@ -279,7 +279,7 @@ def ulysses_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
 def _wrap_shard_map(fn, mesh, axis_name):
     from jax.sharding import PartitionSpec as P
-    from bigdl_tpu.utils.jax_compat import shard_map
+    from jax import shard_map
     spec = P(None, axis_name, None, None)
     return shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
                      out_specs=spec, check_vma=False)

@@ -416,7 +416,7 @@ class DistriOptimizer(LocalOptimizer):
 
     def _build_sharded_step(self) -> Callable:
         from jax.flatten_util import ravel_pytree
-        from bigdl_tpu.utils.jax_compat import shard_map
+        from jax import shard_map
 
         model, criterion, optim = self.model, self.criterion, self.optim_method
         reg_pairs = _regularizer_pairs(model)
@@ -479,12 +479,16 @@ class DistriOptimizer(LocalOptimizer):
             loss = jax.lax.pmean(loss, DATA_AXIS)
             return new_flat, new_buf, new_opt_state, loss
 
-        sharded = shard_map(
-            spmd_step, mesh=mesh,
-            in_specs=(P(), P(), opt_specs, P(), P(DATA_AXIS), P(DATA_AXIS)),
-            out_specs=(P(), P(), opt_specs, P()),
-            check_vma=False)
+        in_specs = (P(), P(), opt_specs, P(), P(DATA_AXIS), P(DATA_AXIS))
+        out_specs = (P(), P(), opt_specs, P())
+        sharded = shard_map(spmd_step, mesh=mesh, in_specs=in_specs,
+                            out_specs=out_specs, check_vma=False)
+        # explicit shardings, as in the other two modes: the step then
+        # compiles once whatever the placement of its first arguments
+        from bigdl_tpu.parallel.fsdp import named_tree
         jitted = tracked_jit(sharded, site="train.step",
+                             in_shardings=named_tree(mesh, in_specs),
+                             out_shardings=named_tree(mesh, out_specs),
                              donate_argnums=(0, 1, 2))
 
         def step(params, buffers, opt_state, rng, data, labels):

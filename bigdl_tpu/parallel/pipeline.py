@@ -45,10 +45,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.lax import axis_size, pcast
 
 from bigdl_tpu.nn.module import Module, functional_apply
 from bigdl_tpu.parallel.mesh import PIPELINE_AXIS
-from bigdl_tpu.utils.jax_compat import axis_size, pcast
 
 
 class PipelineStack(Module):
@@ -325,7 +325,7 @@ def gpipe_loss_fn(stack: PipelineStack, criterion, mesh,
     ``jax.grad`` yields dp-averaged gradients exactly like
     DistriOptimizer's allreduce plane.
     """
-    from bigdl_tpu.utils.jax_compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     p_specs = pipeline_spec_tree(stack, axis_name)
@@ -584,7 +584,7 @@ def stage_pipeline_loss_fn(pipe: StagePipeline, criterion, mesh,
     ``pipe.parameter_tree()`` placed with ``pipe.spec()`` so each device
     holds only its stage's weights. ``data_axis`` composes dp x pp the
     same way (independent pipelines per data group, pmean'd loss)."""
-    from bigdl_tpu.utils.jax_compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     x_spec = P(data_axis) if data_axis else P()

@@ -239,8 +239,8 @@ METRIC_SPECS: List[MetricSpec] = [
                "Live model-FLOPs utilization of the training loop: "
                "cost-analysis FLOPs per dispatch / dispatch wall seconds "
                "/ peak chip FLOP/s (absent when the backend reports no "
-               "cost analysis or the peak is unknown — override with "
-               "BIGDL_TPU_PEAK_FLOPS).", ("mode",)),
+               "cost analysis, or off-TPU, or for a device kind the "
+               "peak table does not list).", ("mode",)),
     MetricSpec("bigdl_device_memory_bytes", "gauge",
                "Device 0 bytes currently allocated (sampled at step "
                "boundaries and slot admission; absent on runtimes "

@@ -29,8 +29,10 @@ lands in:
 
 Cost fields are present-or-None: backends that cannot answer (some CPU
 builds, PJRT plugins without analysis support) degrade to counting and
-timing only — never to an exception on the serving path. Any AOT failure
-falls back to the plain jitted call for that signature, still counted.
+timing only. A compilation error propagates to the caller from the
+``lower().compile()`` that raised it; the only call that bypasses the
+recorder is one made with tracer arguments (inside another trace), which
+cannot be lowered ahead of time.
 
 The per-signature executable cache is bounded (``cache_size``) with
 OLDEST-FIRST SINGLE-ENTRY eviction — evicting one program on overflow
@@ -45,7 +47,6 @@ jax-free at import (the telemetry package contract): jax loads on first
 
 from __future__ import annotations
 
-import os
 import time
 from collections import OrderedDict
 from typing import Any, Callable, Dict, Optional, Tuple
@@ -54,7 +55,8 @@ from bigdl_tpu.telemetry.registry import MetricsRegistry, get_registry
 from bigdl_tpu.telemetry.tracing import span
 
 __all__ = ["tracked_jit", "TrackedJit", "CompileEvent", "peak_flops",
-           "sample_device_memory", "DEFAULT_CACHE_SIZE"]
+           "kind_peak_flops", "require_tpu", "sample_device_memory",
+           "DEFAULT_CACHE_SIZE"]
 
 #: Default retained-executable bound per tracked site. Generous for
 #: steady-state sites (a training loop has ONE signature) and for the
@@ -88,13 +90,15 @@ class CompileEvent:
         return {k: getattr(self, k) for k in self.__slots__}
 
 
-def _leaf_key(x) -> Tuple:
+def _leaf_key(x, with_sharding: bool) -> Tuple:
     """Hashable abstract descriptor of one argument leaf. jax arrays key
     on (shape, dtype, weak_type, sharding) — sharding included because a
     compiled executable is specialized to its input layout (a mesh-
     committed and an uncommitted array of the same shape need different
-    programs). Non-array leaves key on their type: a Python scalar traces
-    as a weak-typed 0-d input, so its VALUE does not split programs.
+    programs) — unless the jit fixes that layout itself
+    (``with_sharding=False``, see ``TrackedJit.__init__``). Non-array
+    leaves key on their type: a Python scalar traces as a weak-typed 0-d
+    input, so its VALUE does not split programs.
 
     TRACER leaves raise TypeError: a tracked fn called inside another
     trace (the eval scorer calls the tracked forward) must inline through
@@ -106,7 +110,7 @@ def _leaf_key(x) -> Tuple:
     aval = getattr(x, "aval", None)
     if aval is not None:                       # jax.Array fast path
         return (aval.shape, str(aval.dtype), bool(aval.weak_type),
-                getattr(x, "sharding", None))
+                getattr(x, "sharding", None) if with_sharding else None)
     shape = getattr(x, "shape", None)
     if shape is not None and hasattr(x, "dtype"):   # numpy array
         return (tuple(shape), str(x.dtype), False, None)
@@ -154,10 +158,16 @@ class TrackedJit:
         self.site = site
         self.cache_size = max(1, int(cache_size))
         self._jitted = jax.jit(fn, **jit_kwargs)
+        # explicit in_shardings fix the program's input layout, so the
+        # arguments' own placement must not split programs: a mesh step's
+        # first call sees fresh single-device state and every later call
+        # the mesh-committed outputs of the one before — one program, not
+        # two (and a third when the loop swaps in a fresh epoch scalar).
+        # The executable reshards uncommitted arguments itself and rejects
+        # a committed argument laid out otherwise.
+        self._key_on_sharding = "in_shardings" not in jit_kwargs
         self._registry = registry if registry is not None else get_registry()
         self._tm = instruments(self._registry)
-        # signature -> compiled executable (None = AOT unsupported for
-        # that signature; dispatch through the plain jitted wrapper)
         self._programs: "OrderedDict[Tuple, Any]" = OrderedDict()
         self.events: list = []            # CompileEvent, oldest first
         self.last_event: Optional[CompileEvent] = None
@@ -172,7 +182,8 @@ class TrackedJit:
     def _signature(self, args) -> Tuple:
         import jax
         leaves, treedef = jax.tree_util.tree_flatten(args)
-        return (treedef, tuple(_leaf_key(x) for x in leaves))
+        with_sharding = self._key_on_sharding
+        return (treedef, tuple(_leaf_key(x, with_sharding) for x in leaves))
 
     def _describe(self, args) -> str:
         """Human-readable shape signature for the event/span (kept terse:
@@ -188,20 +199,19 @@ class TrackedJit:
 
     def _record(self, seconds: float, compiled, signature: str) -> None:
         flops = bytes_accessed = temp = outb = argb = None
-        if compiled is not None:
-            try:
-                analysis = compiled.cost_analysis()
-                flops = _cost_number(analysis, "flops")
-                bytes_accessed = _cost_number(analysis, "bytes accessed")
-            except Exception:       # noqa: BLE001 — analysis is best-effort
-                pass
-            try:
-                mem = compiled.memory_analysis()
-                temp = int(getattr(mem, "temp_size_in_bytes", None))
-                outb = int(getattr(mem, "output_size_in_bytes", None))
-                argb = int(getattr(mem, "argument_size_in_bytes", None))
-            except Exception:       # noqa: BLE001
-                pass
+        try:
+            analysis = compiled.cost_analysis()
+            flops = _cost_number(analysis, "flops")
+            bytes_accessed = _cost_number(analysis, "bytes accessed")
+        except Exception:       # noqa: BLE001 — analysis is best-effort
+            pass
+        try:
+            mem = compiled.memory_analysis()
+            temp = int(getattr(mem, "temp_size_in_bytes", None))
+            outb = int(getattr(mem, "output_size_in_bytes", None))
+            argb = int(getattr(mem, "argument_size_in_bytes", None))
+        except Exception:       # noqa: BLE001
+            pass
         ev = CompileEvent(self.site, signature, seconds, flops,
                           bytes_accessed, temp, outb, argb)
         self.events.append(ev)
@@ -225,36 +235,22 @@ class TrackedJit:
         programs = self._programs
         try:
             key = self._signature(args)
-        except TypeError:         # unhashable leaf metadata: bypass tracking
+        except TypeError:         # tracer arguments: inline through jit
             return self._jitted(*args)
-        compiled = programs.get(key, _MISS)
-        if compiled is _MISS:
+        compiled = programs.get(key)
+        if compiled is None:
             compiled = self._compile(key, args)
-        elif compiled is None:    # known-unsupported signature
-            return self._jitted(*args)
         else:
             programs.move_to_end(key)
         return compiled(*args)
 
     def _compile(self, key, args):
         """AOT-compile a new signature, record the event, bound the cache.
-        Returns the executable, or falls back to (and returns the result
-        semantics of) the plain jitted path by caching ``None``."""
+        A compile error (Mosaic, HBM) propagates from here, once."""
         desc = self._describe(args)
         t0 = time.perf_counter()
-        try:
-            with span("profiling.compile", site=self.site, signature=desc):
-                compiled = self._jitted.lower(*args).compile()
-        except Exception:       # noqa: BLE001 — AOT unsupported here: the
-            # plain jit call must still work (and still counts: its first
-            # dispatch IS the compile, timed around the call)
-            self._programs[key] = None
-            result = self._jitted(*args)
-            self._record(time.perf_counter() - t0, None, desc)
-            self._evict()
-            # hand the caller the already-computed result through the
-            # normal `compiled(*args)` return path
-            return _Precomputed(result)
+        with span("profiling.compile", site=self.site, signature=desc):
+            compiled = self._jitted.lower(*args).compile()
         self._record(time.perf_counter() - t0, compiled, desc)
         self._programs[key] = compiled
         self._evict()
@@ -275,25 +271,15 @@ class TrackedJit:
         tests lower and inspect programs without executing them)."""
         return self._jitted.lower(*args, **kwargs)
 
+    def compiled_texts(self) -> list:
+        """Optimized-HLO text of every retained executable, oldest first:
+        the programs that actually ran (``chip_smoke.py`` looks there for
+        the Mosaic custom calls and the all-reduce)."""
+        return [c.as_text() for c in self._programs.values()]
+
     def __repr__(self) -> str:
         return (f"TrackedJit(site={self.site!r}, compiles={self.compiles}, "
                 f"cached={len(self._programs)})")
-
-
-class _Precomputed:
-    """Adapter so ``_compile``'s fallback path can return 'an executable'
-    whose one pending call result is already known."""
-
-    __slots__ = ("_result",)
-
-    def __init__(self, result):
-        self._result = result
-
-    def __call__(self, *args):
-        return self._result
-
-
-_MISS = object()
 
 
 def tracked_jit(fn: Callable, *, site: str,
@@ -309,8 +295,10 @@ def tracked_jit(fn: Callable, *, site: str,
 # Peak-FLOPs model + MFU
 # ---------------------------------------------------------------------------
 
-# bf16 peak FLOP/s by device kind substring (the roofline numerators the
-# PERF.md analyses already use; first match wins)
+# The one peak table: bf16 peak FLOP/s of one chip by ``device_kind``
+# substring, first match wins (Google Cloud TPU documentation, per-chip
+# figures; a v5e reports itself as "TPU v5 lite"). bench.py, chip_smoke.py
+# and the live MFU gauge all read it; a kind that is not here has no MFU.
 _PEAK_BY_KIND = (
     ("v5 lite", 197e12), ("v5e", 197e12),
     ("v5p", 459e12),
@@ -323,30 +311,36 @@ _PEAK_BY_KIND = (
 _peak_cache: Dict[str, Optional[float]] = {}
 
 
-def peak_flops() -> Optional[float]:
-    """Per-chip peak FLOP/s for MFU computation, or None when unknown.
+def kind_peak_flops(device_kind: str) -> Optional[float]:
+    """The table's bf16 peak for a ``device_kind`` string, or None."""
+    kind = device_kind.lower()
+    return next((f for sub, f in _PEAK_BY_KIND if sub in kind), None)
 
-    ``BIGDL_TPU_PEAK_FLOPS`` overrides (any backend — the only way to get
-    MFU on CPU or an unrecognized accelerator); otherwise the TPU device
-    kind maps through the table above. Unknown = None: an MFU computed
-    against a made-up roof is worse than no MFU."""
-    env = os.environ.get("BIGDL_TPU_PEAK_FLOPS", "")
-    if env:
-        try:
-            return float(env)
-        except ValueError:
-            pass
+
+def require_tpu():
+    """Gate of the measurement paths (``bench.py``, ``chip_smoke.py``):
+    returns ``(device, peak_flops)`` for the first device, and raises
+    unless it is a TPU whose kind the peak table knows — a number taken
+    anywhere else is not a device metric."""
+    import jax
+    dev = jax.devices()[0]
+    peak = kind_peak_flops(dev.device_kind) if dev.platform == "tpu" else None
+    if peak is None:
+        raise RuntimeError(
+            f"needs a TPU the peak table knows: jax's default backend is "
+            f"{jax.default_backend()!r}, device kind {dev.device_kind!r}")
+    return dev, peak
+
+
+def peak_flops() -> Optional[float]:
+    """Per-chip peak FLOP/s for the live MFU gauge: the table's entry for
+    this process's first device, None off-TPU or for an unknown kind — an
+    MFU computed against a made-up roof is worse than no MFU."""
     if "kind" not in _peak_cache:
-        kind = ""
-        try:
-            import jax
-            dev = jax.local_devices()[0]
-            if dev.platform == "tpu":
-                kind = getattr(dev, "device_kind", "").lower()
-        except Exception:       # noqa: BLE001 — no backend, no roof
-            kind = ""
-        _peak_cache["kind"] = next(
-            (f for sub, f in _PEAK_BY_KIND if sub in kind), None)
+        import jax
+        dev = jax.local_devices()[0]
+        _peak_cache["kind"] = (kind_peak_flops(dev.device_kind)
+                               if dev.platform == "tpu" else None)
     return _peak_cache["kind"]
 
 

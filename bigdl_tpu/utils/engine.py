@@ -42,6 +42,30 @@ class _EngineState:
 
 _state = _EngineState()
 
+#: Where compiled programs persist when the environment names no place: a
+#: fixed path inside the checkout (``.gitignore`` lists it). The path is
+#: part of the cache key, so it never derives from $TMPDIR, a pid or a time.
+_CHECKOUT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
+
+def compile_cache_dir() -> str:
+    """The one compile-cache rule; returns the directory in use.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, jax reads it itself and
+    nothing is set in code. Where it is not, the cache goes to
+    ``<checkout>/.jax_cache``. ``import bigdl_tpu`` calls this, so every
+    entry point (apps, ``bench.py``, ``chip_smoke.py``, scripts) follows
+    the same rule and a second run of any of them starts warm."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+    if jax.config.jax_compilation_cache_dir != _CHECKOUT_CACHE_DIR:
+        jax.config.update("jax_compilation_cache_dir", _CHECKOUT_CACHE_DIR)
+    return _CHECKOUT_CACHE_DIR
+
 
 class Engine:
     """Process-global topology singleton (reference ``utils/Engine.scala``)."""
@@ -81,20 +105,16 @@ class Engine:
         required spark conf (``Engine.checkSparkContext``,
         ``utils/Engine.scala:269-293`` against ``spark-bigdl.conf:31-43``).
 
-        ``scripts/bigdl-tpu.sh`` sets these; a bare ``python`` invocation
-        gets warnings (or, with ``strict=True`` ≙ the reference's
+        ``scripts/bigdl-tpu.sh`` sets this; a bare ``python`` invocation
+        gets a warning (or, with ``strict=True`` ≙ the reference's
         ``forceCheck``, an error) listing what's off. Returns the list of
         complaint strings. Suppress with ``BIGDL_TPU_DISABLE_ENV_CHECK=1``
-        (reference ``bigdl.disableCheckSysEnv``)."""
+        (reference ``bigdl.disableCheckSysEnv``). The compile cache is not
+        checked: ``compile_cache_dir`` gives every process one."""
         problems: List[str] = []
         disable = os.environ.get("BIGDL_TPU_DISABLE_ENV_CHECK", "")
         if disable.strip().lower() in ("1", "true", "yes", "y", "on"):
             return problems
-        if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
-            problems.append(
-                "JAX_COMPILATION_CACHE_DIR is unset: every process pays the "
-                "full XLA compile (20-40s for large models); run under "
-                "scripts/bigdl-tpu.sh or export a cache dir")
         omp = os.environ.get("OMP_NUM_THREADS")
         if omp is not None:
             omp = omp.strip()
@@ -139,21 +159,10 @@ class Engine:
             _state.dist_checked = True
             return
         import jax
-        # jax < 0.5 has no jax.distributed.is_initialized; _state.dist_checked
-        # already makes this call once-per-process, so absence just means we
-        # proceed straight to initialize
-        is_init = getattr(jax.distributed, "is_initialized", None)
-        if is_init is not None and is_init():
+        if jax.distributed.is_initialized():
             _state.dist_checked = True
             return
-        # CPU multi-process collectives need the gloo implementation
-        # selected BEFORE the backend initializes (jax >= 0.4.34 otherwise
-        # refuses cross-process computations on CPU); a no-op on TPU pods
-        # and on jax versions without the option.
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except Exception:  # unknown option on this jax — leave defaults
-            pass
+        # (CPU multi-process collectives ride gloo, jax's default.)
         # A genuine connect failure must RAISE: swallowing it would let N
         # hosts silently train independently against one checkpoint path.
         if coord:

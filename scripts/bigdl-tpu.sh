@@ -70,9 +70,9 @@ if [[ "${1:-}" == "serve" ]]; then
   exec python -m bigdl_tpu.apps.transformer serve "$@"
 fi
 
-# --- compilation cache: first compile of a big model is 20-40s; persist it
-export JAX_COMPILATION_CACHE_DIR="${JAX_COMPILATION_CACHE_DIR:-${TMPDIR:-/tmp}/bigdl_tpu_jax_cache}"
-mkdir -p "$JAX_COMPILATION_CACHE_DIR"
+# --- compilation cache: nothing to do here. JAX_COMPILATION_CACHE_DIR, when
+#     exported, is read by jax itself; otherwise `import bigdl_tpu` points the
+#     cache at <checkout>/.jax_cache (utils/engine.py compile_cache_dir).
 
 # --- host-side threading: BLAS/OpenMP on the host should not fight the
 # data-pipeline IO pool (reference pins OMP_NUM_THREADS=1, KMP_BLOCKTIME=0)

@@ -16,12 +16,6 @@ _flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in _flags:
     os.environ["XLA_FLAGS"] = (_flags + " --xla_force_host_platform_device_count=8").strip()
 
-# Plugins may have imported jax before this conftest ran, freezing the
-# platform choice from the ambient env — override through the live config.
-import jax  # noqa: E402
-
-jax.config.update("jax_platforms", "cpu")
-
 import pytest  # noqa: E402
 
 

@@ -194,11 +194,6 @@ class TestPerfHarness:
         loop writes (model.N, state.N) pairs through the resilience
         coordinator, and a resume continues epoch/neval counters from the
         saved driver instead of raising (ISSUE: transformer.py:150)."""
-        pytest.importorskip("jax").__version__
-        try:
-            from bigdl_tpu.utils.jax_compat import shard_map  # noqa: F401 — cp loop
-        except ImportError:
-            pytest.skip("jax.shard_map unavailable on this toolchain")
         from bigdl_tpu.apps import transformer
         from bigdl_tpu.resilience import coordinator
         ck = str(tmp_path / "ck")
@@ -317,7 +312,7 @@ class TestPerfHarness:
         want = float(crit.apply(out, targets))
 
         # seq-parallel loss via the app's own loop internals
-        from bigdl_tpu.utils.jax_compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         from bigdl_tpu.parallel.mesh import MeshTopology
         mesh = MeshTopology(sequence=8).build()

@@ -1,12 +1,13 @@
 """Unit tests for bench.py's orchestration logic (the driver-facing
-contract: ALWAYS emit one parseable JSON line, survive wedged backends,
-respect the global wall budget). The worker side runs on real hardware; here
-the attempt/probe layers are stubbed.
+contract: ALWAYS emit one parseable JSON line, respect the global wall
+budget, and never print a device metric from anything but a TPU). The worker
+side runs on real hardware; here the attempt layer is stubbed.
 """
 
 import importlib.util
 import json
 import os
+import subprocess
 import sys
 
 import pytest
@@ -18,23 +19,23 @@ bench = importlib.util.module_from_spec(_SPEC)
 _SPEC.loader.exec_module(bench)
 
 
-def run_main(monkeypatch, capsys, argv, attempts_log, probe=True,
-             results=None, env=None):
-    """Drive bench.main() with _attempt/_probe_backend stubbed; returns the
-    parsed final JSON line."""
+def run_main(monkeypatch, capsys, argv, attempts_log, results=None,
+             env=None, started_ago=0):
+    """Drive bench.main() with _attempt stubbed; returns the parsed final
+    JSON line."""
     results = results or {}
 
-    def fake_attempt(name, worker, batch, steps, budget, platform="",
-                     precision="bf16", grace=90, seq_len=None):
-        attempts_log.append((name, worker, batch, budget, platform))
+    def fake_attempt(name, worker, batch, steps, budget, precision="bf16",
+                     grace=90, seq_len=None):
+        attempts_log.append((name, worker, batch, budget))
         return results.get(name)
 
     monkeypatch.setattr(bench, "_attempt", fake_attempt)
-    monkeypatch.setattr(bench, "_probe_backend", lambda *a, **k: probe)
     for k, v in (env or {}).items():
         monkeypatch.setenv(k, v)
     monkeypatch.setattr(sys, "argv", ["bench.py"] + argv)
-    monkeypatch.setattr(bench, "_T_START", bench.time.monotonic())
+    monkeypatch.setattr(bench, "_T_START",
+                        bench.time.monotonic() - started_ago)
     code = 0
     try:
         bench.main()
@@ -65,24 +66,10 @@ def test_all_fail_emits_diagnostic_json(monkeypatch, capsys):
     assert len(log) >= 3
 
 
-def test_dead_probe_skips_tpu_attempts(monkeypatch, capsys):
-    log = []
-    parsed, code = run_main(monkeypatch, capsys, [], log, probe=False)
-    assert all(a[4] == "cpu" for a in log), log
-
-
-def test_model_filter_keeps_cpu_fallback(monkeypatch, capsys):
-    log = []
-    parsed, _ = run_main(monkeypatch, capsys, ["--model", "resnet50"], log)
-    workers = {a[1] for a in log}
-    assert workers == {"resnet50"}
-    assert any(a[4] == "cpu" for a in log), "no CPU fallback attempt"
-
-
 def test_batch_override_dedupes_attempts(monkeypatch, capsys):
     log = []
     run_main(monkeypatch, capsys, ["--batch", "64"], log)
-    keys = [(a[1], a[2], a[4]) for a in log]
+    keys = [(a[1], a[2]) for a in log]
     assert len(keys) == len(set(keys)), f"duplicate attempts: {keys}"
     assert all(a[2] == 64 for a in log)
 
@@ -94,49 +81,26 @@ def test_unparseable_total_budget_ignored(monkeypatch, capsys):
     assert parsed["metric"] == "bench_failed" and len(log) >= 3
 
 
-def test_exhausted_budget_skips_straight_to_cpu(monkeypatch, capsys):
+def test_exhausted_budget_skips_every_attempt(monkeypatch, capsys):
+    # pretend the run started ~18 min ago: no attempt can compile in what
+    # is left, so none is started and the run fails with a parseable line
     log = []
-    # pretend the run started ~18 min ago: no TPU attempt fits, but the CPU
-    # fallback must still be attempted rather than emitting nothing
-    monkeypatch.setattr(bench, "_T_START", bench.time.monotonic() - 1100)
-    res = {"lenet-cpu": {"metric": "m", "value": 1.0, "unit": "u",
-                         "vs_baseline": 0.0}}
-
-    def fake_attempt(name, worker, batch, steps, budget, platform="",
-                     precision="bf16", grace=90, seq_len=None):
-        log.append((name, worker, batch, budget, platform))
-        return res.get(name)
-
-    monkeypatch.setattr(bench, "_attempt", fake_attempt)
-    monkeypatch.setattr(bench, "_probe_backend", lambda *a, **k: True)
-    monkeypatch.setattr(sys, "argv", ["bench.py"])
-    try:
-        bench.main()
-    except SystemExit:
-        pass
-    out = capsys.readouterr().out.strip().splitlines()
-    parsed = json.loads(out[-1])
-    assert all(a[4] == "cpu" for a in log), log
-    assert parsed["value"] == 1.0
+    parsed, code = run_main(monkeypatch, capsys, [], log, started_ago=1100)
+    assert log == [] and parsed["metric"] == "bench_failed" and code == 1
 
 
-def test_no_fused_self_ab_runs(monkeypatch, capsys):
-    # the fused self-A/B was removed after round-3 hardware measurement
-    # (plain 2539 vs fused 1112-1854 img/s): a plain win must not spawn
-    # any extra fused attempt on either backend
-    log = []
-    res = {"resnet50-b256": {"metric": "m", "value": 2526.0,
-                             "unit": "u", "vs_baseline": 0.6},
-           "lenet-cpu": {"metric": "m", "value": 100.0,
-                         "unit": "u", "vs_baseline": 1.0}}
-    parsed, _ = run_main(monkeypatch, capsys, [], log, results=res)
-    assert parsed["value"] == 2526.0
-    assert not any("fused" in n for n, *_ in log)
-    log2 = []
-    parsed2, _ = run_main(monkeypatch, capsys, [], log2, probe=False,
-                          results=res)
-    assert parsed2["value"] == 100.0
-    assert not any("fused" in n for n, *_ in log2)
+def test_non_tpu_worker_is_an_error():
+    """The real worker under JAX_PLATFORMS=cpu: it must fail before it
+    compiles anything and print no metric line — a number from anything
+    but a TPU is not a device metric."""
+    env = {**os.environ, "JAX_PLATFORMS": "cpu"}
+    r = subprocess.run(
+        [sys.executable, bench.__file__, "--worker", "lenet",
+         "--steps", "1", "--budget", "60"],
+        capture_output=True, timeout=120, env=env)
+    assert r.returncode != 0
+    assert r.stdout.strip() == b""
+    assert b"default backend is 'cpu'" in r.stderr
 
 
 def _load_script(name):
@@ -200,21 +164,19 @@ def test_moe_ablate_emits_cost_rows_for_all_dispatches(monkeypatch,
 
 def test_all_mode_one_line_per_workload(monkeypatch, capsys):
     # --all emits one JSON line per BASELINE workload, falling down each
-    # model's ladder independently; dead-TPU probe limits it to CPU
-    # fallbacks but still covers every model
+    # model's ladder independently (here: every first rung fails)
     log = []
-    res = {f"{m}-cpu": {"metric": f"{m}_x", "value": 1.0 + i,
-                        "unit": "u", "vs_baseline": 0.1}
-           for i, m in enumerate(bench._MODELS)}
-    results = dict(res)
+    results = {f"{m}-b{bench._LADDERS[m][-1][0]}":
+               {"metric": f"{m}_x", "value": 1.0 + i, "unit": "u",
+                "vs_baseline": 0.1}
+               for i, m in enumerate(bench._MODELS)}
 
-    def fake_attempt(name, worker, batch, steps, budget, platform="",
-                     precision="bf16", grace=90, seq_len=None):
-        log.append((name, platform))
+    def fake_attempt(name, worker, batch, steps, budget, precision="bf16",
+                     grace=90, seq_len=None):
+        log.append(name)
         return results.get(name)
 
     monkeypatch.setattr(bench, "_attempt", fake_attempt)
-    monkeypatch.setattr(bench, "_probe_backend", lambda *a, **k: False)
     monkeypatch.setattr(sys, "argv", ["bench.py", "--all"])
     monkeypatch.setattr(bench, "_T_START", bench.time.monotonic())
     code = 0
@@ -227,5 +189,4 @@ def test_all_mode_one_line_per_workload(monkeypatch, capsys):
     assert code == 0
     assert len(lines) == len(bench._MODELS)
     assert {l["model"] for l in lines} == set(bench._MODELS)
-    # dead probe: no TPU attempts were made at all
-    assert all(p == "cpu" for _, p in log)
+    assert len(log) == sum(len(v) for v in bench._LADDERS.values())

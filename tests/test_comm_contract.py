@@ -64,7 +64,7 @@ def test_allreduce_step_compiles_to_all_reduce():
 def test_ring_attention_compiles_to_collective_permute():
     # ring attention's defining trait: K/V blocks ROTATE around the ring
     # (ppermute -> collective-permute), no all-gather of the full sequence
-    from bigdl_tpu.utils.jax_compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     from bigdl_tpu.nn.module import functional_apply
     enc = nn.TransformerEncoder(1, 16, 2, 32, causal=True, seq_axis="seq")
@@ -92,7 +92,7 @@ def test_dp_cp_ring_stays_in_coset_and_grads_all_reduce():
     the ring over all 8 devices would mix sequence shards from
     different batch slices (silent numerics corruption, not a crash)."""
     import re
-    from bigdl_tpu.utils.jax_compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     from bigdl_tpu.nn.module import functional_apply
     enc = nn.TransformerEncoder(1, 16, 2, 32, causal=True, seq_axis="seq")

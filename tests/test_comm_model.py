@@ -177,6 +177,24 @@ def test_hlo_parser_handles_async_and_group_forms():
     assert meas["per_op"]["collective-permute"]["wire_bytes"] == 128
 
 
+def test_hlo_parser_handles_variadic_and_tpu_layout_forms():
+    """jax 0.9.0 emits ONE plain all-reduce whose tuple result holds every
+    gradient (summed, not last-element); a TPU compile annotates each shape
+    with a tiled layout that nests parentheses inside the tuple type."""
+    txt = "\n".join([
+        "  %all-reduce.7 = (f32[64]{0}, f32[8,4]{1,0}, bf16[2]{0})"
+        " all-reduce(%a, %b, %c), replica_groups=[1,4]<=[4], to_apply=%add",
+        "  %all-reduce.361 = (f32[64]{0:T(128)S(1)}, f32[64]{0:T(128)S(1)})"
+        " all-reduce(%get-tuple-element.18, %get-tuple-element.17),"
+        " channel_id=2, replica_groups=[1,4]<=[4],"
+        " use_global_device_ids=true, to_apply=%region_1.2.clone",
+    ])
+    ar = commcost.collective_bytes_from_hlo(txt)["per_op"]["all-reduce"]
+    assert ar["count"] == 2
+    assert ar["payload_bytes"] == (64 + 32) * 4 + 2 * 2 + 2 * 64 * 4
+    assert ar["wire_bytes"] == pytest.approx(2 * ar["payload_bytes"] * 3 / 4)
+
+
 def test_mode_model_is_exact_algebra():
     # all-reduce = reduce-scatter + all-gather, per the op table
     b, s = 1 << 20, 8

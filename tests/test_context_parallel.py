@@ -79,7 +79,7 @@ def test_ring_jits_and_shards():
 def test_transformer_encoder_context_parallel():
     # Full transformer stack sharded over the seq axis inside shard_map
     # matches the single-device stack with identical weights.
-    from bigdl_tpu.utils.jax_compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     from bigdl_tpu import nn
     from bigdl_tpu.nn.module import functional_apply
@@ -252,7 +252,7 @@ class TestRopeContextParallel:
         ("ring", "contiguous"), ("ring", "zigzag"),
         ("ulysses", "contiguous")])
     def test_forward_and_grad_match_unsharded(self, mode, layout):
-        from bigdl_tpu.utils.jax_compat import shard_map as _sm
+        from jax import shard_map as _sm
         from jax.sharding import PartitionSpec as P
         from bigdl_tpu.nn.module import functional_apply
         from bigdl_tpu.parallel.context import (zigzag_inverse,

@@ -4,6 +4,8 @@ Reference analogues: ``$T/utils/TableSpec``, ``EngineSpec``, module protocol
 behaviour from ``$T/nn/`` specs.
 """
 
+import os
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -201,22 +203,44 @@ class TestEngineEnvCheck:
         monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
         monkeypatch.setenv("OMP_NUM_THREADS", "16")
         problems = Engine.check_env()
-        assert len(problems) == 2
+        assert len(problems) == 1 and "OMP_NUM_THREADS" in problems[0]
         with pytest.raises(RuntimeError, match="environment check"):
             Engine.check_env(strict=True)
 
     def test_clean_env_passes(self, monkeypatch):
         from bigdl_tpu.utils.engine import Engine
         monkeypatch.delenv("BIGDL_TPU_DISABLE_ENV_CHECK", raising=False)
-        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/tmp/c")
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
         monkeypatch.setenv("OMP_NUM_THREADS", "1")
         assert Engine.check_env(strict=True) == []
 
     def test_disable_switch(self, monkeypatch):
         from bigdl_tpu.utils.engine import Engine
-        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        monkeypatch.setenv("OMP_NUM_THREADS", "16")
         monkeypatch.setenv("BIGDL_TPU_DISABLE_ENV_CHECK", "1")
         assert Engine.check_env(strict=True) == []
+
+
+class TestCompileCacheRule:
+    """One rule, one setter (``utils/engine.py::compile_cache_dir``)."""
+
+    def test_unset_env_gives_the_same_in_checkout_path(self, monkeypatch):
+        from bigdl_tpu.utils import engine
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        first = engine.compile_cache_dir()
+        assert first == os.path.join(repo, ".jax_cache")
+        assert engine.compile_cache_dir() == first
+        assert jax.config.jax_compilation_cache_dir == first
+
+    def test_set_env_leaves_the_config_alone(self, monkeypatch):
+        from bigdl_tpu.utils import engine
+        calls = []
+        monkeypatch.setattr(jax.config, "update",
+                            lambda *a, **k: calls.append(a))
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+        assert engine.compile_cache_dir() == "/elsewhere"
+        assert calls == []
 
 
 class TestRandomGeneratorDistributions:

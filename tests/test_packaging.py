@@ -48,14 +48,18 @@ def test_launcher_execs_command(tmp_path):
     env = dict(os.environ)
     env.pop("JAX_COMPILATION_CACHE_DIR", None)
     env["TMPDIR"] = str(tmp_path)
-    r = subprocess.run(
-        [launcher, "--", sys.executable, "-c",
-         "import os; print(os.environ['JAX_COMPILATION_CACHE_DIR']); "
-         "print(os.environ['OMP_NUM_THREADS'])"],
-        capture_output=True, timeout=60, env=env)
+    probe = [launcher, "--", sys.executable, "-c",
+             "import os; print(os.environ.get('JAX_COMPILATION_CACHE_DIR')); "
+             "print(os.environ['OMP_NUM_THREADS'])"]
+    r = subprocess.run(probe, capture_output=True, timeout=60, env=env)
     assert r.returncode == 0, r.stderr.decode(errors="replace")
-    out = r.stdout.decode()
-    assert "bigdl_tpu_jax_cache" in out
+    # the launcher names no cache directory (least of all one under
+    # $TMPDIR): the package's one rule does, at import
+    assert r.stdout.decode().split() == ["None", "1"]
+    env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "elsewhere")
+    r = subprocess.run(probe, capture_output=True, timeout=60, env=env)
+    assert r.stdout.decode().split()[0] == str(tmp_path / "elsewhere")
+    del env["JAX_COMPILATION_CACHE_DIR"]
 
     # BIGDL_TPU_SIMULATE=4 must force a 4-device CPU platform
     env["BIGDL_TPU_SIMULATE"] = "4"

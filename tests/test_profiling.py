@@ -178,6 +178,40 @@ class TestTrackedJit:
 
         assert float(outer(jnp.asarray(3.0))) == 7.0
 
+    def test_compile_error_propagates_once(self):
+        """No second attempt through plain jit: the error comes from the
+        one lower().compile(), nothing is recorded or cached."""
+        calls = []
+
+        def bad(x):
+            calls.append(1)
+            raise ValueError("refused")
+
+        tj = profiling.tracked_jit(bad, site="t.bad",
+                                   registry=MetricsRegistry())
+        with pytest.raises(ValueError, match="refused"):
+            tj(jnp.ones((4,)))
+        assert len(calls) == 1 and tj.compiles == 0 and not tj._programs
+
+    def test_in_shardings_make_placement_irrelevant(self):
+        """A mesh step sees fresh single-device state on its first call
+        and its own mesh-committed outputs afterwards: with explicit
+        in_shardings that is ONE program (a DistriOptimizer run compiled
+        train.step three times before)."""
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+        mesh = Mesh(np.asarray(jax.devices()[:4]), ("data",))
+        rep = NamedSharding(mesh, P())
+        bat = NamedSharding(mesh, P("data"))
+        tj = profiling.tracked_jit(
+            lambda w, x: (w + x.sum(), x * 2), site="t.mesh",
+            registry=MetricsRegistry(), in_shardings=(rep, bat),
+            out_shardings=(rep, bat))
+        w, x = tj(jnp.zeros(()), jnp.ones((8,)))    # uncommitted inputs
+        w, x = tj(w, x)                             # committed outputs
+        w, x = tj(jnp.zeros(()), x)                 # mixed
+        assert tj.compiles == 1
+        assert len(x.sharding.device_set) == 4
+
     def test_pytree_and_scalar_args(self):
         tj, _ = self._tracked()
         reg = MetricsRegistry()
@@ -199,18 +233,20 @@ class TestTrackedJit:
 
 class TestMfuAndMemory:
     def test_mfu_helper(self, monkeypatch):
-        monkeypatch.setenv("BIGDL_TPU_PEAK_FLOPS", "1e12")
+        assert profiling.peak_flops() is None      # a CPU has no roof
+        assert profiling.mfu(1e9, 0.01) is None
+        monkeypatch.setattr(profiling, "peak_flops", lambda: 1e12)
         assert profiling.mfu(1e9, 0.01) == pytest.approx(0.1)
         assert profiling.mfu(None, 0.01) is None
         assert profiling.mfu(1e9, 0.0) is None
 
     def test_training_loop_sets_mfu_gauge(self, monkeypatch):
         """The live MFU gauge: cost-analysis FLOPs of the dispatched step
-        program over wall seconds over the (env-pinned) peak — sane means
+        program over wall seconds over the (pinned) peak — sane means
         strictly positive and far below 1 for a toy model on CPU."""
         from bigdl_tpu.dataset.base import DataSet, Sample, SampleToBatch
         from bigdl_tpu.optim import Optimizer, SGD, Trigger
-        monkeypatch.setenv("BIGDL_TPU_PEAK_FLOPS", "1e15")
+        monkeypatch.setattr(profiling, "peak_flops", lambda: 1e15)
         rng = np.random.default_rng(0)
         samples = [Sample(rng.normal(size=(8,)).astype("float32"),
                           float(rng.integers(1, 5))) for _ in range(32)]
@@ -225,6 +261,12 @@ class TestMfuAndMemory:
         # the step site recorded exactly one compile with its cost gauges
         assert tm.compiles_total.labels(site="train.step").value >= 1
         assert tm.program_flops.labels(site="train.step").value > 0
+
+    def test_peak_table_and_tpu_gate(self):
+        assert profiling.kind_peak_flops("TPU v5 lite") == 197e12
+        assert profiling.kind_peak_flops("cpu") is None
+        with pytest.raises(RuntimeError, match="'cpu'"):
+            profiling.require_tpu()
 
     def test_sample_device_memory_never_raises(self):
         # CPU has no allocator stats: must be a silent None, never a crash
