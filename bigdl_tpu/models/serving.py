@@ -78,7 +78,7 @@ import queue
 import threading
 import time
 from dataclasses import dataclass, field
-from typing import List, Optional
+from typing import List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -150,6 +150,24 @@ class ServerDead(ReplicaUnavailable):
 
 
 @dataclass
+class RequestTiming:
+    """Raw times of one request, ``time.perf_counter()`` seconds, always
+    recorded (tracer on or off). ``token_times`` has one entry for each
+    token THIS server emitted, in order (a resumed ``emitted`` prefix has
+    none): the moment the token reached the host, which for the first is
+    ``first_token`` (the admission sample's fetch, before the insert) and
+    for the others the fetch of their decode block, so the tokens of one
+    block share a time. ``submitted <= admitted <= first_token <=
+    token_times[...] <= done``; ``admitted`` and ``first_token`` are None
+    for a request that failed or was answered before admission."""
+    submitted: float
+    admitted: Optional[float] = None        # admission started
+    first_token: Optional[float] = None
+    token_times: List[float] = field(default_factory=list)
+    done: Optional[float] = None
+
+
+@dataclass
 class _Request:
     ids: List[int]
     max_new: int
@@ -157,6 +175,7 @@ class _Request:
     result: Optional[List[int]] = None
     error: Optional[str] = None
     t_submit: float = 0.0               # perf_counter at submit (TTFT/SLO)
+    timing: Optional[RequestTiming] = None
     rid: int = 0                        # trace-lifecycle id (serving.request)
     emitted0: List[int] = field(default_factory=list)  # resume-cursor prefix
     state_blob: Optional[bytes] = None  # shipped prefill partition (disagg)
@@ -571,7 +590,19 @@ class ContinuousLMServer:
                timeout: Optional[float] = None, *,
                emitted: Optional[List[int]] = None,
                state: Optional[bytes] = None) -> List[int]:
-        """Serve one prompt. ``emitted`` resumes a migrated request from
+        """``submit_timed(...)[0]``: the tokens alone."""
+        return self.submit_timed(prompt_ids, max_new_tokens, timeout,
+                                 emitted=emitted, state=state)[0]
+
+    def submit_timed(self, prompt_ids,
+                     max_new_tokens: Optional[int] = None,
+                     timeout: Optional[float] = None, *,
+                     emitted: Optional[List[int]] = None,
+                     state: Optional[bytes] = None
+                     ) -> Tuple[List[int], RequestTiming]:
+        """Serve one prompt; returns ``(tokens, RequestTiming)``: the raw
+        per-request times first-token latency and inter-token gaps are
+        computed from. ``emitted`` resumes a migrated request from
         its ``HandoffCursor``: the server re-prefills ``prompt + emitted``
         (deterministic, so the greedy continuation is bit-identical to
         the donor's unkilled run) and the result INCLUDES the resumed
@@ -593,10 +624,12 @@ class ContinuousLMServer:
             # a cursor that already satisfied its budget (or hit eos)
             # needs no decode at all — the donor just never got to
             # deliver the result
+            now = time.perf_counter()
             if self.eos_id is not None and self.eos_id in emitted0:
-                return emitted0[:emitted0.index(self.eos_id) + 1][:max_new]
+                return (emitted0[:emitted0.index(self.eos_id) + 1][:max_new],
+                        RequestTiming(now, done=now))
             if len(emitted0) >= max_new:
-                return emitted0[:max_new]
+                return emitted0[:max_new], RequestTiming(now, done=now)
         if state is not None and self.draft is not None:
             raise ValueError(
                 "state handoff is incompatible with speculative serving "
@@ -614,6 +647,7 @@ class ContinuousLMServer:
         req.state_blob = state
         req.rid = next(_REQUEST_IDS)
         req.t_submit = time.perf_counter()
+        req.timing = RequestTiming(req.t_submit)
         # request lifecycle: one async lane per rid in the Chrome trace —
         # submit opens it, admission marks it, completion/failure closes
         # it; the queue_wait/prefill/insert spans carry the same rid
@@ -641,7 +675,7 @@ class ContinuousLMServer:
             if req.fail_kind == "dead":
                 raise ServerDead(req.error, cursor=req.handoff)
             raise RuntimeError(req.error)
-        return req.result
+        return req.result, req.timing
 
     @property
     def queue_depth(self) -> int:
@@ -948,7 +982,7 @@ class ContinuousLMServer:
         # cursor prefix (a migrated request re-prefills both — that
         # deterministic replay is what keeps greedy outputs bit-exact)
         plen = len(req.ids) + len(req.emitted0)
-        t_admit = time.perf_counter()
+        t_admit = req.timing.admitted = time.perf_counter()
         # queue-wait attribution: the retrodicted submit->admission span
         # plus an instant on the request's async lane, both under its rid
         tracing.complete_event("serving.queue_wait", req.t_submit, t_admit,
@@ -969,6 +1003,12 @@ class ContinuousLMServer:
                 self._n_admitted += 1
                 key = jax.random.fold_in(self._admit_key, self._n_admitted)
                 tok = int(sample_token(lp, key, **self.sampling)[0])
+            # the first token is on the host: what a streaming client
+            # would see now, before the insert
+            req.timing.first_token = time.perf_counter()
+            req.timing.token_times.append(req.timing.first_token)
+            tracing.async_instant("serving.request", req.rid,
+                                  phase="first_token")
             # peek, insert, THEN pop: an insert failure must not leak the
             # slot. (The insert donates self.buffers; a RUNTIME failure
             # mid-insert can still invalidate them — compile-time errors,
@@ -1025,6 +1065,7 @@ class ContinuousLMServer:
         hit_eos = eos is not None and sl.emitted and sl.emitted[-1] == eos
         if hit_eos or sl.new_count >= sl.req.max_new:
             sl.req.result = sl.emitted[:sl.req.max_new]
+            sl.req.timing.done = time.perf_counter()
             sl.req.done.set()
             tracing.async_end("serving.request", sl.req.rid,
                               tokens=len(sl.req.result))
@@ -1192,6 +1233,9 @@ class ContinuousLMServer:
                 # immediately (ADVICE medium finding, serving.py:302).
                 self._die(f"decode step failed: {type(e).__name__}: {e}")
                 return
+            # the block's tokens are on the host (np.asarray was the sync):
+            # the one clock read every token of the block is timed by
+            t_host = time.perf_counter()
             live = list(self._active.keys())
             # per-token latency: round wall-clock (np.asarray is the host
             # sync) amortized over the tokens the round produced — fixed
@@ -1201,7 +1245,7 @@ class ContinuousLMServer:
             per_round = (self.decode_block if counts is None
                          else float(np.mean(counts[live])))
             self._tm.serving_token_latency_seconds.observe(
-                (time.perf_counter() - t_block) / per_round)
+                (t_host - t_block) / per_round)
             self._tm.serving_decode_blocks_total.inc()
             if counts is not None:
                 # each live row was proposed spec_len draft tokens and
@@ -1216,17 +1260,24 @@ class ContinuousLMServer:
                               else toks[:, -1].astype(np.int32))
             eos = self.eos_id
             live_tokens = 0
+            emitted = []            # tokens each live request got, in order
             for slot, sl in list(self._active.items()):
                 row = (toks[slot] if counts is None
                        else toks[slot][:counts[slot]])
+                n0 = sl.new_count
                 for t in row:
                     t = int(t)
                     sl.emitted.append(t)
                     sl.new_count += 1
-                    live_tokens += 1
                     if ((eos is not None and t == eos)
                             or sl.new_count >= sl.req.max_new):
                         break
+                n = sl.new_count - n0
+                live_tokens += n
+                emitted.append(n)
+                sl.req.timing.token_times.extend([t_host] * n)
                 self._finish_if_done(slot, sl)
+            # beside rids, in their order (late: ring buffer only)
+            sp.annotate(tokens=emitted)
             if live_tokens:
                 self._tm.serving_tokens_total.inc(live_tokens)

@@ -135,6 +135,7 @@ def _flash_fwd_lse(q, k, v, causal, scale, block_q, block_k, interpret):
         out_specs=(pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
                    pl.BlockSpec((1, 1, block_q), lambda i, j: (i, 0, j))),
         interpret=interpret,
+        name="flash_fwd",
     )(qt, kt, vt)
     out = out[:, :sq].reshape(b, n, sq, d).transpose(0, 2, 1, 3)
     lse = lse[:, 0, :sq].reshape(b, n, sq)
@@ -283,6 +284,7 @@ def _flash_bwd(q, k, v, o, lse, g_o, g_l, causal, scale, block_q, block_k,
         ],
         out_specs=pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(qt, kt, vt, dot, lt, delta)
 
     dk, dv = pl.pallas_call(
@@ -302,6 +304,7 @@ def _flash_bwd(q, k, v, o, lse, g_o, g_l, causal, scale, block_q, block_k,
         out_specs=(pl.BlockSpec((1, block_k, d), lambda i, j: (i, j, 0)),
                    pl.BlockSpec((1, block_k, d), lambda i, j: (i, j, 0))),
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(qt, kt, vt, dot, lt, delta)
 
     dq = dq[:, :sq].reshape(b, n, sq, d).transpose(0, 2, 1, 3)

@@ -111,6 +111,7 @@ def _int8_matmul_pallas(x2, w_q, scale_row, interpret=False):
         out_specs=pl.BlockSpec((mp, to), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((mp, out_dim), jnp.float32),
         interpret=interpret,
+        name="int8_matmul",
     )
     out = call(xp, w_q, scale_row.reshape(1, out_dim).astype(jnp.float32))
     return out[:m]

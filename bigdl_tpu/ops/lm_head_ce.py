@@ -60,6 +60,9 @@ def _lm_head_ce(h, w, b, valid, tgt0, chunk):
     return out
 
 
+# the scope names both scans in the trace (under custom_vjp the backward
+# is traced on its own, so it carries the scope itself)
+@jax.named_scope("lm_head_ce")
 def _lm_head_ce_fwd(h, w, b, valid, tgt0, chunk):
     n = h.shape[0]
     wp, bp, n_chunks = _pad_vocab(w, b, chunk)
@@ -88,6 +91,7 @@ def _lm_head_ce_fwd(h, w, b, valid, tgt0, chunk):
     return (loss_sum, n_valid, lse), (h, w, b, valid, tgt0, lse)
 
 
+@jax.named_scope("lm_head_ce")
 def _lm_head_ce_bwd(chunk, res, cts):
     h, w, b, valid, tgt0, lse = res
     g_sum, _, g_lse = cts  # cotangents for (loss_sum, n_valid, lse)

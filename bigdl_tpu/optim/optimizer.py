@@ -34,7 +34,7 @@ from bigdl_tpu.optim.triggers import Trigger
 from bigdl_tpu.optim.validation import ValidationMethod
 from bigdl_tpu.resilience.preemption import (PreemptionHandler,
                                              TrainingPreempted)
-from bigdl_tpu.telemetry import get_registry, instruments, span
+from bigdl_tpu.telemetry import get_registry, instruments, span, tracing
 from bigdl_tpu.telemetry import profiling
 from bigdl_tpu.telemetry.profiling import sample_device_memory, tracked_jit
 from bigdl_tpu.utils import file_io
@@ -410,12 +410,29 @@ class Optimizer:
         ``AbstractModule.scala:134-145``): every module forward runs under
         ``jax.named_scope(module.name)``, so the trace's HLO ops are
         attributed to layers; open the dump with TensorBoard's profile
-        plugin or Perfetto."""
+        plugin or Perfetto. The span tracer (``telemetry/tracing.py``) is
+        on for the profiled window, so the loop's ``train.*`` spans are in
+        the same trace, on the thread that enqueues the step."""
         self._profile = (log_dir, int(start_iteration), int(n_iterations))
         return self
 
     def optimize(self) -> Module:
         raise NotImplementedError
+
+    def _start_profile(self, log_dir: str) -> None:
+        jax.profiler.start_trace(log_dir)
+        self._profiling_active = True
+        # the spans go into the profile; a tracer somebody else enabled
+        # is left as it is when the profile stops
+        self._profile_owns_tracer = not tracing.is_enabled()
+        if self._profile_owns_tracer:
+            tracing.enable()
+
+    def _stop_profile(self) -> None:
+        jax.profiler.stop_trace()
+        self._profiling_active = False
+        if self._profile_owns_tracer:
+            tracing.disable()
 
     def _telemetry_mode(self) -> str:
         """Label value for the ``bigdl_train_*`` metric families
@@ -451,7 +468,6 @@ class Optimizer:
             steps=tm.train_steps_total.labels(mode=mode),
             records=tm.train_records_total.labels(mode=mode),
             rps=tm.train_records_per_second.labels(mode=mode),
-            compiles=tm.train_compiles_total.labels(mode=mode),
             mfu=tm.train_mfu.labels(mode=mode),
             validation=tm.train_validation_seconds.labels(mode=mode))
         self._tm_cache = cached
@@ -859,9 +875,17 @@ class LocalOptimizer(Optimizer):
             # dispatch (set_steps_per_dispatch) returns (K,) losses — one
             # exact log line per iteration either way.
             t_sync = time.time()
-            with span("train.sync", k=len(p["iters"])):
+            neval_p = p["iters"][0]["neval"]  # the dispatch this waits for
+            with span("train.sync", k=len(p["iters"]), neval=neval_p):
                 losses = np.atleast_1d(np.asarray(p["losses"], np.float32))
             tm.sync.observe(time.time() - t_sync)
+            with span("train.log", k=len(p["iters"]), neval=neval_p):
+                log_window(p, losses)
+
+        def log_window(p, losses):
+            """The host work after a window's loss fetch: metrics, MFU
+            gauge, memory sample, one log line an iteration, summaries."""
+            nonlocal last_done
             # inter-completion interval ~= per-dispatch device time in
             # steady state; measuring to the NEXT dispatch instead would
             # fold hook time and the next batch's data wait into
@@ -875,7 +899,6 @@ class LocalOptimizer(Optimizer):
             if first_window:
                 # first step pays tracing+XLA compile (unless cached)
                 self.metrics.add("compile and first-step time", window_time)
-                tm.compiles.inc()
             # live MFU: the dispatched program's cost-analysis FLOPs (one
             # program ran the whole window, K iterations included) over
             # the window wall-clock and the chip's peak — absent when the
@@ -918,6 +941,7 @@ class LocalOptimizer(Optimizer):
                             "LearningRate", float(meta["lr"]), meta["neval"])
 
         stop = False
+        epoch_end = None  # the open train.epoch_end span, if any
         while not stop and not self.end_when(driver_state):
             self.dataset.shuffle()
             epoch = int(driver_state["epoch"])
@@ -982,140 +1006,162 @@ class LocalOptimizer(Optimizer):
                 epoch_records = int(resume_cursor.get("epoch_records", 0))
             resume_cursor = None  # first resumed epoch only
             while True:
-                try:
-                    batch = next(data_iter)
-                except StopIteration:
-                    break
-                window = [batch]
                 neval0 = int(driver_state["neval"])
-                while (can_window
-                       and len(window) < self._steps_per_dispatch
-                       and extension_ok(neval0, len(window))):
-                    try:
-                        window.append(next(data_iter))
-                    except StopIteration:
-                        break
-                dw = time.time() - t_data
-                data_wait += dw
-                tm.data_wait.observe(dw)
-                k = len(window)
-                last_neval = neval0 + k - 1
                 if self._profile is not None:
+                    # between two iterations, so the profile holds whole
+                    # train.iteration spans (a fused window may start it up
+                    # to one window early)
                     pdir, pstart, pn = self._profile
-                    if (neval0 <= pstart <= last_neval
-                            and not self._profiling_active):
-                        jax.profiler.start_trace(pdir)
-                        self._profiling_active = True
-                t0 = time.time()
-                used_fn = step  # which tracked program served the window
-                with span("train.dispatch", k=k):
-                    if k == 1:
-                        data, labels = self._place_batch(window[0])
-                        params, buffers, opt_state, losses = step(
-                            params, buffers, opt_state, rng.next_key(),
-                            data, labels)
-                    else:
-                        from bigdl_tpu.dataset.device_cache import \
-                            CachedSliceBatch
-                        keys = jnp.stack([rng.next_key() for _ in window])
-                        if (all(isinstance(b, CachedSliceBatch)
-                                for b in window)
-                                and len({id(b.source)
-                                         for b in window}) == 1):
-                            # gathers happen inside the fused program: ONE
-                            # dispatch per window
-                            src = window[0].source
-                            idx = jnp.stack([b.idx for b in window])
-                            used_fn = multi_step_cached
-                            params, buffers, opt_state, losses = \
-                                multi_step_cached(params, buffers,
-                                                  opt_state, keys,
-                                                  src._x, src._y, idx)
+                    if self._profiling_active:
+                        if neval0 >= pstart + pn:
+                            self._stop_profile()
+                            logger.info("[Profiler] trace for iterations "
+                                        "%d-%d written to %s", pstart,
+                                        neval0 - 1, pdir)
+                    elif neval0 <= pstart < neval0 + self._steps_per_dispatch:
+                        self._start_profile(pdir)
+                if epoch_end is not None:
+                    # the boundary ends where the next epoch's first
+                    # iteration starts
+                    epoch_end.__exit__(None, None, None)
+                    epoch_end = None
+                with tracing.step_span("train.iteration", neval0,
+                                       epoch=epoch) as iter_span:
+                    with span("train.data", neval=neval0, epoch=epoch):
+                        window = []
+                        while not window or (
+                                can_window
+                                and len(window) < self._steps_per_dispatch
+                                and extension_ok(neval0, len(window))):
+                            try:
+                                window.append(next(data_iter))
+                            except StopIteration:
+                                break
+                        k = len(window)
+                        iter_span.annotate(k=k)
+                        if k:
+                            dw = time.time() - t_data
+                            data_wait += dw
+                            tm.data_wait.observe(dw)
+                    if not k:
+                        # the iterator is exhausted: a pass with k=0 and
+                        # no dispatch
+                        break
+                    last_neval = neval0 + k - 1
+                    t0 = time.time()
+                    used_fn = step  # which tracked program served the window
+                    with span("train.dispatch", k=k, neval=neval0):
+                        if k == 1:
+                            data, labels = self._place_batch(window[0])
+                            params, buffers, opt_state, losses = step(
+                                params, buffers, opt_state, rng.next_key(),
+                                data, labels)
                         else:
-                            # host batches: one fused H2D + dispatch per
-                            # window
-                            xs = jnp.stack([jnp.asarray(b.data)
-                                            for b in window])
-                            ys = jnp.stack([jnp.asarray(b.labels)
-                                            for b in window])
-                            used_fn = multi_step
-                            params, buffers, opt_state, losses = multi_step(
-                                params, buffers, opt_state, keys, xs, ys)
-                # host time enqueueing the window (async; device compute
-                # lands in the NEXT flush's sync wait)
-                tm.dispatch.observe(time.time() - t0)
-                flush()  # previous dispatch: fetch losses, log, summarize
-                # snapshot the lr as its own small array NOW: opt_state's
-                # buffers are donated to the next dispatch and deleted
-                # (* 1 forces a fresh buffer if the schedule returns a state
-                # array by identity). One snapshot per dispatch: intra-window
-                # schedule steps are not observable host-side.
-                lr_arr = None
-                if (self.train_summary is not None
-                        and hasattr(self.optim_method, "current_rate")):
-                    lr_arr = self.optim_method.current_rate(opt_state)
-                    if not isinstance(lr_arr, (int, float)):
-                        lr_arr = lr_arr * 1
-                iters = []
-                for j, b in enumerate(window):
-                    epoch_records += b.size()
-                    iters.append({"neval": neval0 + j, "epoch": epoch,
-                                  "n_records": b.size(),
-                                  "epoch_records": epoch_records,
-                                  "size": self.dataset.size(),
-                                  "lr": lr_arr})
-                pending = {"losses": losses, "iters": iters, "t0": t0,
-                           "fn": used_fn}
-                if self._profiling_active and last_neval >= pstart + pn - 1:
-                    jax.profiler.stop_trace()
-                    self._profiling_active = False
-                    logger.info("[Profiler] trace for iterations %d-%d "
-                                "written to %s", pstart, last_neval, pdir)
-                # non-final window members were probed trigger-silent; the
-                # final member gets the real per-iteration hook slot
-                driver_state["neval"] = last_neval
-                if ptrig is not None and ptrig(driver_state):
-                    self._summarize_parameters(params, last_neval)
-                driver_state["neval"] = last_neval + 1
-                epoch_batches += k
-                # the data-iterator cursor any checkpoint written at this
-                # boundary records in its RESUME marker
-                self._loop_cursor = {"epoch": epoch,
-                                     "epoch_batches": epoch_batches,
-                                     "epoch_records": epoch_records}
-                if uses_loss_any:
-                    # loss-sensitive stop/hook triggers must see THIS
-                    # iteration's loss, not the pipelined previous one
-                    flush()
-                self._hooks(params, buffers, opt_state, driver_state, fwd,
-                            epoch_done=False, flush=flush)
-                for inj in chaos_injectors:
-                    inj.on_step(last_neval)
-                if handler is not None:
-                    fresh = handler.drain_notices()
-                    if fresh:
-                        instruments(get_registry()) \
-                            .resilience_preemptions_total.inc(fresh)
-                if preemption_agreed(last_neval):
-                    flush()
-                    self._preempt_snapshot(params, buffers, opt_state,
-                                           driver_state)
-                if self.end_when(driver_state):  # iteration/loss-based stops
-                    stop = True
-                    break
-                t_data = time.time()
+                            from bigdl_tpu.dataset.device_cache import \
+                                CachedSliceBatch
+                            keys = jnp.stack([rng.next_key() for _ in window])
+                            if (all(isinstance(b, CachedSliceBatch)
+                                    for b in window)
+                                    and len({id(b.source)
+                                             for b in window}) == 1):
+                                # gathers happen inside the fused program: ONE
+                                # dispatch per window
+                                src = window[0].source
+                                idx = jnp.stack([b.idx for b in window])
+                                used_fn = multi_step_cached
+                                params, buffers, opt_state, losses = \
+                                    multi_step_cached(params, buffers,
+                                                      opt_state, keys,
+                                                      src._x, src._y, idx)
+                            else:
+                                # host batches: one fused H2D + dispatch per
+                                # window
+                                xs = jnp.stack([jnp.asarray(b.data)
+                                                for b in window])
+                                ys = jnp.stack([jnp.asarray(b.labels)
+                                                for b in window])
+                                used_fn = multi_step
+                                params, buffers, opt_state, losses = multi_step(
+                                    params, buffers, opt_state, keys, xs, ys)
+                    # host time enqueueing the window (async; device compute
+                    # lands in the NEXT flush's sync wait)
+                    tm.dispatch.observe(time.time() - t0)
+                    flush()  # previous dispatch: fetch losses, log, summarize
+                    # snapshot the lr as its own small array NOW: opt_state's
+                    # buffers are donated to the next dispatch and deleted
+                    # (* 1 forces a fresh buffer if the schedule returns a state
+                    # array by identity). One snapshot per dispatch: intra-window
+                    # schedule steps are not observable host-side.
+                    lr_arr = None
+                    if (self.train_summary is not None
+                            and hasattr(self.optim_method, "current_rate")):
+                        lr_arr = self.optim_method.current_rate(opt_state)
+                        if not isinstance(lr_arr, (int, float)):
+                            lr_arr = lr_arr * 1
+                    iters = []
+                    for j, b in enumerate(window):
+                        epoch_records += b.size()
+                        iters.append({"neval": neval0 + j, "epoch": epoch,
+                                      "n_records": b.size(),
+                                      "epoch_records": epoch_records,
+                                      "size": self.dataset.size(),
+                                      "lr": lr_arr})
+                    pending = {"losses": losses, "iters": iters, "t0": t0,
+                               "fn": used_fn}
+                    # non-final window members were probed trigger-silent; the
+                    # final member gets the real per-iteration hook slot
+                    driver_state["neval"] = last_neval
+                    if ptrig is not None and ptrig(driver_state):
+                        self._summarize_parameters(params, last_neval)
+                    driver_state["neval"] = last_neval + 1
+                    epoch_batches += k
+                    # the data-iterator cursor any checkpoint written at this
+                    # boundary records in its RESUME marker
+                    self._loop_cursor = {"epoch": epoch,
+                                         "epoch_batches": epoch_batches,
+                                         "epoch_records": epoch_records}
+                    if uses_loss_any:
+                        # loss-sensitive stop/hook triggers must see THIS
+                        # iteration's loss, not the pipelined previous one
+                        flush()
+                    with span("train.hooks", neval=last_neval, epoch=epoch):
+                        self._hooks(params, buffers, opt_state, driver_state,
+                                    fwd, epoch_done=False, flush=flush)
+                    for inj in chaos_injectors:
+                        inj.on_step(last_neval)
+                    if handler is not None:
+                        fresh = handler.drain_notices()
+                        if fresh:
+                            instruments(get_registry()) \
+                                .resilience_preemptions_total.inc(fresh)
+                    if preemption_agreed(last_neval):
+                        flush()
+                        self._preempt_snapshot(params, buffers, opt_state,
+                                               driver_state)
+                    if self.end_when(driver_state):  # iteration/loss-based stops
+                        stop = True
+                        break
+                    t_data = time.time()
+            # from the drain of the epoch's last window to the first
+            # iteration of the next epoch (closed there, or after the loop)
+            epoch_end = span("train.epoch_end", epoch=epoch,
+                             neval=int(driver_state["neval"]))
+            epoch_end.__enter__()
             flush()  # drain the pipeline at epoch end (exact epoch log)
             self._close_data_iter()
             self.metrics.add("data wait time", data_wait)
             logger.info("[Epoch %d] Epoch finished. Wall clock time is %.1f ms (%d records)",
                         epoch, (time.time() - epoch_start) * 1e3, epoch_records)
             driver_state["epoch"] = epoch + 1
-            self._hooks(params, buffers, opt_state, driver_state, fwd,
-                        epoch_done=True)
+            with span("train.hooks", neval=int(driver_state["neval"]) - 1,
+                      epoch=epoch):
+                self._hooks(params, buffers, opt_state, driver_state, fwd,
+                            epoch_done=True)
 
+        if epoch_end is not None:
+            epoch_end.__exit__(None, None, None)
         if self._profiling_active:  # window outran training: close the trace
-            jax.profiler.stop_trace()
-            self._profiling_active = False
+            self._stop_profile()
         model.load_parameter_tree(self._finalize_params(params))
         model.load_buffer_tree(buffers)
         return model

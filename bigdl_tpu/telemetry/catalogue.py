@@ -142,9 +142,6 @@ METRIC_SPECS: List[MetricSpec] = [
     MetricSpec("bigdl_train_records_per_second", "gauge",
                "Most recent per-iteration throughput (records or tokens "
                "per second).", ("mode",)),
-    MetricSpec("bigdl_train_compiles_total", "counter",
-               "Trace+compile events charged to the loop (first dispatch "
-               "of a step program).", ("mode",)),
     MetricSpec("bigdl_train_validation_seconds", "histogram",
                "Wall-clock of in-training validation passes.",
                ("mode",), DEFAULT_LATENCY_BUCKETS),
@@ -296,9 +293,23 @@ SPAN_SPECS: List[Tuple[str, str]] = [
      "device cache on first use; the same wall time lands in "
      "bigdl_ingest_stall_seconds_total{stage=materialize} "
      "(dataset/device_cache.py)."),
+    ("train.iteration", "One pass of the training loop, a "
+     "jax.profiler.StepTraceAnnotation (step_num = neval of the window's "
+     "first iteration); its children below partition it. The pass that "
+     "finds the epoch's iterator exhausted has k=0 and no dispatch."),
+    ("train.data", "Fetching the window's batches from the data iterator "
+     "(for the device cache: its gather and index programs)."),
     ("train.dispatch", "Handing one training window to the device (H2D + "
      "enqueue)."),
-    ("train.sync", "Blocking fetch of the pipelined window losses."),
+    ("train.sync", "Blocking fetch of the pipelined window losses (neval "
+     "= the dispatch it waits for, one window back)."),
+    ("train.log", "Host work after the loss fetch: metrics, MFU gauge, "
+     "memory sample, the per-iteration log line, summaries."),
+    ("train.hooks", "Validation, checkpoint and summary triggers at an "
+     "iteration or epoch boundary."),
+    ("train.epoch_end", "From the drain of the epoch's last window to the "
+     "first train.iteration of the next epoch: epoch log, hooks, shuffle, "
+     "iterator rebuild."),
     ("train.validate", "In-training validation pass."),
     ("resilience.snapshot", "End-of-step preemption snapshot: model + "
      "state + RESUME marker (optim/optimizer.py)."),

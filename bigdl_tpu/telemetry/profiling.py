@@ -363,27 +363,31 @@ _mem_unsupported = False
 
 def sample_device_memory(registry: Optional[MetricsRegistry] = None) -> \
         Optional[int]:
-    """Sample device 0's memory stats into the
-    ``bigdl_device_memory_bytes`` / ``_peak_bytes`` gauges; returns the
-    peak, or None where the runtime has no allocator stats (CPU). Called
-    at step boundaries and slot admission — cheap (one PJRT call), and a
-    no-op forever after the first unsupported answer."""
+    """Sample every local device's memory stats and publish the FULLEST
+    (most ``bytes_in_use``; its peak beside it) into the
+    ``bigdl_device_memory_bytes`` / ``_peak_bytes`` gauges: on a mesh the
+    chip that runs out first is the one that matters, and device 0 is not
+    always it. Returns that peak, or None where the runtime has no
+    allocator stats (CPU). Called at step boundaries and slot admission —
+    one PJRT call a device, and a no-op forever after the first
+    unsupported answer."""
     global _mem_unsupported
     if _mem_unsupported:
         return None
-    stats = None
     try:
         import jax
-        stats = jax.local_devices()[0].memory_stats()
+        stats = [d.memory_stats() for d in jax.local_devices()]
     except Exception:       # noqa: BLE001 — absent backend == unsupported
-        stats = None
+        stats = []
+    stats = [s for s in stats if s]
     if not stats:
         _mem_unsupported = True
         return None
     from bigdl_tpu.telemetry.catalogue import instruments
     tm = instruments(registry if registry is not None else get_registry())
-    in_use = stats.get("bytes_in_use")
-    peak = stats.get("peak_bytes_in_use")
+    fullest = max(stats, key=lambda s: s.get("bytes_in_use") or 0)
+    in_use = fullest.get("bytes_in_use")
+    peak = fullest.get("peak_bytes_in_use")
     if in_use is not None:
         tm.device_memory_bytes.set(in_use)
     if peak is not None:

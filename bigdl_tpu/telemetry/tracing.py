@@ -15,6 +15,18 @@ at exit), then ``dump()``/``to_chrome_trace()``. The buffer is a
 ``deque(maxlen=capacity)``: a forgotten-enabled tracer costs bounded
 memory and keeps the newest events, matching how operators actually use
 a flight recorder.
+
+One timeline. While the tracer is enabled every ``span()`` is ALSO a
+``jax.profiler.TraceAnnotation(name, **args)`` around the same block (and
+``step_span()`` a ``StepTraceAnnotation``), so whenever a profiler session
+is running (``Optimizer.set_profiling``, ``jax.profiler.start_trace``) the
+program's spans sit in the xplane's ``/host:CPU`` plane, on the thread that
+made them, beside the runtime's ``DoEnqueueProgram`` / ``CompleteCallbacks``
+events and on their clock: open the profile in xprof or Perfetto and the
+spans are in it. With no session running an annotation is a flag check
+inside the runtime. ``jax`` is imported on the first ``enable()``, never at
+import. Ring-buffer timestamps are microseconds of ``time.perf_counter()``
+from ``origin()``.
 """
 
 from __future__ import annotations
@@ -30,7 +42,7 @@ from typing import List, Optional
 __all__ = ["span", "enable", "disable", "is_enabled", "clear", "events",
            "to_chrome_trace", "dump", "set_capacity", "capacity",
            "async_begin", "async_instant", "async_end", "complete_event",
-           "DEFAULT_CAPACITY"]
+           "step_span", "origin", "DEFAULT_CAPACITY"]
 
 DEFAULT_CAPACITY = 65536
 
@@ -40,6 +52,15 @@ _buffer: deque = deque(maxlen=DEFAULT_CAPACITY)
 # perf_counter origin for µs timestamps: monotonic, shared by every
 # thread, zeroed at import so traces start near t=0
 _T0 = time.perf_counter()
+# jax.profiler.TraceAnnotation / StepTraceAnnotation, bound by the first
+# enable(): the package stays importable without jax touching a backend
+_annotation = _step_annotation = None
+
+
+def origin() -> float:
+    """The ``time.perf_counter()`` value every event's ``ts`` counts from:
+    ``origin() + ev["ts"] / 1e6`` is the event's start on ``perf_counter``."""
+    return _T0
 
 
 def is_enabled() -> bool:
@@ -48,10 +69,14 @@ def is_enabled() -> bool:
 
 def enable(capacity: Optional[int] = None) -> None:
     """Turn the tracer on (optionally resizing the ring buffer; existing
-    events carry over, newest-first retention)."""
-    global _enabled
+    events carry over, newest-first retention). From here on every span
+    is mirrored into the profiler's trace as a ``TraceAnnotation``."""
+    global _enabled, _annotation, _step_annotation
     if capacity is not None:
         set_capacity(capacity)
+    if _annotation is None:
+        from jax.profiler import StepTraceAnnotation, TraceAnnotation
+        _annotation, _step_annotation = TraceAnnotation, StepTraceAnnotation
     _enabled = True
 
 
@@ -102,23 +127,30 @@ _NOOP = _NoopSpan()
 
 
 class _Span:
-    __slots__ = ("_name", "_cat", "_args", "_t0")
+    __slots__ = ("_name", "_cat", "_args", "_t0", "_ann")
 
-    def __init__(self, name: str, cat: str, args: dict):
+    def __init__(self, name: str, cat: str, args: dict, ann):
         self._name = name
         self._cat = cat
         self._args = args
+        self._ann = ann     # the profiler annotation over the same block
 
     def __enter__(self):
+        self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def annotate(self, **kwargs) -> None:
-        """Attach key/values mid-span (they land in the event's args)."""
+        """Attach key/values mid-span (they land in the event's args and
+        in the annotation's stats). After the block has closed, keys still
+        reach the ring-buffer event if it already had args (the dict is
+        shared), never the annotation."""
         self._args.update(kwargs)
+        self._ann.set_metadata(**kwargs)
 
     def __exit__(self, exc_type, exc, tb):
         t1 = time.perf_counter()
+        self._ann.__exit__(exc_type, exc, tb)
         ev = {"name": self._name, "cat": self._cat, "ph": "X",
               "ts": (self._t0 - _T0) * 1e6, "dur": (t1 - self._t0) * 1e6,
               "pid": os.getpid(), "tid": threading.get_ident()}
@@ -140,7 +172,18 @@ def span(name: str, cat: str = "bigdl", **args):
     thread id, and any keyword args."""
     if not _enabled:
         return _NOOP
-    return _Span(name, cat, dict(args))
+    return _Span(name, cat, args, _annotation(name, **args))
+
+
+def step_span(name: str, step_num: int, cat: str = "bigdl", **args):
+    """``span()`` for one step of a loop: the annotation is a
+    ``jax.profiler.StepTraceAnnotation(name, step_num=step_num)``, which
+    xprof uses to group device operations by step; the ring-buffer event
+    carries ``step_num`` among its args."""
+    if not _enabled:
+        return _NOOP
+    args["step_num"] = step_num
+    return _Span(name, cat, args, _step_annotation(name, **args))
 
 
 def _async_event(ph: str, name: str, id: int, cat: str, args: dict) -> None:
@@ -181,7 +224,9 @@ def complete_event(name: str, t0: float, t1: float, cat: str = "bigdl",
     """Record an X event for an ALREADY-elapsed [t0, t1] window
     (``time.perf_counter()`` values) — e.g. a request's queue wait, whose
     start happened on another thread before anyone knew how long it would
-    be. ``span()`` covers the with-block case; this covers retrodiction."""
+    be. ``span()`` covers the with-block case; this covers retrodiction.
+    Ring buffer only: a profiler annotation cannot be opened in the past,
+    so these events are NOT in the profiler's trace."""
     if not _enabled:
         return
     ev = {"name": name, "cat": cat, "ph": "X", "ts": (t0 - _T0) * 1e6,

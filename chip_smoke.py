@@ -17,7 +17,12 @@ out by the repo's own means:
    every projection and the V=32000 head through the int8 Pallas kernel;
 4. ``kernels``: flash attention and the int8 matmul against their XLA
    formulations on the same shapes, within the repo's test tolerances;
-5. ``resnet50_mesh``: phase 1 through ``DistriOptimizer`` over every visible
+5. ``timeline``: a 5-step ``set_profiling`` profile of phase 2's step, read
+   back with the benchmark's own readers: the loop's ``train.*`` spans as
+   annotations beside the runtime's enqueues, the three ``flash_*`` kernel
+   names on the Mosaic calls, the ``lm_head_ce`` scope on both ``while``
+   loops — so a jax upgrade that renames any of them fails here, cheaply;
+6. ``resnet50_mesh``: phase 1 through ``DistriOptimizer`` over every visible
    device, 256 per chip — when there is more than one device.
 
 Every phase asserts: finite losses, the last lower than the first,
@@ -29,9 +34,12 @@ failed check raises, so the run cannot end 0 with a phase failed. The last
 line of stdout is one JSON object naming the device as jax reports it.
 """
 
+import glob
 import json
 import logging
+import os
 import re
+import shutil
 import sys
 import time
 
@@ -138,10 +146,11 @@ def token_samples(n, seq, seed, subset=512):
 
 
 def train(model, criterion, samples, batch, iters, lr, cast, distributed,
-          clip=None):
+          clip=None, profile=None):
     """``apps/perf.py:main``'s wiring: device-resident cache -> Optimizer
     facade -> bf16 policy -> optimize(), then the checks every trainer
-    phase shares. Returns (optimizer, losses, rates)."""
+    phase shares. ``profile`` is ``set_profiling``'s arguments. Returns
+    (optimizer, losses, rates)."""
     import jax
     from bigdl_tpu.dataset import DeviceCachedDataSet
     from bigdl_tpu.dataset.base import DataSet
@@ -158,6 +167,8 @@ def train(model, criterion, samples, batch, iters, lr, cast, distributed,
     if clip:
         opt.set_gradient_clipping_by_l2_norm(clip)
     opt.set_end_when(Trigger.max_iteration(iters))
+    if profile:
+        opt.set_profiling(*profile)
     before = jax.tree_util.tree_map(np.asarray, model.parameter_tree())
 
     log, tap = logging.getLogger("bigdl_tpu.optim"), _Lines()
@@ -285,6 +296,64 @@ def phase_lm(report):
         say(f"  Mosaic custom calls in the compiled LM train step: {fwd} "
             f"forward, {bwd} backward")
     return model
+
+
+LOOP_SPANS = ("train.iteration", "train.data", "train.dispatch",
+              "train.sync", "train.log", "train.hooks")
+FLASH_KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
+PROFILE_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                           ".bench_scratch", "smoke_profile")
+
+
+def phase_timeline(report):
+    """The names the benchmark's readers stand on, in a real profile."""
+    from benchmark import reduce_xplane as rx
+    from benchmark import timeline
+    from bigdl_tpu import nn
+    from bigdl_tpu.apps.perf import _build_model
+
+    with Phase("timeline", report):
+        model, (seq,), *_ = _build_model("transformer_134m")
+        shutil.rmtree(PROFILE_DIR, ignore_errors=True)
+        opt, _, _ = train(model, nn.FusedLMHeadCriterion(),
+                          token_samples(16, seq, seed=2), 8, LM_ITERS,
+                          lr=0.1, cast=None, distributed=False, clip=1.0,
+                          profile=(PROFILE_DIR, 3, 5))
+        found = sorted(glob.glob(os.path.join(
+            PROFILE_DIR, "plugins", "profile", "*", "*.xplane.pb")))
+        check(found, f"set_profiling left no xplane under {PROFILE_DIR}")
+        host, trace = timeline.load_host(found[-1]), rx.load(found[-1])
+        for name in LOOP_SPANS:
+            check(host.named(name), f"no {name!r} annotation in the "
+                  "profile's host plane")
+        steps = sorted(s[3].get("step_num") for s in
+                       host.named("train.iteration") if s[3].get("k"))
+        check(steps == [3, 4, 5, 6, 7] and all(
+            s[3].get("_r") == 1 for s in host.named("train.iteration")),
+            f"train.iteration step markers {steps}, not 3..7")
+        dev = trace.devices[0]
+        lo, hi = rx.slice_bounds(trace)
+        runs = rx.program_runs(dev, lo, hi)
+        check(len(runs) >= 4 and all(
+            host.enqueue_of(r[3], 0) is not None for r in runs),
+            f"{len(runs)} runs of the step program, not every one with its "
+            "DoEnqueueProgram (by run_id)")
+        mosaic = {rx.family(o) for o in dev.ops if o.is_mosaic}
+        for kernel in FLASH_KERNELS:
+            check(any(kernel in fam for fam in mosaic), f"no Mosaic call "
+                  f"named after {kernel!r} on the device: {sorted(mosaic)}")
+        scoped = timeline.scope_instructions(step_text(opt), "lm_head_ce")
+        whiles = {o.name for o in dev.ops
+                  if o.opcode == "while" and o.name in scoped}
+        check(len(whiles) == 2, f"{sorted(whiles)} of the step's while "
+              "loops run under the scope 'lm_head_ce', not 2")
+        sec, n = timeline.scope_seconds(trace, scoped, lo, hi)
+        say(f"  profile of steps {steps}: {len(host.spans)} train.* "
+            f"annotations, {len(host.enqueues)} enqueues; Mosaic calls "
+            f"{sorted(mosaic)}; lm_head_ce loops {sorted(whiles)}, "
+            f"{1e3 * sec / n:.2f} ms a step of "
+            f"{1e3 * (runs[0][2] - runs[0][1]):.2f}; device clock "
+            f"{1e3 * rx.device_clock_lag(trace):.2f} ms behind the host's")
 
 
 def phase_decode(report, lm):
@@ -420,6 +489,7 @@ def main():
     lm = phase_lm(report)
     phase_decode(report, lm)
     phase_kernels(report)
+    phase_timeline(report)
     if n_dev > 1:
         phase_resnet(report, distributed=True)
     else:
