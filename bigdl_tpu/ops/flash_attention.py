@@ -15,8 +15,32 @@ equivalent "hand kernel" for its hottest new op). Three kernels:
   per key tile (no O(S^2) materialisation) and accumulates
   dq += (p * (dO v^T - delta)) k * scale.
 - backward dK/dV: grid over key tiles; streams query tiles, accumulating
-  dv += p^T dO and dk += (p * (dO v^T - delta))^T q * scale. Causal runs
-  start at the diagonal query tile.
+  dv += p^T dO and dk += (p * (dO v^T - delta))^T q * scale on TRANSPOSED
+  (key, query) tiles. Causal runs start at the diagonal query tile.
+
+What the MXU is fed: every matmul takes its operands in the dtype the
+caller's arrays have and accumulates in float32. The float32 intermediates
+that feed a second matmul (p, ds) are cast to the operand dtype just before
+it, as ``attention_core.dot_product_attention`` casts its weights; the
+softmax statistics, the LSE, delta and the accumulators stay float32.
+float32 callers get float32 operands. (On the v5e Mosaic rounds a float32
+operand to bf16 inside the MXU, one pass either way: bf16 and up-cast tiles
+measure the same and give the same bits; the operand dtype saves the casts,
+not MXU passes. PERF.md section 6, PR 24.) No operand is transposed in
+VMEM: ``q k^T`` and ``dO v^T`` contract the head dim of both operands
+(``dot_general``, NT), and dK/dV works on (key, query) tiles so that its
+other two products are plain.
+
+Where the time went, and what the loop bodies do about it: a kernel's tile
+loop runs within 5-20% of what its matmuls need with head 64 (half of the
+128-wide MXU); the rest was around it. With square tiles on an unpadded
+causal sequence the one tile the diagonal crosses is done as two
+half-height strips, each against only the keys it can see (3/4 of its
+products), and the tiles below it run unmasked in the one loop there is.
+Every other call keeps one loop whose tiles are all masked (causal, or a
+padded key tile) or all plain (``_masked``). The forward's LSE leaves
+through ``_to_lanes``, not Mosaic's sublane-to-lane relayout. Measured
+times and roofline shares: PERF.md section 5 (ledger, PR 24).
 
 The LSE output is a first-class differentiable output: its cotangent folds
 into the delta term (d lse_i / d logits_ij = p_ij, so delta_i becomes
@@ -32,6 +56,7 @@ On CPU the kernels run in Pallas interpret mode (tests); dispatch via
 from __future__ import annotations
 
 import functools
+import math
 import os
 from typing import Optional, Tuple
 
@@ -41,12 +66,79 @@ from jax import lax
 from jax.experimental import pallas as pl
 
 _NEG = float(jnp.finfo(jnp.float32).min)
+_NT = (((1,), (1,)), ((), ()))        # a (M, K) x b (N, K) -> (M, N): b as it lies
+
+
+def _dot(a, b):
+    return jnp.dot(a, b, preferred_element_type=jnp.float32)
+
+
+def _dot_nt(a, b):
+    return lax.dot_general(a, b, _NT, preferred_element_type=jnp.float32)
+
+
+def _exact_scale(scale) -> bool:
+    """A power of two scales an operand without rounding in any float
+    dtype (1/sqrt(64) = 0.125: every head-64 model); any other scale goes
+    on the float32 logits."""
+    return scale > 0 and math.frexp(scale)[0] == 0.5
+
+
+def _below(rows: int, cols: int, offset: int):
+    """(rows, cols) mask, true where column <= row + offset."""
+    return (lax.broadcasted_iota(jnp.int32, (rows, cols), 1)
+            <= lax.broadcasted_iota(jnp.int32, (rows, cols), 0) + offset)
+
+
+def _visible(shape, key_axis: int, k0, q0, sk: int, causal: bool):
+    """Mask of a tile whose `key_axis` runs over the keys from position k0
+    and whose other axis over the queries from q0: true where the key is
+    real (not padding) and, under a causal mask, not after the query."""
+    k_pos = k0 + lax.broadcasted_iota(jnp.int32, shape, key_axis)
+    valid = k_pos < sk
+    if causal:
+        valid = valid & (k_pos <= q0 + lax.broadcasted_iota(
+            jnp.int32, shape, 1 - key_axis))
+    return valid
+
+
+def _halved_diagonal(causal, sq, sk, block_q, block_k) -> bool:
+    """Whether the one tile the causal diagonal crosses is done as two
+    half-height strips, each against only the keys it can see (3/4 of the
+    tile's products). Needs square tiles on the diagonal and halves that
+    are whole lane groups; every other shape masks whole tiles."""
+    return (causal and sq == sk and block_q == block_k
+            and sk % block_k == 0 and block_k % 256 == 0)
+
+
+def _masked(causal, sk, block_k) -> bool:
+    """Whether a kernel off the halved-diagonal path masks its tiles: all of
+    them or none, decided from the call, so that there is one tile loop. A
+    second, unmasked loop for the tiles below the diagonal cost more in
+    carry shuffling between the loops than the masks it saved (PR 24)."""
+    return causal or sk % block_k != 0
+
+
+def _to_lanes(col):
+    """(N, 1) column -> (1, N) row. Mosaic's own sublane-to-lane relayout
+    is 576 ``vperm`` for 512 values; selecting the diagonal of each
+    (128, 128) block of the broadcast column and summing over its rows took
+    the forward's epilogue from 1,282 to 739 bundles a program (PR 24)."""
+    n = col.shape[0]
+    c = min(n, 128)
+    if n % c:
+        return col[:, 0][None, :]
+    eye = (lax.broadcasted_iota(jnp.int32, (c, c), 0)
+           == lax.broadcasted_iota(jnp.int32, (c, c), 1))
+    return jnp.concatenate(
+        [jnp.sum(jnp.where(eye, col[i:i + c], 0.0), axis=0, keepdims=True)
+         for i in range(0, n, c)], axis=1)
 
 
 # ------------------------------------------------------------------ forward
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, l_ref, *, block_k: int, sk: int,
-                causal: bool, scale: float, block_q: int):
+                causal: bool, scale: float, block_q: int, diagonal: bool):
     # q_ref: (1, BQ, D); k_ref/v_ref: (1, Sk_pad, D); o_ref: (1, BQ, D);
     # l_ref: (1, 1, BQ) row logsumexp of the scaled, masked logits. The
     # LSE rides a (BH, 1, S) array so its block's penultimate dim equals
@@ -54,59 +146,73 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, l_ref, *, block_k: int, sk: int,
     # (BH, S) array (last-two-dims divisibility rule; interpret mode does
     # not enforce it, which is how this shipped unverified in round 2).
     j = pl.program_id(1)
-    q = q_ref[0].astype(jnp.float32) * scale                # (BQ, D)
+    q = q_ref[0]                                            # (BQ, D)
     bq, d = q.shape
     nkb = k_ref.shape[1] // block_k
+    prescaled = _exact_scale(scale)
+    if prescaled:
+        q = q * jnp.asarray(scale, q.dtype)
 
-    q_pos = j * block_q + lax.broadcasted_iota(jnp.int32, (bq, block_k), 0)
-
-    def body(kb, carry):
-        acc, rsum, rmax = carry
-        kblk = k_ref[0, pl.ds(kb * block_k, block_k), :].astype(jnp.float32)
-        vblk = v_ref[0, pl.ds(kb * block_k, block_k), :].astype(jnp.float32)
-        logits = jnp.dot(q, kblk.T, preferred_element_type=jnp.float32)
-        k_pos = kb * block_k + lax.broadcasted_iota(
-            jnp.int32, (bq, block_k), 1)
-        valid = k_pos < sk
-        if causal:
-            valid = valid & (k_pos <= q_pos)
-        logits = jnp.where(valid, logits, _NEG)
-        blk_max = jnp.max(logits, axis=-1)
-        new_max = jnp.maximum(rmax, blk_max)
-        p = jnp.exp(logits - new_max[:, None])
-        dead = new_max <= _NEG / 2                      # all-masked row so far
-        p = jnp.where(dead[:, None], 0.0, p)
-        corr = jnp.where(dead, 1.0, jnp.exp(rmax - new_max))
-        new_sum = rsum * corr + jnp.sum(p, axis=-1)
-        pv = jnp.dot(p, vblk, preferred_element_type=jnp.float32)
-        new_acc = acc * corr[:, None] + pv
+    def update(carry, rows, k0, width, valid):
+        # One online-softmax step of the query rows `rows` against the keys
+        # [k0, k0 + width). Key 0 is visible to every row and is in the
+        # first tile a row meets, so no row is all-masked when its running
+        # maximum is first used.
+        acc, rsum, rmax = (x[rows] for x in carry)
+        kblk = k_ref[0, pl.ds(k0, width), :]
+        vblk = v_ref[0, pl.ds(k0, width), :]
+        logits = _dot_nt(q[rows], kblk)                     # f32
+        if not prescaled:
+            logits = logits * scale
+        if valid is not None:
+            logits = jnp.where(valid, logits, _NEG)
+        new_max = jnp.maximum(rmax, jnp.max(logits, axis=-1, keepdims=True))
+        p = jnp.exp(logits - new_max)
+        corr = jnp.exp(rmax - new_max)
+        new_sum = rsum * corr + jnp.sum(p, axis=-1, keepdims=True)
+        new_acc = acc * corr + _dot(p.astype(vblk.dtype), vblk)
         return new_acc, new_sum, new_max
 
-    if causal:
-        # Key tiles strictly above the diagonal contribute nothing: the last
-        # key position this query tile can see is its own last row.
-        last_q = j * block_q + bq - 1
-        nkb_eff = lax.min(nkb, lax.div(last_q, block_k) + 1)
+    def tile(kb, carry, masked):
+        valid = _visible((bq, block_k), 1, kb * block_k, j * block_q, sk,
+                         causal) if masked else None
+        return update(carry, slice(None), kb * block_k, block_k, valid)
+
+    carry = (jnp.zeros((bq, d), jnp.float32), jnp.zeros((bq, 1), jnp.float32),
+             jnp.full((bq, 1), _NEG, jnp.float32))
+    if diagonal:
+        # key tiles [0, j) lie wholly below the diagonal; tile j is on it
+        carry = lax.fori_loop(0, j, functools.partial(tile, masked=False),
+                              carry)
+        h = bq // 2
+        upper = update(carry, slice(0, h), j * block_k, h, _below(h, h, 0))
+        lower = update(carry, slice(h, bq), j * block_k, block_k,
+                       _below(h, block_k, h))
+        acc, rsum, rmax = (jnp.concatenate(x) for x in zip(upper, lower))
     else:
-        nkb_eff = nkb
-    acc0 = jnp.zeros((bq, d), jnp.float32)
-    sum0 = jnp.zeros((bq,), jnp.float32)
-    max0 = jnp.full((bq,), _NEG, jnp.float32)
-    acc, rsum, rmax = lax.fori_loop(0, nkb_eff, body, (acc0, sum0, max0))
+        # One loop, masked or not as a whole (_masked). Key tiles strictly
+        # above the diagonal contribute nothing: the last key this query
+        # tile can see is its own last row.
+        if causal:
+            nkb = lax.min(nkb, lax.div(j * block_q + bq - 1, block_k) + 1)
+        acc, rsum, rmax = lax.fori_loop(
+            0, nkb, functools.partial(
+                tile, masked=_masked(causal, sk, block_k)), carry)
     dead = rmax <= _NEG / 2
     rsum_safe = jnp.maximum(rsum, 1e-37)
-    o_ref[0] = (acc / rsum_safe[:, None]).astype(o_ref.dtype)
+    o_ref[0] = (acc / rsum_safe).astype(o_ref.dtype)
     # Dead rows keep the finite _NEG sentinel (NOT -inf): downstream
     # logaddexp-style combines stay NaN-free on all-masked rows.
-    l_ref[0, 0] = jnp.where(dead, _NEG, rmax + jnp.log(rsum_safe))
+    l_ref[0] = _to_lanes(jnp.where(dead, _NEG, rmax + jnp.log(rsum_safe)))
 
 
 def _flash_fwd_lse(q, k, v, causal, scale, block_q, block_k, interpret):
     """Returns (o (B,Sq,N,D), lse (B,N,Sq) f32)."""
     b, sq, n, d = q.shape
     sk = k.shape[1]
-    block_q = min(block_q, sq)
-    block_k = min(block_k, sk)
+    default = _fwd_block(sq, sk, d, q.dtype.itemsize)
+    block_q = min(_block(block_q, "Q", default), sq)
+    block_k = min(_block(block_k, "K", default), sk)
     # BSND -> (B*N, S, D): one grid row per (batch, head).
     qt = q.transpose(0, 2, 1, 3).reshape(b * n, sq, d)
     kt = k.transpose(0, 2, 1, 3).reshape(b * n, sk, d)
@@ -123,7 +229,9 @@ def _flash_fwd_lse(q, k, v, causal, scale, block_q, block_k, interpret):
     grid = (b * n, sq_p // block_q)
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, block_k=block_k, sk=sk,
-                          causal=causal, scale=scale, block_q=block_q),
+                          causal=causal, scale=scale, block_q=block_q,
+                          diagonal=_halved_diagonal(causal, sq, sk, block_q,
+                                                    block_k)),
         out_shape=(jax.ShapeDtypeStruct((b * n, sq_p, d), q.dtype),
                    jax.ShapeDtypeStruct((b * n, 1, sq_p), jnp.float32)),
         grid=grid,
@@ -146,91 +254,124 @@ def _flash_fwd_lse(q, k, v, causal, scale, block_q, block_k, interpret):
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, l_ref, d_ref, dq_ref, *,
                    block_k: int, sk: int, causal: bool, scale: float,
-                   block_q: int):
+                   block_q: int, diagonal: bool):
     # Per query tile: stream key tiles, recompute p from the saved LSE.
     j = pl.program_id(1)
-    q = q_ref[0].astype(jnp.float32)                        # (BQ, D)
-    do = do_ref[0].astype(jnp.float32)                      # (BQ, D)
-    lse = l_ref[0, 0]                                       # (BQ,)
-    delta = d_ref[0, 0]                                     # (BQ,)
+    q = q_ref[0]                                            # (BQ, D)
+    do = do_ref[0]                                          # (BQ, D)
+    # Kept as the lane-dense (BQ,) rows they are stored as and turned into
+    # columns where a tile uses them: hoisted (BQ, 1) columns are 128 vregs
+    # that live across the whole loop, and cost 15% of the kernel (PR 24).
+    lse = l_ref[0, 0]                                       # (BQ,) f32
+    delta = d_ref[0, 0]                                     # (BQ,) f32
     bq, d = q.shape
     nkb = k_ref.shape[1] // block_k
-    q_pos = j * block_q + lax.broadcasted_iota(jnp.int32, (bq, block_k), 0)
+    prescaled = _exact_scale(scale)
+    if prescaled:
+        q = q * jnp.asarray(scale, q.dtype)
 
-    def body(kb, dq):
-        kblk = k_ref[0, pl.ds(kb * block_k, block_k), :].astype(jnp.float32)
-        vblk = v_ref[0, pl.ds(kb * block_k, block_k), :].astype(jnp.float32)
-        logits = jnp.dot(q, kblk.T,
-                         preferred_element_type=jnp.float32) * scale
-        k_pos = kb * block_k + lax.broadcasted_iota(
-            jnp.int32, (bq, block_k), 1)
-        valid = k_pos < sk
-        if causal:
-            valid = valid & (k_pos <= q_pos)
-        # guard the exponent BEFORE exp (dead rows carry the _NEG sentinel;
-        # the raw exponent would overflow), then mask
-        expo = jnp.where(valid, logits - lse[:, None], 0.0)
-        p = jnp.where(valid, jnp.exp(expo), 0.0)
-        dp = jnp.dot(do, vblk.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None]) * scale
-        return dq + jnp.dot(ds, kblk, preferred_element_type=jnp.float32)
+    def part(rows, k0, width, valid):
+        # sum(ds k) of the query rows `rows` over the keys [k0, k0 + width)
+        kblk = k_ref[0, pl.ds(k0, width), :]
+        vblk = v_ref[0, pl.ds(k0, width), :]
+        logits = _dot_nt(q[rows], kblk)                     # f32
+        if not prescaled:
+            logits = logits * scale
+        if valid is None:
+            p = jnp.exp(logits - lse[rows][:, None])
+        else:
+            # guard the exponent BEFORE exp (dead rows carry the _NEG
+            # sentinel; the raw exponent would overflow), then mask
+            expo = jnp.where(valid, logits - lse[rows][:, None], 0.0)
+            p = jnp.where(valid, jnp.exp(expo), 0.0)
+        ds = p * (_dot_nt(do[rows], vblk) - delta[rows][:, None])
+        return _dot(ds.astype(kblk.dtype), kblk)
 
-    if causal:
-        last_q = j * block_q + bq - 1
-        nkb_eff = lax.min(nkb, lax.div(last_q, block_k) + 1)
+    def tile(kb, dq, masked):
+        valid = _visible((bq, block_k), 1, kb * block_k, j * block_q, sk,
+                         causal) if masked else None
+        return dq + part(slice(None), kb * block_k, block_k, valid)
+
+    dq = jnp.zeros((bq, d), jnp.float32)
+    if diagonal:
+        dq = lax.fori_loop(0, j, functools.partial(tile, masked=False), dq)
+        h = bq // 2
+        dq = dq + jnp.concatenate([
+            part(slice(0, h), j * block_k, h, _below(h, h, 0)),
+            part(slice(h, bq), j * block_k, block_k, _below(h, block_k, h))])
     else:
-        nkb_eff = nkb
-    dq = lax.fori_loop(0, nkb_eff, body, jnp.zeros((bq, d), jnp.float32))
-    dq_ref[0] = dq.astype(dq_ref.dtype)
+        if causal:
+            nkb = lax.min(nkb, lax.div(j * block_q + bq - 1, block_k) + 1)
+        dq = lax.fori_loop(0, nkb, functools.partial(
+            tile, masked=_masked(causal, sk, block_k)), dq)
+    # dq = scale * sum(ds k): once on the float32 accumulator, not a tile
+    dq_ref[0] = (dq * scale).astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, l_ref, d_ref,
-                    dk_ref, dv_ref, *, block_q: int, sk: int, sq: int,
-                    causal: bool, scale: float, block_k: int):
-    # Per key tile: stream query tiles. Padded query rows are masked out
-    # explicitly (q_pos < sq): they carry the _NEG LSE sentinel, and
-    # exp(logits - _NEG) = inf would otherwise poison dk/dv with inf*0=NaN
-    # whenever seq is not a block_q multiple.
+                    dk_ref, dv_ref, *, block_q: int, sk: int,
+                    causal: bool, scale: float, block_k: int,
+                    diagonal: bool):
+    # Per key tile: stream query tiles, everything TRANSPOSED: the logits
+    # tile is (BK, BQ), so the four matmuls take their operands as they lie
+    # (k q^T and v dO^T contract the shared head dim, p^T dO and ds^T q are
+    # plain) and the LSE and delta rows broadcast from the lanes they are
+    # stored in. Padded query rows need no mask: _flash_bwd pads q, dO, the
+    # LSE and delta with zeros, so there p = exp(0 - 0) = 1 meets dO = 0 in
+    # dV and ds = 1 * (0 - 0) in dK.
     jkb = pl.program_id(1)
-    k = k_ref[0].astype(jnp.float32)                        # (BK, D)
-    v = v_ref[0].astype(jnp.float32)
+    k = k_ref[0]                                            # (BK, D)
+    v = v_ref[0]
     bk, d = k.shape
     nqb = q_ref.shape[1] // block_q
-    k_pos = jkb * block_k + lax.broadcasted_iota(jnp.int32, (block_q, bk), 1)
+    prescaled = _exact_scale(scale)
+    ks = k * jnp.asarray(scale, k.dtype) if prescaled else k
 
-    def body(qb, carry):
-        dk, dv = carry
-        qblk = q_ref[0, pl.ds(qb * block_q, block_q), :].astype(jnp.float32)
-        doblk = do_ref[0, pl.ds(qb * block_q, block_q), :].astype(jnp.float32)
-        lblk = l_ref[0, 0, pl.ds(qb * block_q, block_q)]    # (BQ,)
-        dblk = d_ref[0, 0, pl.ds(qb * block_q, block_q)]    # (BQ,)
-        logits = jnp.dot(qblk, k.T,
-                         preferred_element_type=jnp.float32) * scale
-        q_pos = qb * block_q + lax.broadcasted_iota(
-            jnp.int32, (block_q, bk), 0)
-        valid = (k_pos < sk) & (q_pos < sq)
-        if causal:
-            valid = valid & (k_pos <= q_pos)
-        # guard the exponent BEFORE exp: a padded/dead row's _NEG sentinel
-        # would overflow to inf and inf*0 -> NaN survives jnp.where
-        expo = jnp.where(valid, logits - lblk[:, None], 0.0)
-        p = jnp.where(valid, jnp.exp(expo), 0.0)            # (BQ, BK)
-        dv = dv + jnp.dot(p.T, doblk, preferred_element_type=jnp.float32)
-        dp = jnp.dot(doblk, v.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - dblk[:, None]) * scale
-        dk = dk + jnp.dot(ds.T, qblk, preferred_element_type=jnp.float32)
-        return dk, dv
+    def part(keys, q0, width, valid):
+        # (sum(ds^T q), sum(p^T dO)) of the key rows `keys` over the
+        # queries [q0, q0 + width)
+        qblk = q_ref[0, pl.ds(q0, width), :]
+        doblk = do_ref[0, pl.ds(q0, width), :]
+        lrow = l_ref[0, :, pl.ds(q0, width)]                # (1, width) f32
+        drow = d_ref[0, :, pl.ds(q0, width)]
+        logits = _dot_nt(ks[keys], qblk)                    # f32
+        if not prescaled:
+            logits = logits * scale
+        if valid is None:
+            p = jnp.exp(logits - lrow)
+        else:
+            # guard the exponent BEFORE exp: a dead row's _NEG sentinel
+            # would overflow to inf and inf*0 -> NaN survives jnp.where
+            expo = jnp.where(valid, logits - lrow, 0.0)
+            p = jnp.where(valid, jnp.exp(expo), 0.0)
+        ds = p * (_dot_nt(v[keys], doblk) - drow)
+        return (_dot(ds.astype(qblk.dtype), qblk),
+                _dot(p.astype(doblk.dtype), doblk))
 
-    if causal:
-        # Query tiles strictly before this key tile's first row see none of
-        # its keys.
-        first_qb = lax.div(jkb * block_k, block_q)
+    def tile(qb, carry, masked):
+        valid = _visible((bk, block_q), 0, jkb * block_k, qb * block_q, sk,
+                         causal) if masked else None
+        dk, dv = part(slice(None), qb * block_q, block_q, valid)
+        return carry[0] + dk, carry[1] + dv
+
+    zeros = (jnp.zeros((bk, d), jnp.float32), jnp.zeros((bk, d), jnp.float32))
+    if diagonal:
+        # query tile jkb is on the diagonal: its first half sees only the
+        # first half of the keys; query tiles after it see every key
+        h = bk // 2
+        upper = part(slice(0, h), jkb * block_q, block_q,
+                     ~_below(h, block_q, -1))
+        lower = part(slice(h, bk), jkb * block_q + h, h, ~_below(h, h, -1))
+        carry = tuple(jnp.concatenate(x) for x in zip(upper, lower))
+        dk, dv = lax.fori_loop(jkb + 1, nqb,
+                               functools.partial(tile, masked=False), carry)
     else:
-        first_qb = 0
-    dk0 = jnp.zeros((bk, d), jnp.float32)
-    dv0 = jnp.zeros((bk, d), jnp.float32)
-    dk, dv = lax.fori_loop(first_qb, nqb, body, (dk0, dv0))
-    dk_ref[0] = dk.astype(dk_ref.dtype)
+        # Causal: query tiles strictly before this key tile's first row see
+        # none of its keys.
+        first_qb = lax.div(jkb * block_k, block_q) if causal else 0
+        dk, dv = lax.fori_loop(first_qb, nqb, functools.partial(
+            tile, masked=_masked(causal, sk, block_k)), zeros)
+    dk_ref[0] = (dk * scale).astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
 
@@ -238,8 +379,8 @@ def _flash_bwd(q, k, v, o, lse, g_o, g_l, causal, scale, block_q, block_k,
                interpret):
     b, sq, n, d = q.shape
     sk = k.shape[1]
-    block_q = min(block_q, sq)
-    block_k = min(block_k, sk)
+    block_q = min(_block(block_q, "Q", _BLOCK), sq)
+    block_k = min(_block(block_k, "K", _BLOCK), sk)
     qt = q.transpose(0, 2, 1, 3).reshape(b * n, sq, d)
     kt = k.transpose(0, 2, 1, 3).reshape(b * n, sk, d)
     vt = v.transpose(0, 2, 1, 3).reshape(b * n, sk, d)
@@ -260,18 +401,20 @@ def _flash_bwd(q, k, v, o, lse, g_o, g_l, causal, scale, block_q, block_k,
     if pad_q:
         qt = jnp.pad(qt, ((0, 0), (0, pad_q), (0, 0)))
         dot = jnp.pad(dot, ((0, 0), (0, pad_q), (0, 0)))
-        # pad value is irrelevant (padded query rows are masked by
-        # q_pos < sq in both kernels); 0 keeps the exponent finite
+        # zeros, so that padded query rows add nothing to dK and dV
+        # (p = exp(0 - 0) = 1 times dO = 0 and ds = 0): see _bwd_dkv_kernel
         lt = jnp.pad(lt, ((0, 0), (0, 0), (0, pad_q)))
         delta = jnp.pad(delta, ((0, 0), (0, 0), (0, pad_q)))
     if pad_k:
         kt = jnp.pad(kt, ((0, 0), (0, pad_k), (0, 0)))
         vt = jnp.pad(vt, ((0, 0), (0, pad_k), (0, 0)))
     sq_p, sk_p = qt.shape[1], kt.shape[1]
+    diagonal = _halved_diagonal(causal, sq, sk, block_q, block_k)
 
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, block_k=block_k, sk=sk,
-                          causal=causal, scale=scale, block_q=block_q),
+                          causal=causal, scale=scale, block_q=block_q,
+                          diagonal=diagonal),
         out_shape=jax.ShapeDtypeStruct((b * n, sq_p, d), q.dtype),
         grid=(b * n, sq_p // block_q),
         in_specs=[
@@ -288,8 +431,9 @@ def _flash_bwd(q, k, v, o, lse, g_o, g_l, causal, scale, block_q, block_k,
     )(qt, kt, vt, dot, lt, delta)
 
     dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, block_q=block_q, sk=sk, sq=sq,
-                          causal=causal, scale=scale, block_k=block_k),
+        functools.partial(_bwd_dkv_kernel, block_q=block_q, sk=sk,
+                          causal=causal, scale=scale, block_k=block_k,
+                          diagonal=diagonal),
         out_shape=(jax.ShapeDtypeStruct((b * n, sk_p, d), k.dtype),
                    jax.ShapeDtypeStruct((b * n, sk_p, d), v.dtype)),
         grid=(b * n, sk_p // block_k),
@@ -334,7 +478,8 @@ def _flash_lse_vjp_bwd(causal, scale, block_q, block_k, interpret, res, g):
         # plumbing — valid only when nothing consumes lse downstream.
         from bigdl_tpu.ops.attention_core import blockwise_attention
         f = lambda q_, k_, v_: blockwise_attention(
-            q_, k_, v_, causal=causal, scale=scale, block_size=block_k)
+            q_, k_, v_, causal=causal, scale=scale,
+            block_size=_block(block_k, "K", _BLOCK))
         _, vjp = jax.vjp(jax.checkpoint(f), q, k, v)
         return vjp(g_o)
     return _flash_bwd(q, k, v, o, lse, g_o, g_l, causal, scale,
@@ -346,16 +491,49 @@ _flash_lse.defvjp(_flash_lse_vjp_fwd, _flash_lse_vjp_bwd)
 
 # ------------------------------------------------------------- public entry
 
-# In-model on-chip default (PERF.md round-3 crossover table): 512/512 beat
-# 256/256 and 128/128 at every measured LM config, op-level AND in-model.
-_DEFAULT_BLOCK = 512
+# The square tile, when the caller names none. Measured op-level on the v5e,
+# bf16, at head 64 and 128, sequences 1024 to 8192, causal and not (PERF.md
+# section 6, PR 24). The backward kernels are fastest at 512 (at the LM
+# cell's shape dQ + dK/dV 0.36 + 0.46 ms a call against 0.43 + 0.47 at 1024).
+# The forward gains 8-32% at 1024 where no tile of the call is masked as a
+# whole (half as many programs share its fixed work), loses 11% where every
+# tile is (a padded causal sequence), and at 1024 its two (BQ, BK) float32
+# intermediates take 8 MiB of the 16 MiB of VMEM a call gets, beside K and V
+# which lie there whole: see _fwd_block.
+_FWD_BLOCK = 1024
+_BLOCK = 512
+_VMEM_BUDGET = 15 << 20
 
 
-def _env_block(name: str, default: int) -> int:
-    """On-chip block-size tuning without code edits
-    (``BIGDL_TPU_FLASH_BLOCK_Q`` / ``BIGDL_TPU_FLASH_BLOCK_K``)."""
+def _fwd_block(sq: int, sk: int, d: int, itemsize: int) -> int:
+    """The forward's default tile, from what the call can see: 1024 where
+    the sequence is a whole number of such tiles (so the causal diagonal is
+    halved and nothing else is masked) and the call's VMEM stays under the
+    budget; else 512, the parent's. The estimate: K and V whole and the q
+    and o tiles, each twice (Mosaic double-buffers its operands), two
+    (BQ, BK) float32 intermediates and three (BQ, D) float32 accumulators.
+    Every shape it admits compiled for the v5e (bf16 and float32, head 64
+    to 256, 1024 to 16384 keys, causal and not), and it refuses every one
+    that did not at 1024 (bf16: head 128 from 8192 keys, head 256 from
+    2048; float32: head 128 from 4096) with a few that would have
+    (PERF.md section 6, PR 24)."""
+    big = _FWD_BLOCK
+    vmem = (4 * (sk + big) * d * itemsize + 2 * big * big * 4
+            + 3 * big * d * 4)
+    if sq == sk and sk % big == 0 and vmem <= _VMEM_BUDGET:
+        return big
+    return _BLOCK
+
+
+def _block(given: Optional[int], axis: str, default: int) -> int:
+    """The caller's block, else ``BIGDL_TPU_FLASH_BLOCK_Q`` /
+    ``BIGDL_TPU_FLASH_BLOCK_K`` (on-chip tuning without code edits), else
+    the kernel's measured default."""
+    if given is not None:
+        return given
     try:
-        return int(os.environ.get(name, "") or default)
+        return int(os.environ.get("BIGDL_TPU_FLASH_BLOCK_" + axis, "")
+                   or default)
     except ValueError:
         return default
 
@@ -370,10 +548,6 @@ def flash_attention(q, k, v, causal: bool = False,
         scale = 1.0 / float(q.shape[-1]) ** 0.5
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    if block_q is None:
-        block_q = _env_block("BIGDL_TPU_FLASH_BLOCK_Q", _DEFAULT_BLOCK)
-    if block_k is None:
-        block_k = _env_block("BIGDL_TPU_FLASH_BLOCK_K", _DEFAULT_BLOCK)
     o, _ = _flash_lse(q, k, v, causal, scale, block_q, block_k, interpret)
     return o
 
@@ -393,10 +567,6 @@ def flash_attention_with_lse(
         scale = 1.0 / float(q.shape[-1]) ** 0.5
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    if block_q is None:
-        block_q = _env_block("BIGDL_TPU_FLASH_BLOCK_Q", _DEFAULT_BLOCK)
-    if block_k is None:
-        block_k = _env_block("BIGDL_TPU_FLASH_BLOCK_K", _DEFAULT_BLOCK)
     return _flash_lse(q, k, v, causal, scale, block_q, block_k, interpret)
 
 
@@ -405,13 +575,14 @@ def use_flash(q, mask) -> bool:
     unmasked sequences (masked paths use the XLA cores which take an
     arbitrary additive bias).
 
-    Gate encodes the measured in-model crossover (PERF.md round-3 table,
-    real v5e): at seq 512 XLA's fused attention wins (the opaque
-    pallas_call costs more in lost fusion + layout copies around it than
-    online softmax saves there); from seq 1024 the kernel wins in-model —
-    +22% tokens/s at 1024, +50% at 2048, +87% at 4096 (blocks 512/512).
-    Op-level microbenchmarks showed flash ahead even at 512 — gate on
-    IN-MODEL data, not op-level.
+    The gate is the in-model crossover measured in round 3 on a v5e, with
+    the kernels then fed float32 (ROADMAP S5 keeps those numbers): at seq
+    512 XLA's fused attention won (the opaque pallas_call costs more in
+    lost fusion + layout copies around it than online softmax saves
+    there); from seq 1024 the kernel won in-model. Op-level the kernel was
+    ahead even at 512 — gate on IN-MODEL data, not op-level. Not re-measured
+    since the kernels take bf16 operands (PR 24): the benchmark has no cell
+    below seq 2048; what they cost there is in PERF.md section 5.
     """
     if os.environ.get("BIGDL_TPU_DISABLE_FLASH"):
         return False

@@ -303,6 +303,22 @@ LOOP_SPANS = ("train.iteration", "train.data", "train.dispatch",
 FLASH_KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
 PROFILE_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                            ".bench_scratch", "smoke_profile")
+KEPT_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                        "chiprun_out")
+
+
+def own_runs(runs, host, lag):
+    """Of the step runs ``rx.program_runs`` found in a profile, those the
+    profile's own iterations dispatched. The session opens while the step of
+    the iteration before it still runs: the device plane holds that run cut
+    off at the session's start, its ``DoEnqueueProgram`` is from before the
+    session, and the reader takes the stump for a whole run whenever the
+    cut falls on the slice's first operation (5 of 8 profiles, PR 24's call
+    32). A run of the profile cannot begin on the device before the
+    profile's first ``train.dispatch`` began on the host (``lag`` puts the
+    device's clock on the host's)."""
+    first = min(s[1] for s in host.named("train.dispatch"))
+    return [r for r in runs if r[1] + lag >= first]
 
 
 def phase_timeline(report):
@@ -333,11 +349,17 @@ def phase_timeline(report):
             f"train.iteration step markers {steps}, not 3..7")
         dev = trace.devices[0]
         lo, hi = rx.slice_bounds(trace)
-        runs = rx.program_runs(dev, lo, hi)
-        check(len(runs) >= 4 and all(
-            host.enqueue_of(r[3], 0) is not None for r in runs),
-            f"{len(runs)} runs of the step program, not every one with its "
-            "DoEnqueueProgram (by run_id)")
+        lag = rx.device_clock_lag(trace)
+        runs = own_runs(rx.program_runs(dev, lo, hi), host, lag)
+        lost = [r[3] for r in runs if host.enqueue_of(r[3], 0) is None]
+        if lost:
+            os.makedirs(KEPT_DIR, exist_ok=True)
+            shutil.copy(found[-1], os.path.join(
+                KEPT_DIR, "smoke_timeline_unmatched.xplane.pb"))
+        check(len(runs) >= 4 and not lost,
+              f"{len(runs)} runs of the step program, those with run_id "
+              f"{lost} without their DoEnqueueProgram; the xplane is kept "
+              f"under {KEPT_DIR}")
         mosaic = {rx.family(o) for o in dev.ops if o.is_mosaic}
         for kernel in FLASH_KERNELS:
             check(any(kernel in fam for fam in mosaic), f"no Mosaic call "
@@ -349,11 +371,12 @@ def phase_timeline(report):
               "loops run under the scope 'lm_head_ce', not 2")
         sec, n = timeline.scope_seconds(trace, scoped, lo, hi)
         say(f"  profile of steps {steps}: {len(host.spans)} train.* "
-            f"annotations, {len(host.enqueues)} enqueues; Mosaic calls "
+            f"annotations, {len(host.enqueues)} enqueues ({len(runs)} step "
+            f"runs, each with its own); Mosaic calls "
             f"{sorted(mosaic)}; lm_head_ce loops {sorted(whiles)}, "
             f"{1e3 * sec / n:.2f} ms a step of "
             f"{1e3 * (runs[0][2] - runs[0][1]):.2f}; device clock "
-            f"{1e3 * rx.device_clock_lag(trace):.2f} ms behind the host's")
+            f"{1e3 * lag:.2f} ms behind the host's")
 
 
 def phase_decode(report, lm):
@@ -388,6 +411,57 @@ def phase_decode(report, lm):
             "bigdl_int8_fallbacks_total == 0")
 
 
+def flash_kernel_ms(q, k, v, runs=8, calls=6):
+    """Milliseconds a run of each of the three flash kernels alone, causal,
+    at the blocks the model runs them with. The arrays go in as
+    (B*N, S, 1, D), the kernels' own layout, so no transpose runs beside
+    them. One timed call is one jit that runs the kernel ``runs`` times, each
+    on the last one's result, so the device is never waiting for a dispatch;
+    the backward kernels are timed one at a time (the other is unused and
+    dropped by XLA; the small ``delta`` reduction they both read is computed
+    once a call). A timed region is ``calls`` calls ended by a device->host
+    fetch; the best of three."""
+    import jax
+    from bigdl_tpu.ops import flash_attention as fa
+
+    b, s, n, d = q.shape
+    q, k, v = (x.transpose(0, 2, 1, 3).reshape(b * n, s, 1, d)
+               for x in (q, k, v))
+    # causal, the model's scale, each kernel's own default blocks; interpret
+    # mode off the chip (tests)
+    args = (True, 1.0 / d ** 0.5, None, None, PLATFORM != "tpu")
+    o, lse = jax.jit(lambda q, k, v: fa._flash_fwd_lse(q, k, v, *args))(
+        q, k, v)
+
+    def bwd(q, k, v):
+        return fa._flash_bwd(q, k, v, o, lse, o, None, *args)
+
+    steps = {
+        "flash_fwd": lambda q, k, v: (fa._flash_fwd_lse(q, k, v, *args)[0],
+                                      k, v),
+        "flash_bwd_dq": lambda q, k, v: (bwd(q, k, v)[0], k, v),
+        "flash_bwd_dkv": lambda q, k, v: (q,) + bwd(q, k, v)[1:],
+    }
+    out = {}
+    for name, step in steps.items():
+        def chain(q, k, v, step=step):
+            for _ in range(runs):
+                q, k, v = step(q, k, v)
+            return q, k, v
+        fn = jax.jit(chain)
+        jax.block_until_ready(fn(q, k, v))
+        best = float("inf")
+        for _ in range(3):
+            t0 = time.perf_counter()
+            for _ in range(calls):
+                res = fn(q, k, v)
+            np.asarray(res[0][0, 0, 0])
+            best = min(best,
+                       (time.perf_counter() - t0) / (calls * runs) * 1e3)
+        out[name] = round(best, 4)
+    return out
+
+
 def phase_kernels(report):
     """Kernel vs XLA formulation on the shapes phases 2-3 ran. Tolerances
     are the repo's own: flash forward 2e-2 abs in bf16 and gradients 5e-2
@@ -403,39 +477,46 @@ def phase_kernels(report):
     def f32(x):
         return np.asarray(x.astype(jnp.float32))
 
+    def loss(attend):
+        return lambda q, k, v: jnp.sum(
+            attend(q, k, v, causal=True).astype(jnp.float32) ** 2)
+
+    def both(attend, q, k, v):
+        fn = jax.jit(lambda q, k, v: (
+            attend(q, k, v, causal=True),
+            jax.grad(loss(attend), argnums=(0, 1, 2))(q, k, v)))
+        return fn, fn(q, k, v)
+
     with Phase("kernels", report):
         rng = np.random.RandomState(4)
-        q, k, v = (jnp.asarray(rng.randn(2, 1024, 12, 64), jnp.bfloat16)
-                   for _ in range(3))
-        check(use_flash(q, None), "use_flash rejects the LM's own shape")
-
-        def loss(attend):
-            return lambda q, k, v: jnp.sum(
-                attend(q, k, v, causal=True).astype(jnp.float32) ** 2)
-
-        def both(attend):
-            fn = jax.jit(lambda q, k, v: (
-                attend(q, k, v, causal=True),
-                jax.grad(loss(attend), argnums=(0, 1, 2))(q, k, v)))
-            return fn, fn(q, k, v)
-
-        fn, (o_k, g_k) = both(flash_attention)
-        fwd, bwd = mosaic_calls(fn.lower(q, k, v).compile().as_text())
-        check(fwd >= 1 and bwd == 2, f"{fwd} forward + {bwd} backward "
-              "Mosaic custom calls: flash did not lower to its three kernels")
-        _, (o_x, g_x) = both(attention_core.dot_product_attention)
-        err = float(np.max(np.abs(f32(o_k) - f32(o_x))))
-        check(np.isfinite(f32(o_k)).all() and err < 2e-2,
-              f"flash forward differs from XLA by {err}")
-        rels = []
-        for name, a, b in zip(("dq", "dk", "dv"), g_k, g_x):
-            rel = float(np.max(np.abs(f32(a) - f32(b)))
-                        / (np.max(np.abs(f32(b))) + 1e-9))
-            check(np.isfinite(f32(a)).all() and rel < 5e-2,
-                  f"flash {name} differs from XLA by rel {rel}")
-            rels.append(round(rel, 4))
-        say(f"  flash (2,1024,12,64) bf16 causal vs XLA core: forward max "
-            f"err {err:.2e}; dq/dk/dv rel err {rels}")
+        # the 134M LM's attention and the benchmark's LM cell's own
+        for shape in ((2, 1024, 12, 64), (2, 2048, 14, 64)):
+            q, k, v = (jnp.asarray(rng.randn(*shape), jnp.bfloat16)
+                       for _ in range(3))
+            check(use_flash(q, None), f"use_flash rejects {shape}")
+            fn, (o_k, g_k) = both(flash_attention, q, k, v)
+            fwd, bwd = mosaic_calls(fn.lower(q, k, v).compile().as_text())
+            check(fwd >= 1 and bwd == 2, f"{fwd} forward + {bwd} backward "
+                  "Mosaic custom calls: flash did not lower to its three "
+                  "kernels")
+            _, (o_x, g_x) = both(attention_core.dot_product_attention,
+                                 q, k, v)
+            err = float(np.max(np.abs(f32(o_k) - f32(o_x))))
+            check(np.isfinite(f32(o_k)).all() and err < 2e-2,
+                  f"flash forward differs from XLA by {err}")
+            rels = []
+            for name, a, b in zip(("dq", "dk", "dv"), g_k, g_x):
+                rel = float(np.max(np.abs(f32(a) - f32(b)))
+                            / (np.max(np.abs(f32(b))) + 1e-9))
+                check(np.isfinite(f32(a)).all() and rel < 5e-2,
+                      f"flash {name} differs from XLA by rel {rel}")
+                rels.append(round(rel, 4))
+            ms = flash_kernel_ms(q, k, v)
+            report.setdefault("flash_kernel_ms", {})[
+                "x".join(map(str, shape))] = ms
+            say(f"  flash {shape} bf16 causal vs XLA core: forward max err "
+                f"{err:.2e}; dq/dk/dv rel err {rels}; op-level ms a run "
+                f"(8 dependent runs a call, fetch at the end) {ms}")
 
         # the 134M decode's matmuls: qkv, out, ffn up/down, the V=32000 head
         # (31 full 1024-row tiles and one quarter tile)

@@ -228,6 +228,38 @@ class TestFlashKernel:
                                    np.asarray(jax.grad(loss_ref)(q)),
                                    rtol=1e-4, atol=1e-4)
 
+    @pytest.mark.parametrize("s,with_lse", [(512, False), (768, True)])
+    def test_halved_diagonal_tile_matches_plain(self, s, with_lse):
+        # Square 256-tiles on an unpadded causal sequence: the tile the
+        # diagonal crosses runs as two half-height strips, each against
+        # only the keys it can see (the path the on-chip defaults take on
+        # an unpadded causal sequence). Output, LSE and all three gradients
+        # against autodiff through the plain formulation.
+        from bigdl_tpu.ops import flash_attention as fa
+        assert fa._halved_diagonal(True, s, s, 256, 256)
+        assert not fa._halved_diagonal(True, s + 8, s + 8, 256, 256)
+        b, n, d = 1, 2, 8
+        q, k, v, g = (jnp.asarray(_rand(b, s, n, d)) for _ in range(4))
+        gl = jnp.asarray(_rand(b, n, s) * with_lse)
+
+        def plain(q_, k_, v_):
+            return _attention_and_lse(q_, k_, v_, True)
+
+        def kernel(q_, k_, v_):
+            return fa.flash_attention_with_lse(
+                q_, k_, v_, causal=True, block_q=256, block_k=256,
+                interpret=True)
+
+        def run(f):
+            out, vjp = jax.vjp(f, q, k, v)
+            return out + vjp((g, gl))
+
+        for r, o, name in zip(run(plain), run(kernel),
+                              ("o", "lse", "dq", "dk", "dv")):
+            np.testing.assert_allclose(np.asarray(o), np.asarray(r),
+                                       rtol=2e-4, atol=2e-4,
+                                       err_msg=f"{name} mismatch")
+
     def test_xla_bwd_fallback_env(self, monkeypatch):
         monkeypatch.setenv("BIGDL_TPU_FLASH_XLA_BWD", "1")
         b, s, n, d = 1, 16, 1, 8
@@ -239,6 +271,145 @@ class TestFlashKernel:
             q_, k, v, causal=True) ** 2))(q)
         np.testing.assert_allclose(np.asarray(g_flash), np.asarray(g_plain),
                                    rtol=1e-4, atol=1e-4)
+
+
+def _attention_and_lse(q, k, v, causal):
+    """(o, lse) by the XLA core's recipe in the dtype of q: the oracle (on
+    float32 inputs) and the baseline (on bfloat16) of the kernel tests."""
+    o = ac.dot_product_attention(q, k, v, causal=causal)
+    logits = (jnp.einsum("bqnd,bknd->bnqk", q, k)
+              * (1.0 / q.shape[-1] ** 0.5)).astype(jnp.float32)
+    if causal:
+        keep = jnp.tril(jnp.ones(logits.shape[-2:], bool))
+        logits = jnp.where(keep, logits, jnp.finfo(jnp.float32).min)
+    return o, jax.nn.logsumexp(logits, axis=-1)
+
+
+def _sub_jaxprs(eqn):
+    for val in eqn.params.values():
+        for item in (val if isinstance(val, (list, tuple)) else (val,)):
+            inner = getattr(item, "jaxpr", item)
+            if hasattr(inner, "eqns"):
+                yield inner
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr, loops, branches and kernels included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for inner in _sub_jaxprs(eqn):
+            yield from _eqns(inner)
+
+
+@pytest.mark.parametrize("sq,sk,d,itemsize,want", [
+    (2048, 2048, 64, 2, 1024),      # the LM cell
+    (1024, 1024, 64, 2, 1024),      # chip_smoke's 134M LM
+    (8192, 8192, 64, 2, 1024),
+    (4096, 4096, 128, 2, 1024),
+    (8192, 8192, 128, 2, 512),      # did not compile at 1024: VMEM
+    (4096, 4096, 128, 4, 512),      # float32: the same
+    (2048, 2048, 256, 2, 512),      # causal did not compile at 1024
+    (3000, 3000, 64, 2, 512),       # padded: every tile masked, 11% slower
+    (2048, 4096, 64, 2, 512),       # oblong: no halved diagonal
+])
+def test_forward_tile_follows_the_call(sq, sk, d, itemsize, want):
+    """The forward's default tile is 1024 only where a v5e compile and the
+    on-chip times say so (PERF.md section 6, PR 24); everything else keeps
+    the 512 the backward kernels and the parent use."""
+    from bigdl_tpu.ops import flash_attention as fa
+    assert fa._fwd_block(sq, sk, d, itemsize) == want
+
+
+class TestFlashKernelDtypeContract:
+    """What the three kernels hand the MXU follows the caller's dtype:
+    bfloat16 in, bfloat16 operands and float32 accumulators; float32 in,
+    float32 throughout. The jaxpr guards the contract and the casts it
+    saves, not MXU passes: on the v5e an up-cast tile measured the same
+    time and gave the same bits (PERF.md section 6, PR 24), and no run on a
+    CPU would show one coming back."""
+
+    HEAD = 8
+    # the kernel keeps float32 logits where the XLA core rounds them to
+    # bfloat16, so it is usually the closer of the two; 1.5x the core's
+    # own error, plus a bfloat16 ulp of the largest value, is the bound
+    FACTOR, FLOOR = 1.5, 2.0 ** -8
+
+    @pytest.mark.parametrize("with_lse", [False, True],
+                             ids=["out_only", "lse_cotangent"])
+    @pytest.mark.parametrize("s,block", [(48, 16), (40, 16), (512, 256)],
+                             ids=["block_multiple", "ragged", "on_chip_tiles"])
+    @pytest.mark.parametrize("causal", [True, False],
+                             ids=["causal", "full"])
+    def test_bf16_as_close_to_f32_oracle_as_xla_bf16(self, causal, s, block,
+                                                     with_lse):
+        from bigdl_tpu.ops.flash_attention import flash_attention_with_lse
+        rng = np.random.RandomState(s + 2 * causal + with_lse)
+        b, n, d = 2, 2, self.HEAD
+        q, k, v, g = (jnp.asarray(rng.randn(b, s, n, d), jnp.bfloat16)
+                      for _ in range(4))
+        gl = jnp.asarray(rng.randn(b, n, s) * with_lse, jnp.float32)
+
+        def run(attend, *xs):
+            (o, lse), vjp = jax.vjp(attend, *xs)
+            return (o, lse) + tuple(vjp((g.astype(o.dtype), gl)))
+
+        def kernel(q_, k_, v_):
+            return flash_attention_with_lse(
+                q_, k_, v_, causal=causal, block_q=block, block_k=block,
+                interpret=True)
+
+        def core(q_, k_, v_):
+            return _attention_and_lse(q_, k_, v_, causal)
+
+        oracle = run(core, *(x.astype(jnp.float32) for x in (q, k, v)))
+        got, base = run(kernel, q, k, v), run(core, q, k, v)
+        for name, a, x, ref in zip(("o", "lse", "dq", "dk", "dv"),
+                                   got, base, oracle):
+            a, x, ref = (np.asarray(t, np.float32) for t in (a, x, ref))
+            assert np.isfinite(a).all(), f"{name} has NaN/inf"
+            top = np.abs(ref).max()
+            err, err_core = np.abs(a - ref).max(), np.abs(x - ref).max()
+            assert err <= self.FACTOR * err_core + self.FLOOR * top, (
+                f"{name}: kernel {err:.3e} against the XLA core's "
+                f"{err_core:.3e} (largest value {top:.3e})")
+
+    @pytest.mark.parametrize("block,s", [(16, 40), (256, 512)],
+                             ids=["masked_tiles", "halved_diagonal"])
+    @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                             ids=["bf16", "f32"])
+    def test_mxu_operands_follow_input_dtype(self, dtype, block, s):
+        from bigdl_tpu.ops.flash_attention import flash_attention_with_lse
+        b, n, d = 1, 2, self.HEAD
+        x = jnp.zeros((b, s, n, d), dtype)
+
+        def grads(q, k, v, g, gl):
+            _, vjp = jax.vjp(lambda *a: flash_attention_with_lse(
+                *a, causal=True, block_q=block, block_k=block,
+                interpret=True), q, k, v)
+            return vjp((g, gl))
+
+        jaxpr = jax.make_jaxpr(grads)(
+            x, x, x, x, jnp.zeros((b, n, s), jnp.float32)).jaxpr
+        calls = {e.params["name"]: e.params["jaxpr"] for e in _eqns(jaxpr)
+                 if e.primitive.name == "pallas_call"}
+        assert sorted(calls) == ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+        dots = {"flash_fwd": 2, "flash_bwd_dq": 3, "flash_bwd_dkv": 4}
+        for name, body in calls.items():
+            eqns = list(_eqns(body))
+            found = [e for e in eqns if e.primitive.name == "dot_general"]
+            # every traced tile body (plain, masked, half strips) holds all
+            assert found and len(found) % dots[name] == 0, (name, len(found))
+            for e in found:
+                assert [t.aval.dtype for t in e.invars] == [dtype, dtype], (
+                    f"{name}: MXU operands "
+                    f"{[str(t.aval.dtype) for t in e.invars]}")
+                assert e.outvars[0].aval.dtype == jnp.float32
+            upcast = [e for e in eqns
+                      if e.primitive.name == "convert_element_type"
+                      and e.params["new_dtype"] == jnp.float32
+                      and e.invars[0].aval.dtype != jnp.float32
+                      and e.invars[0].aval.shape[-1:] == (d,)]
+            assert not upcast, f"{name}: a (rows, D) tile is up-cast"
 
 
 class TestMultiHeadAttention:

@@ -39,6 +39,41 @@ def test_mosaic_calls_splits_forward_from_backward():
     assert chip_smoke.mosaic_calls(text) == (1, 2)
 
 
+def test_flash_kernel_timer_runs_each_kernel_alone(monkeypatch):
+    """The kernels phase's op-level timer off-chip (interpret mode): one
+    positive time for each of the three kernels, in their own names."""
+    import jax.numpy as jnp
+
+    monkeypatch.setattr(chip_smoke, "PLATFORM", "cpu")
+    x = jnp.ones((1, 16, 2, 8), jnp.bfloat16)
+    ms = chip_smoke.flash_kernel_ms(x, x, x, runs=2, calls=1)
+    assert list(ms) == ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"]
+    assert all(t > 0 for t in ms.values())
+
+
+def test_own_runs_drops_the_run_the_session_cut_off():
+    """The timeline phase's run-to-enqueue check is over the profile's own
+    runs: the times are those of a v5e profile (PR 24, call 32, r2) in
+    which the step of the iteration before the session, cut off at the
+    session's start, stood in the slice as a whole run without an enqueue."""
+    from benchmark import timeline
+
+    lag = 0.00061
+    host = timeline.Host()
+    host.spans += [("train.dispatch", 0.0580, 0.0731, {"neval": 3}),
+                   ("train.dispatch", 0.0733, 0.0880, {"neval": 4})]
+    host.enqueues.update({(509, 0): 0.0716, (527, 0): 0.0867})
+    runs = [("jit_step(1)", t0 - lag, t1 - lag, rid) for t0, t1, rid in
+            ((0.0404, 0.0536, 490), (0.0717, 0.1278, 509),
+             (0.1279, 0.1840, 527))]
+    own = chip_smoke.own_runs(runs, host, lag)
+    assert [r[3] for r in own] == [509, 527]
+    assert all(host.enqueue_of(r[3], 0) is not None for r in own)
+    # a run of the profile that lost its enqueue stays, to fail the check
+    del host.enqueues[(527, 0)]
+    assert [r[3] for r in chip_smoke.own_runs(runs, host, lag)] == [509, 527]
+
+
 def test_mesh_trainer_plumbing_on_virtual_devices(monkeypatch):
     """The smoke's own train() + check_mesh() over the 8 virtual devices at
     LeNet size: DistriOptimizer through the facade, every progress line
