@@ -296,6 +296,91 @@ def load_qwen2(config: Dict[str, Any], state_dict: Dict[str, Any]) -> Module:
     return import_lm_state_dict(model, ours, strict=strict)
 
 
+# -------------------------------------------------------------- Nemotron-H
+
+def nemotron_h_lm_kwargs(config: Dict[str, Any],
+                         held_experts=None) -> Dict[str, Any]:
+    """``models.hybrid.build_hybrid_lm`` kwargs for an HF ``nemotron_h``
+    ``config.json`` dict: the per-layer ``hybrid_override_pattern`` of
+    Mamba-2 (``M``), mixture-of-experts (``E``) and attention (``*``)
+    blocks, each one mixer under a pre-norm residual.
+
+    ``held_experts`` lists the routed experts that live on this chip of an
+    expert-parallel deployment (default: all ``n_routed_experts``, which is
+    always the router's width); ``vocab_size`` may be a slice.
+
+    Read from the config but not applied, as in the public modelling code:
+    ``rope_theta`` / ``partial_rotary_factor`` (the family's attention
+    layers take no positional term). Refused rather than guessed: expert
+    groups with a group limit (``n_group`` > 1), a ``-`` (dense MLP)
+    block, a sliding window, a head tied to the embedding, a bias on the
+    Mamba projections or none on their convolution (no published
+    configuration of the family has any of the three)."""
+    pattern = config["hybrid_override_pattern"]
+    if len(pattern) != int(config["num_hidden_layers"]):
+        raise ValueError(
+            f"hybrid_override_pattern has {len(pattern)} blocks, "
+            f"num_hidden_layers says {config['num_hidden_layers']}")
+    if set(pattern) - set("ME*"):
+        raise ValueError(f"unmapped block kinds in pattern {pattern!r} "
+                         "(M, E and * are)")
+    if int(config.get("n_group", 1)) != 1 \
+            or int(config.get("topk_group", 1)) != 1:
+        raise ValueError("group-limited expert routing (n_group > 1) is "
+                         "not mapped")
+    if config.get("sliding_window"):
+        raise ValueError("nemotron_h sliding-window attention is not mapped")
+    acts = {"relu2": "relu2", "relu": "relu", "gelu": "gelu"}
+    act = config.get("mlp_hidden_act", "relu2")
+    if act not in acts or config.get("mamba_hidden_act", "silu") != "silu":
+        raise ValueError(f"unsupported nemotron_h activations {act!r} / "
+                         f"{config.get('mamba_hidden_act')!r}")
+    if not config.get("norm_topk_prob", True):
+        raise ValueError("norm_topk_prob=false is not mapped")
+    if config.get("mamba_proj_bias", config.get("use_bias", False)) \
+            or not config.get("use_conv_bias", True):
+        raise ValueError("a bias on the Mamba projections, or none on the "
+                         "convolution, is not mapped")
+    if config.get("tie_word_embeddings", False):
+        raise ValueError("a head tied to the embedding is not mapped")
+    eps = float(config.get("layer_norm_epsilon", 1e-5))
+    kwargs = dict(
+        vocab_size=int(config["vocab_size"]),
+        embed_dim=int(config["hidden_size"]),
+        pattern=pattern, norm_eps=eps)
+    if "M" in pattern:
+        kwargs["mamba"] = dict(
+            num_heads=int(config["mamba_num_heads"]),
+            head_dim=int(config["mamba_head_dim"]),
+            state_size=int(config["ssm_state_size"]),
+            n_groups=int(config["n_groups"]),
+            conv_kernel=int(config["conv_kernel"]),
+            chunk_size=int(config.get("chunk_size", 128)), norm_eps=eps,
+            dt_min=float(config.get("time_step_min", 1e-3)),
+            dt_max=float(config.get("time_step_max", 0.1)),
+            dt_floor=float(config.get("time_step_floor", 1e-4)))
+    if "E" in pattern:
+        if int(config.get("n_shared_experts", 1)) not in (0, 1):
+            raise ValueError("more than one shared expert is not mapped")
+        kwargs["moe"] = dict(
+            hidden_size=int(config["moe_intermediate_size"]),
+            n_experts=int(config["n_routed_experts"]),
+            k=int(config["num_experts_per_tok"]),
+            activation=acts[act], dispatch="held",
+            held=None if held_experts is None else tuple(held_experts),
+            bias=bool(config.get("mlp_bias", False)),
+            shared_hidden=int(config["moe_shared_expert_intermediate_size"])
+            * int(config.get("n_shared_experts", 1)),
+            route_scale=float(config.get("routed_scaling_factor", 1.0)))
+    if "*" in pattern:
+        kwargs["attention"] = dict(
+            num_heads=int(config["num_attention_heads"]),
+            num_kv_heads=int(config["num_key_value_heads"]),
+            head_dim=int(config["head_dim"]),
+            with_bias=bool(config.get("attention_bias", False)))
+    return kwargs
+
+
 # ------------------------------------------------------------------- export
 
 def export_gpt2_state_dict(model: Module) -> Dict[str, np.ndarray]:

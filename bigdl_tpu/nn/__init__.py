@@ -76,3 +76,5 @@ from bigdl_tpu.nn.attention import (
     LayerNorm, RMSNorm, MultiHeadAttention, PositionalEncoding,
     LearnedPositionalEncoding, TransformerEncoderLayer, TransformerEncoder,
 )
+from bigdl_tpu.nn.mamba import Mamba2
+from bigdl_tpu.nn.hybrid import HybridBlock, HybridDecoder

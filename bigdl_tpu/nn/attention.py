@@ -113,9 +113,17 @@ class MultiHeadAttention(Module):
                  rope_theta: float = 10000.0,
                  window: Optional[int] = None,
                  rope_scaling: Optional[dict] = None,
-                 qkv_bias: bool = False):
+                 qkv_bias: bool = False,
+                 head_dim: Optional[int] = None):
         super().__init__()
-        assert embed_dim % num_heads == 0, "embed_dim must divide num_heads"
+        # head_dim: the size of one head where the model sets it apart
+        # from the stream's width (the projections are then
+        # (num_heads * head_dim) wide, not embed_dim); default, the usual
+        # embed_dim // num_heads
+        if head_dim is None:
+            assert embed_dim % num_heads == 0, \
+                "embed_dim must divide num_heads"
+            head_dim = embed_dim // num_heads
         # window: sliding-window (banded causal) attention — query i sees
         # keys (i - window, i], the Mistral convention. Requires causal;
         # runs on the XLA cores (the flash kernel and context-parallel
@@ -146,7 +154,7 @@ class MultiHeadAttention(Module):
         # model then needs NO additive PositionalEncoding). Rotation uses
         # absolute positions (decode_pos-offset while decoding), so cached
         # keys carry their rotation and the q@k score is relative.
-        if rope and (embed_dim // num_heads) % 2 != 0:
+        if rope and head_dim % 2 != 0:
             raise ValueError("rope needs an even head_dim")
         self.rope = rope
         self.rope_theta = rope_theta
@@ -167,7 +175,7 @@ class MultiHeadAttention(Module):
         self.seq_layout = seq_layout
         self.embed_dim = embed_dim
         self.num_heads = num_heads
-        self.head_dim = embed_dim // num_heads
+        self.head_dim = head_dim
         # applied to the normalised attention PROBABILITIES in training
         # (torch nn.MultiheadAttention semantics; round-3 misplaced it on
         # the output projection). Excluded from the flash/blockwise paths
@@ -185,19 +193,20 @@ class MultiHeadAttention(Module):
         self.block_size = block_size
         e_kv = self.num_kv_heads * self.head_dim
         self._e_kv = e_kv
+        e_q = self._e_q = num_heads * self.head_dim   # embed_dim by default
         self.register_parameter(
-            "in_proj_weight", init.xavier((embed_dim + 2 * e_kv, embed_dim),
-                                          embed_dim, embed_dim))
+            "in_proj_weight", init.xavier((e_q + 2 * e_kv, embed_dim),
+                                          embed_dim, e_q))
         self.register_parameter(
-            "out_proj_weight", init.xavier((embed_dim, embed_dim),
-                                           embed_dim, embed_dim))
+            "out_proj_weight", init.xavier((embed_dim, e_q),
+                                           e_q, embed_dim))
         # qkv_bias: bias on the q/k/v projections ONLY (Qwen2's layout:
         # with_bias=False drops the out-proj + FFN biases, qkv_bias=True
         # restores the input-projection one)
         self.qkv_bias = qkv_bias
         if with_bias or qkv_bias:
             self.register_parameter("in_proj_bias",
-                                    init.zeros((embed_dim + 2 * e_kv,)))
+                                    init.zeros((e_q + 2 * e_kv,)))
         if with_bias:
             self.register_parameter("out_proj_bias", init.zeros((embed_dim,)))
         self.attn_mask: Optional[jax.Array] = None
@@ -495,7 +504,7 @@ class MultiHeadAttention(Module):
         """(q, k, v) pre-head-split — the quantized twin overrides this
         (and ``_out_projection``) to run the fused int8 kernel on the raw
         int8 row-slices instead of dequantizing the full matrix."""
-        e = self.embed_dim
+        e = getattr(self, "_e_q", self.embed_dim)
         ekv = getattr(self, "_e_kv", e)
         w = self.in_proj_weight
         wq, wk, wv = w[:e], w[e:e + ekv], w[e + ekv:]
@@ -528,7 +537,7 @@ class MultiHeadAttention(Module):
         else:
             query = key = value = input
 
-        e = self.embed_dim
+        e = getattr(self, "_e_q", self.embed_dim)
         pq, pk, pv = self._in_projections(query, key, value)
         q = self._split_heads(pq)
         k = self._split_heads(pk)

@@ -1,0 +1,124 @@
+"""Mamba-2 mixer (Dao & Gu 2024), the state-space layer of the hybrid
+decoders (Nemotron-H, Granite-4-H, Zamba2): no attention, no positional
+term, a per-head recurrent state of ``head_dim x state_size``.
+
+    [z | xBC | dt] = u W_in                      (d_inner | conv_dim | H)
+    xBC = silu(causal_depthwise_conv(xBC, k) + b)
+    x, B, C = split(xBC)                         (H x P | G x N | G x N)
+    dt = softplus(dt + dt_bias);  a = -exp(A_log)
+    y = ssd_scan(x, dt, a, B, C) + D * x          (ops/ssd_scan.py)
+    y = GroupRMSNorm_G(y * silu(z)) * w
+    out = y W_out
+
+Parameter names and layouts follow the public modelling code
+(``in_proj_weight`` (d_in_proj, E) and ``out_proj_weight`` (E, d_inner) in
+Linear's (out, in) layout, ``conv_weight`` (conv_dim, k)). As in every
+published configuration of the family, the projections carry no bias and
+the convolution does.
+"""
+
+from __future__ import annotations
+
+import numpy as np
+
+import jax
+import jax.numpy as jnp
+
+from bigdl_tpu.nn import initialization as init
+from bigdl_tpu.nn.module import TensorModule
+from bigdl_tpu.ops.precision import match_compute
+from bigdl_tpu.ops.ssd_scan import ssd_scan
+from bigdl_tpu.utils.rng import RandomGenerator
+
+
+class Mamba2(TensorModule):
+    """Input (B, L, E) -> (B, L, E). Training/prefill form only: the whole
+    sequence through the chunked scan from a zero state."""
+
+    def __init__(self, embed_dim: int, num_heads: int, head_dim: int,
+                 state_size: int, n_groups: int = 1, conv_kernel: int = 4,
+                 chunk_size: int = 128, norm_eps: float = 1e-5,
+                 dt_min: float = 1e-3, dt_max: float = 0.1,
+                 dt_floor: float = 1e-4):
+        super().__init__()
+        if num_heads % n_groups:
+            raise ValueError(f"n_groups {n_groups} must divide num_heads "
+                             f"{num_heads}")
+        self.embed_dim, self.num_heads, self.head_dim = \
+            embed_dim, num_heads, head_dim
+        self.state_size, self.n_groups = state_size, n_groups
+        self.conv_kernel, self.chunk_size = conv_kernel, chunk_size
+        self.norm_eps = norm_eps
+        d_inner = self.d_inner = num_heads * head_dim
+        conv_dim = self.conv_dim = d_inner + 2 * n_groups * state_size
+        d_in = d_inner + conv_dim + num_heads
+        rng = RandomGenerator.RNG()
+        self.register_parameter("in_proj_weight",
+                                init.default_init((d_in, embed_dim),
+                                                  embed_dim))
+        self.register_parameter("conv_weight",
+                                init.default_init((conv_dim, conv_kernel),
+                                                  conv_kernel))
+        self.register_parameter("conv_bias",
+                                init.default_init((conv_dim,), conv_kernel))
+        # the family's own: dt log-uniform in [dt_min, dt_max], floored,
+        # stored through softplus' inverse; A uniform in [1, 16]; D = 1
+        dt = np.exp(rng.uniform(np.log(dt_min), np.log(dt_max),
+                                (num_heads,)))
+        dt = np.maximum(dt, dt_floor)
+        self.register_parameter(
+            "dt_bias", (dt + np.log(-np.expm1(-dt))).astype(np.float32))
+        self.register_parameter(
+            "A_log", np.log(rng.uniform(1.0, 16.0, (num_heads,))
+                            ).astype(np.float32))
+        self.register_parameter("D", init.ones((num_heads,)))
+        self.register_parameter("norm_weight", init.ones((d_inner,)))
+        self.register_parameter("out_proj_weight",
+                                init.default_init((embed_dim, d_inner),
+                                                  d_inner))
+
+    def _conv(self, xbc):
+        """Causal depthwise convolution along the sequence: position t
+        reads t-k+1 .. t, zeros before the start."""
+        k = self.conv_kernel
+        length = xbc.shape[1]
+        w = self.conv_weight.astype(jnp.float32)
+        padded = jnp.pad(xbc.astype(jnp.float32), [(0, 0), (k - 1, 0), (0, 0)])
+        out = sum(padded[:, j:j + length] * w[:, j] for j in range(k))
+        out = out + self.conv_bias.astype(jnp.float32)
+        return jax.nn.silu(out).astype(xbc.dtype)
+
+    def update_output(self, input):
+        h, p, g, n = (self.num_heads, self.head_dim, self.n_groups,
+                      self.state_size)
+        d_inner, conv_dim = self.d_inner, self.conv_dim
+        bsz, length, _ = input.shape
+        w_in = self.in_proj_weight
+        zxbcdt = jnp.matmul(match_compute(input, w_in), w_in.T)
+        z = zxbcdt[..., :d_inner]
+        xbc = self._conv(zxbcdt[..., d_inner:d_inner + conv_dim])
+        dt = zxbcdt[..., d_inner + conv_dim:]
+        x = xbc[..., :d_inner].reshape(bsz, length, h, p)
+        b = xbc[..., d_inner:d_inner + g * n].reshape(bsz, length, g, n)
+        c = xbc[..., d_inner + g * n:].reshape(bsz, length, g, n)
+        dt = jax.nn.softplus(dt.astype(jnp.float32)
+                             + self.dt_bias.astype(jnp.float32))
+        a = -jnp.exp(self.A_log.astype(jnp.float32))
+        y = ssd_scan(x, dt, a, b, c, self.chunk_size)
+        y = y.astype(jnp.float32) + (self.D.astype(jnp.float32)[:, None]
+                                     * x.astype(jnp.float32))
+        # gated RMSNorm over each of the G groups of the inner width
+        y = y.reshape(bsz, length, d_inner) \
+            * jax.nn.silu(z.astype(jnp.float32))
+        yg = y.reshape(bsz, length, g, d_inner // g)
+        yg = yg * jax.lax.rsqrt(jnp.mean(jnp.square(yg), axis=-1,
+                                         keepdims=True) + self.norm_eps)
+        y = yg.reshape(bsz, length, d_inner).astype(input.dtype) \
+            * self.norm_weight
+        w_out = self.out_proj_weight
+        return jnp.matmul(match_compute(y, w_out), w_out.T)
+
+    def __repr__(self):
+        return (f"Mamba2({self.embed_dim}, heads={self.num_heads}x"
+                f"{self.head_dim}, state={self.state_size}, "
+                f"groups={self.n_groups})")

@@ -148,7 +148,7 @@ class QuantizedMultiHeadAttention(_QuantizedMixin, MultiHeadAttention):
 
     def _in_projections(self, query, key, value):
         from bigdl_tpu.ops.int8_matmul import int8_matmul
-        e = self.embed_dim
+        e = getattr(self, "_e_q", self.embed_dim)
         ekv = self._e_kv
         wq = self._buffers["in_proj_weight_q"]
         sq = self._buffers["in_proj_weight_scale"]

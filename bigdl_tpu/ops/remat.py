@@ -23,3 +23,15 @@ REMAT_SAVED_NAMES = ("conv_out", "bn_stats")
 def conv_remat_policy():
     """Save conv outputs + BN statistics; recompute the elementwise tail."""
     return jax.checkpoint_policies.save_only_these_names(*REMAT_SAVED_NAMES)
+
+
+#: ``checkpoint_name`` of a held-expert layer's routed output
+#: (``parallel/expert.py``): block remat keeps it, so the loop over the
+#: experts' row blocks is not run a second time in the backward.
+MOE_ROUTED_OUT = "moe_routed_out"
+
+
+def block_remat_policy():
+    """Per-block checkpointing of a decoder: recompute everything inside a
+    block but the routed experts' output."""
+    return jax.checkpoint_policies.save_only_these_names(MOE_ROUTED_OUT)

@@ -280,8 +280,9 @@ class Optimizer:
 
         Off by default (compute-bound models should keep activations)."""
         from bigdl_tpu.nn.attention import TransformerEncoder
+        from bigdl_tpu.nn.hybrid import HybridDecoder
         encs = [m for m in self.model.modules()
-                if isinstance(m, TransformerEncoder)]
+                if isinstance(m, (TransformerEncoder, HybridDecoder))]
         for enc in encs:  # reset; "block" re-enables below
             enc.remat_blocks = False
         if isinstance(enabled, str):
@@ -292,7 +293,8 @@ class Optimizer:
             elif enabled == "block":
                 if not encs:
                     raise ValueError("remat='block' needs a model with "
-                                     "TransformerEncoder blocks")
+                                     "TransformerEncoder or HybridDecoder "
+                                     "blocks")
                 for enc in encs:
                     enc.remat_blocks = True
                 self._remat = False  # per-block checkpoints, no outer wrap
@@ -787,7 +789,26 @@ class LocalOptimizer(Optimizer):
             opt_state = self._init_opt_state(params)
         params, buffers, opt_state = self._place_state(params, buffers,
                                                        opt_state)
+        # The trainer works on its private copies; the model's own
+        # parameter arrays would sit in HBM beside them for the whole run
+        # (4 bytes a parameter, 2 GB for a 0.5B model). Keep them on the
+        # host until the trained values are loaded back at the end. The
+        # device arrays are not deleted, only let go of: one that a clone
+        # or user code still points at lives on.
+        model.load_parameter_tree(jax.tree_util.tree_map(
+            np.asarray, model.parameter_tree()))
+        try:
+            return self._train_loop(params, buffers, opt_state,
+                                    driver_state, marker)
+        except BaseException:
+            # left as it was found: device arrays (of the values it had)
+            model.load_parameter_tree(jax.tree_util.tree_map(
+                jnp.asarray, model.parameter_tree()))
+            raise
 
+    def _train_loop(self, params, buffers, opt_state, driver_state,
+                    marker) -> Module:
+        model = self.model
         step = self.step_fn = self._build_step()
         fwd = self._build_forward()
         uses_loss_any = (getattr(self.end_when, "uses_loss", False)

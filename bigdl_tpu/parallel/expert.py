@@ -30,18 +30,35 @@ GShard/Switch way for TPU:
 Tokens over capacity are dropped (their combine weight is zero and they
 pass through the residual connection unchanged when used inside a
 transformer block).
+
+``dispatch="held"`` is the dropless layer of an expert-parallel
+deployment seen from ONE of its chips: the router scores all
+``n_experts`` by a sigmoid, picks the top k of score + selection bias,
+renormalises the picked scores and scales them by ``route_scale`` (no
+auxiliary loss: ``aux_loss_weight`` is not read); the layer holds the
+experts whose ids ``held`` lists and computes the part of the result that
+those give, plus an always-on shared expert (``shared_hidden``); what the
+absent experts would add is the other chips' to compute and is not stood
+in for. The local picks are sorted by expert and the held experts run as
+one grouped product over row blocks (``_grouped_rows``): no capacity, no
+dropped token, and the work follows the rows that really landed here
+(rounded up to whole blocks an expert), not the static bound
+``T * min(k, len(held))``.
 """
 
 from __future__ import annotations
 
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from bigdl_tpu.nn import initialization as init
 from bigdl_tpu.nn.module import Module
+from bigdl_tpu.ops.remat import MOE_ROUTED_OUT
 from bigdl_tpu.parallel.mesh import EXPERT_AXIS
 
 
@@ -68,17 +85,38 @@ class MoE(Module):
     """Top-k gated mixture of expert FFNs (distributed ``MixtureTable``).
 
     Input (..., D) — leading axes are flattened into a token axis. Each
-    expert is a two-layer FFN D -> H -> D.
+    expert is a two-layer FFN D -> H -> D. ``held``, ``shared_hidden`` and
+    ``route_scale`` belong to ``dispatch="held"`` (module docstring).
     """
 
     def __init__(self, input_size: int, hidden_size: int, n_experts: int,
                  k: int = 2, capacity_factor: float = 1.25,
                  activation: str = "gelu", aux_loss_weight: float = 1e-2,
-                 dispatch: str = "sort"):
+                 dispatch: str = "sort", held=None, bias: bool = True,
+                 shared_hidden: int = 0, route_scale: float = 1.0):
         super().__init__()
-        if dispatch not in ("sort", "scatter", "einsum"):
-            raise ValueError(f"dispatch must be 'sort', 'scatter' or "
-                             f"'einsum', got {dispatch!r}")
+        if dispatch not in ("sort", "scatter", "einsum", "held"):
+            raise ValueError(f"dispatch must be 'sort', 'scatter', "
+                             f"'einsum' or 'held', got {dispatch!r}")
+        if activation not in ("gelu", "relu", "relu2"):
+            raise ValueError(f"unknown expert activation {activation!r}")
+        if dispatch != "held" and (held is not None or shared_hidden
+                                   or route_scale != 1.0):
+            raise ValueError("held, shared_hidden and route_scale belong to "
+                             "dispatch='held' (the capacity paths route by "
+                             "softmax over experts that are all here)")
+        # ids of the experts whose weights live here (all of them unless
+        # dispatch='held' names a share); the router is n_experts wide
+        # either way
+        self.held = tuple(range(n_experts)) if held is None \
+            else tuple(int(i) for i in held)
+        if (len(set(self.held)) != len(self.held) or not self.held
+                or min(self.held) < 0 or max(self.held) >= n_experts):
+            raise ValueError(f"held must list distinct expert ids in "
+                             f"[0, {n_experts}), got {held!r}")
+        self.bias = bias
+        self.shared_hidden = shared_hidden
+        self.route_scale = route_scale
         self.input_size = input_size
         self.hidden_size = hidden_size
         self.n_experts = n_experts
@@ -88,18 +126,105 @@ class MoE(Module):
         self.aux_loss_weight = aux_loss_weight
         self.dispatch = dispatch
         d, h, e = input_size, hidden_size, n_experts
+        n = len(self.held)
         self.register_parameter("gate_weight", init.xavier((d, e), d, e))
+        if dispatch == "held":
+            # selection bias: moves which experts are picked, never their
+            # weights; a buffer (no gradient), set by a balancing rule
+            self.register_buffer("select_bias", init.zeros((e,)))
         self.register_parameter(
-            "w1", np.stack([init.xavier((d, h), d, h) for _ in range(e)]))
-        self.register_parameter("b1", init.zeros((e, h)))
+            "w1", np.stack([init.xavier((d, h), d, h) for _ in range(n)]))
+        if bias:
+            self.register_parameter("b1", init.zeros((n, h)))
         self.register_parameter(
-            "w2", np.stack([init.xavier((h, d), h, d) for _ in range(e)]))
-        self.register_parameter("b2", init.zeros((e, d)))
+            "w2", np.stack([init.xavier((h, d), h, d) for _ in range(n)]))
+        if bias:
+            self.register_parameter("b2", init.zeros((n, d)))
+        if shared_hidden:
+            # an always-on expert of its own width beside the routed ones
+            hs = shared_hidden
+            self.register_parameter("shared_w1", init.xavier((d, hs), d, hs))
+            self.register_parameter("shared_w2", init.xavier((hs, d), hs, d))
+            if bias:
+                self.register_parameter("shared_b1", init.zeros((hs,)))
+                self.register_parameter("shared_b2", init.zeros((d,)))
 
     def _act(self, x):
-        return jax.nn.gelu(x) if self.activation == "gelu" else jax.nn.relu(x)
+        if self.activation == "gelu":
+            return jax.nn.gelu(x)
+        if self.activation == "relu2":
+            return jnp.square(jax.nn.relu(x))
+        return jax.nn.relu(x)
+
+    def _hidden(self, w, x):
+        """An expert's activations on its rows, before its output matrix:
+        ``w`` holds that expert's matrices under the stacked leaves' names
+        (``shared_`` stripped)."""
+        f32 = jnp.float32
+        cd = x.dtype
+        hid = jnp.dot(x, w["w1"].astype(cd), preferred_element_type=f32)
+        if "b1" in w:
+            hid = hid + w["b1"].astype(f32)
+        return self._act(hid).astype(cd)
+
+    def _ffn(self, w, x):
+        """One expert on its rows."""
+        return _project(w, self._hidden(w, x))
+
+    def _route(self, x):
+        """The held layer's router: (expert ids (T, k), combine weights
+        (T, k) float32) of every token over ALL n_experts."""
+        scores = jax.nn.sigmoid(
+            jnp.dot(x, self.gate_weight.astype(x.dtype),
+                    preferred_element_type=jnp.float32))
+        _, picked = jax.lax.top_k(
+            scores + jax.lax.stop_gradient(
+                self.select_bias.astype(jnp.float32)), self.k)
+        w = jnp.take_along_axis(scores, picked, axis=-1)
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+        return picked, w * self.route_scale
+
+    def _held_forward(self, input):
+        """The dropless layer over the experts held here."""
+        orig_shape = input.shape
+        d, e, k = self.input_size, self.n_experts, self.k
+        x = input.reshape(-1, d)
+        t = x.shape[0]
+        n = len(self.held)
+        with jax.named_scope("moe_route"):
+            picked, weight = self._route(x)
+            # local id of each pick: position of its expert in ``held``,
+            # n where the expert lives on another chip
+            local_of = np.full((e,), n, np.int32)
+            local_of[list(self.held)] = np.arange(n, dtype=np.int32)
+            lid = jnp.asarray(local_of)[picked.reshape(-1)]      # (T*k,)
+            rows = t * min(k, n)            # the picks that CAN land here
+            order = jnp.argsort(lid, stable=True)[:rows]
+            counts = jnp.bincount(lid, length=n + 1)[:n].astype(jnp.int32)
+            tok = (order // k).astype(jnp.int32)
+            gate = weight.reshape(-1)[order]
+        with jax.named_scope("moe_experts"):
+            routed = {p: v for p, v in self._parameters.items()
+                      if p in ("w1", "b1", "w2", "b2")}
+            y = _grouped_rows(self._hidden, routed, x, tok, gate, counts)
+            # kept across a block's rematerialisation
+            # (ops.remat.block_remat_policy): the loop runs once forward
+            y = checkpoint_name(y.astype(input.dtype), MOE_ROUTED_OUT) \
+                .astype(jnp.float32)
+        if self.shared_hidden:
+            with jax.named_scope("moe_shared"):
+                shared = {p[len("shared_"):]: v
+                          for p, v in self._parameters.items()
+                          if p.startswith("shared_")}
+                y = y + self._ffn(shared, x).astype(jnp.float32)
+        return y.astype(input.dtype).reshape(orig_shape)
 
     def update_output(self, input):
+        if self.dispatch == "held":
+            from bigdl_tpu.telemetry import get_registry, instruments
+            instruments(get_registry()).moe_dispatch_total.labels(
+                path="held").inc()
+            return self._held_forward(input)
         orig_shape = input.shape
         d, e, k = self.input_size, self.n_experts, self.k
         x = input.reshape(-1, d)
@@ -215,11 +340,13 @@ class MoE(Module):
                 dispatch_t = dispatch_t + dc
             xe = jnp.einsum("tec,td->ecd", dispatch_t, xc)  # (E, C, D)
 
-        hdn = self._act(jnp.einsum("ecd,edh->ech", xe,
-                                   self.w1.astype(cd))
-                        + self.b1.astype(cd)[:, None, :])
-        ye = (jnp.einsum("ech,ehd->ecd", hdn, self.w2.astype(cd))
-              + self.b2.astype(cd)[:, None, :])
+        hdn = jnp.einsum("ecd,edh->ech", xe, self.w1.astype(cd))
+        if self.bias:
+            hdn = hdn + self.b1.astype(cd)[:, None, :]
+        hdn = self._act(hdn)
+        ye = jnp.einsum("ech,ehd->ecd", hdn, self.w2.astype(cd))
+        if self.bias:
+            ye = ye + self.b2.astype(cd)[:, None, :]
 
         if self.dispatch in ("sort", "scatter"):
             # combine by (expert, slot) gather-back — same op order on
@@ -248,12 +375,163 @@ class MoE(Module):
         return y.reshape(orig_shape)
 
     def __repr__(self):
+        held = "" if len(self.held) == self.n_experts \
+            else f", held={len(self.held)}"
         return (f"MoE({self.input_size}->{self.hidden_size}, "
-                f"experts={self.n_experts}, k={self.k})")
+                f"experts={self.n_experts}{held}, k={self.k})")
+
+
+#: rows of one block of the grouped product. An expert's run of rows is cut
+#: into blocks of this many; its last block is padded past the run's end
+#: with rows whose pick weight is zero (they are multiplied like any row
+#: and add nothing). One block is one pass over that expert's matrices, one
+#: float32 read-modify-write of their gradients (125 us: as long as the
+#: block's products at 512 rows) and scatter-adds of its rows. Chosen by
+#: throughput on the v5e at 8,192 tokens, top-6 of 128, 8 held, about 384
+#: rows an expert (PERF.md section 6, PR 25): 512 rows 3.245 records/s,
+#: 1,024 rows 3.229, 2,048 rows 3.03. Telling the scatters that a block's
+#: token ids are sorted and unique cost 8%.
+_BLOCK_ROWS = 512
+
+
+def _block_table(counts, block, n_blocks_max):
+    """For block i of the sorted rows: (expert, first row, end of that
+    expert's run), and how many blocks there are. ``counts`` (n,) rows an
+    expert; runs lie back to back in expert order."""
+    offsets = jnp.cumsum(counts) - counts
+    per = (counts + block - 1) // block
+    last = jnp.cumsum(per)
+    i = jnp.arange(n_blocks_max, dtype=jnp.int32)
+    e = jnp.minimum(jnp.sum(i[:, None] >= last[None, :], axis=1),
+                    counts.shape[0] - 1).astype(jnp.int32)
+    start = offsets[e] + (i - (last[e] - per[e])) * block
+    return e, start.astype(jnp.int32), (offsets + counts)[e], last[-1]
+
+
+def _project(w, hid):
+    """An expert's output matrix (and bias) on its activations."""
+    out = jnp.dot(hid, w["w2"].astype(hid.dtype),
+                  preferred_element_type=jnp.float32)
+    if "b2" in w:
+        out = out + w["b2"].astype(jnp.float32)
+    return out.astype(hid.dtype)
+
+
+def _grouped_rows(hidden, weights, x, tok, gate, counts):
+    """sum over the sorted picks r of ``gate[r] * expert_r(x[tok[r]])``
+    scattered to row ``tok[r]`` -> (T, d) float32, an expert being
+    ``_project(w, hidden(w, x))``.
+
+    ``tok``/``gate`` (R,) hold the picks sorted by held expert, expert e's
+    run ``counts[e]`` long; entries past the runs are ignored. A loop whose
+    trip count is the number of blocks that hold real rows (at most one
+    partial block an expert): the rows of a block past its run's end are
+    multiplied with the rest and weigh zero in the result. The backward is
+    a second such loop: it recomputes a block's activations, takes
+    ``jax.vjp`` of ``hidden`` on them (so any activation or bias
+    differentiates) and the linear
+    output stage by hand (one product gives both the pick weights'
+    gradient and the activations'); weight gradients accumulate in
+    float32."""
+    block = min(_BLOCK_ROWS, -(-tok.shape[0] // 8) * 8)
+    n_max = tok.shape[0] // block + counts.shape[0]
+    pad = n_max * block - tok.shape[0]
+    tok = jnp.pad(tok, (0, pad))
+    gate = jnp.pad(gate, (0, pad))
+    return _grouped(hidden, block, n_max, weights, x, gate, tok, counts)
+
+
+def _block(i, table, block, tok, gate, weights):
+    """Block i: its expert, first row, which rows are the expert's own,
+    their tokens and pick weights (zero past the run's end), the expert's
+    matrices."""
+    e, start, end, _ = table
+    valid = start[i] + jnp.arange(block, dtype=jnp.int32) < end[i]
+    t_b = jax.lax.dynamic_slice(tok, (start[i],), (block,))
+    g_b = jnp.where(valid, jax.lax.dynamic_slice(gate, (start[i],),
+                                                 (block,)), 0.0)
+    w_e = {k: jax.lax.dynamic_index_in_dim(v, e[i], keepdims=False)
+           for k, v in weights.items()}
+    return e[i], start[i], valid, t_b, g_b, w_e
+
+
+def _grouped_fwd_loop(hidden, block, n_max, weights, x, gate, tok, counts):
+    table = _block_table(counts, block, n_max)
+
+    def body(i, out):
+        _, _, _, t_b, g_b, w_e = _block(i, table, block, tok, gate, weights)
+        y_b = _project(w_e, hidden(w_e, x[t_b])).astype(jnp.float32)
+        return out.at[t_b].add(g_b[:, None] * y_b)
+
+    return jax.lax.fori_loop(0, table[3], body,
+                             jnp.zeros(x.shape, jnp.float32))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _grouped(hidden, block, n_max, weights, x, gate, tok, counts):
+    return _grouped_fwd_loop(hidden, block, n_max, weights, x, gate, tok,
+                             counts)
+
+
+def _grouped_fwd(hidden, block, n_max, weights, x, gate, tok, counts):
+    out = _grouped_fwd_loop(hidden, block, n_max, weights, x, gate, tok,
+                            counts)
+    return out, (weights, x, gate, tok, counts)
+
+
+def _grouped_bwd(hidden, block, n_max, res, dout):
+    weights, x, gate, tok, counts = res
+    with jax.named_scope("moe_experts"):    # the forward's scope, by hand
+        return _grouped_bwd_loop(hidden, block, n_max, weights, x, gate,
+                                 tok, counts, dout)
+
+
+def _grouped_bwd_loop(hidden, block, n_max, weights, x, gate, tok, counts,
+                      dout):
+    table = _block_table(counts, block, n_max)
+    f32 = jnp.float32
+    first = [k for k in weights if k not in ("w2", "b2")]
+    has_b2 = "b2" in weights
+
+    def body(i, carry):
+        dw, dx, dgate = carry
+        e, start, valid, t_b, g_b, w_e = _block(i, table, block, tok, gate,
+                                                weights)
+        hid, vjp = jax.vjp(hidden, {k: w_e[k] for k in first}, x[t_b])
+        dy_b = dout[t_b].astype(f32)
+        # y = hid @ w2 (+ b2), out += g * y: dy @ w2^T serves both d(g)
+        # = <dy, y> = <dy @ w2^T, hid> (+ <dy, b2>) and d(hid) = g * it
+        back = jnp.dot(dy_b.astype(hid.dtype), w_e["w2"].astype(hid.dtype).T,
+                       preferred_element_type=f32)
+        dg_b = jnp.sum(back * hid.astype(f32), axis=-1)
+        gdy = (g_b[:, None] * dy_b).astype(hid.dtype)
+        add = {"w2": jnp.dot(hid.T, gdy, preferred_element_type=f32)}
+        if has_b2:
+            dg_b = dg_b + jnp.sum(dy_b * w_e["b2"].astype(f32), axis=-1)
+            add["b2"] = jnp.sum(gdy.astype(f32), axis=0)
+        d_first, dx_b = vjp((g_b[:, None] * back).astype(hid.dtype))
+        add.update(d_first)
+        dw = {k: dw[k].at[e].add(add[k].astype(f32)) for k in dw}
+        dx = dx.at[t_b].add(dx_b.astype(f32))
+        dgate = jax.lax.dynamic_update_slice(
+            dgate, jax.lax.dynamic_slice(dgate, (start,), (block,))
+            + jnp.where(valid, dg_b, 0.0), (start,))
+        return dw, dx, dgate
+
+    dw, dx, dgate = jax.lax.fori_loop(
+        0, table[3], body,
+        ({k: jnp.zeros(v.shape, f32) for k, v in weights.items()},
+         jnp.zeros(x.shape, f32), jnp.zeros(gate.shape, f32)))
+    return ({k: dw[k].astype(v.dtype) for k, v in weights.items()},
+            dx.astype(x.dtype), dgate.astype(gate.dtype), None, None)
+
+
+_grouped.defvjp(_grouped_fwd, _grouped_bwd)
 
 
 def expert_param_specs(moe: MoE, axis: str = EXPERT_AXIS):
-    """PartitionSpecs sharding the stacked expert leaves over ``expert``."""
-    return {"gate_weight": P(),
-            "w1": P(axis, None, None), "b1": P(axis, None),
-            "w2": P(axis, None, None), "b2": P(axis, None)}
+    """PartitionSpecs sharding the stacked expert leaves over ``expert``;
+    the router and a shared expert are replicated."""
+    stacked = {"w1": P(axis, None, None), "b1": P(axis, None),
+               "w2": P(axis, None, None), "b2": P(axis, None)}
+    return {name: stacked.get(name, P()) for name in moe._parameters}

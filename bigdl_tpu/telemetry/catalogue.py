@@ -192,13 +192,20 @@ METRIC_SPECS: List[MetricSpec] = [
     # ---- kernel dispatch (ops/int8_matmul.py, parallel/expert.py)
     MetricSpec("bigdl_moe_dispatch_total", "counter",
                "MoE forwards by dispatch formulation (path label: "
-               "sort / scatter / einsum). Counted once per eager call / "
+               "sort / scatter / einsum / held). Counted once per eager call / "
                "once per TRACE under jit — the branch runs at trace "
                "time, so this records which formulation each compiled "
                "MoE program uses, not per-step traffic. 'sort' (the "
                "round-10 default) replaces the k-fold one-hot+cumsum+"
                "scatter-add chains with one stable argsort plus "
-               "gathers.", ("path",)),
+               "gathers; 'held' is the dropless layer over the share of "
+               "the experts that lives on this chip.", ("path",)),
+    MetricSpec("bigdl_ssd_scan_total", "counter",
+               "Mamba-2 state-space scans by form (form label: chunked, "
+               "the one form ops/ssd_scan.py has). Counted once per eager "
+               "call / once per TRACE under jit, as "
+               "bigdl_moe_dispatch_total: which form each compiled "
+               "program holds, not per-step traffic.", ("form",)),
     MetricSpec("bigdl_int8_fallbacks_total", "counter",
                "int8_matmul decode-shaped calls that LOST the fused "
                "kernel because K is off the 128-lane quantum (XLA "
