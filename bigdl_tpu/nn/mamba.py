@@ -6,7 +6,9 @@ term, a per-head recurrent state of ``head_dim x state_size``.
     xBC = silu(causal_depthwise_conv(xBC, k) + b)
     x, B, C = split(xBC)                         (H x P | G x N | G x N)
     dt = softplus(dt + dt_bias);  a = -exp(A_log)
-    y = ssd_scan(x, dt, a, B, C) + D * x          (ops/ssd_scan.py)
+    y = ssd_scan(x, dt, a, B, C) + D * x          (ops/ssd_scan.py: Mosaic
+                                                 kernels on a TPU at shapes
+                                                 they tile, else XLA)
     y = GroupRMSNorm_G(y * silu(z)) * w
     out = y W_out
 

@@ -201,10 +201,11 @@ METRIC_SPECS: List[MetricSpec] = [
                "gathers; 'held' is the dropless layer over the share of "
                "the experts that lives on this chip.", ("path",)),
     MetricSpec("bigdl_ssd_scan_total", "counter",
-               "Mamba-2 state-space scans by form (form label: chunked, "
-               "the one form ops/ssd_scan.py has). Counted once per eager "
-               "call / once per TRACE under jit, as "
-               "bigdl_moe_dispatch_total: which form each compiled "
+               "Mamba-2 state-space scans by form (form label: kernel, "
+               "the Mosaic kernels of ops/ssd_scan.py, taken on a TPU at "
+               "shapes they tile; chunked, the XLA einsums, everywhere "
+               "else). Counted once per eager call / once per TRACE under "
+               "jit, as bigdl_moe_dispatch_total: which form each compiled "
                "program holds, not per-step traffic.", ("form",)),
     MetricSpec("bigdl_int8_fallbacks_total", "counter",
                "int8_matmul decode-shaped calls that LOST the fused "

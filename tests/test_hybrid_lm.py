@@ -18,6 +18,7 @@ from benchmark.builders import nemotron_h as builder
 from benchmark.reference import nemotron_h as reference
 from bigdl_tpu import nn
 from bigdl_tpu.nn.module import functional_apply
+from bigdl_tpu.ops import ssd_scan as scan_module
 from bigdl_tpu.ops.ssd_scan import ssd_scan
 from bigdl_tpu.parallel import expert
 from bigdl_tpu.parallel.expert import MoE, expert_param_specs
@@ -90,6 +91,92 @@ def test_chunked_scan_matches_the_per_token_recurrence(chunk, length):
         _close(g, w)
 
 
+def _tile_inputs(length, dtype, seed=0, heads=4, groups=2, head_dim=64,
+                 state=128):
+    """The cell's tile (chunk 128, state 128, head 64) at two groups of two
+    heads; decays fast and slow, operands in ``dtype``."""
+    rng = _rng(seed)
+    x = _normal(rng, 1, length, heads, head_dim).astype(dtype)
+    dt = jax.nn.softplus(_normal(rng, 1, length, heads) - 2.0)
+    a = -jnp.exp(_normal(rng, heads, scale=0.5))
+    b = _normal(rng, 1, length, groups, state, scale=0.3).astype(dtype)
+    c = _normal(rng, 1, length, groups, state, scale=0.3).astype(dtype)
+    return x, dt, a, b, c
+
+
+def _kernel_form(*args):
+    return scan_module._ssd_kernel(*args, 128, interpret=True)
+
+
+def _xla_form(*args):
+    return scan_module._ssd_chunked(*args, 128)
+
+
+@pytest.fixture(scope="module")
+def tile_results():
+    """{(dtype name, length): forward and five gradients of the kernel form
+    (Pallas' interpreter), of the XLA form and of the per-token recurrence
+    in float32 on the same (rounded) inputs}, computed once a case."""
+    cache = {}
+
+    def get(dtype, length):
+        key = (jnp.dtype(dtype).name, length)
+        if key not in cache:
+            args = _tile_inputs(length, dtype)
+            probe = _normal(_rng(9), *args[0].shape)
+            wide = [t.astype(jnp.float32) for t in args]
+            out = {}
+            for name, form, inp in (
+                    ("kernel", _kernel_form, args),
+                    ("xla", _xla_form, args),
+                    ("recurrence", reference.selective_scan, wide)):
+                def probed(*t, form=form):
+                    return jnp.sum(form(*t).astype(jnp.float32) * probe)
+                out[name] = (form(*inp),) + jax.grad(
+                    probed, argnums=range(5))(*inp)
+            cache[key] = out
+        return cache[key]
+    return get
+
+
+@pytest.mark.parametrize("oracle", ["xla", "recurrence"])
+@pytest.mark.parametrize("what", ["y", "dx", "ddt", "da", "dB", "dC"])
+@pytest.mark.parametrize("length", [256, 300])
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
+                                       (jnp.bfloat16, 2e-2)])
+def test_scan_kernels_match_the_xla_form_and_the_recurrence(
+        tile_results, dtype, tol, length, what, oracle):
+    """The four Mosaic kernels (Pallas' interpreter on a CPU) at the cell's
+    tile, a whole number of chunks (256) and not (300): the forward and
+    each of the five gradients against the XLA form, which they replace on
+    the chip, and against the per-token recurrence."""
+    got = tile_results(dtype, length)
+    i = ["y", "dx", "ddt", "da", "dB", "dC"].index(what)
+    assert got["kernel"][i].dtype == got["xla"][i].dtype
+    _close(np.asarray(got["kernel"][i], np.float32),
+           np.asarray(got[oracle][i], np.float32), tol=tol)
+
+
+def test_scan_kernels_run_the_carry_that_is_planted(monkeypatch):
+    """The carry stays a seam: with ``_chunk_states`` replaced by name (the
+    ``bf16_scan_state`` control) the kernel form runs it, forward and
+    backward, and its numbers move (from the third chunk on: the second
+    starts from one chunk's own state, which the read-out rounds anyway)."""
+    args = _tile_inputs(512, jnp.bfloat16)
+
+    def loss(*t):
+        return jnp.sum(_kernel_form(*t).astype(jnp.float32) ** 2)
+
+    sound = jax.grad(loss, argnums=(0, 2))(*args)
+    monkeypatch.setattr(scan_module, "_chunk_states",
+                        builder._bf16_chunk_states)
+    planted = jax.grad(loss, argnums=(0, 2))(*args)
+    for s_, p_ in zip(sound, planted):
+        assert np.isfinite(np.asarray(p_, np.float32)).all()
+        assert np.abs(np.asarray(s_, np.float32)
+                      - np.asarray(p_, np.float32)).max() > 0
+
+
 def _scan_with_bf16_state(x, dt, a, b, c):
     """The per-token recurrence with the state ROUNDED to bf16 after every
     token: what carrying it in the compute dtype would give."""
@@ -112,26 +199,72 @@ def _scan_with_bf16_state(x, dt, a, b, c):
     return jnp.moveaxis(y, 0, 1)
 
 
-def test_scan_accumulates_its_state_in_float32_under_bf16_operands():
+@pytest.mark.parametrize("form,head_dim,state,chunk", [
+    ("chunked", 8, 8, 64), ("kernel", 64, 128, 128)])
+def test_scan_accumulates_its_state_in_float32_under_bf16_operands(
+        form, head_dim, state, chunk):
     """Slow decays over 2,048 tokens, bf16 x, B and C: small increments to
-    a large state. The chunked scan stays within 1% of the float32
-    recurrence on the same (rounded) inputs; a state carried in bf16 is
-    several times further off (12x in the maximum, when written)."""
+    a large state. Either form of the chunked scan stays within 1% of the
+    float32 recurrence on the same (rounded) inputs; a state carried in bf16
+    is several times further off (12x in the maximum, when written)."""
     rng = _rng(3)
     length = 2048
     x, b, c = (_normal(rng, 1, length, *shape).astype(jnp.bfloat16)
-               for shape in ((2, 8), (1, 8), (1, 8)))
+               for shape in ((2, head_dim), (1, state), (1, state)))
     dt = jnp.full((1, length, 2), 0.05, jnp.float32)
     a = jnp.asarray([-0.02, -0.05], jnp.float32)
     f32 = [t.astype(jnp.float32) for t in (x, b, c)]
     want = np.asarray(reference.selective_scan(f32[0], dt, a, *f32[1:]))
-    got = ssd_scan(x, dt, a, b, c, chunk=64)
+    if form == "kernel":
+        got = scan_module._ssd_kernel(x, dt, a, b, c, chunk, interpret=True)
+    else:
+        got = ssd_scan(x, dt, a, b, c, chunk=chunk)
     assert got.dtype == jnp.bfloat16
     low = np.asarray(_scan_with_bf16_state(f32[0], dt, a, *f32[1:]))
     err = np.abs(np.asarray(got, np.float32) - want).max()
     err_low = np.abs(low - want).max()
     assert err < 0.01 * np.abs(want).max()
     assert err_low > 5 * err
+
+
+@pytest.mark.parametrize("backend,chunk,head_dim,state,per_group,kernel", [
+    ("tpu", 128, 64, 128, 8, True),     # the Nemotron cell
+    ("tpu", 128, 128, 256, 1, True),    # a head that is a whole lane tile
+    ("tpu", 128, 64, 128, 2, True),     # the tests' tile
+    ("cpu", 128, 64, 128, 8, False),    # the cell's CPU rehearsal, tier-1
+    ("gpu", 128, 64, 128, 8, False),
+    ("tpu", 8, 8, 8, 2, False),         # tier-1's small shapes
+    ("tpu", 64, 64, 128, 8, False),     # a chunk under a lane tile
+    ("tpu", 256, 64, 128, 8, False),    # and one over it
+    ("tpu", 128, 64, 128, 16, False),   # more heads a group than 8
+    ("tpu", 128, 64, 16, 8, False),     # a state of 16
+    ("tpu", 128, 32, 128, 8, False),    # a head of 32
+    ("tpu", 128, 64, 128, 1, False),    # one head of 64: half a lane tile
+])
+def test_which_scans_take_the_kernels(backend, chunk, head_dim, state,
+                                      per_group, kernel):
+    """The path rule as the docstring states it: backend and shapes."""
+    assert scan_module.takes_kernel(backend, chunk, head_dim, state,
+                                    per_group) is kernel
+
+
+def test_the_rehearsal_and_a_cpu_take_the_xla_form(monkeypatch):
+    """The cell's rehearsal shapes take the XLA form even on a TPU, and on
+    this CPU the cell's own tile does: ``form="chunked"`` counts it."""
+    from bigdl_tpu.telemetry import get_registry, instruments
+    _, cfg = harness.load_cell("nemotron-3-nano-30b-a3b-train-s8192",
+                               rehearse=True)
+    assert not scan_module.takes_kernel(
+        "tpu", cfg["chunk_size"], cfg["mamba_head_dim"],
+        cfg["ssm_state_size"], cfg["mamba_num_heads"] // cfg["n_groups"])
+    ins = instruments(get_registry())
+    before = {f: ins.ssd_scan_total.labels(form=f).value
+              for f in ("chunked", "kernel")}
+    monkeypatch.setattr(scan_module, "_ssd_kernel", None)   # must not run
+    ssd_scan(*_tile_inputs(128, jnp.float32), chunk=128)
+    assert ins.ssd_scan_total.labels(form="chunked").value \
+        == before["chunked"] + 1
+    assert ins.ssd_scan_total.labels(form="kernel").value == before["kernel"]
 
 
 # ---------------------------------------------------------------- the mixers
@@ -446,7 +579,6 @@ def test_a_fault_planted_in_the_system_goes_through_the_reference_check(
 
 def test_a_planted_fault_is_taken_out_again(cut):
     from bigdl_tpu.nn.mamba import Mamba2
-    from bigdl_tpu.ops import ssd_scan as scan_module
     _, _, model = cut
     mixers = [m for m in model.modules() if isinstance(m, (Mamba2, MoE))]
     route, states = MoE._route, scan_module._chunk_states
@@ -574,6 +706,70 @@ def test_scopes_and_counters_of_the_new_layers(cut):
     whiles = [n for n in timeline.scope_instructions(hlo, "moe_experts")
               if n.startswith("while")]
     assert len(whiles) >= 2
+
+
+def _scan_and_its_backward(x, dt, a, b, c, ct):
+    y, back = jax.vjp(lambda *t: ssd_scan(*t, chunk=128), x, dt, a, b, c)
+    return y, back(ct)
+
+
+def test_scope_and_counter_of_the_scan_kernels(monkeypatch):
+    """With the kernel path forced on this CPU (the kernels in Pallas'
+    interpreter): ``form="kernel"`` counts it, and EVERY instruction of the
+    compiled scan and its backward is under the scope ``ssd_scan``, the four
+    calls' own included: a ``custom_vjp`` rule is traced outside the
+    forward's name stack and enters the scope by hand."""
+    from bigdl_tpu.telemetry import get_registry, instruments
+    ins = instruments(get_registry())
+    before = {f: ins.ssd_scan_total.labels(form=f).value
+              for f in ("chunked", "kernel")}
+    monkeypatch.setattr(scan_module, "takes_kernel", lambda *a: True)
+    args = _tile_inputs(256, jnp.float32)
+    hlo = jax.jit(_scan_and_its_backward).lower(
+        *args, args[0]).compile().as_text()
+    assert ins.ssd_scan_total.labels(form="kernel").value \
+        == before["kernel"] + 1
+    assert ins.ssd_scan_total.labels(form="chunked").value \
+        == before["chunked"]
+    found = timeline.scope_instructions(hlo, "ssd_scan")
+    # an op_name that is a path; a reducer's body and a bitcast of an
+    # argument carry a bare word ("reduce_sum", "dt")
+    named = [m for m in map(timeline._INSTR.match, hlo.splitlines())
+             if m and m.group(2).startswith("jit(")]
+    assert len(named) > 500
+    assert [m.group(1) for m in named if m.group(1) not in found] == []
+    for call in ("ssd_fwd_state", "ssd_fwd_out", "ssd_bwd_out",
+                 "ssd_bwd_state"):
+        assert any(f"/{call}/" in m.group(2) for m in named), call
+
+
+def test_the_scan_kernels_on_a_tpu_are_named_and_no_flash_call(monkeypatch):
+    """Lowered for a TPU at the Nemotron cell's shape (no chip needed): four
+    Mosaic calls, named ``ssd_*`` (the ledger's ``mosaic:ssd_*``), none
+    with an operand or result of the shape by which the flash readers know
+    a flash call in that cell (``builders/nemotron_h.flash_shape``)."""
+    import re
+    cell, cfg = harness.load_cell("nemotron-3-nano-30b-a3b-train-s8192")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    bsz, length = cell["batch_size"], cell["seq_len"]
+    h, p = cfg["mamba_num_heads"], cfg["mamba_head_dim"]
+    g, n = cfg["n_groups"], cfg["ssm_state_size"]
+    bf16 = jnp.bfloat16
+    x = jax.ShapeDtypeStruct((bsz, length, h, p), bf16)
+    bc = jax.ShapeDtypeStruct((bsz, length, g, n), bf16)
+    text = jax.jit(_scan_and_its_backward).trace(
+        x, jax.ShapeDtypeStruct((bsz, length, h), jnp.float32),
+        jax.ShapeDtypeStruct((h,), jnp.float32), bc, bc, x
+    ).lower(lowering_platforms=("tpu",)).as_text()
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert sorted(re.search(r'kernel_name = "(\w+)"', c).group(1)
+                  for c in calls) == ["ssd_bwd_out", "ssd_bwd_state",
+                                      "ssd_fwd_out", "ssd_fwd_state"]
+    fb, fh, fs, fd = builder.flash_shape(cfg, cell)
+    for c in calls:
+        types = c.rsplit(" : ", 1)[1]
+        assert f"tensor<{fb * fh}x{fs}x{fd}x" not in types
+        assert f"tensor<{bsz}x{length}x{h * p}xbf16>" in types
 
 
 # ------------------------------------------------- the optimizer's one copy
