@@ -287,11 +287,6 @@ def _train(args) -> None:
     opt.set_optim_method(SGD(learningrate=0.01))
     opt.set_precision(DtypePolicy.bf16())
     opt.set_end_when(Trigger.max_iteration(args.iterations))
-    if args.stepsPerDispatch > 1:
-        # K-fused dispatch: stack K real batches per device dispatch —
-        # amortizes the per-dispatch host cost exactly like the
-        # synthetic benches (bench.py K=60)
-        opt.set_steps_per_dispatch(args.stepsPerDispatch)
 
     rates = []
 
@@ -355,7 +350,6 @@ def main(argv=None) -> None:
                     "(nn.InputNormalize): 4x fewer host->device bytes")
     ap.add_argument("--iterations", "-i", type=int, default=10)
     ap.add_argument("--warmup", type=int, default=2)
-    ap.add_argument("--stepsPerDispatch", "-k", type=int, default=1)
     args = ap.parse_args(argv)
     {"generate": _gen, "read": _read, "decode": _decode,
      "train": _train, "pipeline": _pipeline_mode}[args.mode](args)

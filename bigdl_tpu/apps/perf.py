@@ -111,9 +111,6 @@ def main(argv=None) -> None:
     ap.add_argument("--precision", choices=("fp32", "bf16"), default="bf16")
     ap.add_argument("--distributed", action="store_true",
                     help="DistriOptimizer over all visible devices")
-    ap.add_argument("--stepsPerDispatch", "-k", type=int, default=1,
-                    help="fuse K iterations per jitted dispatch "
-                    "(set_steps_per_dispatch; local runs only)")
     ap.add_argument("--optim", choices=("sgd", "adamw"), default="sgd",
                     help="adamw: the transformer-LM optimizer (lr 1e-4)")
     ap.add_argument("--optStateDtype", choices=("fp32", "bf16"),
@@ -158,9 +155,7 @@ def main(argv=None) -> None:
         moe_experts=args.moeExperts, moe_dispatch=args.moeDispatch)
 
     rng = np.random.RandomState(0)
-    # enough records that a K-fused window fits inside one epoch (epoch
-    # boundaries bound dispatch windows)
-    n_records = args.batchSize * max(2, args.stepsPerDispatch)
+    n_records = args.batchSize * 2
     if args.dataType == "constant":
         feats = [np.ones(shape, np.float32) for _ in range(n_records)]
     elif int_vocab:  # 1-based token indices (LookupTable input)
@@ -221,8 +216,6 @@ def main(argv=None) -> None:
         opt.set_optim_method(SGD(learningrate=0.01))
     if args.remat != "none":
         opt.set_remat(True if args.remat == "full" else args.remat)
-    if args.stepsPerDispatch > 1:
-        opt.set_steps_per_dispatch(args.stepsPerDispatch)
     if args.precision == "bf16":
         opt.set_precision(DtypePolicy.bf16())
     total_iters = args.warmup + args.iteration
@@ -254,12 +247,7 @@ def main(argv=None) -> None:
             k: stats[k] for k in ("bytes_in_use", "peak_bytes_in_use",
                                   "bytes_limit", "largest_alloc_size")
             if k in stats}}), file=sys.stderr)
-    # a K-fused window spreads its dispatch time over K per-iteration
-    # entries: the first (compile-bearing) window must be excluded WHOLE or
-    # its tail contaminates the steady state (measured: 1554 vs the true
-    # 2308 rec/s at K=5)
-    warmup_eff = max(args.warmup, 2 * args.stepsPerDispatch)
-    steady = recorder.throughputs[warmup_eff:]
+    steady = recorder.throughputs[args.warmup:]
     print(json.dumps({
         "harness": "perf", "model": args.model, "batch": args.batchSize,
         "iterations": args.iteration, "wall_s": round(wall, 3),

@@ -35,11 +35,8 @@ from bigdl_tpu.utils.rng import RandomGenerator
 class CachedSliceBatch:
     """Lazy MiniBatch: indices into the device cache, gathered on access.
 
-    ``data``/``labels`` are properties so the single-dispatch path is
-    transparent (``jnp.asarray(batch.data)`` triggers the gather), while the
-    K-fused dispatch path (``set_steps_per_dispatch``) reads ``.idx`` and
-    performs the gathers INSIDE the jitted multi-step — one dispatch per
-    window instead of one per gather."""
+    ``data``/``labels`` are properties, so ``jnp.asarray(batch.data)``
+    triggers the gather."""
 
     __slots__ = ("source", "idx")
 

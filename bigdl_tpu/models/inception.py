@@ -68,26 +68,7 @@ def build(class_num: int = 1000) -> nn.Sequential:
 
 def _conv_bn(n_in, n_out, kw, kh, sw=1, sh=1, pw=0, ph=0, name=""):
     """conv -> BN(eps=1e-3) -> ReLU triple used throughout Inception v2
-    (reference ``Inception_v2.scala`` Inception_Layer_v2). 1x1 pairs
-    collapse into the Pallas-fused module under ``BIGDL_TPU_FUSED_1X1=1``
-    (same opt-in as the ResNet builder; see PERF.md)."""
-    from bigdl_tpu.nn.fused import (FusedConv1x1BN, FusedConv3x3BN,
-                                    use_fused_1x1, use_fused_3x3)
-    if (kw, kh, pw, ph) == (1, 1, 0, 0) and sw == sh and use_fused_1x1():
-        # with_bias: the unfused pair's conv carries a bias (reference
-        # default) — keep the parameter schema identical across the flag
-        return (nn.Sequential()
-                .add(FusedConv1x1BN(n_in, n_out, sw, eps=1e-3,
-                                    init_method="xavier",
-                                    with_bias=True).set_name(name))
-                .add(nn.ReLU(True)))
-    if ((kw, kh, pw, ph, sw, sh) == (3, 3, 1, 1, 1, 1)
-            and use_fused_3x3()):
-        return (nn.Sequential()
-                .add(FusedConv3x3BN(n_in, n_out, eps=1e-3,
-                                    init_method="xavier",
-                                    with_bias=True).set_name(name))
-                .add(nn.ReLU(True)))
+    (reference ``Inception_v2.scala`` Inception_Layer_v2)."""
     if (kw, kh, sw, sh, pw, ph) == (7, 7, 2, 2, 3, 3):
         # ImageNet stem: space-to-depth form (PERF.md round 3)
         return (nn.Sequential()

@@ -26,26 +26,7 @@ def _conv(n_in, n_out, k, stride=1, pad=0):
                                  with_bias=False, init_method="kaiming")
 
 
-
-
-def _use_fused_1x1() -> bool:
-    from bigdl_tpu.nn.fused import use_fused_1x1
-    return use_fused_1x1()
-
-
 def _add_conv_bn(seq, n_in, n_out, k, stride=1, pad=0):
-    """conv(+BN) pair; 1x1 pairs collapse into the Pallas-fused module when
-    ``BIGDL_TPU_FUSED_1X1=1``, stride-1 3x3 pairs when
-    ``BIGDL_TPU_FUSED_3X3=1`` (opt-in pending the on-chip A/B — see PERF.md;
-    note the fused modules change parameter-tree naming, so checkpoints are
-    not interchangeable across the flags)."""
-    if k == 1 and pad == 0 and _use_fused_1x1():
-        from bigdl_tpu.nn.fused import FusedConv1x1BN
-        return seq.add(FusedConv1x1BN(n_in, n_out, stride))
-    if k == 3 and pad == 1 and stride == 1:
-        from bigdl_tpu.nn.fused import FusedConv3x3BN, use_fused_3x3
-        if use_fused_3x3():
-            return seq.add(FusedConv3x3BN(n_in, n_out))
     return (seq.add(_conv(n_in, n_out, k, stride, pad))
             .add(nn.SpatialBatchNormalization(n_out)))
 

@@ -16,14 +16,6 @@ _IMAGENET_CFG = {
 
 
 def _conv_bn_relu(model, n_in, n_out):
-    from bigdl_tpu.nn.fused import FusedConv3x3BN, use_fused_3x3
-    if use_fused_3x3():
-        # every VGG conv is a stride-1 3x3+BN pair: the whole conv stack
-        # rides the one-pass Pallas conv+stats kernel under the flag
-        (model.add(FusedConv3x3BN(n_in, n_out, init_method="kaiming",
-                                  with_bias=True))
-              .add(nn.ReLU(True)))
-        return n_out
     (model.add(nn.SpatialConvolution(n_in, n_out, 3, 3, 1, 1, 1, 1,
                                      init_method="kaiming"))
           .add(nn.SpatialBatchNormalization(n_out))

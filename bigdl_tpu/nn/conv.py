@@ -105,18 +105,12 @@ class SpatialShareConvolution(SpatialConvolution):
 
 def stem_conv7(n_in: int, n_out: int, with_bias: bool = True,
                init_method: str = "default", name: str = ""):
-    """Factory for the 7x7/s2/p3 ImageNet stem: SpaceToDepthConv7 (the
-    measured-faster packed form) unless ``BIGDL_TPU_NO_S2D=1`` restores the
-    plain SpatialConvolution. Both share one parameter schema
-    ("weight" (7,7,C,O) [+ "bias"]), so checkpoints interchange."""
-    import os
-    if os.environ.get("BIGDL_TPU_NO_S2D"):
-        mod = SpatialConvolution(n_in, n_out, 7, 7, 2, 2, 3, 3,
-                                 with_bias=with_bias,
-                                 init_method=init_method)
-    else:
-        mod = SpaceToDepthConv7(n_in, n_out, with_bias=with_bias,
-                                init_method=init_method)
+    """Factory for the 7x7/s2/p3 ImageNet stem: SpaceToDepthConv7, the
+    measured-faster packed form. Its parameter schema is the plain
+    SpatialConvolution's ("weight" (7,7,C,O) [+ "bias"]), so checkpoints
+    interchange."""
+    mod = SpaceToDepthConv7(n_in, n_out, with_bias=with_bias,
+                            init_method=init_method)
     return mod.set_name(name) if name else mod
 
 

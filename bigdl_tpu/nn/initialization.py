@@ -52,8 +52,8 @@ def bilinear_filler(shape: Sequence[int]) -> np.ndarray:
 
 def conv_weight(method: str, shape: Sequence[int], fan_in: int,
                 fan_out: int) -> np.ndarray:
-    """Conv-weight init dispatch shared by SpatialConvolution and the fused
-    conv modules ("xavier" | "kaiming" | "default")."""
+    """Conv-weight init dispatch shared by SpatialConvolution and the
+    packed stem ("xavier" | "kaiming" | "default")."""
     if method == "xavier":
         return xavier(shape, fan_in, fan_out)
     if method == "kaiming":

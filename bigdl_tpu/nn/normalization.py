@@ -23,20 +23,6 @@ from bigdl_tpu.nn import initialization as init
 from bigdl_tpu.nn.module import TensorModule
 
 
-def blend_running_stats(module, mean, var, n: int, momentum: float) -> None:
-    """Shared running-stat update (BatchNormalization and the fused
-    conv+BN module): unbiased-variance correction, stop_gradient (stats
-    feed buffers only, never the loss), momentum blend. The functional
-    buffer assignment is collected by ``functional_apply``."""
-    unbiased = var * (n / max(1, n - 1))
-    mean = jax.lax.stop_gradient(mean)
-    unbiased = jax.lax.stop_gradient(unbiased)
-    module.running_mean = ((1 - momentum) * module.running_mean
-                           + momentum * mean)
-    module.running_var = ((1 - momentum) * module.running_var
-                          + momentum * unbiased)
-
-
 class BatchNormalization(TensorModule):
     """Batch norm over (N, C) inputs (reference ``nn/BatchNormalization.scala:50``)."""
 
@@ -62,8 +48,16 @@ class BatchNormalization(TensorModule):
                 gamma = jnp.ones((self.n_output,), input.dtype)
                 beta = jnp.zeros((self.n_output,), input.dtype)
             out, mean, var = batch_norm_train(input, gamma, beta, self.eps)
+            # running stats: unbiased variance, momentum blend; they feed
+            # buffers only, never the loss. functional_apply collects the
+            # assignment.
             n = input.size // input.shape[-1]
-            blend_running_stats(self, mean, var, n, self.momentum)
+            mean = jax.lax.stop_gradient(mean)
+            unbiased = jax.lax.stop_gradient(var * (n / max(1, n - 1)))
+            self.running_mean = ((1 - self.momentum) * self.running_mean
+                                 + self.momentum * mean)
+            self.running_var = ((1 - self.momentum) * self.running_var
+                                + self.momentum * unbiased)
             return out
         mean, var = self.running_mean, self.running_var
         inv = jax.lax.rsqrt(var + self.eps)

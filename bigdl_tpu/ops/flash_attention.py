@@ -49,15 +49,12 @@ backprop through cross-device online-softmax combines.
 
 On CPU the kernels run in Pallas interpret mode (tests); dispatch via
 ``use_flash`` selects the kernel on real TPU backends.
-``BIGDL_TPU_FLASH_XLA_BWD=1`` falls back to the recompute-via-XLA backward
-(A/B lever; it was the only backward before round 3).
 """
 
 from __future__ import annotations
 
 import functools
 import math
-import os
 from typing import Optional, Tuple
 
 import jax
@@ -211,8 +208,8 @@ def _flash_fwd_lse(q, k, v, causal, scale, block_q, block_k, interpret):
     b, sq, n, d = q.shape
     sk = k.shape[1]
     default = _fwd_block(sq, sk, d, q.dtype.itemsize)
-    block_q = min(_block(block_q, "Q", default), sq)
-    block_k = min(_block(block_k, "K", default), sk)
+    block_q = min(block_q or default, sq)
+    block_k = min(block_k or default, sk)
     # BSND -> (B*N, S, D): one grid row per (batch, head).
     qt = q.transpose(0, 2, 1, 3).reshape(b * n, sq, d)
     kt = k.transpose(0, 2, 1, 3).reshape(b * n, sk, d)
@@ -379,8 +376,8 @@ def _flash_bwd(q, k, v, o, lse, g_o, g_l, causal, scale, block_q, block_k,
                interpret):
     b, sq, n, d = q.shape
     sk = k.shape[1]
-    block_q = min(_block(block_q, "Q", _BLOCK), sq)
-    block_k = min(_block(block_k, "K", _BLOCK), sk)
+    block_q = min(block_q or _BLOCK, sq)
+    block_k = min(block_k or _BLOCK, sk)
     qt = q.transpose(0, 2, 1, 3).reshape(b * n, sq, d)
     kt = k.transpose(0, 2, 1, 3).reshape(b * n, sk, d)
     vt = v.transpose(0, 2, 1, 3).reshape(b * n, sk, d)
@@ -473,15 +470,6 @@ def _flash_lse_vjp_fwd(q, k, v, causal, scale, block_q, block_k, interpret):
 def _flash_lse_vjp_bwd(causal, scale, block_q, block_k, interpret, res, g):
     g_o, g_l = g
     q, k, v, o, lse = res
-    if os.environ.get("BIGDL_TPU_FLASH_XLA_BWD"):
-        # Pre-round-3 recompute path (A/B lever). Has no LSE cotangent
-        # plumbing — valid only when nothing consumes lse downstream.
-        from bigdl_tpu.ops.attention_core import blockwise_attention
-        f = lambda q_, k_, v_: blockwise_attention(
-            q_, k_, v_, causal=causal, scale=scale,
-            block_size=_block(block_k, "K", _BLOCK))
-        _, vjp = jax.vjp(jax.checkpoint(f), q, k, v)
-        return vjp(g_o)
     return _flash_bwd(q, k, v, o, lse, g_o, g_l, causal, scale,
                       block_q, block_k, interpret)
 
@@ -523,19 +511,6 @@ def _fwd_block(sq: int, sk: int, d: int, itemsize: int) -> int:
     if sq == sk and sk % big == 0 and vmem <= _VMEM_BUDGET:
         return big
     return _BLOCK
-
-
-def _block(given: Optional[int], axis: str, default: int) -> int:
-    """The caller's block, else ``BIGDL_TPU_FLASH_BLOCK_Q`` /
-    ``BIGDL_TPU_FLASH_BLOCK_K`` (on-chip tuning without code edits), else
-    the kernel's measured default."""
-    if given is not None:
-        return given
-    try:
-        return int(os.environ.get("BIGDL_TPU_FLASH_BLOCK_" + axis, "")
-                   or default)
-    except ValueError:
-        return default
 
 
 def flash_attention(q, k, v, causal: bool = False,
@@ -584,8 +559,6 @@ def use_flash(q, mask) -> bool:
     since the kernels take bf16 operands (PR 24): the benchmark has no cell
     below seq 2048; what they cost there is in PERF.md section 5.
     """
-    if os.environ.get("BIGDL_TPU_DISABLE_FLASH"):
-        return False
     if mask is not None:
         return False
     if jax.default_backend() != "tpu":

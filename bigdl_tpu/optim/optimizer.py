@@ -194,7 +194,6 @@ class Optimizer:
         self._profile: Optional[Tuple[str, int, int]] = None
         self._remat = False
         self._grad_clip = {}
-        self._steps_per_dispatch = 1
         self._eval_cache = {}  # validation scorer jit, traced once
         #: the tracked ``train.step`` of the latest ``optimize()``, kept for
         #: inspection after the run (compile events, program text)
@@ -330,25 +329,6 @@ class Optimizer:
     def disable_gradient_clipping(self) -> "Optimizer":
         """reference ``Optimizer.disableGradientClipping`` (clears both)."""
         self._grad_clip = {}
-        return self
-
-    def set_steps_per_dispatch(self, k: int) -> "Optimizer":
-        """Fuse up to ``k`` training iterations into ONE jitted dispatch
-        (``lax.scan`` over stacked batches) — amortizes per-dispatch host
-        overhead the way the bench harness's K-step fusion does, while
-        keeping per-iteration logs exact (the k losses come back as an
-        array).
-
-        Windows never cross a trigger firing: before extending a window
-        past iteration m, the validation/checkpoint/summary/end triggers
-        are probed at ``neval = m+1`` and a firing bounds the window, so
-        hooks always run against the params of the iteration they follow.
-        Built-in trigger factories are pure under this probing (windows
-        never span epoch boundaries); loss-based triggers force k=1.
-        Local (single-program) training only — DistriOptimizer ignores it."""
-        if int(k) < 1:
-            raise ValueError(f"steps_per_dispatch must be >= 1, got {k}")
-        self._steps_per_dispatch = int(k)
         return self
 
     def set_precision(self, policy) -> "Optimizer":
@@ -555,10 +535,6 @@ class Optimizer:
 class LocalOptimizer(Optimizer):
     """Single-chip training loop (reference ``optim/LocalOptimizer.scala:39``)."""
 
-    #: K-fused dispatch works on the single-program path; DistriOptimizer
-    #: overrides to False (stacking sharded batches would break placements)
-    supports_multi_dispatch = True
-
     # Subclass hooks (DistriOptimizer overrides for mesh placement/sharding).
     def _place_batch(self, batch: MiniBatch):
         return jnp.asarray(batch.data), jnp.asarray(batch.labels)
@@ -594,64 +570,6 @@ class LocalOptimizer(Optimizer):
         # and yields the program's cost analysis — the FLOPs numerator of
         # the live bigdl_train_mfu gauge (telemetry/profiling.py)
         return tracked_jit(step, site="train.step", donate_argnums=(0, 1, 2))
-
-    def _build_multi_step(self) -> Callable:
-        """K fused iterations per dispatch (``set_steps_per_dispatch``):
-        ``lax.scan`` over leading-axis-stacked (keys, data, labels); returns
-        the K per-iteration losses so logging stays exact."""
-        model, criterion, optim = self.model, self.criterion, self.optim_method
-        reg_pairs = _regularizer_pairs(model)
-        policy = self.precision
-        remat = self._remat
-
-        clip = make_grad_clipper(self._grad_clip)
-
-        def multi(params, buffers, opt_state, keys, datas, labels):
-            def body(carry, inp):
-                p, b, o = carry
-                key, x, y = inp
-                loss_fn = make_training_loss_fn(
-                    model, criterion, policy, reg_pairs, remat, b, key, x, y)
-                grads, (nb, loss) = jax.grad(loss_fn, has_aux=True)(p)
-                np_, no = optim.update(clip(grads), o, p)
-                return (np_, nb, no), loss
-
-            (p, b, o), losses = jax.lax.scan(
-                body, (params, buffers, opt_state), (keys, datas, labels))
-            return p, b, o, losses
-
-        return tracked_jit(multi, site="train.multi_step",
-                           donate_argnums=(0, 1, 2))
-
-    def _build_multi_step_cached(self) -> Callable:
-        """K-fused dispatch over a device-resident dataset cache
-        (``DeviceCachedDataSet``): the scan body gathers each iteration's
-        batch from the cache arrays by index INSIDE the program, so a
-        window costs exactly one dispatch (stacking pre-gathered batches
-        would re-pay one dispatch per gather)."""
-        model, criterion, optim = self.model, self.criterion, self.optim_method
-        reg_pairs = _regularizer_pairs(model)
-        policy = self.precision
-        remat = self._remat
-        clip = make_grad_clipper(self._grad_clip)
-
-        def multi(params, buffers, opt_state, keys, x_cache, y_cache, idx):
-            def body(carry, inp):
-                p, b, o = carry
-                key, ix = inp
-                loss_fn = make_training_loss_fn(
-                    model, criterion, policy, reg_pairs, remat, b, key,
-                    x_cache[ix], y_cache[ix])
-                grads, (nb, loss) = jax.grad(loss_fn, has_aux=True)(p)
-                np_, no = optim.update(clip(grads), o, p)
-                return (np_, nb, no), loss
-
-            (p, b, o), losses = jax.lax.scan(
-                body, (params, buffers, opt_state), (keys, idx))
-            return p, b, o, losses
-
-        return tracked_jit(multi, site="train.multi_step_cached",
-                           donate_argnums=(0, 1, 2))
 
     def _build_forward(self) -> Callable:
         model = self.model
@@ -816,14 +734,6 @@ class LocalOptimizer(Optimizer):
                                     False)
                          or getattr(self.checkpoint_trigger, "uses_loss",
                                     False))
-        # K-fused dispatch (set_steps_per_dispatch): loss-based triggers
-        # need per-iteration losses on the host -> windows of 1
-        multi_step = (self._build_multi_step()
-                      if (self._steps_per_dispatch > 1
-                          and self.supports_multi_dispatch
-                          and not uses_loss_any) else None)
-        multi_step_cached = (self._build_multi_step_cached()
-                             if multi_step is not None else None)
         self._profiling_active = False
         rng = RandomGenerator.RNG()
         from bigdl_tpu.utils.engine import Engine
@@ -885,81 +795,72 @@ class LocalOptimizer(Optimizer):
         pending = None  # in-flight dispatch awaiting its loss fetch
         last_done = None  # wall time the previous dispatch's losses landed
         tm = self._train_instruments()
+        step_tracked = getattr(step, "tracked", step)  # ZeRO-1 wraps its TrackedJit
 
         def flush():
-            nonlocal pending, last_done
+            nonlocal pending
             if pending is None:
                 return
             p = pending
             pending = None
-            # sync point: blocks until the dispatch is done. A K-fused
-            # dispatch (set_steps_per_dispatch) returns (K,) losses — one
-            # exact log line per iteration either way.
+            # sync point: blocks until the dispatch is done
             t_sync = time.time()
-            neval_p = p["iters"][0]["neval"]  # the dispatch this waits for
-            with span("train.sync", k=len(p["iters"]), neval=neval_p):
-                losses = np.atleast_1d(np.asarray(p["losses"], np.float32))
+            with span("train.sync", k=1, neval=p["neval"]):
+                loss = float(np.asarray(p["loss"], np.float32))
             tm.sync.observe(time.time() - t_sync)
-            with span("train.log", k=len(p["iters"]), neval=neval_p):
-                log_window(p, losses)
+            with span("train.log", k=1, neval=p["neval"]):
+                log_iteration(p, loss)
 
-        def log_window(p, losses):
-            """The host work after a window's loss fetch: metrics, MFU
-            gauge, memory sample, one log line an iteration, summaries."""
+        def log_iteration(p, loss):
+            """The host work after an iteration's loss fetch: metrics, MFU
+            gauge, memory sample, the log line, summaries."""
             nonlocal last_done
             # inter-completion interval ~= per-dispatch device time in
             # steady state; measuring to the NEXT dispatch instead would
             # fold hook time and the next batch's data wait into
             # "computing time"
             done = time.time()
-            window_time = done - (last_done if last_done is not None
-                                  and last_done > p["t0"] else p["t0"])
+            iter_time = done - (last_done if last_done is not None
+                                and last_done > p["t0"] else p["t0"])
             last_done = done
-            iter_time = window_time / len(p["iters"])
-            first_window = p["iters"][0]["neval"] == 1
-            if first_window:
+            first = p["neval"] == 1
+            if first:
                 # first step pays tracing+XLA compile (unless cached)
-                self.metrics.add("compile and first-step time", window_time)
-            # live MFU: the dispatched program's cost-analysis FLOPs (one
-            # program ran the whole window, K iterations included) over
-            # the window wall-clock and the chip's peak — absent when the
+                self.metrics.add("compile and first-step time", iter_time)
+            # live MFU: the step program's cost-analysis FLOPs over the
+            # iteration's wall-clock and the chip's peak — absent when the
             # backend has no cost analysis or no known roof. The compile-
-            # bearing first window is SKIPPED: its wall-clock is mostly
+            # bearing first iteration is SKIPPED: its wall-clock is mostly
             # XLA, and publishing FLOPs/(compile+step) would trip any
             # dashboard threshold at every (re)start.
-            fn = p.get("fn")
-            fn = getattr(fn, "tracked", fn)  # ZeRO-1 wraps its TrackedJit
-            if not first_window:
-                m = profiling.mfu(getattr(fn, "last_flops", None),
-                                  window_time)
+            if not first:
+                m = profiling.mfu(getattr(step_tracked, "last_flops", None),
+                                  iter_time)
                 if m is not None:
                     tm.mfu.set(m)
             # step-boundary device-memory watermark (no-op on CPU)
             sample_device_memory()
-            for meta, loss_f in zip(p["iters"], losses):
-                loss_f = float(loss_f)
-                throughput = meta["n_records"] / max(iter_time, 1e-9)
-                tm.step.observe(iter_time)
-                tm.steps.inc()
-                tm.records.inc(meta["n_records"])
-                tm.rps.set(throughput)
-                driver_state["trainingLoss"] = loss_f
-                logger.info(
-                    "[Epoch %d %d/%d][Iteration %d][Wall %.3fs] Trained %d "
-                    "records in %.4fs. Throughput is %.1f records/second. "
-                    "Loss is %.5f.",
-                    meta["epoch"], meta["epoch_records"], meta["size"],
-                    meta["neval"], time.time() - wall_start,
-                    meta["n_records"], iter_time, throughput, loss_f)
-                self.metrics.add("computing time average", iter_time)
-                if self.train_summary is not None:
-                    self.train_summary.add_scalar("Loss", loss_f,
-                                                  meta["neval"])
-                    self.train_summary.add_scalar("Throughput", throughput,
-                                                  meta["neval"])
-                    if meta["lr"] is not None:
-                        self.train_summary.add_scalar(
-                            "LearningRate", float(meta["lr"]), meta["neval"])
+            throughput = p["n_records"] / max(iter_time, 1e-9)
+            tm.step.observe(iter_time)
+            tm.steps.inc()
+            tm.records.inc(p["n_records"])
+            tm.rps.set(throughput)
+            driver_state["trainingLoss"] = loss
+            logger.info(
+                "[Epoch %d %d/%d][Iteration %d][Wall %.3fs] Trained %d "
+                "records in %.4fs. Throughput is %.1f records/second. "
+                "Loss is %.5f.",
+                p["epoch"], p["epoch_records"], p["size"], p["neval"],
+                time.time() - wall_start, p["n_records"], iter_time,
+                throughput, loss)
+            self.metrics.add("computing time average", iter_time)
+            if self.train_summary is not None:
+                self.train_summary.add_scalar("Loss", loss, p["neval"])
+                self.train_summary.add_scalar("Throughput", throughput,
+                                              p["neval"])
+                if p["lr"] is not None:
+                    self.train_summary.add_scalar(
+                        "LearningRate", float(p["lr"]), p["neval"])
 
         stop = False
         epoch_end = None  # the open train.epoch_end span, if any
@@ -975,39 +876,6 @@ class LocalOptimizer(Optimizer):
                      if (self.train_summary is not None
                          and hasattr(self.train_summary,
                                      "get_summary_trigger")) else None)
-            # window bounding PROBES triggers at simulated nevals: a custom
-            # stateful predicate (probe_safe=False, the Trigger(fn) default)
-            # would be corrupted, so its presence forces windows of 1
-            can_window = multi_step is not None and all(
-                getattr(t, "probe_safe", False)
-                for t in (self.validation_trigger, self.checkpoint_trigger,
-                          self.end_when, ptrig) if t is not None)
-
-            def probe(trigger, neval_at):
-                """Evaluate a trigger at a simulated neval (same epoch —
-                windows never span epoch boundaries, under which the
-                built-in factories are pure)."""
-                if trigger is None:
-                    return False
-                st = T()
-                st.update(driver_state)
-                st["neval"] = neval_at
-                return bool(trigger(st))
-
-            def extension_ok(neval0, j):
-                """May the window grow to include iteration neval0+j?
-                Member neval0+j-1 then loses its per-iteration hook slot,
-                so nothing may fire there: no Parameters summary at
-                neval=neval0+j-1 (checked pre-increment), no
-                validation/checkpoint/end at neval=neval0+j."""
-                if probe(ptrig, neval0 + j - 1):
-                    return False
-                for trig in (self.validation_trigger,
-                             self.checkpoint_trigger, self.end_when):
-                    if probe(trig, neval0 + j):
-                        return False
-                return True
-
             data_iter = iter(self.dataset.data(train=True))
             # tracked for deterministic teardown: an engine-backed iterator
             # owns worker threads; epoch end AND the exception path out of
@@ -1027,115 +895,66 @@ class LocalOptimizer(Optimizer):
                 epoch_records = int(resume_cursor.get("epoch_records", 0))
             resume_cursor = None  # first resumed epoch only
             while True:
-                neval0 = int(driver_state["neval"])
+                neval = int(driver_state["neval"])
                 if self._profile is not None:
                     # between two iterations, so the profile holds whole
-                    # train.iteration spans (a fused window may start it up
-                    # to one window early)
+                    # train.iteration spans
                     pdir, pstart, pn = self._profile
                     if self._profiling_active:
-                        if neval0 >= pstart + pn:
+                        if neval >= pstart + pn:
                             self._stop_profile()
                             logger.info("[Profiler] trace for iterations "
                                         "%d-%d written to %s", pstart,
-                                        neval0 - 1, pdir)
-                    elif neval0 <= pstart < neval0 + self._steps_per_dispatch:
+                                        neval - 1, pdir)
+                    elif neval == pstart:
                         self._start_profile(pdir)
                 if epoch_end is not None:
                     # the boundary ends where the next epoch's first
                     # iteration starts
                     epoch_end.__exit__(None, None, None)
                     epoch_end = None
-                with tracing.step_span("train.iteration", neval0,
+                with tracing.step_span("train.iteration", neval,
                                        epoch=epoch) as iter_span:
-                    with span("train.data", neval=neval0, epoch=epoch):
-                        window = []
-                        while not window or (
-                                can_window
-                                and len(window) < self._steps_per_dispatch
-                                and extension_ok(neval0, len(window))):
-                            try:
-                                window.append(next(data_iter))
-                            except StopIteration:
-                                break
-                        k = len(window)
-                        iter_span.annotate(k=k)
-                        if k:
+                    with span("train.data", neval=neval, epoch=epoch):
+                        batch = next(data_iter, None)
+                        iter_span.annotate(k=0 if batch is None else 1)
+                        if batch is not None:
                             dw = time.time() - t_data
                             data_wait += dw
                             tm.data_wait.observe(dw)
-                    if not k:
+                    if batch is None:
                         # the iterator is exhausted: a pass with k=0 and
                         # no dispatch
                         break
-                    last_neval = neval0 + k - 1
                     t0 = time.time()
-                    used_fn = step  # which tracked program served the window
-                    with span("train.dispatch", k=k, neval=neval0):
-                        if k == 1:
-                            data, labels = self._place_batch(window[0])
-                            params, buffers, opt_state, losses = step(
-                                params, buffers, opt_state, rng.next_key(),
-                                data, labels)
-                        else:
-                            from bigdl_tpu.dataset.device_cache import \
-                                CachedSliceBatch
-                            keys = jnp.stack([rng.next_key() for _ in window])
-                            if (all(isinstance(b, CachedSliceBatch)
-                                    for b in window)
-                                    and len({id(b.source)
-                                             for b in window}) == 1):
-                                # gathers happen inside the fused program: ONE
-                                # dispatch per window
-                                src = window[0].source
-                                idx = jnp.stack([b.idx for b in window])
-                                used_fn = multi_step_cached
-                                params, buffers, opt_state, losses = \
-                                    multi_step_cached(params, buffers,
-                                                      opt_state, keys,
-                                                      src._x, src._y, idx)
-                            else:
-                                # host batches: one fused H2D + dispatch per
-                                # window
-                                xs = jnp.stack([jnp.asarray(b.data)
-                                                for b in window])
-                                ys = jnp.stack([jnp.asarray(b.labels)
-                                                for b in window])
-                                used_fn = multi_step
-                                params, buffers, opt_state, losses = multi_step(
-                                    params, buffers, opt_state, keys, xs, ys)
-                    # host time enqueueing the window (async; device compute
+                    with span("train.dispatch", k=1, neval=neval):
+                        data, labels = self._place_batch(batch)
+                        params, buffers, opt_state, loss = step(
+                            params, buffers, opt_state, rng.next_key(),
+                            data, labels)
+                    # host time enqueueing the step (async; device compute
                     # lands in the NEXT flush's sync wait)
                     tm.dispatch.observe(time.time() - t0)
-                    flush()  # previous dispatch: fetch losses, log, summarize
+                    flush()  # previous iteration: fetch loss, log, summarize
                     # snapshot the lr as its own small array NOW: opt_state's
                     # buffers are donated to the next dispatch and deleted
                     # (* 1 forces a fresh buffer if the schedule returns a state
-                    # array by identity). One snapshot per dispatch: intra-window
-                    # schedule steps are not observable host-side.
+                    # array by identity)
                     lr_arr = None
                     if (self.train_summary is not None
                             and hasattr(self.optim_method, "current_rate")):
                         lr_arr = self.optim_method.current_rate(opt_state)
                         if not isinstance(lr_arr, (int, float)):
                             lr_arr = lr_arr * 1
-                    iters = []
-                    for j, b in enumerate(window):
-                        epoch_records += b.size()
-                        iters.append({"neval": neval0 + j, "epoch": epoch,
-                                      "n_records": b.size(),
-                                      "epoch_records": epoch_records,
-                                      "size": self.dataset.size(),
-                                      "lr": lr_arr})
-                    pending = {"losses": losses, "iters": iters, "t0": t0,
-                               "fn": used_fn}
-                    # non-final window members were probed trigger-silent; the
-                    # final member gets the real per-iteration hook slot
-                    driver_state["neval"] = last_neval
+                    epoch_records += batch.size()
+                    pending = {"loss": loss, "t0": t0, "neval": neval,
+                               "epoch": epoch, "n_records": batch.size(),
+                               "epoch_records": epoch_records,
+                               "size": self.dataset.size(), "lr": lr_arr}
                     if ptrig is not None and ptrig(driver_state):
-                        self._summarize_parameters(params, last_neval)
-                    driver_state["neval"] = last_neval + 1
-                    epoch_batches += k
+                        self._summarize_parameters(params, neval)
+                    driver_state["neval"] = neval + 1
+                    epoch_batches += 1
                     # the data-iterator cursor any checkpoint written at this
                     # boundary records in its RESUME marker
                     self._loop_cursor = {"epoch": epoch,
@@ -1145,17 +964,17 @@ class LocalOptimizer(Optimizer):
                         # loss-sensitive stop/hook triggers must see THIS
                         # iteration's loss, not the pipelined previous one
                         flush()
-                    with span("train.hooks", neval=last_neval, epoch=epoch):
+                    with span("train.hooks", neval=neval, epoch=epoch):
                         self._hooks(params, buffers, opt_state, driver_state,
                                     fwd, epoch_done=False, flush=flush)
                     for inj in chaos_injectors:
-                        inj.on_step(last_neval)
+                        inj.on_step(neval)
                     if handler is not None:
                         fresh = handler.drain_notices()
                         if fresh:
                             instruments(get_registry()) \
                                 .resilience_preemptions_total.inc(fresh)
-                    if preemption_agreed(last_neval):
+                    if preemption_agreed(neval):
                         flush()
                         self._preempt_snapshot(params, buffers, opt_state,
                                                driver_state)
@@ -1163,7 +982,7 @@ class LocalOptimizer(Optimizer):
                         stop = True
                         break
                     t_data = time.time()
-            # from the drain of the epoch's last window to the first
+            # from the drain of the epoch's last iteration to the first
             # iteration of the next epoch (closed there, or after the loop)
             epoch_end = span("train.epoch_end", epoch=epoch,
                              neval=int(driver_state["neval"]))

@@ -27,20 +27,13 @@ class Trigger:
     """
 
     def __init__(self, fn: Callable[[Table], bool], name: str = "trigger",
-                 uses_loss: bool = False, probe_safe: bool = False):
+                 uses_loss: bool = False):
         self._fn = fn
         self.name = name
         # loss-sensitive triggers force the training loop to drain its
         # one-step loss pipeline before each end_when check, so they see
         # the CURRENT iteration's loss, not the previous one
         self.uses_loss = uses_loss
-        # probe_safe: the K-fused dispatch loop (set_steps_per_dispatch)
-        # may evaluate the trigger at SIMULATED future nevals (same epoch)
-        # to bound a window; a trigger whose predicate latches internal
-        # state across calls would be corrupted by that, so custom
-        # Trigger(fn) defaults to NOT probe-safe (forcing windows of 1).
-        # All built-in factories are probe-safe under same-epoch probing.
-        self.probe_safe = probe_safe
 
     def __call__(self, state: Table) -> bool:
         return bool(self._fn(state))
@@ -62,52 +55,49 @@ class Trigger:
                 return True
             return False
 
-        # stateful, but only on epoch CHANGE - pure under same-epoch probing
-        return Trigger(fn, "everyEpoch", probe_safe=True)
+        return Trigger(fn, "everyEpoch")
 
     @staticmethod
     def several_iteration(interval: int) -> "Trigger":
         def fn(state: Table) -> bool:
             return int(state["neval"]) % interval == 0
 
-        return Trigger(fn, f"severalIteration({interval})", probe_safe=True)
+        return Trigger(fn, f"severalIteration({interval})")
 
     @staticmethod
     def max_epoch(maximum: int) -> "Trigger":
         def fn(state: Table) -> bool:
             return int(state["epoch"]) > maximum
 
-        return Trigger(fn, f"maxEpoch({maximum})", probe_safe=True)
+        return Trigger(fn, f"maxEpoch({maximum})")
 
     @staticmethod
     def max_iteration(maximum: int) -> "Trigger":
         def fn(state: Table) -> bool:
             return int(state["neval"]) > maximum
 
-        return Trigger(fn, f"maxIteration({maximum})", probe_safe=True)
+        return Trigger(fn, f"maxIteration({maximum})")
 
     @staticmethod
     def max_score(maximum: float) -> "Trigger":
         def fn(state: Table) -> bool:
             return float(state.get("score", float("-inf"))) > maximum
 
-        return Trigger(fn, f"maxScore({maximum})", probe_safe=True)
+        return Trigger(fn, f"maxScore({maximum})")
 
     @staticmethod
     def min_loss(minimum: float) -> "Trigger":
         def fn(state: Table) -> bool:
             return float(state.get("trainingLoss", float("inf"))) < minimum
 
-        return Trigger(fn, f"minLoss({minimum})", uses_loss=True, probe_safe=True)
+        return Trigger(fn, f"minLoss({minimum})", uses_loss=True)
 
     @staticmethod
     def and_(*triggers: "Trigger") -> "Trigger":
         return Trigger(lambda s: all(t(s) for t in triggers), "and",
-                       uses_loss=any(t.uses_loss for t in triggers),
-                       probe_safe=all(t.probe_safe for t in triggers))
+                       uses_loss=any(t.uses_loss for t in triggers))
 
     @staticmethod
     def or_(*triggers: "Trigger") -> "Trigger":
         return Trigger(lambda s: any(t(s) for t in triggers), "or",
-                       uses_loss=any(t.uses_loss for t in triggers),
-                       probe_safe=all(t.probe_safe for t in triggers))
+                       uses_loss=any(t.uses_loss for t in triggers))

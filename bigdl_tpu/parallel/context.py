@@ -180,12 +180,7 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     if layout not in ("contiguous", "zigzag"):
         raise ValueError(f"unknown ring layout {layout!r}")
     if use_kernel is None:
-        import os
-        # BIGDL_TPU_FLASH_XLA_BWD's recompute backward has no LSE-cotangent
-        # plumbing, and the kernel-hop combine differentiates through lse —
-        # the A/B lever must push the ring back to the XLA partial path.
-        use_kernel = (jax.default_backend() == "tpu"
-                      and not os.environ.get("BIGDL_TPU_FLASH_XLA_BWD"))
+        use_kernel = jax.default_backend() == "tpu"
     p = axis_size(axis_name)
     my = lax.axis_index(axis_name)
     chunk = q.shape[1]

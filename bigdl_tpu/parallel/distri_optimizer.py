@@ -64,10 +64,6 @@ logger = logging.getLogger("bigdl_tpu.optim")
 class DistriOptimizer(LocalOptimizer):
     """Mesh data-parallel optimizer (reference ``DistriOptimizer``)."""
 
-    # set_steps_per_dispatch: the K-fused path jnp.stack's raw batches,
-    # which would collapse the mesh placement _place_batch establishes
-    supports_multi_dispatch = False
-
     def __init__(self, model, dataset, criterion,
                  topology: Optional[MeshTopology] = None,
                  sync_mode: str = "allreduce",

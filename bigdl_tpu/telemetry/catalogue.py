@@ -122,14 +122,14 @@ METRIC_SPECS: List[MetricSpec] = [
                "Requests queued or held awaiting same-length company."),
     # ---- training loops (optim/optimizer.py, parallel/distri_optimizer.py)
     MetricSpec("bigdl_train_step_seconds", "histogram",
-               "Per-iteration device step time (window wall-clock / "
-               "iterations in the dispatch window).",
+               "Per-iteration device step time (from one loss fetch's "
+               "completion to the next).",
                ("mode",), DEFAULT_LATENCY_BUCKETS),
     MetricSpec("bigdl_train_data_wait_seconds", "histogram",
-               "Host wait on the data pipeline per dispatch window.",
+               "Host wait on the data pipeline per iteration.",
                ("mode",), DEFAULT_LATENCY_BUCKETS),
     MetricSpec("bigdl_train_dispatch_seconds", "histogram",
-               "Host time handing a window to the device (H2D + enqueue; "
+               "Host time handing a step to the device (H2D + enqueue; "
                "async — excludes device compute).",
                ("mode",), DEFAULT_LATENCY_BUCKETS),
     MetricSpec("bigdl_train_sync_seconds", "histogram",
@@ -302,20 +302,20 @@ SPAN_SPECS: List[Tuple[str, str]] = [
      "bigdl_ingest_stall_seconds_total{stage=materialize} "
      "(dataset/device_cache.py)."),
     ("train.iteration", "One pass of the training loop, a "
-     "jax.profiler.StepTraceAnnotation (step_num = neval of the window's "
-     "first iteration); its children below partition it. The pass that "
-     "finds the epoch's iterator exhausted has k=0 and no dispatch."),
-    ("train.data", "Fetching the window's batches from the data iterator "
+     "jax.profiler.StepTraceAnnotation (step_num = neval, k = 1); its "
+     "children below partition it. The pass that finds the epoch's "
+     "iterator exhausted has k=0 and no dispatch."),
+    ("train.data", "Fetching the iteration's batch from the data iterator "
      "(for the device cache: its gather and index programs)."),
-    ("train.dispatch", "Handing one training window to the device (H2D + "
+    ("train.dispatch", "Handing one training step to the device (H2D + "
      "enqueue)."),
-    ("train.sync", "Blocking fetch of the pipelined window losses (neval "
-     "= the dispatch it waits for, one window back)."),
+    ("train.sync", "Blocking fetch of the pipelined loss (neval = the "
+     "dispatch it waits for, one iteration back)."),
     ("train.log", "Host work after the loss fetch: metrics, MFU gauge, "
      "memory sample, the per-iteration log line, summaries."),
     ("train.hooks", "Validation, checkpoint and summary triggers at an "
      "iteration or epoch boundary."),
-    ("train.epoch_end", "From the drain of the epoch's last window to the "
+    ("train.epoch_end", "From the drain of the epoch's last iteration to the "
      "first train.iteration of the next epoch: epoch log, hooks, shuffle, "
      "iterator rebuild."),
     ("train.validate", "In-training validation pass."),

@@ -15,7 +15,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 MODS = [
     ("bigdl_tpu.tensor", "Tensor core"),
     ("bigdl_tpu.nn", "Layers, containers, criterions"),
-    ("bigdl_tpu.nn.fused", "Fused kernel-backed layers"),
     ("bigdl_tpu.dataset", "Data pipeline"),
     ("bigdl_tpu.dataset.image", "Image transformers"),
     ("bigdl_tpu.dataset.text", "Text transformers"),
@@ -26,8 +25,6 @@ MODS = [
     ("bigdl_tpu.models.generation", "Autoregressive decoding"),
     ("bigdl_tpu.ops.flash_attention", "Flash attention (Pallas)"),
     ("bigdl_tpu.ops.lm_head_ce", "Fused LM-head cross-entropy"),
-    ("bigdl_tpu.ops.matmul_bn", "Fused matmul + BN stats (Pallas)"),
-    ("bigdl_tpu.ops.conv_bn", "Fused conv1x1+BN training op"),
     ("bigdl_tpu.ops.precision", "Mixed-precision policy"),
     ("bigdl_tpu.interop", "Torch/Caffe import-export"),
     ("bigdl_tpu.ml", "sklearn-protocol classifier"),

@@ -279,7 +279,7 @@ class TestPerfHarness:
         with pytest.raises(SystemExit, match="not both"):
             transformer.generate_cmd(["--fromHF", "x", "--model", "y"])
 
-    @pytest.mark.slow  # shard_map compile; needed the compat shim to run
+    @pytest.mark.slow  # 10.0-13.5s: two perf-harness compiles, one under shard_map
     def test_context_parallel_matches_sequential_loss(self):
         # PE offsets + pmean correctness: first-step loss of the seq-parallel
         # path must equal the plain path on the same weights and batch
@@ -469,7 +469,6 @@ class TestLlamaBlockContextParallel:
     """--llamaBlock --contextParallel: the long-context rope training
     recipe is CLI-reachable end to end (round 5)."""
 
-    @pytest.mark.slow  # shard_map compile; needed the compat shim to run
     def test_train_ring_rope(self, capsys):
         from bigdl_tpu.apps import transformer
         transformer.train(["-b", "8", "--seqLen", "32", "--maxEpoch", "1",

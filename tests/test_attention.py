@@ -260,18 +260,6 @@ class TestFlashKernel:
                                        rtol=2e-4, atol=2e-4,
                                        err_msg=f"{name} mismatch")
 
-    def test_xla_bwd_fallback_env(self, monkeypatch):
-        monkeypatch.setenv("BIGDL_TPU_FLASH_XLA_BWD", "1")
-        b, s, n, d = 1, 16, 1, 8
-        q, k, v = (jnp.asarray(_rand(b, s, n, d)) for _ in range(3))
-        g_flash = jax.grad(lambda q_: jnp.sum(flash_attention(
-            q_, k, v, causal=True, block_q=8, block_k=8,
-            interpret=True) ** 2))(q)
-        g_plain = jax.grad(lambda q_: jnp.sum(ac.dot_product_attention(
-            q_, k, v, causal=True) ** 2))(q)
-        np.testing.assert_allclose(np.asarray(g_flash), np.asarray(g_plain),
-                                   rtol=1e-4, atol=1e-4)
-
 
 def _attention_and_lse(q, k, v, causal):
     """(o, lse) by the XLA core's recipe in the dtype of q: the oracle (on

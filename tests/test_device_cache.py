@@ -156,36 +156,6 @@ def test_training_through_device_cache_matches_host_path(monkeypatch):
         np.testing.assert_allclose(b, a, rtol=1e-5, atol=1e-6)
 
 
-def test_k_fused_dispatch_over_cache_matches_k1(monkeypatch):
-    # device cache + set_steps_per_dispatch: the in-jit gather path must
-    # train identically to single-step dispatch over the same cache
-    from bigdl_tpu.dataset.base import LocalDataSet
-    monkeypatch.setattr(
-        DeviceCachedDataSet, "shuffle",
-        lambda self: setattr(self, "_perm",
-                             np.arange(self.size(), dtype=np.int32)))
-
-    def run(k):
-        bt.utils.manual_seed(13)
-        rng = np.random.default_rng(5)
-        samples = [Sample(rng.normal(0, 1, (28, 28, 1)).astype(np.float32),
-                          float(rng.integers(1, 11))) for _ in range(128)]
-        ds = DeviceCachedDataSet(DataSet.array(samples), batch_size=32)
-        model = lenet.build(10)
-        opt = Optimizer(model, ds, nn.ClassNLLCriterion())
-        opt.set_optim_method(SGD(learningrate=0.1)) \
-           .set_end_when(Trigger.max_iteration(6)) \
-           .set_steps_per_dispatch(k)
-        trained = opt.optimize()
-        import jax
-        return [np.asarray(x) for x in
-                jax.tree_util.tree_leaves(trained.parameter_tree())]
-
-    for a, b in zip(run(1), run(4)):
-        np.testing.assert_allclose(b, a, rtol=1e-5, atol=1e-6)
-
-
-@pytest.mark.slow  # seed-failing pre compat shim
 class TestShardedCache:
     """Sharded device cache under DistriOptimizer (8-device virtual mesh):
     per-shard reshuffle (reference CachedDistriDataSet's per-partition

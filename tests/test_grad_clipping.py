@@ -56,7 +56,7 @@ def build_model(dim=8):
             .add(nn.Linear(16, 2)).add(nn.LogSoftMax()))
 
 
-def run_steps(distributed=False, clip=None, k=1, iters=2):
+def run_steps(distributed=False, clip=None, iters=2):
     from bigdl_tpu.utils.rng import manual_seed
     manual_seed(123)
     model = build_model()
@@ -71,8 +71,6 @@ def run_steps(distributed=False, clip=None, k=1, iters=2):
         opt = Optimizer(model, ds, nn.ClassNLLCriterion())
     opt.set_optim_method(SGD(learningrate=1.0))  # big LR amplifies grads
     opt.set_end_when(Trigger.max_iteration(iters))
-    if k > 1:
-        opt.set_steps_per_dispatch(k)
     if clip == "l2":
         opt.set_gradient_clipping_by_l2_norm(0.01)
     elif clip == "constant":
@@ -96,15 +94,10 @@ class TestOptimizerClipping:
         # every element moved at most 1e-4 (lr 1)
         assert moved <= 1e-4 * np.sqrt(8 * 16 + 16 + 16 * 2 + 2) + 1e-6
 
-    def test_l2_bounds_update_multi_dispatch(self):
-        moved = run_steps(clip="l2", k=2, iters=2)
-        assert moved <= 2 * 0.01 + 1e-6
-
     def test_l2_bounds_update_distributed(self):
         moved = run_steps(distributed=True, clip="l2", iters=2)
         assert moved <= 2 * 0.01 + 1e-6
 
-    @pytest.mark.slow  # seed-failing pre compat shim
     def test_l2_bounds_update_sharded(self):
         from bigdl_tpu.utils.rng import manual_seed
         from bigdl_tpu.parallel import MeshTopology
@@ -213,7 +206,6 @@ class TestAdamW:
 
 
 class TestShardedPadLanes:
-    @pytest.mark.slow  # seed-failing pre compat shim
     def test_asymmetric_clamp_parity_with_allreduce(self):
         """178 params over 8 devices leaves 6 pad lanes; a clamp range
         excluding 0 must NOT lift them into the global norm (regression:

@@ -307,6 +307,22 @@ def test_comm_model_drift_gate():
         "(lint --comm-model COMM_MODEL.json)")
 
 
+def test_environment_chooses_no_layer_or_kernel():
+    """Which module a builder adds and which kernel an op runs is decided
+    by the code, from its arguments and the backend: a variable in the
+    process environment changes neither."""
+    import glob
+    pkg = os.path.join(REPO, "bigdl_tpu")
+    files = (glob.glob(os.path.join(pkg, "nn", "*.py"))
+             + glob.glob(os.path.join(pkg, "ops", "*.py"))
+             + [os.path.join(pkg, "models", f"{m}.py")
+                for m in ("resnet", "inception", "vgg")])
+    assert len(files) > 25
+    readers = [os.path.relpath(f, REPO) for f in files
+               if any(w in open(f).read() for w in ("os.environ", "getenv"))]
+    assert readers == []
+
+
 def test_ingest_r01_artifact():
     """Round-13 ingest artifact gate (INGEST_r01.json): the serial vs
     pipelined comparison must carry a full stage ledger and an HONEST

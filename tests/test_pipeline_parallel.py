@@ -54,7 +54,6 @@ def test_gpipe_matches_sequential(n_micro):
                                rtol=1e-5, atol=1e-5)
 
 
-@pytest.mark.slow  # seed-failing before the shard_map compat shim
 def test_gpipe_grads_match_sequential():
     mesh = MeshTopology(pipeline=4).build()
     stack = PipelineStack(_block, depth=4)
@@ -77,7 +76,6 @@ def test_gpipe_grads_match_sequential():
                                    rtol=1e-4, atol=1e-4)
 
 
-@pytest.mark.slow  # seed-failing before the shard_map compat shim
 def test_gpipe_remat_grads_identical():
     # jax.checkpoint trades FLOPs for memory; gradients must be unchanged
     mesh = MeshTopology(pipeline=4).build()
@@ -95,7 +93,6 @@ def test_gpipe_remat_grads_identical():
                                    rtol=1e-5, atol=1e-6)
 
 
-@pytest.mark.slow  # seed-failing before the shard_map compat shim
 def test_gpipe_with_head_and_sharded_params():
     # Train-shaped usage: params placed sharded over pipe axis, classifier
     # head on top, one SGD step decreases the loss.
@@ -186,7 +183,6 @@ class TestCircularSchedule:
     def test_interleave2_min_microbatches(self):
         self._run(depth=8, p=4, v=2, n_micro=4)  # M == P edge (delay 0)
 
-    @pytest.mark.slow  # seed-failing before the shard_map compat shim
     def test_interleave2_grads(self):
         self._run(depth=8, p=4, v=2, n_micro=8, grads=True)
 
@@ -242,7 +238,6 @@ class TestBufferedStack:
                                        rtol=1e-4, atol=1e-4)
 
 
-@pytest.mark.slow  # seed-failing before the shard_map compat shim
 def test_dp_x_pp_matches_sequential():
     # data=2 x pipe=4: each data group pipelines its batch slice; pmean'd
     # loss and grads match the full-batch sequential oracle

@@ -191,7 +191,6 @@ SHARDED_METHODS = [
 ]
 
 
-@pytest.mark.slow  # seed-failing pre compat shim
 class TestShardedDifferential:
     """sync_mode='sharded' (ZeRO-1 slice ownership: psum_scatter + slice
     update + all_gather) must be numerically interchangeable with
@@ -260,9 +259,7 @@ def _np_rmsprop_update(lr=0.01, rho=0.99, eps=1e-8):
 
 
 class TestNumpyOracle:
-    @pytest.mark.parametrize("sync_mode", ["allreduce", pytest.param(
-        "sharded",
-        marks=pytest.mark.slow)])  # seed-failing pre compat shim
+    @pytest.mark.parametrize("sync_mode", ["allreduce", "sharded"])
     def test_adam_matches_numpy(self, sync_mode):
         batches = _fixed_batches(n_batches=3, batch=32)
         init = _fresh_init(13)
@@ -271,9 +268,7 @@ class TestNumpyOracle:
                             sync_mode)
         np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
 
-    @pytest.mark.parametrize("sync_mode", ["allreduce", pytest.param(
-        "sharded",
-        marks=pytest.mark.slow)])  # seed-failing pre compat shim
+    @pytest.mark.parametrize("sync_mode", ["allreduce", "sharded"])
     def test_rmsprop_matches_numpy(self, sync_mode):
         batches = _fixed_batches(n_batches=3, batch=32)
         init = _fresh_init(17)

@@ -249,7 +249,6 @@ class TestOptimizerShardedResume:
 
         np.testing.assert_allclose(resumed, ref, rtol=1e-5, atol=1e-6)
 
-    @pytest.mark.slow  # seed-failing pre compat shim
     def test_zero1_sharded_checkpoint_refused(self, tmp_path):
         opt = DistriOptimizer(_mk_model(), _FixedDataSet(_fixed_batches()),
                               nn.ClassNLLCriterion(),

@@ -68,17 +68,17 @@ def test_unbatched_and_repr():
     assert "space-to-depth" in repr(s2d)
 
 
-def test_resnet_stem_uses_s2d_and_matches_plain(monkeypatch):
-    # resnet.build adopts the packed stem by default; BIGDL_TPU_NO_S2D=1
-    # restores the plain conv, and both compute the same function when
-    # weights are copied across.
+def test_resnet_stem_uses_s2d_and_matches_plain():
+    # resnet.build has the packed stem; the same layers behind the plain
+    # 7x7 conv compute the same function on the same weights.
     from bigdl_tpu.models import resnet
     rng = np.random.default_rng(3)
     m_s2d = resnet.build(class_num=10, depth=18)
     assert isinstance(m_s2d._modules["0"], nn.SpaceToDepthConv7)
-    monkeypatch.setenv("BIGDL_TPU_NO_S2D", "1")
-    m_plain = resnet.build(class_num=10, depth=18)
-    assert isinstance(m_plain._modules["0"], nn.SpatialConvolution)
+    m_plain = nn.Sequential().add(nn.SpatialConvolution(
+        3, 64, 7, 7, 2, 2, 3, 3, with_bias=False, init_method="kaiming"))
+    for i in range(1, len(m_s2d)):
+        m_plain.add(m_s2d[i])
 
     params = m_s2d.parameter_tree()
     x = jnp.asarray(rng.normal(0, 1, (2, 224, 224, 3)), jnp.float32)

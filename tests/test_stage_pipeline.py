@@ -58,7 +58,6 @@ class TestStagePipelineLM:
                             devices=jax.devices()[:3]).build()
         return pipe, crit, mesh, jnp.asarray(x), jnp.asarray(y)
 
-    @pytest.mark.slow  # seed-failing before the shard_map compat shim
     def test_loss_matches_sequential(self):
         pipe, crit, mesh, x, y = self._setup()
         loss_fn = stage_pipeline_loss_fn(pipe, crit, mesh, n_micro=4)
@@ -67,7 +66,6 @@ class TestStagePipelineLM:
         ref = crit.apply(ref_out, y)
         np.testing.assert_allclose(float(got), float(ref), rtol=1e-5)
 
-    @pytest.mark.slow  # seed-failing before the shard_map compat shim
     def test_grads_match_sequential(self):
         pipe, crit, mesh, x, y = self._setup()
         loss_fn = stage_pipeline_loss_fn(pipe, crit, mesh, n_micro=4)
@@ -82,7 +80,6 @@ class TestStagePipelineLM:
         np.testing.assert_allclose(np.asarray(g_pipe), np.asarray(g_ref),
                                    rtol=2e-4, atol=1e-6)
 
-    @pytest.mark.slow  # seed-failing before the shard_map compat shim
     def test_remat_grads_exact(self):
         pipe, crit, mesh, x, y = self._setup()
         f0 = stage_pipeline_loss_fn(pipe, crit, mesh, n_micro=4)
@@ -106,7 +103,6 @@ class TestStagePipelineLM:
 
 
 class TestStagePipelineConv:
-    @pytest.mark.slow  # seed-failing before the shard_map compat shim
     def test_heterogeneous_shapes_loss_and_grads(self):
         stages = _conv_stages()
         rng = np.random.default_rng(1)

@@ -112,9 +112,7 @@ class TestLocalTraining:
 
 
 class TestDistributedTraining:
-    @pytest.mark.parametrize("sync_mode", ["allreduce", pytest.param(
-        "sharded",
-        marks=pytest.mark.slow)])  # seed-failing pre compat shim
+    @pytest.mark.parametrize("sync_mode", ["allreduce", "sharded"])
     def test_lenet_distributed_converges(self, sync_mode):
         bt.utils.manual_seed(1)
         model = lenet.build(10)
@@ -215,9 +213,7 @@ class TestRemat:
             Optimizer(lenet.build(10), make_dataset(128, 64),
                       nn.ClassNLLCriterion()).set_remat("gibberish")
 
-    @pytest.mark.parametrize("sync_mode", ["allreduce", pytest.param(
-        "sharded",
-        marks=pytest.mark.slow)])  # seed-failing pre compat shim
+    @pytest.mark.parametrize("sync_mode", ["allreduce", "sharded"])
     def test_remat_distributed_matches_plain(self, sync_mode):
         def run(remat):
             bt.utils.manual_seed(22)
@@ -234,46 +230,6 @@ class TestRemat:
 
         for a, b in zip(run(False), run(True)):
             np.testing.assert_allclose(b, a, rtol=1e-6, atol=1e-7)
-
-
-@pytest.mark.slow  # ~11s: convergence loop; tier-1 wall budget
-def test_cifar_resnet_converges_under_fused_kernels(monkeypatch):
-    # Fused conv+BN kernels (1x1 + 3x3, interpret mode on CPU) through the
-    # REAL training path: loss must fall on a learnable synthetic task.
-    # Catches running-stat / backward bugs a forward parity test can miss.
-    monkeypatch.setenv("BIGDL_TPU_FUSED_1X1", "1")
-    monkeypatch.setenv("BIGDL_TPU_FUSED_3X3", "1")
-    import numpy as np
-    import bigdl_tpu as bt
-    from bigdl_tpu import nn
-    from bigdl_tpu.dataset.base import DataSet, Sample, SampleToBatch
-    from bigdl_tpu.models import resnet
-    from bigdl_tpu.optim import Optimizer, SGD, Trigger
-
-    bt.utils.manual_seed(4)
-    rng = np.random.RandomState(0)
-    # class = sign pattern of a fixed channel direction: trivially learnable
-    w_true = rng.randn(3)
-    samples = []
-    while len(samples) < 128:
-        img = rng.randn(32, 32, 3).astype(np.float32)
-        score = float(img.mean((0, 1)) @ w_true)
-        if abs(score) < 0.05:   # keep classes well-separated
-            continue
-        img += 2.0 * np.sign(score) * w_true / np.linalg.norm(w_true)
-        samples.append(Sample(img, 1.0 + float(score > 0)))
-    ds = DataSet.array(samples) >> SampleToBatch(32)
-    model = resnet.build_cifar(class_num=2, depth=8, shortcut_type="A")
-    assert "FusedConv3x3BN" in repr(model)
-    opt = (Optimizer(model, ds, nn.ClassNLLCriterion())
-           .set_optim_method(SGD(learningrate=0.1, momentum=0.9))
-           .set_end_when(Trigger.max_epoch(8)))
-    opt.optimize()
-    # training loss after 8 epochs must beat ln(2) chance by a margin
-    from bigdl_tpu.optim import Loss
-    result = model.evaluate(ds, [Loss(nn.ClassNLLCriterion())])
-    final = float(result[0][0].result()[0])
-    assert np.isfinite(final) and final < 0.55, final
 
 
 def test_transformer_tp_with_sequence_parallel_regions_trains():
@@ -315,96 +271,63 @@ def test_transformer_tp_with_sequence_parallel_regions_trains():
         assert np.isfinite(np.asarray(leaf)).all()
 
 
-class TestStepsPerDispatch:
-    """set_steps_per_dispatch: K-fused dispatch (PERF.md round 3) must be a
-    pure scheduling change — identical numerics, exact per-iteration logs,
-    trigger-bounded windows."""
+class TestLoopContracts:
+    """The training loop dispatches one iteration at a time: exact
+    per-iteration logs, stops, checkpoints and hook slots."""
 
-    def _run(self, k, iters=6, trigger=None, checkpoint_dir=None):
+    def _run(self, iters=6, trigger=None, checkpoint_dir=None):
         bt.utils.manual_seed(31)
         model = lenet.build(10)
         opt = Optimizer(model, make_dataset(512, 64), nn.ClassNLLCriterion())
         opt.set_optim_method(SGD(learningrate=0.05, momentum=0.9)) \
-           .set_end_when(Trigger.max_iteration(iters)) \
-           .set_steps_per_dispatch(k)
+           .set_end_when(Trigger.max_iteration(iters))
         if trigger is not None:
             opt.set_validation(trigger, make_dataset(128, 64),
                                [Top1Accuracy()])
         if checkpoint_dir is not None:
             opt.set_checkpoint(checkpoint_dir,
                                Trigger.several_iteration(2))
-        losses = []
+        logged, validated = [], []
 
         class Sink:
+            def __init__(self, tag, steps):
+                self.tag, self.steps = tag, steps
+
             def add_scalar(self, tag, value, step):
-                if tag == "Loss":
-                    losses.append((step, float(value)))
+                if tag == self.tag:
+                    self.steps.append(step)
 
             def get_summary_trigger(self, name):
                 return None
 
-        opt.set_train_summary(Sink())
-        trained = opt.optimize()
-        import jax
-        leaves = [np.asarray(x) for x in
-                  jax.tree_util.tree_leaves(trained.parameter_tree())]
-        return leaves, losses
+        opt.set_train_summary(Sink("Loss", logged))
+        opt.set_validation_summary(Sink("Top1Accuracy", validated))
+        opt.optimize()
+        return logged, validated
 
-    def test_numerics_and_logs_match_k1(self):
-        p1, l1 = self._run(1)
-        p4, l4 = self._run(4)
-        assert [s for s, _ in l1] == list(range(1, 7))  # every iter logged
-        assert [s for s, _ in l4] == [s for s, _ in l1]  # exact per-iter logs
-        for (s1, a), (s4, b) in zip(l1, l4):
-            assert abs(a - b) < 1e-5, (s1, a, b)
-        for a, b in zip(p1, p4):
-            np.testing.assert_allclose(b, a, rtol=1e-5, atol=1e-6)
+    def test_every_iteration_logged_once_in_order(self):
+        logged, _ = self._run()
+        assert logged == [1, 2, 3, 4, 5, 6]
 
     def test_respects_max_iteration_exactly(self):
-        _, losses = self._run(4, iters=5)
-        assert [s for s, _ in losses] == [1, 2, 3, 4, 5]
+        logged, _ = self._run(iters=5)
+        assert logged == [1, 2, 3, 4, 5]
 
-    def test_checkpoints_match_k1(self, tmp_path):
-        d1, d4 = tmp_path / "k1", tmp_path / "k4"
-        d1.mkdir(), d4.mkdir()
-        self._run(1, iters=6, checkpoint_dir=str(d1))
-        self._run(4, iters=6, checkpoint_dir=str(d4))
-        from bigdl_tpu.utils import file_io
-        names = sorted(p.name for p in d1.iterdir())
-        assert names == sorted(p.name for p in d4.iterdir())
-        assert any(n.startswith("model") for n in names)
-        import jax
-        for n in names:
-            if not n.startswith("model"):
-                continue
-            a = file_io.load(str(d1 / n))["params"]
-            b = file_io.load(str(d4 / n))["params"]
-            for la, lb in zip(jax.tree_util.tree_leaves(a),
-                              jax.tree_util.tree_leaves(b)):
-                np.testing.assert_allclose(np.asarray(lb), np.asarray(la),
-                                           rtol=1e-5, atol=1e-6)
+    def test_checkpoints_at_several_iteration(self, tmp_path):
+        self._run(iters=6, checkpoint_dir=str(tmp_path))
+        names = sorted(p.name for p in tmp_path.iterdir())
+        assert names == ["model.2", "model.4", "model.6"] + [
+            f"state.{n}{tail}" for n in (2, 4, 6)
+            for tail in ("", ".resume.json")]
 
-    def test_validation_windows_bounded(self):
-        # validation every 2 iterations with K=4: windows must shrink so
-        # validation always runs against the params of the iteration it
-        # follows -> same validation COUNT as K=1 and identical numerics
-        p1, _ = self._run(1, iters=6, trigger=Trigger.several_iteration(2))
-        p4, _ = self._run(4, iters=6, trigger=Trigger.several_iteration(2))
-        for a, b in zip(p1, p4):
-            np.testing.assert_allclose(b, a, rtol=1e-5, atol=1e-6)
+    def test_validation_runs_in_its_hook_slot(self):
+        # the trigger sees neval already advanced, so it fires after
+        # iterations 1, 3 and 5 (neval 2, 4, 6)
+        _, validated = self._run(iters=6,
+                                 trigger=Trigger.several_iteration(2))
+        assert validated == [1, 3, 5]
 
-    def test_rejects_bad_k(self):
-        opt = Optimizer(lenet.build(10), make_dataset(128, 64),
-                        nn.ClassNLLCriterion())
-        with pytest.raises(ValueError):
-            opt.set_steps_per_dispatch(0)
-
-    def test_custom_stateful_trigger_forces_windows_of_1(self):
-        # Trigger(fn) defaults to probe_safe=False: the window-bounding
-        # probe would corrupt a stateful predicate, so its presence must
-        # collapse windows to 1 — the trigger then sees exactly one real
-        # evaluation per iteration, in order.
-        from bigdl_tpu.optim.triggers import Trigger as Trig
+    def test_stateful_trigger_evaluated_once_an_iteration(self):
         seen = []
 
         def fn(state):
@@ -415,9 +338,27 @@ class TestStepsPerDispatch:
         opt = Optimizer(lenet.build(10), make_dataset(512, 64),
                         nn.ClassNLLCriterion())
         opt.set_optim_method(SGD(learningrate=0.05)) \
-           .set_end_when(Trigger.max_iteration(5)) \
-           .set_steps_per_dispatch(4)
-        opt.set_validation(Trig(fn), make_dataset(64, 64), [Top1Accuracy()])
+           .set_end_when(Trigger.max_iteration(5))
+        opt.set_validation(Trigger(fn), make_dataset(64, 64),
+                           [Top1Accuracy()])
         opt.optimize()
-        per_iter = [n for n in seen]
-        assert per_iter[:5] == [2, 3, 4, 5, 6], per_iter
+        # once an iteration, in order, and once more at the epoch's end
+        assert seen == [2, 3, 4, 5, 6, 6], seen
+
+    def test_one_step_program(self):
+        """A second step program cannot come back unnoticed: the loop
+        compiles `train.step` once and `train.forward` for validation,
+        and nothing else under `train.`."""
+        from bigdl_tpu.telemetry import (MetricsRegistry, get_registry,
+                                         instruments, set_registry)
+        assert not hasattr(Optimizer, "set_steps_per_dispatch")
+        prev = set_registry(MetricsRegistry())
+        try:
+            self._run(iters=3, trigger=Trigger.several_iteration(3))
+            compiles = {lv[0]: c.value for lv, c in instruments(
+                get_registry()).compiles_total.children()
+                if lv[0].startswith("train.")}
+        finally:
+            set_registry(prev)
+        assert set(compiles) == {"train.step", "train.forward"}, compiles
+        assert compiles["train.step"] == 1
