@@ -523,13 +523,14 @@ class TimeDistributedCriterion(Criterion):
 
 
 class FusedLMHeadCriterion(Criterion):
-    """Chunked-vocab cross-entropy paired with ``nn.LMHead``.
+    """Row-tiled cross-entropy paired with ``nn.LMHead``.
 
     Training path: ``input`` is the Table ``(hidden, weight[, bias])`` that
     ``LMHead`` emits in training mode; the loss is computed by
-    ``ops/lm_head_ce.fused_lm_head_ce`` — an online-logsumexp scan over
-    vocab chunks whose custom VJP recomputes per chunk, so neither the
-    logits nor their cotangent ever materialise at (N, V).
+    ``ops/lm_head_ce.fused_lm_head_ce`` — one scan over tiles of ``chunk``
+    rows (default: from the shapes) that forms the loss and its gradients
+    while a tile's logits are live, so neither the logits nor their
+    cotangent ever materialise at (N, V).
 
     Validation path: when ``input`` is a plain array it is taken as
     LOG-PROBABILITIES over the trailing axis (LMHead's eval output) and
@@ -542,7 +543,8 @@ class FusedLMHeadCriterion(Criterion):
     i.e. the loss is the flat mean over every position).
     """
 
-    def __init__(self, chunk: int = 16384, size_average: bool = True,
+    def __init__(self, chunk: Optional[int] = None,
+                 size_average: bool = True,
                  ignore_index: Optional[int] = None):
         super().__init__()
         self.chunk = chunk

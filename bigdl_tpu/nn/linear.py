@@ -250,9 +250,9 @@ class LMHead(Module):
 
     Replaces ``TimeDistributed(Linear(E, V)) -> LogSoftMax`` when training
     with ``FusedLMHeadCriterion``: in TRAINING mode the output is a Table
-    ``(hidden, weight, bias)`` — the criterion computes chunked cross-entropy
-    directly from the hidden states, so the (B, S, V) logits never hit HBM
-    (``ops/lm_head_ce.py``; measured at 54% of the LM step unfused, PERF.md).
+    ``(hidden, weight, bias)`` — the criterion computes the cross-entropy
+    and its gradients a tile of rows at a time directly from the hidden
+    states, so the (B, S, V) logits never hit HBM (``ops/lm_head_ce.py``).
     In EVAL mode it computes ordinary log-probabilities, so validation
     metrics, ``predict`` and ``models.generate`` see the standard tail.
 

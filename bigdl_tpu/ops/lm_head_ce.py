@@ -1,24 +1,32 @@
-"""Fused LM-head cross-entropy: logits are never materialised.
+"""Fused LM-head cross-entropy: the loss and its gradients from ONE pass
+over the logits, which are never materialised at (N, V).
 
 The standard causal-LM tail — ``TimeDistributed(Linear(E, V)) -> LogSoftMax
--> ClassNLL`` — materialises the (B*S, V) logits (plus the normalised
-log-probs and their cotangent) in HBM. At B*S = 16K, V = 32K that is ~1 GB
-per array per pass, and an on-chip probe measured the head at **54% of the
-whole training step** (PERF.md round 3). The reference has no analogue (its
-``nn/LogSoftMax.scala`` + ``ClassNLLCriterion.scala`` pair materialises the
-full activation just the same — at reference scale V is tiny).
+-> ClassNLL`` — materialises the (B*S, V) logits, the normalised log-probs
+and their cotangent in HBM: 2.5 GB an array in float32 at B*S = 4,096,
+V = 151,936. The reference has no analogue (its ``nn/LogSoftMax.scala`` +
+``ClassNLLCriterion.scala`` pair materialises the full activation just the
+same — at reference scale V is tiny).
 
 This op computes ``mean(logsumexp(h @ W^T + b) - logit[target])`` by a
-``lax.scan`` over VOCAB CHUNKS with an online (flash-style) logsumexp:
+``lax.scan`` over ROW TILES (tokens). A tile holds its whole row of logits,
+so its logsumexp is final the moment the tile's product is done:
 
-- forward: per chunk, one (N, C) matmul + running (max, sumexp, target-logit)
-  — only the (N, C) chunk is ever live;
-- backward (custom VJP): recompute each chunk's logits from the saved
-  row logsumexp, form ``softmax - onehot`` in place, and accumulate
-  ``dh`` and the per-chunk rows of ``dW``/``db``.
+- under ``grad`` (the ``custom_vjp``'s forward rule): per tile, the logits
+  product, the row max / sum / target logit, ``softmax - onehot`` formed
+  while the tile is live, ``dh_t = g @ W`` and ``dW += g^T @ h_t``: three
+  products, nothing recomputed. The residuals ARE the gradients for a unit
+  cotangent; the backward rule scales them. (A frozen head, gradient wrt
+  ``h`` alone, still pays for the ``dW`` it drops.)
+- not differentiated: the same tiles, the logits product alone.
 
-Matmuls run in the inputs' compute dtype (bf16 under the mixed policy);
-softmax statistics and accumulations are fp32.
+Rows a tile come from the shapes (``rows_per_tile``): a tile's logits are
+live memory, and ``dW`` is read and written once a tile, in the open (on a
+v5e at T=4,096, V=151,936: 20.7 ms in one tile, 22.1 in two, 24.5 in four,
+30.3 in eight, against 31.6 for two scans over vocabulary chunks; PERF.md
+section 6, PR 28), so the fewest tiles that fit win. Matmuls run in the inputs' compute dtype (bf16
+under the mixed policy) at the vocabulary's own size; softmax statistics
+and the ``dW`` accumulation are fp32.
 """
 
 from __future__ import annotations
@@ -31,97 +39,106 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-_NEG = -1e30  # effective -inf that survives exp without NaNs
+# what the logits of one row tile may hold, reckoned in float32 (XLA keeps the
+# tile in the compute dtype: half of it under the bf16 policy): one tile at
+# T=4,096, V=151,936 (2.49 GB) and at T=8,192, V=16,384 (537 MB)
+_TILE_BYTES = 5 << 29
 
 
-def _pad_vocab(w: jax.Array, b: jax.Array, chunk: int):
-    v = w.shape[0]
-    n_chunks = -(-v // chunk)
-    pad = n_chunks * chunk - v
-    if pad:
-        w = jnp.pad(w, ((0, pad), (0, 0)))
-        # padded rows get bias -inf so exp() contributes 0 mass
-        b = jnp.pad(b, (0, pad), constant_values=_NEG)
-    return w, b, n_chunks
+def rows_per_tile(n: int, v: int, chunk: Optional[int] = None) -> int:
+    """Rows a tile: ``chunk`` clamped to the ``n`` rows where given, else an
+    even split of ``n`` into the fewest tiles whose float32 logits each fit
+    ``_TILE_BYTES``."""
+    if chunk is not None:
+        return max(1, min(int(chunk), n))
+    return -(-n // max(1, -(-4 * n * v // _TILE_BYTES)))
 
 
-def _chunk_logits(h, w, b, c, chunk):
-    """(N, C) logits of chunk c in compute dtype, fp32 out."""
-    w_c = lax.dynamic_slice_in_dim(w, c * chunk, chunk, axis=0)
-    b_c = lax.dynamic_slice_in_dim(b, c * chunk, chunk, axis=0)
-    logits = jnp.matmul(h, w_c.T.astype(h.dtype))
-    return logits.astype(jnp.float32) + b_c.astype(jnp.float32)
+def _tile(h, tgt0, valid, w, b, grads):
+    """One row tile: its loss sum and, with ``grads``, (dh, dW, db) of that
+    sum; ``w`` already in the compute dtype, ``b`` None or (V,)."""
+    logits = jnp.matmul(h, w.T).astype(jnp.float32)
+    if b is not None:
+        logits = logits + b.astype(jnp.float32)
+    m = jnp.max(logits, axis=-1)
+    lse = m + jnp.log(jnp.sum(jnp.exp(logits - m[:, None]), axis=-1))
+    # the target logit as a masked row sum: it rides the exp-and-sum pass,
+    # where a gather would make XLA keep a float32 copy of the tile for it
+    onehot = jnp.arange(w.shape[0])[None, :] == tgt0[:, None]
+    zt = jnp.sum(jnp.where(onehot, logits, 0.0), axis=-1)
+    loss = jnp.sum(jnp.where(valid, lse - zt, 0.0))
+    if not grads:
+        return loss, (None, None, None)
+    # d loss / d logits = (softmax - onehot) on valid rows
+    g = jnp.where(valid[:, None],
+                  jnp.exp(logits - lse[:, None]) - onehot, 0.0)
+    gl = g.astype(h.dtype)
+    dw = lax.dot_general(gl, h, (((0,), (0,)), ((), ())),
+                         preferred_element_type=jnp.float32)
+    db = None if b is None else jnp.sum(g, axis=0)
+    return loss, (jnp.matmul(gl, w), dw, db)
+
+
+def _over_tiles(h, w, b, valid, tgt0, rows, grads):
+    """Scan ``_tile`` over tiles of ``rows`` rows (the last one padded with
+    invalid rows): loss_sum and, with ``grads``, the (dh, dW, db) of it."""
+    n, e = h.shape
+    tiles = -(-n // rows)
+    pad = tiles * rows - n
+
+    def tiled(x):
+        if pad:     # rows only, and only where ``rows`` does not divide
+            x = jnp.pad(x, ((0, pad),) + ((0, 0),) * (x.ndim - 1))
+        return x.reshape((tiles, rows) + x.shape[1:])
+
+    wc = w.astype(h.dtype)      # once, at its own V
+
+    def body(acc, x):
+        loss, (dh, dw, db) = _tile(*x, wc, b, grads)
+        return jax.tree.map(jnp.add, acc, (loss, dw, db)), dh
+
+    def zeros(x):
+        if grads and x is not None:
+            return jnp.zeros(x.shape, jnp.float32)
+
+    (loss, dw, db), dh = lax.scan(
+        body, (jnp.zeros((), jnp.float32), zeros(w), zeros(b)),
+        (tiled(h), tiled(tgt0), tiled(valid)))
+    if grads:
+        dh = dh.reshape(tiles * rows, e)[:n]
+    return loss, (dh, dw, db)
+
+
+def _count(form):
+    # trace-time count, as bigdl_ssd_scan_total: which form a compiled
+    # program holds
+    from bigdl_tpu.telemetry import get_registry, instruments
+    instruments(get_registry()).lm_head_ce_total.labels(form=form).inc()
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def _lm_head_ce(h, w, b, valid, tgt0, chunk):
-    """Per-row CE over valid rows; returns (loss_sum, n_valid, lse)."""
-    out, _ = _lm_head_ce_fwd(h, w, b, valid, tgt0, chunk)
-    return out
-
-
-# the scope names both scans in the trace (under custom_vjp the backward
-# is traced on its own, so it carries the scope itself)
 @jax.named_scope("lm_head_ce")
-def _lm_head_ce_fwd(h, w, b, valid, tgt0, chunk):
-    n = h.shape[0]
-    wp, bp, n_chunks = _pad_vocab(w, b, chunk)
+def _lm_head_ce(h, w, b, valid, tgt0, rows):
+    """CE summed over the valid rows; ``b`` may be None."""
+    _count("forward_only")
+    return _over_tiles(h, w, b, valid, tgt0, rows, grads=False)[0]
 
-    def body(carry, c):
-        m, s, zt = carry
-        logits = _chunk_logits(h, wp, bp, c, chunk)
-        m_new = jnp.maximum(m, jnp.max(logits, axis=-1))
-        s = s * jnp.exp(m - m_new) + jnp.sum(
-            jnp.exp(logits - m_new[:, None]), axis=-1)
-        idx = tgt0 - c * chunk
-        in_c = (idx >= 0) & (idx < chunk)
-        z = jnp.take_along_axis(
-            logits, jnp.clip(idx, 0, chunk - 1)[:, None], axis=1)[:, 0]
-        zt = jnp.where(in_c, z, zt)
-        return (m_new, s, zt), None
 
-    init = (jnp.full((n,), _NEG, jnp.float32),
-            jnp.zeros((n,), jnp.float32),
-            jnp.full((n,), _NEG, jnp.float32))
-    (m, s, zt), _ = lax.scan(body, init, jnp.arange(n_chunks))
-    lse = m + jnp.log(jnp.maximum(s, 1e-37))
-    per_row = jnp.where(valid, lse - zt, 0.0)
-    loss_sum = jnp.sum(per_row)
-    n_valid = jnp.sum(valid.astype(jnp.float32))
-    return (loss_sum, n_valid, lse), (h, w, b, valid, tgt0, lse)
+# the scope names the head's operations in the trace (a custom_vjp rule is
+# traced outside the primal's name stack, so each carries the scope itself)
+@jax.named_scope("lm_head_ce")
+def _lm_head_ce_fwd(h, w, b, valid, tgt0, rows):
+    _count("one_pass")
+    loss, grads = _over_tiles(h, w, b, valid, tgt0, rows, grads=True)
+    return loss, (*jax.tree.map(lambda g, x: g.astype(x.dtype), grads,
+                                (h, w, b)), valid, tgt0)
 
 
 @jax.named_scope("lm_head_ce")
-def _lm_head_ce_bwd(chunk, res, cts):
-    h, w, b, valid, tgt0, lse = res
-    g_sum, _, g_lse = cts  # cotangents for (loss_sum, n_valid, lse)
-    wp, bp, n_chunks = _pad_vocab(w, b, chunk)
-    n, e = h.shape
-    vmask = valid.astype(jnp.float32)
-    # d loss_sum / d logits_c = (softmax - onehot) * valid; plus the lse
-    # cotangent's softmax term (lse is also an output — g_lse is zero in
-    # the criterion path but keeps the op a correct VJP in general).
-    row_g = g_sum * vmask + g_lse
-
-    def body(dh, c):
-        logits = _chunk_logits(h, wp, bp, c, chunk)
-        p = jnp.exp(logits - lse[:, None])
-        idx = tgt0 - c * chunk
-        onehot = ((jnp.arange(chunk)[None, :] == idx[:, None])
-                  .astype(jnp.float32))
-        g_logits = p * row_g[:, None] - onehot * (g_sum * vmask)[:, None]
-        w_c = lax.dynamic_slice_in_dim(wp, c * chunk, chunk, axis=0)
-        gl = g_logits.astype(h.dtype)
-        dh = dh + jnp.matmul(gl, w_c.astype(h.dtype)).astype(jnp.float32)
-        dw_c = jnp.matmul(gl.T, h).astype(jnp.float32)
-        return dh, (dw_c, jnp.sum(g_logits, axis=0))
-
-    dh, (dw_chunks, db_chunks) = lax.scan(
-        body, jnp.zeros((n, e), jnp.float32), jnp.arange(n_chunks))
-    v = w.shape[0]
-    dw = dw_chunks.reshape(n_chunks * chunk, e)[:v]
-    db = db_chunks.reshape(n_chunks * chunk)[:v]
-    return (dh.astype(h.dtype), dw.astype(w.dtype), db.astype(b.dtype),
+def _lm_head_ce_bwd(rows, res, g_sum):
+    *grads, valid, tgt0 = res
+    return (*jax.tree.map(lambda g: (g * g_sum).astype(g.dtype),
+                          tuple(grads)),
             np.zeros(valid.shape, dtype=jax.dtypes.float0),
             np.zeros(tgt0.shape, dtype=jax.dtypes.float0))
 
@@ -131,28 +148,27 @@ _lm_head_ce.defvjp(_lm_head_ce_fwd, _lm_head_ce_bwd)
 
 def fused_lm_head_ce(hidden: jax.Array, weight: jax.Array,
                      bias: Optional[jax.Array], targets: jax.Array, *,
-                     chunk: int = 16384, size_average: bool = True,
+                     chunk: Optional[int] = None, size_average: bool = True,
                      ignore_index: Optional[int] = None) -> jax.Array:
     """Cross-entropy of ``hidden @ weight.T + bias`` against 1-based targets.
 
     ``hidden``: (..., E); ``weight``: (V, E); ``targets``: hidden's leading
     shape, values in 1..V (any numeric dtype). Rows whose target equals
     ``ignore_index`` contribute nothing (and don't count toward the mean).
-    Numerically equal to ``ClassNLL(LogSoftMax(logits), targets)`` without
-    ever materialising (N, V) logits.
+    ``chunk`` is the rows a tile (default: from the shapes,
+    ``rows_per_tile``). Numerically equal to ``ClassNLL(LogSoftMax(logits),
+    targets)`` without ever materialising (N, V) logits.
     """
     e = hidden.shape[-1]
     h2 = hidden.reshape(-1, e)
-    tgt = targets.reshape(-1)
-    tgt0 = tgt.astype(jnp.int32) - 1
+    tgt = targets.reshape(-1).astype(jnp.int32)
     if ignore_index is not None:
-        valid = (tgt.astype(jnp.int32) != int(ignore_index))
+        valid = tgt != int(ignore_index)
     else:
-        valid = jnp.ones(tgt0.shape, bool)
-    if bias is None:
-        bias = jnp.zeros((weight.shape[0],), weight.dtype)
-    chunk = min(int(chunk), weight.shape[0])
-    loss_sum, n_valid, _ = _lm_head_ce(h2, weight, bias, valid, tgt0, chunk)
+        valid = jnp.ones(tgt.shape, bool)
+    rows = rows_per_tile(h2.shape[0], weight.shape[0], chunk)
+    loss_sum = _lm_head_ce(h2, weight, bias, valid, tgt - 1, rows)
     if size_average:
-        return loss_sum / jnp.maximum(n_valid, 1.0)
+        return loss_sum / jnp.maximum(jnp.sum(valid.astype(jnp.float32)),
+                                      1.0)
     return loss_sum

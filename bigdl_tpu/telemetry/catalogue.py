@@ -207,6 +207,13 @@ METRIC_SPECS: List[MetricSpec] = [
                "else). Counted once per eager call / once per TRACE under "
                "jit, as bigdl_moe_dispatch_total: which form each compiled "
                "program holds, not per-step traffic.", ("form",)),
+    MetricSpec("bigdl_lm_head_ce_total", "counter",
+               "Fused LM-head cross-entropies by form (form label: "
+               "one_pass, the loss and its three gradients from one scan "
+               "over row tiles, traced under grad; forward_only, the loss "
+               "alone, not differentiated). Counted once per eager call / "
+               "once per TRACE under jit, as bigdl_ssd_scan_total.",
+               ("form",)),
     MetricSpec("bigdl_int8_fallbacks_total", "counter",
                "int8_matmul decode-shaped calls that LOST the fused "
                "kernel because K is off the 128-lane quantum (XLA "

@@ -20,8 +20,9 @@ out by the repo's own means:
 5. ``timeline``: a 5-step ``set_profiling`` profile of phase 2's step, read
    back with the benchmark's own readers: the loop's ``train.*`` spans as
    annotations beside the runtime's enqueues, the three ``flash_*`` kernel
-   names on the Mosaic calls, the ``lm_head_ce`` scope on both ``while``
-   loops — so a jax upgrade that renames any of them fails here, cheaply;
+   names on the Mosaic calls, the ``lm_head_ce`` scope on the head's one
+   ``while`` loop — so a jax upgrade that renames any of them fails here,
+   cheaply;
 6. ``resnet50_mesh``: phase 1 through ``DistriOptimizer`` over every visible
    device, 256 per chip — when there is more than one device.
 
@@ -327,9 +328,10 @@ def phase_timeline(report):
     from benchmark import timeline
     from bigdl_tpu import nn
     from bigdl_tpu.apps.perf import _build_model
+    from bigdl_tpu.ops.lm_head_ce import rows_per_tile
 
     with Phase("timeline", report):
-        model, (seq,), *_ = _build_model("transformer_134m")
+        model, (seq,), vocab, *_ = _build_model("transformer_134m")
         shutil.rmtree(PROFILE_DIR, ignore_errors=True)
         opt, _, _ = train(model, nn.FusedLMHeadCriterion(),
                           token_samples(16, seq, seed=2), 8, LM_ITERS,
@@ -367,8 +369,11 @@ def phase_timeline(report):
         scoped = timeline.scope_instructions(step_text(opt), "lm_head_ce")
         whiles = {o.name for o in dev.ops
                   if o.opcode == "while" and o.name in scoped}
-        check(len(whiles) == 2, f"{sorted(whiles)} of the step's while "
-              "loops run under the scope 'lm_head_ce', not 2")
+        # loss and gradients in one loop over row tiles; none at one tile
+        loops = int(rows_per_tile(8 * seq, vocab) < 8 * seq)
+        check(scoped and len(whiles) == loops, f"{sorted(whiles)} of the "
+              "step's while loops run under the scope 'lm_head_ce', not "
+              f"{loops}")
         sec, n = timeline.scope_seconds(trace, scoped, lo, hi)
         say(f"  profile of steps {steps}: {len(host.spans)} train.* "
             f"annotations, {len(host.enqueues)} enqueues ({len(runs)} step "

@@ -7,9 +7,9 @@ import pytest
 
 from bigdl_tpu import nn
 from bigdl_tpu.models import transformer
-from bigdl_tpu.ops.lm_head_ce import fused_lm_head_ce
+from bigdl_tpu.ops.lm_head_ce import fused_lm_head_ce, rows_per_tile
 
-N, E, V = 24, 16, 37  # deliberately not chunk-aligned
+N, E, V = 24, 16, 37  # rows a tile of 7 and 16 leave a ragged last tile
 
 
 def ref_ce(h, w, b, tgt, size_average=True, ignore_index=None):
@@ -92,6 +92,121 @@ class TestFusedOp:
         want = ref_ce(h, w, b, tgt)
         assert np.isfinite(float(got))
         np.testing.assert_allclose(float(got), float(want), rtol=0.05)
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr, those of its sub-jaxprs (scan bodies,
+    custom_vjp calls, pjit) included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+def _vocab_products(fn, *args):
+    """dot_generals of ``fn``'s jaxpr with V among their shapes."""
+    return [e for e in _eqns(jax.make_jaxpr(fn)(*args).jaxpr)
+            if e.primitive.name == "dot_general"
+            and any(V in v.aval.shape for v in (*e.invars, *e.outvars))]
+
+
+class TestOnePass:
+    @pytest.mark.parametrize("chunk", [8, None])   # three tiles; one
+    def test_gradient_holds_three_products_and_the_primal_one(self, chunk):
+        h, w, b, tgt = make_inputs(7)
+
+        def loss(h, w, b):
+            return fused_lm_head_ce(h, w, b, tgt, chunk=chunk)
+
+        assert len(_vocab_products(
+            jax.grad(loss, argnums=(0, 1, 2)), h, w, b)) == 3
+        assert len(_vocab_products(loss, h, w, b)) == 1
+
+    def test_no_vocabulary_padding(self):
+        """W enters every product at its own V: no array of the gradient
+        program is wider than the inputs."""
+        h, w, b, tgt = make_inputs(8)
+        jaxpr = jax.make_jaxpr(jax.grad(lambda h, w, b: fused_lm_head_ce(
+            h, w, b, tgt, chunk=8), argnums=(0, 1, 2)))(h, w, b).jaxpr
+        assert not [e for e in _eqns(jaxpr) if e.primitive.name == "pad"]
+        assert max(d for e in _eqns(jaxpr) for v in e.outvars
+                   for d in v.aval.shape) == V
+
+    @pytest.mark.parametrize("shape,tiles", [
+        ((4096, 896, 151936), 1),      # the Qwen cell
+        ((8192, 2688, 16384), 1),      # the Nemotron cell
+        ((16384, 896, 151936), 4),     # the Qwen cell at batch 8
+        ((N, E, V), 1)])
+    def test_tile_rule_at_the_cells_shapes(self, shape, tiles):
+        n, _, v = shape
+        rows = rows_per_tile(n, v)
+        assert rows * tiles == n
+        assert rows >= min(n, 1024)     # under that dW's traffic binds
+        assert rows_per_tile(n, v, 7) == 7
+        assert rows_per_tile(n, v, 10 ** 9) == n    # clamps to one tile
+
+    def test_ragged_tile_with_ignored_rows_in_the_padding(self):
+        """N = 24 in tiles of 7: the last tile holds 3 rows and 4 of
+        padding, which must count as ignored rows do."""
+        h, w, b, tgt = make_inputs(9)
+        tgt = tgt.at[::5].set(1.0).at[-1].set(1.0)
+
+        def both(fn):
+            return jax.value_and_grad(fn, argnums=(0, 1, 2))(h, w, b)
+
+        got, g_got = both(lambda h, w, b: fused_lm_head_ce(
+            h, w, b, tgt, chunk=7, ignore_index=1))
+        want, g_want = both(lambda h, w, b: ref_ce(
+            h, w, b, tgt, ignore_index=1))
+        np.testing.assert_allclose(float(got), float(want), rtol=1e-5)
+        for a, e in zip(g_got, g_want):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(e),
+                                       atol=2e-5, rtol=1e-4)
+
+    def test_no_bias_builds_no_db_and_grad_wrt_hidden_alone(self):
+        h, w, _, tgt = make_inputs(10)
+
+        def loss(h, w):
+            return fused_lm_head_ce(h, w, None, tgt, chunk=8)
+
+        jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(h, w).jaxpr
+        assert not [e for e in _eqns(jaxpr)
+                    if e.primitive.name == "reduce_sum"
+                    and e.outvars[0].aval.shape == (V,)]
+        gh = jax.grad(loss)(h, w)
+        want = jax.grad(lambda h: ref_ce(h, w, None, tgt))(h)
+        np.testing.assert_allclose(np.asarray(gh), np.asarray(want),
+                                   atol=2e-5, rtol=1e-4)
+
+    def test_upstream_cotangent_scales_all_three_gradients(self):
+        h, w, b, tgt = make_inputs(11)
+        gf = jax.grad(lambda h, w, b: 3.0 * fused_lm_head_ce(
+            h, w, b, tgt, chunk=16), argnums=(0, 1, 2))(h, w, b)
+        gr = jax.grad(lambda h, w, b: ref_ce(h, w, b, tgt),
+                      argnums=(0, 1, 2))(h, w, b)
+        for a, e in zip(gf, gr):
+            np.testing.assert_allclose(np.asarray(a), 3.0 * np.asarray(e),
+                                       atol=6e-5, rtol=1e-4)
+
+    def test_counter_names_the_form(self):
+        from bigdl_tpu.telemetry import get_registry, instruments
+        total = instruments(get_registry()).lm_head_ce_total
+        h, w, b, tgt = make_inputs(12)
+
+        def counts():
+            return [total.labels(form=f).value
+                    for f in ("one_pass", "forward_only")]
+
+        def loss(h):
+            return fused_lm_head_ce(h, w, b, tgt)
+
+        before = counts()
+        loss(h)
+        assert counts() == [before[0], before[1] + 1]
+        grad = jax.jit(jax.grad(loss))
+        grad(h)
+        grad(h)                         # counted once a compiled program
+        assert counts() == [before[0] + 1, before[1] + 1]
 
 
 class TestCriterionAndHead:

@@ -203,10 +203,14 @@ def test_flash_and_int8_kernels_carry_their_names():
     assert "name=int8_matmul" in text
 
 
-def test_lm_head_ce_scope_names_both_scans_in_a_tiny_lm_step():
-    """The compiled train step of a tiny LM with the fused head: both scans
-    (the forward's, and the backward's, traced on its own under custom_vjp)
-    are ``while`` instructions whose op_name holds the scope."""
+@pytest.mark.parametrize("chunk,loops", [(8, 1), (None, 0)])
+def test_lm_head_ce_scope_names_its_loop_and_products_in_a_tiny_lm_step(
+        chunk, loops):
+    """The compiled train step of a tiny LM with the fused head: at more
+    than one row tile ONE ``while`` (loss and gradients in one pass, traced
+    as the custom_vjp's forward rule) whose op_name holds the scope; at one
+    tile no loop is left and the head's three products, those with the
+    vocabulary among their shapes, carry the scope themselves."""
     from bigdl_tpu.dataset.base import DataSet, Sample, SampleToBatch
     from bigdl_tpu.models import transformer
     from bigdl_tpu.optim import SGD, Optimizer, Trigger
@@ -216,18 +220,30 @@ def test_lm_head_ce_scope_names_both_scans_in_a_tiny_lm_step():
     model = transformer.build_lm(40, 16, 2, 32, num_layers=1, max_len=16,
                                  fused_head=True)
     opt = Optimizer(model, DataSet.array([Sample(r, r) for r in rows])
-                    >> SampleToBatch(4), nn.FusedLMHeadCriterion(chunk=8))
+                    >> SampleToBatch(4), nn.FusedLMHeadCriterion(chunk=chunk))
     opt.set_optim_method(SGD(learningrate=0.1))
     opt.set_end_when(Trigger.max_iteration(2))
+    from bigdl_tpu.telemetry import get_registry, instruments
+    one_pass = instruments(get_registry()).lm_head_ce_total.labels(
+        form="one_pass")
+    before = one_pass.value
     opt.optimize()
+    assert one_pass.value == before + 1     # once a compiled step
     (hlo,) = getattr(opt.step_fn, "tracked", opt.step_fn).compiled_texts()
-    whiles = [ln for ln in hlo.splitlines()
-              if " while(" in ln and "op_name=" in ln]
-    scoped = [ln.split('op_name="', 1)[1].split('"', 1)[0] for ln in whiles]
-    scoped = [name for name in scoped if "lm_head_ce" in name]
-    assert len(scoped) == 2, whiles
-    assert any("transpose(" in name for name in scoped), scoped  # backward
-    assert any("transpose(" not in name for name in scoped), scoped
+
+    def op_names(opcode, shape_part=""):
+        return [ln.split('op_name="', 1)[1].split('"', 1)[0]
+                for ln in hlo.splitlines()
+                if f" {opcode}(" in ln and "op_name=" in ln
+                and shape_part in ln.split(f" {opcode}(", 1)[0]]
+
+    scoped = [name for name in op_names("while") if "lm_head_ce" in name]
+    assert len(scoped) == loops, scoped
+    assert not any("transpose(" in name for name in scoped), scoped
+    products = [name for name in op_names("dot") if "lm_head_ce" in name]
+    assert len(products) == 3, products         # logits, dh, dW
+    wide = op_names("dot", "40")                # V in the output's shape
+    assert len(wide) == 2 and set(wide) <= set(products), wide
 
 
 # ------------------------------------------------- raw per-request times
