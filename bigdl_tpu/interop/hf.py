@@ -381,6 +381,100 @@ def nemotron_h_lm_kwargs(config: Dict[str, Any],
     return kwargs
 
 
+def afmoe_pattern(layer_types, num_dense_layers: int) -> str:
+    """The ``HybridDecoder`` pattern of an ``afmoe`` stack: each layer is
+    an attention block (``W`` sliding window, ``*`` full) and a
+    feed-forward block (``-`` dense in the leading ``num_dense_layers``,
+    ``E`` experts after them)."""
+    kinds = {"sliding_attention": "W", "full_attention": "*"}
+    bad = set(layer_types) - set(kinds)
+    if bad:
+        raise ValueError(f"unmapped layer_types {sorted(bad)} "
+                         f"({sorted(kinds)} are)")
+    return "".join(kinds[t] + ("-" if i < num_dense_layers else "E")
+                   for i, t in enumerate(layer_types))
+
+
+def afmoe_lm_kwargs(config: Dict[str, Any], held_experts=None,
+                    train_router: bool = True) -> Dict[str, Any]:
+    """``models.hybrid.build_hybrid_lm`` kwargs for an HF ``afmoe``
+    ``config.json`` dict (Arcee Trinity): per ``layer_types`` a
+    sliding-window attention layer that rotates q and k or a full one that
+    does not, both with RMSNorm on each head of q and k and a sigmoid
+    output gate; a dense SwiGLU feed-forward in the leading
+    ``num_dense_layers`` and sigmoid-routed SwiGLU experts with one shared
+    expert after them; a norm before AND after every mixer; the embedding
+    scaled by ``sqrt(hidden_size)`` under ``mup_enabled``.
+
+    ``held_experts`` lists the routed experts that live on this chip of an
+    expert-parallel deployment (default: all ``num_experts``, which is
+    always the router's width); ``vocab_size`` may be a slice.
+    ``train_router=False`` is ``MoE(train_router=False)`` in every expert
+    layer: for such a share trained with no exchange, whose part of the
+    router's gradient would pull the picks onto the held experts.
+
+    Not keys of the config but of the family's public model class, and so
+    fixed here: the q/k norm, the output gate, the four norms a layer, no
+    rotation on full layers, the embedding's multiplier. Refused rather
+    than guessed: expert groups with a group limit, a softmax router,
+    ``route_norm`` false, rope scaling, a tied head, more than one shared
+    expert, an activation other than silu."""
+    types = list(config["layer_types"])
+    if len(types) != int(config["num_hidden_layers"]):
+        raise ValueError(f"layer_types has {len(types)} entries, "
+                         f"num_hidden_layers says "
+                         f"{config['num_hidden_layers']}")
+    if int(config.get("n_group", 1)) != 1 \
+            or int(config.get("topk_group", 1)) != 1:
+        raise ValueError("group-limited expert routing (n_group > 1) is "
+                         "not mapped")
+    if config.get("score_func", "sigmoid") != "sigmoid" \
+            or not config.get("route_norm", True):
+        raise ValueError("afmoe routing other than sigmoid scores with "
+                         "route_norm is not mapped")
+    if config.get("hidden_act", "silu") != "silu":
+        raise ValueError(f"unsupported afmoe activation "
+                         f"{config.get('hidden_act')!r}")
+    if config.get("rope_scaling"):
+        raise ValueError("afmoe rope_scaling is not mapped")
+    if config.get("tie_word_embeddings", False):
+        raise ValueError("a head tied to the embedding is not mapped")
+    if int(config.get("num_shared_experts", 1)) not in (0, 1):
+        raise ValueError("more than one shared expert is not mapped")
+    dense = int(config.get("num_dense_layers", 0))
+    pattern = afmoe_pattern(types, dense)
+    eps = float(config.get("rms_norm_eps", 1e-5))
+    e = int(config["hidden_size"])
+    full = dict(num_heads=int(config["num_attention_heads"]),
+                num_kv_heads=int(config["num_key_value_heads"]),
+                head_dim=int(config["head_dim"]), with_bias=False,
+                qk_norm=True, qk_norm_eps=eps, gated=True)
+    kwargs = dict(vocab_size=int(config["vocab_size"]), embed_dim=e,
+                  pattern=pattern, norm_eps=eps, post_norm=True,
+                  embed_scale=e ** 0.5 if config.get("mup_enabled", False)
+                  else None)
+    if "*" in pattern:
+        kwargs["attention"] = full
+    if "W" in pattern:
+        kwargs["window_attention"] = dict(
+            full, rope=True, rope_theta=float(config.get("rope_theta", 1e4)),
+            window=int(config["sliding_window"]))
+    if "-" in pattern:
+        kwargs["mlp"] = dict(hidden_size=int(config["intermediate_size"]))
+    if "E" in pattern:
+        width = int(config["moe_intermediate_size"])
+        kwargs["moe"] = dict(
+            hidden_size=width, n_experts=int(config["num_experts"]),
+            k=int(config["num_experts_per_tok"]), activation="swiglu",
+            dispatch="held",
+            held=None if held_experts is None else tuple(held_experts),
+            bias=False,
+            shared_hidden=width * int(config.get("num_shared_experts", 1)),
+            route_scale=float(config.get("route_scale", 1.0)),
+            train_router=train_router)
+    return kwargs
+
+
 # ------------------------------------------------------------------- export
 
 def export_gpt2_state_dict(model: Module) -> Dict[str, np.ndarray]:

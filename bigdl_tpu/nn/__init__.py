@@ -77,4 +77,4 @@ from bigdl_tpu.nn.attention import (
     LearnedPositionalEncoding, TransformerEncoderLayer, TransformerEncoder,
 )
 from bigdl_tpu.nn.mamba import Mamba2
-from bigdl_tpu.nn.hybrid import HybridBlock, HybridDecoder
+from bigdl_tpu.nn.hybrid import GatedMLP, HybridBlock, HybridDecoder

@@ -114,7 +114,9 @@ class MultiHeadAttention(Module):
                  window: Optional[int] = None,
                  rope_scaling: Optional[dict] = None,
                  qkv_bias: bool = False,
-                 head_dim: Optional[int] = None):
+                 head_dim: Optional[int] = None,
+                 qk_norm: bool = False, qk_norm_eps: float = 1e-6,
+                 gated: bool = False):
         super().__init__()
         # head_dim: the size of one head where the model sets it apart
         # from the stream's width (the projections are then
@@ -125,9 +127,10 @@ class MultiHeadAttention(Module):
                 "embed_dim must divide num_heads"
             head_dim = embed_dim // num_heads
         # window: sliding-window (banded causal) attention — query i sees
-        # keys (i - window, i], the Mistral convention. Requires causal;
-        # runs on the XLA cores (the flash kernel and context-parallel
-        # paths do not implement the band and are excluded by dispatch).
+        # keys (i - window, i], the Mistral convention. Requires causal.
+        # The flash kernels take the band as an argument and skip the tiles
+        # below it; the XLA cores take it as a mask; the context-parallel
+        # paths do not implement it.
         if window is not None:
             if not causal:
                 raise ValueError("window (sliding-window attention) "
@@ -209,6 +212,20 @@ class MultiHeadAttention(Module):
                                     init.zeros((e_q + 2 * e_kv,)))
         if with_bias:
             self.register_parameter("out_proj_bias", init.zeros((embed_dim,)))
+        # qk_norm: RMSNorm over each head of q and of k (one learned
+        # (head_dim,) gain each, shared by the heads), BEFORE the rotation
+        self.qk_norm = qk_norm
+        if qk_norm:
+            self.q_norm = RMSNorm(head_dim, eps=qk_norm_eps)
+            self.k_norm = RMSNorm(head_dim, eps=qk_norm_eps)
+        # gated: a fourth projection of the QUERY input, as wide as q; the
+        # attention's output is multiplied by its sigmoid before the
+        # out-projection: (attn * sigmoid(x W_g)) W_o
+        self.gated = gated
+        if gated:
+            self.register_parameter(
+                "gate_proj_weight", init.xavier((e_q, embed_dim),
+                                                embed_dim, e_q))
         self.attn_mask: Optional[jax.Array] = None
 
     # ------------------------------------------------------------- decoding
@@ -543,6 +560,9 @@ class MultiHeadAttention(Module):
         k = self._split_heads(pk)
         v = self._split_heads(pv)
 
+        if getattr(self, "qk_norm", False):
+            q, k = self.q_norm.forward(q), self.k_norm.forward(k)
+
         if getattr(self, "rope", False):
             if k.shape[1] != q.shape[1]:
                 raise ValueError(
@@ -581,6 +601,10 @@ class MultiHeadAttention(Module):
 
         b, s, _, _ = ctx.shape
         ctx = ctx.reshape(b, s, e)
+        if getattr(self, "gated", False):
+            gate = self._project(query, self.gate_proj_weight, None)
+            ctx = ctx * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(
+                ctx.dtype)
         return self._out_projection(ctx)
 
     def _attend(self, q, k, v, mask):
@@ -596,20 +620,22 @@ class MultiHeadAttention(Module):
             return context.ulysses_attention(q, k, v,
                                              axis_name=self.seq_axis,
                                              causal=self.causal)
-        if getattr(self, "window", None):
+        window = getattr(self, "window", None)
+        drop = self.dropout_p if (self.training and self.dropout_p) else 0.0
+        # prob-dropout needs the plain core (see __init__)
+        if not drop and flash_attention.use_flash(q, mask):
+            # the band is the kernels' own argument: no (Sq, Sk) mask
+            return flash_attention.flash_attention(
+                q, k, v, causal=self.causal, window=window)
+        if window:
             # banded causal: query i sees keys (i - window, i] (Mistral
-            # convention). The band rides the mask path, which already
-            # excludes the flash kernel.
+            # convention), as a mask for the XLA cores
             sq, sk = q.shape[1], k.shape[1]
             q_pos = jnp.arange(sq)[:, None]
             k_pos = jnp.arange(sk)[None, :]
-            band = k_pos > q_pos - self.window
+            band = k_pos > q_pos - window
             mask = band if mask is None else jnp.logical_and(mask, band)
-        drop = self.dropout_p if (self.training and self.dropout_p) else 0.0
-        if not drop:  # prob-dropout needs the plain core (see __init__)
-            if flash_attention.use_flash(q, mask):
-                return flash_attention.flash_attention(q, k, v,
-                                                       causal=self.causal)
+        if not drop:
             if self.block_size:
                 return attention_core.blockwise_attention(
                     q, k, v, mask=mask, causal=self.causal,

@@ -7,8 +7,13 @@ Every block is pre-norm residual with ONE mixer, ``x <- x + mixer(
 RMSNorm(x))``, and the pattern string names each block's mixer by one
 character: ``M`` a Mamba-2 layer (``nn.Mamba2``), ``E`` a mixture of
 experts (``parallel.expert.MoE``), ``*`` causal self-attention
-(``nn.MultiHeadAttention``). The mixers are built from the keyword groups
-the caller gives for each kind.
+(``nn.MultiHeadAttention``), ``W`` causal self-attention from a SECOND
+keyword group (the sliding-window layers of a model that mixes them with
+full ones: another window, rotation or head count), ``-`` a dense gated
+MLP (``GatedMLP``). The mixers are built from the keyword groups the
+caller gives for each kind. With ``post_norm`` a block norms its mixer's
+output too, ``x <- x + RMSNorm(mixer(RMSNorm(x)))``, so a layer of an
+attention and a feed-forward block holds four norms.
 """
 
 from __future__ import annotations
@@ -20,27 +25,59 @@ from bigdl_tpu.nn.module import Module
 from bigdl_tpu.ops.remat import block_remat_policy
 
 
-class HybridBlock(Module):
-    """``x + mixer(norm(x))``."""
+class GatedMLP(Module):
+    """The dense gated feed-forward, ``(silu(x W_gate) * x W_up) W_down``,
+    without bias.
 
-    def __init__(self, embed_dim: int, mixer: Module, norm_eps: float):
+    ``TransformerEncoderLayer(activation="swiglu")`` computes the same from
+    three ``Linear``s that hang on the LAYER (``linear_gate``, ``linear1``,
+    ``linear2``, with the layer's ``bias``): the state-dict interop, the
+    tensor-parallel specs and the Qwen builder read them under those names,
+    so that layout stays, and a single-mixer block needs its feed-forward
+    as a module of its own. The arithmetic is this one line in both."""
+
+    def __init__(self, embed_dim: int, hidden_size: int):
+        super().__init__()
+        from bigdl_tpu.nn.linear import Linear
+        self.gate = Linear(embed_dim, hidden_size, with_bias=False)
+        self.up = Linear(embed_dim, hidden_size, with_bias=False)
+        self.down = Linear(hidden_size, embed_dim, with_bias=False)
+
+    def update_output(self, input):
+        return self.down.forward(jax.nn.silu(self.gate.forward(input))
+                                 * self.up.forward(input))
+
+
+class HybridBlock(Module):
+    """``x + mixer(norm(x))``, or with ``post_norm``
+    ``x + norm_post(mixer(norm(x)))``."""
+
+    def __init__(self, embed_dim: int, mixer: Module, norm_eps: float,
+                 post_norm: bool = False):
         super().__init__()
         self.norm = RMSNorm(embed_dim, eps=norm_eps)
         self.mixer = mixer
+        if post_norm:
+            self.norm_post = RMSNorm(embed_dim, eps=norm_eps)
 
     def update_output(self, input):
-        return input + self.mixer.forward(self.norm.forward(input))
+        y = self.mixer.forward(self.norm.forward(input))
+        if "norm_post" in self._modules:
+            y = self.norm_post.forward(y)
+        return input + y
 
 
 class HybridDecoder(Module):
     """The stack a pattern string describes, with a final RMSNorm.
 
-    ``mamba``, ``moe`` and ``attention`` are the keyword arguments of
-    ``nn.Mamba2(embed_dim, ...)``, ``MoE(embed_dim, ...)`` and
-    ``nn.MultiHeadAttention(embed_dim, ..., causal=True)``; a kind the
-    pattern does not use needs none."""
+    ``mamba``, ``moe``, ``attention``, ``window_attention`` and ``mlp``
+    are the keyword arguments of ``nn.Mamba2(embed_dim, ...)``,
+    ``MoE(embed_dim, ...)``, ``nn.MultiHeadAttention(embed_dim, ...,
+    causal=True)`` for the ``*`` and for the ``W`` blocks, and
+    ``GatedMLP(embed_dim, ...)``; a kind the pattern does not use needs
+    none."""
 
-    KINDS = "ME*"
+    KINDS = "ME*W-"
 
     #: as ``TransformerEncoder.remat_blocks``: ``Optimizer.set_remat(
     #: "block")`` sets it, and each block then runs under ``jax.checkpoint``
@@ -48,7 +85,8 @@ class HybridDecoder(Module):
     remat_blocks = False
 
     def __init__(self, pattern: str, embed_dim: int, mamba=None, moe=None,
-                 attention=None, norm_eps: float = 1e-5):
+                 attention=None, norm_eps: float = 1e-5,
+                 window_attention=None, mlp=None, post_norm: bool = False):
         super().__init__()
         bad = set(pattern) - set(self.KINDS)
         if bad or not pattern:
@@ -63,11 +101,14 @@ class HybridDecoder(Module):
             elif kind == "E":
                 from bigdl_tpu.parallel.expert import MoE
                 mixer = MoE(embed_dim, **moe)
+            elif kind == "-":
+                mixer = GatedMLP(embed_dim, **mlp)
             else:
-                mixer = MultiHeadAttention(embed_dim, causal=True,
-                                           **attention)
-            self.add_module(f"layer{i}",
-                            HybridBlock(embed_dim, mixer, norm_eps))
+                mixer = MultiHeadAttention(
+                    embed_dim, causal=True,
+                    **(window_attention if kind == "W" else attention))
+            self.add_module(f"layer{i}", HybridBlock(embed_dim, mixer,
+                                                     norm_eps, post_norm))
         self.final_norm = RMSNorm(embed_dim, eps=norm_eps)
 
     def update_output(self, input):

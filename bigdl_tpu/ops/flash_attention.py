@@ -42,6 +42,22 @@ padded key tile) or all plain (``_masked``). The forward's LSE leaves
 through ``_to_lanes``, not Mosaic's sublane-to-lane relayout. Measured
 times and roofline shares: PERF.md section 5 (ledger, PR 24).
 
+A sliding window (``window``, with ``causal``: query i sees the keys
+``(i - window, i]``) is a static argument of the same three kernels. A call
+that names none lowers to the code it lowered to before the band existed;
+a call that names one masks every tile it meets (the causal edge and the
+band's lower edge in one mask), runs no halved diagonal, and bounds its one
+loop on BOTH sides: the forward and dQ start at the key tile that holds the
+oldest key the query tile's first row can see, dK/dV ends at the query tile
+that holds the last query its last key reaches. At 8,192 tokens and a
+window of 2,048 that is 5 of 16 key tiles a query tile, for 14.7M
+query-key pairs where the full call holds 33.6M. Such calls are named
+``flash_band_fwd``, ``flash_band_bwd_dq``, ``flash_band_bwd_dkv``, so a
+trace tells them from full calls of the same operand shape, and
+``bigdl_flash_attention_total{form=band|full}`` counts each form once a
+trace. A window that reaches past the first key is no band: the call is
+the full one, code and name.
+
 The LSE output is a first-class differentiable output: its cotangent folds
 into the delta term (d lse_i / d logits_ij = p_ij, so delta_i becomes
 rowsum(dO_i * O_i) - g_lse_i). Ring attention exploits exactly this to
@@ -87,16 +103,38 @@ def _below(rows: int, cols: int, offset: int):
             <= lax.broadcasted_iota(jnp.int32, (rows, cols), 0) + offset)
 
 
-def _visible(shape, key_axis: int, k0, q0, sk: int, causal: bool):
+def _visible(shape, key_axis: int, k0, q0, sk: int, causal: bool,
+             window: Optional[int] = None):
     """Mask of a tile whose `key_axis` runs over the keys from position k0
     and whose other axis over the queries from q0: true where the key is
-    real (not padding) and, under a causal mask, not after the query."""
+    real (not padding), under a causal mask not after the query, and under
+    a band no more than ``window - 1`` before it."""
     k_pos = k0 + lax.broadcasted_iota(jnp.int32, shape, key_axis)
     valid = k_pos < sk
     if causal:
         valid = valid & (k_pos <= q0 + lax.broadcasted_iota(
             jnp.int32, shape, 1 - key_axis))
+    if window is not None:
+        valid = valid & (k_pos > q0 - window + lax.broadcasted_iota(
+            jnp.int32, shape, 1 - key_axis))
     return valid
+
+
+def _band_tiles(first, rows: int, block: int, n_blocks, window: int,
+                behind: bool):
+    """The tiles of the OTHER axis that a band lets the ``rows`` positions
+    from ``first`` meet, as (first tile, one past the last). ``behind``:
+    the positions are queries and the tiles hold keys, which reach from
+    ``window - 1`` behind the first query up to the last query; else the
+    positions are keys and the tiles hold queries, from the first key up to
+    ``window - 1`` past the last one. Tiles outside are skipped whole, as
+    the causal kernels skip those above the diagonal."""
+    if behind:
+        lo, hi = first - (window - 1), first + rows - 1
+    else:
+        lo, hi = first, first + rows - 1 + (window - 1)
+    return (lax.div(lax.max(lo, 0), block),
+            lax.min(n_blocks, lax.div(hi, block) + 1))
 
 
 def _halved_diagonal(causal, sq, sk, block_q, block_k) -> bool:
@@ -132,10 +170,17 @@ def _to_lanes(col):
          for i in range(0, n, c)], axis=1)
 
 
+def _name(kernel: str, window: Optional[int]) -> str:
+    """The call's name in the HLO and the device trace: a call with a band
+    is told from a full one of the same operand shape by it."""
+    return f"flash_band_{kernel}" if window is not None else f"flash_{kernel}"
+
+
 # ------------------------------------------------------------------ forward
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, l_ref, *, block_k: int, sk: int,
-                causal: bool, scale: float, block_q: int, diagonal: bool):
+                causal: bool, scale: float, block_q: int, diagonal: bool,
+                window: Optional[int] = None):
     # q_ref: (1, BQ, D); k_ref/v_ref: (1, Sk_pad, D); o_ref: (1, BQ, D);
     # l_ref: (1, 1, BQ) row logsumexp of the scaled, masked logits. The
     # LSE rides a (BH, 1, S) array so its block's penultimate dim equals
@@ -152,9 +197,13 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, l_ref, *, block_k: int, sk: int,
 
     def update(carry, rows, k0, width, valid):
         # One online-softmax step of the query rows `rows` against the keys
-        # [k0, k0 + width). Key 0 is visible to every row and is in the
-        # first tile a row meets, so no row is all-masked when its running
-        # maximum is first used.
+        # [k0, k0 + width). Without a band key 0 is visible to every row
+        # and is in the first tile a row meets, so no row is all-masked
+        # when its running maximum is first used. Under a band a row's
+        # first tiles can lie wholly below its window: they leave p = 1
+        # against a running maximum of _NEG, and the first tile that holds
+        # a visible key (the row's own, at the latest) wipes that with
+        # corr = exp(_NEG - max) = 0.
         acc, rsum, rmax = (x[rows] for x in carry)
         kblk = k_ref[0, pl.ds(k0, width), :]
         vblk = v_ref[0, pl.ds(k0, width), :]
@@ -172,7 +221,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, l_ref, *, block_k: int, sk: int,
 
     def tile(kb, carry, masked):
         valid = _visible((bq, block_k), 1, kb * block_k, j * block_q, sk,
-                         causal) if masked else None
+                         causal, window) if masked else None
         return update(carry, slice(None), kb * block_k, block_k, valid)
 
     carry = (jnp.zeros((bq, d), jnp.float32), jnp.zeros((bq, 1), jnp.float32),
@@ -186,6 +235,13 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, l_ref, *, block_k: int, sk: int,
         lower = update(carry, slice(h, bq), j * block_k, block_k,
                        _below(h, block_k, h))
         acc, rsum, rmax = (jnp.concatenate(x) for x in zip(upper, lower))
+    elif window is not None:
+        # The band: the key tiles from the one that holds the first row's
+        # oldest visible key to the one on the diagonal, every one masked.
+        first, last = _band_tiles(j * block_q, bq, block_k, nkb, window,
+                                  behind=True)
+        acc, rsum, rmax = lax.fori_loop(
+            first, last, functools.partial(tile, masked=True), carry)
     else:
         # One loop, masked or not as a whole (_masked). Key tiles strictly
         # above the diagonal contribute nothing: the last key this query
@@ -203,11 +259,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, l_ref, *, block_k: int, sk: int,
     l_ref[0] = _to_lanes(jnp.where(dead, _NEG, rmax + jnp.log(rsum_safe)))
 
 
-def _flash_fwd_lse(q, k, v, causal, scale, block_q, block_k, interpret):
+def _flash_fwd_lse(q, k, v, causal, scale, block_q, block_k, interpret,
+                   window=None):
     """Returns (o (B,Sq,N,D), lse (B,N,Sq) f32)."""
     b, sq, n, d = q.shape
     sk = k.shape[1]
-    default = _fwd_block(sq, sk, d, q.dtype.itemsize)
+    # under a band every tile is masked, which is where the 1024-tile loses
+    default = _fwd_block(sq, sk, d, q.dtype.itemsize) if window is None \
+        else _BLOCK
     block_q = min(block_q or default, sq)
     block_k = min(block_k or default, sk)
     # BSND -> (B*N, S, D): one grid row per (batch, head).
@@ -227,8 +286,9 @@ def _flash_fwd_lse(q, k, v, causal, scale, block_q, block_k, interpret):
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, block_k=block_k, sk=sk,
                           causal=causal, scale=scale, block_q=block_q,
-                          diagonal=_halved_diagonal(causal, sq, sk, block_q,
-                                                    block_k)),
+                          diagonal=window is None and _halved_diagonal(
+                              causal, sq, sk, block_q, block_k),
+                          window=window),
         out_shape=(jax.ShapeDtypeStruct((b * n, sq_p, d), q.dtype),
                    jax.ShapeDtypeStruct((b * n, 1, sq_p), jnp.float32)),
         grid=grid,
@@ -240,7 +300,7 @@ def _flash_fwd_lse(q, k, v, causal, scale, block_q, block_k, interpret):
         out_specs=(pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
                    pl.BlockSpec((1, 1, block_q), lambda i, j: (i, 0, j))),
         interpret=interpret,
-        name="flash_fwd",
+        name=_name("fwd", window),
     )(qt, kt, vt)
     out = out[:, :sq].reshape(b, n, sq, d).transpose(0, 2, 1, 3)
     lse = lse[:, 0, :sq].reshape(b, n, sq)
@@ -251,7 +311,8 @@ def _flash_fwd_lse(q, k, v, causal, scale, block_q, block_k, interpret):
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, l_ref, d_ref, dq_ref, *,
                    block_k: int, sk: int, causal: bool, scale: float,
-                   block_q: int, diagonal: bool):
+                   block_q: int, diagonal: bool,
+                   window: Optional[int] = None):
     # Per query tile: stream key tiles, recompute p from the saved LSE.
     j = pl.program_id(1)
     q = q_ref[0]                                            # (BQ, D)
@@ -286,7 +347,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, l_ref, d_ref, dq_ref, *,
 
     def tile(kb, dq, masked):
         valid = _visible((bq, block_k), 1, kb * block_k, j * block_q, sk,
-                         causal) if masked else None
+                         causal, window) if masked else None
         return dq + part(slice(None), kb * block_k, block_k, valid)
 
     dq = jnp.zeros((bq, d), jnp.float32)
@@ -296,6 +357,11 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, l_ref, d_ref, dq_ref, *,
         dq = dq + jnp.concatenate([
             part(slice(0, h), j * block_k, h, _below(h, h, 0)),
             part(slice(h, bq), j * block_k, block_k, _below(h, block_k, h))])
+    elif window is not None:
+        first, last = _band_tiles(j * block_q, bq, block_k, nkb, window,
+                                  behind=True)
+        dq = lax.fori_loop(first, last,
+                           functools.partial(tile, masked=True), dq)
     else:
         if causal:
             nkb = lax.min(nkb, lax.div(j * block_q + bq - 1, block_k) + 1)
@@ -308,7 +374,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, l_ref, d_ref, dq_ref, *,
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, l_ref, d_ref,
                     dk_ref, dv_ref, *, block_q: int, sk: int,
                     causal: bool, scale: float, block_k: int,
-                    diagonal: bool):
+                    diagonal: bool, window: Optional[int] = None):
     # Per key tile: stream query tiles, everything TRANSPOSED: the logits
     # tile is (BK, BQ), so the four matmuls take their operands as they lie
     # (k q^T and v dO^T contract the shared head dim, p^T dO and ds^T q are
@@ -347,7 +413,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, l_ref, d_ref,
 
     def tile(qb, carry, masked):
         valid = _visible((bk, block_q), 0, jkb * block_k, qb * block_q, sk,
-                         causal) if masked else None
+                         causal, window) if masked else None
         dk, dv = part(slice(None), qb * block_q, block_q, valid)
         return carry[0] + dk, carry[1] + dv
 
@@ -362,6 +428,13 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, l_ref, d_ref,
         carry = tuple(jnp.concatenate(x) for x in zip(upper, lower))
         dk, dv = lax.fori_loop(jkb + 1, nqb,
                                functools.partial(tile, masked=False), carry)
+    elif window is not None:
+        # The band: from the query tile that holds this key tile's first
+        # row to the one that holds the last query its last key reaches.
+        first, last = _band_tiles(jkb * block_k, bk, block_q, nqb, window,
+                                  behind=False)
+        dk, dv = lax.fori_loop(first, last,
+                               functools.partial(tile, masked=True), zeros)
     else:
         # Causal: query tiles strictly before this key tile's first row see
         # none of its keys.
@@ -373,7 +446,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, l_ref, d_ref,
 
 
 def _flash_bwd(q, k, v, o, lse, g_o, g_l, causal, scale, block_q, block_k,
-               interpret):
+               interpret, window=None):
     b, sq, n, d = q.shape
     sk = k.shape[1]
     block_q = min(block_q or _BLOCK, sq)
@@ -406,12 +479,13 @@ def _flash_bwd(q, k, v, o, lse, g_o, g_l, causal, scale, block_q, block_k,
         kt = jnp.pad(kt, ((0, 0), (0, pad_k), (0, 0)))
         vt = jnp.pad(vt, ((0, 0), (0, pad_k), (0, 0)))
     sq_p, sk_p = qt.shape[1], kt.shape[1]
-    diagonal = _halved_diagonal(causal, sq, sk, block_q, block_k)
+    diagonal = window is None and _halved_diagonal(causal, sq, sk, block_q,
+                                                   block_k)
 
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, block_k=block_k, sk=sk,
                           causal=causal, scale=scale, block_q=block_q,
-                          diagonal=diagonal),
+                          diagonal=diagonal, window=window),
         out_shape=jax.ShapeDtypeStruct((b * n, sq_p, d), q.dtype),
         grid=(b * n, sq_p // block_q),
         in_specs=[
@@ -424,13 +498,13 @@ def _flash_bwd(q, k, v, o, lse, g_o, g_l, causal, scale, block_q, block_k,
         ],
         out_specs=pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
         interpret=interpret,
-        name="flash_bwd_dq",
+        name=_name("bwd_dq", window),
     )(qt, kt, vt, dot, lt, delta)
 
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, block_q=block_q, sk=sk,
                           causal=causal, scale=scale, block_k=block_k,
-                          diagonal=diagonal),
+                          diagonal=diagonal, window=window),
         out_shape=(jax.ShapeDtypeStruct((b * n, sk_p, d), k.dtype),
                    jax.ShapeDtypeStruct((b * n, sk_p, d), v.dtype)),
         grid=(b * n, sk_p // block_k),
@@ -445,7 +519,7 @@ def _flash_bwd(q, k, v, o, lse, g_o, g_l, causal, scale, block_q, block_k,
         out_specs=(pl.BlockSpec((1, block_k, d), lambda i, j: (i, j, 0)),
                    pl.BlockSpec((1, block_k, d), lambda i, j: (i, j, 0))),
         interpret=interpret,
-        name="flash_bwd_dkv",
+        name=_name("bwd_dkv", window),
     )(qt, kt, vt, dot, lt, delta)
 
     dq = dq[:, :sq].reshape(b, n, sq, d).transpose(0, 2, 1, 3)
@@ -456,22 +530,25 @@ def _flash_bwd(q, k, v, o, lse, g_o, g_l, causal, scale, block_q, block_k,
 
 # ------------------------------------------------------ differentiable core
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash_lse(q, k, v, causal, scale, block_q, block_k, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash_lse(q, k, v, causal, scale, block_q, block_k, interpret, window):
     return _flash_fwd_lse(q, k, v, causal, scale, block_q, block_k,
-                          interpret)
+                          interpret, window)
 
 
-def _flash_lse_vjp_fwd(q, k, v, causal, scale, block_q, block_k, interpret):
-    o, lse = _flash_lse(q, k, v, causal, scale, block_q, block_k, interpret)
+def _flash_lse_vjp_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
+                       window):
+    o, lse = _flash_lse(q, k, v, causal, scale, block_q, block_k, interpret,
+                        window)
     return (o, lse), (q, k, v, o, lse)
 
 
-def _flash_lse_vjp_bwd(causal, scale, block_q, block_k, interpret, res, g):
+def _flash_lse_vjp_bwd(causal, scale, block_q, block_k, interpret, window,
+                       res, g):
     g_o, g_l = g
     q, k, v, o, lse = res
     return _flash_bwd(q, k, v, o, lse, g_o, g_l, causal, scale,
-                      block_q, block_k, interpret)
+                      block_q, block_k, interpret, window)
 
 
 _flash_lse.defvjp(_flash_lse_vjp_fwd, _flash_lse_vjp_bwd)
@@ -513,24 +590,52 @@ def _fwd_block(sq: int, sk: int, d: int, itemsize: int) -> int:
     return _BLOCK
 
 
+def _band_of(window: Optional[int], causal: bool, sk: int) -> Optional[int]:
+    """The band the kernels are told of, counted by form. A window that
+    reaches past the first key cuts nothing: the call is then the full
+    one, code and name."""
+    if window is not None:
+        if not causal:
+            raise ValueError("window (a banded causal mask) needs "
+                             "causal=True")
+        if window < 1:
+            raise ValueError("window must be >= 1")
+        if window >= sk:
+            window = None
+    from bigdl_tpu.telemetry import get_registry, instruments
+    # trace-time count, as bigdl_ssd_scan_total: which form a compiled
+    # program holds, not per-step traffic
+    instruments(get_registry()).flash_attention_total.labels(
+        form="full" if window is None else "band").inc()
+    return window
+
+
 def flash_attention(q, k, v, causal: bool = False,
                     scale: Optional[float] = None,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
-                    interpret: Optional[bool] = None) -> jax.Array:
-    """Flash attention, shapes (B, S, N, D); differentiable (Pallas fwd+bwd)."""
+                    interpret: Optional[bool] = None,
+                    window: Optional[int] = None) -> jax.Array:
+    """Flash attention, shapes (B, S, N, D); differentiable (Pallas fwd+bwd).
+
+    ``window`` (with ``causal``): query i sees the keys ``(i - window, i]``,
+    the Mistral convention. The three kernels mask the band's lower edge
+    and skip the tiles that lie wholly below it."""
     if scale is None:
         scale = 1.0 / float(q.shape[-1]) ** 0.5
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    o, _ = _flash_lse(q, k, v, causal, scale, block_q, block_k, interpret)
+    window = _band_of(window, causal, k.shape[1])
+    o, _ = _flash_lse(q, k, v, causal, scale, block_q, block_k, interpret,
+                      window)
     return o
 
 
 def flash_attention_with_lse(
         q, k, v, causal: bool = False, scale: Optional[float] = None,
         block_q: Optional[int] = None, block_k: Optional[int] = None,
-        interpret: Optional[bool] = None) -> Tuple[jax.Array, jax.Array]:
+        interpret: Optional[bool] = None, window: Optional[int] = None
+        ) -> Tuple[jax.Array, jax.Array]:
     """Flash attention returning ``(o (B,S,N,D), lse (B,N,S) f32)``.
 
     The LSE is differentiable (its cotangent folds into the softmax
@@ -542,13 +647,16 @@ def flash_attention_with_lse(
         scale = 1.0 / float(q.shape[-1]) ** 0.5
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    return _flash_lse(q, k, v, causal, scale, block_q, block_k, interpret)
+    window = _band_of(window, causal, k.shape[1])
+    return _flash_lse(q, k, v, causal, scale, block_q, block_k, interpret,
+                      window)
 
 
 def use_flash(q, mask) -> bool:
     """Dispatch policy for MultiHeadAttention: Pallas kernel on real TPU for
-    unmasked sequences (masked paths use the XLA cores which take an
-    arbitrary additive bias).
+    sequences without an arbitrary mask (those use the XLA cores, which take
+    any additive bias). A causal mask and a sliding window are no ``mask``
+    here: both are arguments of the kernels.
 
     The gate is the in-model crossover measured in round 3 on a v5e, with
     the kernels then fed float32 (ROADMAP S5 keeps those numbers): at seq

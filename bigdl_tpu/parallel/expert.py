@@ -85,26 +85,40 @@ class MoE(Module):
     """Top-k gated mixture of expert FFNs (distributed ``MixtureTable``).
 
     Input (..., D) — leading axes are flattened into a token axis. Each
-    expert is a two-layer FFN D -> H -> D. ``held``, ``shared_hidden`` and
-    ``route_scale`` belong to ``dispatch="held"`` (module docstring).
+    expert is a two-layer FFN D -> H -> D, or with ``activation="swiglu"``
+    (``dispatch="held"`` only) the gated form of three matrices,
+    ``(silu(x W_g) * x W_1) W_2``, the shared expert alike. ``held``,
+    ``shared_hidden``, ``route_scale`` and ``train_router`` belong to
+    ``dispatch="held"`` (module docstring). ``train_router=False`` takes
+    the combine weights as constants: a chip that holds a share of the
+    experts and exchanges nothing sees only that share of the router's
+    gradient, and applied alone it trains the router TOWARD the held
+    experts; the router then gets no gradient (nor does the layer's input
+    through it) and keeps its picks.
     """
 
     def __init__(self, input_size: int, hidden_size: int, n_experts: int,
                  k: int = 2, capacity_factor: float = 1.25,
                  activation: str = "gelu", aux_loss_weight: float = 1e-2,
                  dispatch: str = "sort", held=None, bias: bool = True,
-                 shared_hidden: int = 0, route_scale: float = 1.0):
+                 shared_hidden: int = 0, route_scale: float = 1.0,
+                 train_router: bool = True):
         super().__init__()
         if dispatch not in ("sort", "scatter", "einsum", "held"):
             raise ValueError(f"dispatch must be 'sort', 'scatter', "
                              f"'einsum' or 'held', got {dispatch!r}")
-        if activation not in ("gelu", "relu", "relu2"):
+        if activation not in ("gelu", "relu", "relu2", "swiglu"):
             raise ValueError(f"unknown expert activation {activation!r}")
+        if activation == "swiglu" and (dispatch != "held" or bias):
+            raise ValueError("swiglu experts (a gate matrix beside w1, no "
+                             "bias) belong to dispatch='held'")
         if dispatch != "held" and (held is not None or shared_hidden
-                                   or route_scale != 1.0):
-            raise ValueError("held, shared_hidden and route_scale belong to "
-                             "dispatch='held' (the capacity paths route by "
-                             "softmax over experts that are all here)")
+                                   or route_scale != 1.0
+                                   or not train_router):
+            raise ValueError("held, shared_hidden, route_scale and "
+                             "train_router belong to dispatch='held' (the "
+                             "capacity paths route by softmax over experts "
+                             "that are all here)")
         # ids of the experts whose weights live here (all of them unless
         # dispatch='held' names a share); the router is n_experts wide
         # either way
@@ -117,6 +131,7 @@ class MoE(Module):
         self.bias = bias
         self.shared_hidden = shared_hidden
         self.route_scale = route_scale
+        self.train_router = train_router
         self.input_size = input_size
         self.hidden_size = hidden_size
         self.n_experts = n_experts
@@ -134,6 +149,9 @@ class MoE(Module):
             self.register_buffer("select_bias", init.zeros((e,)))
         self.register_parameter(
             "w1", np.stack([init.xavier((d, h), d, h) for _ in range(n)]))
+        if activation == "swiglu":
+            self.register_parameter(
+                "wg", np.stack([init.xavier((d, h), d, h) for _ in range(n)]))
         if bias:
             self.register_parameter("b1", init.zeros((n, h)))
         self.register_parameter(
@@ -144,6 +162,9 @@ class MoE(Module):
             # an always-on expert of its own width beside the routed ones
             hs = shared_hidden
             self.register_parameter("shared_w1", init.xavier((d, hs), d, hs))
+            if activation == "swiglu":
+                self.register_parameter("shared_wg",
+                                        init.xavier((d, hs), d, hs))
             self.register_parameter("shared_w2", init.xavier((hs, d), hs, d))
             if bias:
                 self.register_parameter("shared_b1", init.zeros((hs,)))
@@ -163,6 +184,9 @@ class MoE(Module):
         f32 = jnp.float32
         cd = x.dtype
         hid = jnp.dot(x, w["w1"].astype(cd), preferred_element_type=f32)
+        if "wg" in w:
+            gate = jnp.dot(x, w["wg"].astype(cd), preferred_element_type=f32)
+            return (jax.nn.silu(gate) * hid).astype(cd)
         if "b1" in w:
             hid = hid + w["b1"].astype(f32)
         return self._act(hid).astype(cd)
@@ -177,6 +201,8 @@ class MoE(Module):
         scores = jax.nn.sigmoid(
             jnp.dot(x, self.gate_weight.astype(x.dtype),
                     preferred_element_type=jnp.float32))
+        if not self.train_router:
+            scores = jax.lax.stop_gradient(scores)
         _, picked = jax.lax.top_k(
             scores + jax.lax.stop_gradient(
                 self.select_bias.astype(jnp.float32)), self.k)
@@ -205,7 +231,7 @@ class MoE(Module):
             gate = weight.reshape(-1)[order]
         with jax.named_scope("moe_experts"):
             routed = {p: v for p, v in self._parameters.items()
-                      if p in ("w1", "b1", "w2", "b2")}
+                      if p in ("w1", "wg", "b1", "w2", "b2")}
             y = _grouped_rows(self._hidden, routed, x, tok, gate, counts)
             # kept across a block's rematerialisation
             # (ops.remat.block_remat_policy): the loop runs once forward
@@ -532,6 +558,7 @@ _grouped.defvjp(_grouped_fwd, _grouped_bwd)
 def expert_param_specs(moe: MoE, axis: str = EXPERT_AXIS):
     """PartitionSpecs sharding the stacked expert leaves over ``expert``;
     the router and a shared expert are replicated."""
-    stacked = {"w1": P(axis, None, None), "b1": P(axis, None),
-               "w2": P(axis, None, None), "b2": P(axis, None)}
+    stacked = {"w1": P(axis, None, None), "wg": P(axis, None, None),
+               "b1": P(axis, None), "w2": P(axis, None, None),
+               "b2": P(axis, None)}
     return {name: stacked.get(name, P()) for name in moe._parameters}

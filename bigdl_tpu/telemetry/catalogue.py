@@ -214,6 +214,12 @@ METRIC_SPECS: List[MetricSpec] = [
                "alone, not differentiated). Counted once per eager call / "
                "once per TRACE under jit, as bigdl_ssd_scan_total.",
                ("form",)),
+    MetricSpec("bigdl_flash_attention_total", "counter",
+               "Flash-attention calls by form (form label: band, the "
+               "kernels told of a sliding window, named flash_band_*; "
+               "full, no window or one that reaches past the first key). "
+               "Counted once per eager call / once per TRACE under jit, "
+               "as bigdl_ssd_scan_total.", ("form",)),
     MetricSpec("bigdl_int8_fallbacks_total", "counter",
                "int8_matmul decode-shaped calls that LOST the fused "
                "kernel because K is off the 128-lane quantum (XLA "

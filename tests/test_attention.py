@@ -620,3 +620,224 @@ class TestAttentionProbDropout:
         total = sum(float(jnp.abs(leaf).sum())
                     for leaf in jax.tree_util.tree_leaves(g))
         assert np.isfinite(total) and total > 0
+
+
+# ------------------------------------------------- the band in the kernels
+
+def _banded_and_lse(q, k, v, window):
+    """(o, lse) of the masked XLA core under the mask the band stands for:
+    query i sees the keys (i - window, i]."""
+    s = q.shape[1]
+    i, j = jnp.arange(s)[:, None], jnp.arange(s)[None, :]
+    keep = (j <= i) & (j > i - window)
+    o = ac.dot_product_attention(q, k, v, mask=keep, causal=False)
+    logits = jnp.einsum("bqnd,bknd->bnqk", q, k) / q.shape[-1] ** 0.5
+    logits = jnp.where(keep, logits, jnp.finfo(jnp.float32).min)
+    return o, jax.nn.logsumexp(logits, axis=-1)
+
+
+def _pallas_calls(f, *args):
+    return [e.params["name"] for e in _eqns(jax.make_jaxpr(f)(*args).jaxpr)
+            if e.primitive.name == "pallas_call"]
+
+
+class TestFlashBand:
+    """The three kernels told of a sliding window, in the interpreter,
+    against the masked XLA core: output, LSE and all three gradients."""
+
+    @pytest.mark.parametrize("s,block,window", [
+        (48, 16, 8),        # the band's edge inside a tile, shorter than one
+        (48, 16, 16),       # the edge on a tile's edge
+        (48, 16, 20),       # inside a tile, longer than one
+        (64, 16, 32),       # on a tile's edge, two tiles
+        (64, 16, 33),
+        (40, 16, 7),        # a padded last tile
+        (37, 16, 5),
+        (48, 8, 17),
+        (48, 16, 47),       # one key short of the sequence
+        (48, 16, 1),        # a query sees itself alone
+        (512, 256, 300),    # the tiles that would halve the diagonal
+    ])
+    def test_band_matches_the_masked_core(self, s, block, window):
+        from bigdl_tpu.ops.flash_attention import flash_attention_with_lse
+        rng = np.random.RandomState(s + window)
+        q, k, v, g = (jnp.asarray(rng.randn(2, s, 2, 8), jnp.float32)
+                      for _ in range(4))
+        gl = jnp.asarray(rng.randn(2, 2, s), jnp.float32)
+
+        def kernel(q_, k_, v_):
+            return flash_attention_with_lse(
+                q_, k_, v_, causal=True, block_q=block, block_k=block,
+                interpret=True, window=window)
+
+        def run(f):
+            out, vjp = jax.vjp(f, q, k, v)
+            return out + vjp((g, gl))
+
+        want = run(lambda *t: _banded_and_lse(*t, window))
+        for r, o, name in zip(want, run(kernel),
+                              ("o", "lse", "dq", "dk", "dv")):
+            np.testing.assert_allclose(np.asarray(o), np.asarray(r),
+                                       rtol=2e-4, atol=2e-4,
+                                       err_msg=f"{name} mismatch")
+        assert sorted(set(_pallas_calls(lambda *t: run(kernel), q))) == [
+            "flash_band_bwd_dkv", "flash_band_bwd_dq", "flash_band_fwd"]
+
+    @pytest.mark.parametrize("window", [48, 49, 1000])
+    def test_a_window_that_cuts_nothing_is_the_full_call(self, window):
+        """Equal to the sequence or longer: the same kernels, names and
+        bits as the call that names no window."""
+        from bigdl_tpu.ops.flash_attention import flash_attention_with_lse
+        rng = np.random.RandomState(window)
+        q, k, v = (jnp.asarray(rng.randn(1, 48, 2, 8), jnp.float32)
+                   for _ in range(3))
+
+        def f(w):
+            return lambda *t: flash_attention_with_lse(
+                *t, causal=True, block_q=16, block_k=16, interpret=True,
+                window=w)
+
+        for a, b in zip(f(window)(q, k, v), f(None)(q, k, v)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        assert _pallas_calls(f(window), q, k, v) == ["flash_fwd"]
+
+    def test_a_band_skips_the_tiles_below_it(self):
+        """Tiles wholly below the band are never read, as those above the
+        diagonal are not (a mask alone would let 0 * NaN through): NaN keys
+        far below a query's window reach neither its output nor its dQ,
+        and NaN queries far past a key's reach neither its dK nor its dV."""
+        s, block, window = 64, 16, 16
+        rng = np.random.RandomState(0)
+        q, k, v, g = (jnp.asarray(rng.randn(1, s, 1, 8), jnp.float32)
+                      for _ in range(4))
+
+        def f(q_, k_, v_):
+            return flash_attention(q_, k_, v_, causal=True, block_q=block,
+                                   block_k=block, interpret=True,
+                                   window=window)
+
+        # queries from 48 see keys from 33: key tile [0, 16) is skipped
+        out, vjp = jax.vjp(f, q, k.at[:, :16].set(jnp.nan), v)
+        assert np.isfinite(np.asarray(out[:, 48:])).all()
+        assert np.isfinite(np.asarray(vjp(g)[0][:, 48:])).all()
+        # keys before 16 are seen by queries before 31: query tile
+        # [48, 64) is skipped
+        _, vjp = jax.vjp(f, q.at[:, 48:].set(jnp.nan), k, v)
+        _, dk, dv = vjp(g)
+        assert np.isfinite(np.asarray(dk[:, :16])).all()
+        assert np.isfinite(np.asarray(dv[:, :16])).all()
+
+    @pytest.mark.parametrize("case,jaxpr,values", [
+        ((48, 16, True, "float32"), "168e6d0aabf1ef7c", "5b071ac1539792e0"),
+        ((40, 16, True, "float32"), "76d9d3ea67084614", "34a8898cf0fa3456"),
+        ((48, 16, False, "float32"), "b5cf025b4a423cf1", "8aeef2bca7ab1453"),
+        ((512, 256, True, "bfloat16"), "4877a2172aa4b935",
+         "5b7be01b84ace8e7"),
+    ])
+    def test_a_call_without_a_band_is_the_code_it_was(self, case, jaxpr,
+                                                      values):
+        """The jaxpr of the band-less forward and backward, and the bits
+        they give, as the commit before the band gave them (digests taken
+        there, git a75b99c, jax 0.9.0): masked, padded, full and
+        halved-diagonal calls."""
+        import hashlib
+        from bigdl_tpu.ops.flash_attention import flash_attention_with_lse
+        s, block, causal, dtype = case
+        rng = np.random.RandomState(s + block + causal)
+        q, k, v, g = (jnp.asarray(rng.randn(1, s, 2, 8), dtype)
+                      for _ in range(4))
+        gl = jnp.asarray(rng.randn(1, 2, s), jnp.float32)
+
+        def run(q, k, v, g, gl):
+            out, vjp = jax.vjp(lambda *t: flash_attention_with_lse(
+                *t, causal=causal, block_q=block, block_k=block,
+                interpret=True), q, k, v)
+            return out + vjp((g, gl))
+
+        text = str(jax.make_jaxpr(run)(q, k, v, g, gl))
+        assert hashlib.sha256(text.encode()).hexdigest()[:16] == jaxpr
+        h = hashlib.sha256()
+        for t in run(q, k, v, g, gl):
+            h.update(np.asarray(t, np.float32).tobytes())
+        assert h.hexdigest()[:16] == values
+
+    def test_band_needs_causal_and_a_positive_window(self):
+        x = jnp.zeros((1, 16, 1, 8))
+        with pytest.raises(ValueError):
+            flash_attention(x, x, x, causal=False, window=4, interpret=True)
+        with pytest.raises(ValueError):
+            flash_attention(x, x, x, causal=True, window=0, interpret=True)
+
+    def test_window_layers_take_the_kernel_and_build_no_mask(self,
+                                                             monkeypatch):
+        """On a TPU backend a windowed ``MultiHeadAttention`` goes through
+        the banded kernels (no (S, S) tensor in its jaxpr), counts
+        ``form=band``; an arbitrary mask still takes the XLA core."""
+        from bigdl_tpu.ops import flash_attention as fa
+        from bigdl_tpu.telemetry import get_registry, instruments
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        real = fa.flash_attention
+        monkeypatch.setattr(fa, "flash_attention", lambda *a, **kw: real(
+            *a, **dict(kw, interpret=True)))
+        s, e = 1024, 128
+        m = nn.MultiHeadAttention(e, 2, causal=True, window=256,
+                                  with_bias=False, head_dim=64)
+        x = jnp.asarray(_rand(1, s, e))
+        ins = instruments(get_registry())
+        band0 = ins.flash_attention_total.labels(form="band").value
+        jaxpr = jax.make_jaxpr(m.forward)(x).jaxpr
+        assert ins.flash_attention_total.labels(form="band").value \
+            == band0 + 1
+        names = [e_.params["name"] for e_ in _eqns(jaxpr)
+                 if e_.primitive.name == "pallas_call"]
+        assert names == ["flash_band_fwd"]
+        top = [v.aval.shape for e_ in jaxpr.eqns for v in e_.outvars]
+        assert not [sh for sh in top if sh[-2:] == (s, s)]
+        assert fa.use_flash(x.reshape(1, s, 2, 64), None)
+        assert not fa.use_flash(x.reshape(1, s, 2, 64),
+                                jnp.ones((s, s), bool))
+        banded = m.forward(x)
+        monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+        np.testing.assert_allclose(np.asarray(banded),
+                                   np.asarray(m.forward(x)),
+                                   rtol=2e-4, atol=2e-4)
+
+
+class TestGatedNormedAttention:
+    def test_qk_norm_gate_and_rope_by_hand(self):
+        """``qk_norm`` norms each head of q and k BEFORE the rotation and
+        ``gated`` multiplies the attention's output by the sigmoid of a
+        fourth projection before the out-projection."""
+        from bigdl_tpu.nn.attention import rope_rotate
+        e, h, kv, d, s = 32, 4, 2, 8, 12
+        m = nn.MultiHeadAttention(e, h, causal=True, with_bias=False,
+                                  num_kv_heads=kv, head_dim=d, rope=True,
+                                  qk_norm=True, qk_norm_eps=1e-5, gated=True)
+        m.q_norm.weight = jnp.asarray(1.0 + 0.1 * _rand(d))
+        m.k_norm.weight = jnp.asarray(1.0 + 0.1 * _rand(d))
+        assert m.gate_proj_weight.shape == (h * d, e)
+        x = jnp.asarray(_rand(2, s, e))
+        w = m.in_proj_weight
+        q = (x @ w[:h * d].T).reshape(2, s, h, d)
+        k = (x @ w[h * d:(h + kv) * d].T).reshape(2, s, kv, d)
+        v = (x @ w[(h + kv) * d:].T).reshape(2, s, kv, d)
+
+        def norm(t, g):
+            return t * jax.lax.rsqrt(jnp.mean(t * t, -1, keepdims=True)
+                                     + 1e-5) * g
+
+        pos = jnp.arange(s)
+        q = rope_rotate(norm(q, m.q_norm.weight), pos)
+        k = rope_rotate(norm(k, m.k_norm.weight), pos)
+        k, v = (jnp.repeat(t, h // kv, axis=2) for t in (k, v))
+        ctx = ac.dot_product_attention(q, k, v, causal=True)
+        ctx = ctx.reshape(2, s, h * d) \
+            * jax.nn.sigmoid(x @ m.gate_proj_weight.T)
+        np.testing.assert_allclose(np.asarray(m.forward(x)),
+                                   np.asarray(ctx @ m.out_proj_weight.T),
+                                   rtol=RTOL, atol=ATOL)
+
+    def test_without_them_the_layer_has_the_parameters_it_had(self):
+        m = nn.MultiHeadAttention(16, 4)
+        assert sorted(m._parameters) == ["in_proj_bias", "in_proj_weight",
+                                         "out_proj_bias", "out_proj_weight"]
