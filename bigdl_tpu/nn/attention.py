@@ -27,6 +27,7 @@ from jax.lax import axis_size
 from bigdl_tpu.nn import initialization as init
 from bigdl_tpu.nn.module import Module, TensorModule
 from bigdl_tpu.ops.precision import match_compute
+from bigdl_tpu.ops.remat import ATTN_PROJ, keep
 
 
 class LayerNorm(TensorModule):
@@ -555,7 +556,11 @@ class MultiHeadAttention(Module):
             query = key = value = input
 
         e = getattr(self, "_e_q", self.embed_dim)
-        pq, pk, pv = self._in_projections(query, key, value)
+        # the projections' outputs are kept across a block's
+        # rematerialisation (ops.remat) BEFORE norm and rotation: q/k norm's
+        # backward reads the un-normed value, and both are element-wise
+        pq, pk, pv = (keep(p, ATTN_PROJ)
+                      for p in self._in_projections(query, key, value))
         q = self._split_heads(pq)
         k = self._split_heads(pk)
         v = self._split_heads(pv)
@@ -602,10 +607,12 @@ class MultiHeadAttention(Module):
         b, s, _, _ = ctx.shape
         ctx = ctx.reshape(b, s, e)
         if getattr(self, "gated", False):
-            gate = self._project(query, self.gate_proj_weight, None)
+            gate = keep(self._project(query, self.gate_proj_weight, None),
+                        ATTN_PROJ)
             ctx = ctx * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(
                 ctx.dtype)
-        return self._out_projection(ctx)
+        # read again only by a norm on this output (HybridBlock.norm_post)
+        return keep(self._out_projection(ctx), ATTN_PROJ)
 
     def _attend(self, q, k, v, mask):
         from bigdl_tpu.ops import attention_core, flash_attention

@@ -22,7 +22,7 @@ import jax
 
 from bigdl_tpu.nn.attention import MultiHeadAttention, RMSNorm
 from bigdl_tpu.nn.module import Module
-from bigdl_tpu.ops.remat import block_remat_policy
+from bigdl_tpu.ops.remat import MLP_PROJ, block_remat_policy, keep
 
 
 class GatedMLP(Module):
@@ -44,8 +44,12 @@ class GatedMLP(Module):
         self.down = Linear(hidden_size, embed_dim, with_bias=False)
 
     def update_output(self, input):
-        return self.down.forward(jax.nn.silu(self.gate.forward(input))
-                                 * self.up.forward(input))
+        # the three products' outputs are kept across a block's
+        # rematerialisation (ops.remat); the down one is read again only by
+        # a norm on it (HybridBlock.norm_post)
+        gate = keep(self.gate.forward(input), MLP_PROJ)
+        up = keep(self.up.forward(input), MLP_PROJ)
+        return keep(self.down.forward(jax.nn.silu(gate) * up), MLP_PROJ)
 
 
 class HybridBlock(Module):
@@ -81,7 +85,18 @@ class HybridDecoder(Module):
 
     #: as ``TransformerEncoder.remat_blocks``: ``Optimizer.set_remat(
     #: "block")`` sets it, and each block then runs under ``jax.checkpoint``
-    #: in training, so the backward keeps block boundaries only
+    #: in training. The backward keeps the block boundaries and what
+    #: ``ops.remat.BLOCK_SAVED_NAMES`` lists (the module docstring there has
+    #: the table): an attention block's five projection outputs and flash's
+    #: ``o`` and ``lse`` (30.8 KB a token at 32 / 4 heads of 128 with a gate
+    #: and a second norm, 17.5 KB at 32 / 2 with neither), a dense block's
+    #: gate, up and down outputs (28.7 KB at hidden 6,144 over 2,048), a
+    #: Mamba-2 block's in-projection output (20.6 KB at 10,304 wide), an
+    #: expert block's routing tables, routed output and its shared
+    #: expert's float32 first products (128 B at top-8, 2 bytes a channel,
+    #: 4 bytes a hidden unit or 8 for SwiGLU); norms, rotation, gates, the
+    #: convolution, the scan, the router's product and the shared expert's
+    #: second product run a second time
     remat_blocks = False
 
     def __init__(self, pattern: str, embed_dim: int, mamba=None, moe=None,
@@ -117,8 +132,7 @@ class HybridDecoder(Module):
         for i in range(self.num_layers):
             layer = self._modules[f"layer{i}"]
             if ckpt:
-                # a held-expert layer's routed output is kept: its loop
-                # over row blocks is not run a second time
+                # kept by name, whatever the block is made of (ops.remat)
                 x = jax.checkpoint(lambda h, _l=layer: _l.forward(h),
                                    policy=block_remat_policy())(x)
             else:

@@ -29,6 +29,7 @@ import jax.numpy as jnp
 from bigdl_tpu.nn import initialization as init
 from bigdl_tpu.nn.module import TensorModule
 from bigdl_tpu.ops.precision import match_compute
+from bigdl_tpu.ops.remat import MAMBA_IN_PROJ, keep
 from bigdl_tpu.ops.ssd_scan import ssd_scan
 from bigdl_tpu.utils.rng import RandomGenerator
 
@@ -96,7 +97,10 @@ class Mamba2(TensorModule):
         d_inner, conv_dim = self.d_inner, self.conv_dim
         bsz, length, _ = input.shape
         w_in = self.in_proj_weight
-        zxbcdt = jnp.matmul(match_compute(input, w_in), w_in.T)
+        # kept across a block's rematerialisation (ops.remat): the widest
+        # product of the block runs once; conv, softplus, gate and scan twice
+        zxbcdt = keep(jnp.matmul(match_compute(input, w_in), w_in.T),
+                      MAMBA_IN_PROJ)
         z = zxbcdt[..., :d_inner]
         xbc = self._conv(zxbcdt[..., d_inner:d_inner + conv_dim])
         dt = zxbcdt[..., d_inner + conv_dim:]

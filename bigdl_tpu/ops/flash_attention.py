@@ -78,6 +78,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
+from bigdl_tpu.ops.remat import FLASH_OUT, keep
+
 _NEG = float(jnp.finfo(jnp.float32).min)
 _NT = (((1,), (1,)), ((), ()))        # a (M, K) x b (N, K) -> (M, N): b as it lies
 
@@ -540,6 +542,11 @@ def _flash_lse_vjp_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
                        window):
     o, lse = _flash_lse(q, k, v, causal, scale, block_q, block_k, interpret,
                         window)
+    # kept across a block's rematerialisation (ops.remat), HERE because
+    # both are residuals of this rule as well as outputs: a tag on the
+    # caller's ``o`` alone leaves ``lse`` to be recomputed, which runs the
+    # forward kernel a second time
+    o, lse = keep(o, FLASH_OUT), keep(lse, FLASH_OUT)
     return (o, lse), (q, k, v, o, lse)
 
 

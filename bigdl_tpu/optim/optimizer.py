@@ -272,8 +272,19 @@ class Optimizer:
         materializing those activation copies to HBM.
 
         ``"block"``: per-transformer-block checkpointing — every
-        ``TransformerEncoder`` in the model recomputes inside each block
-        during the backward, keeping only block-boundary activations. THE
+        ``TransformerEncoder`` or ``HybridDecoder`` in the model recomputes
+        inside each block during the backward. A ``TransformerEncoder``
+        keeps only block-boundary activations. A ``HybridDecoder`` also
+        keeps what ``ops.remat.BLOCK_SAVED_NAMES`` lists, one list for
+        every caller: flash attention's ``o`` and ``lse``, the q, k, v,
+        gate and out projections' outputs, a dense gated MLP's three
+        products, a Mamba-2 in-projection's output, a held-expert layer's
+        routing tables, routed output and its shared expert's float32
+        first products (17.5-30.8 KB a token an attention block, 28.7 KB a
+        dense block, 20.6 KB a Mamba-2 block, 12.4-20.3 KB an expert block
+        at the benchmark's widths in bf16), so the backward's second
+        forward is norms, rotation, gates, the convolution, the scan, the
+        router's product and the shared expert's second product. THE
         policy for billion-param LMs (full remat saves nothing there: one
         outer checkpoint re-materialises all intermediates in its replay).
 

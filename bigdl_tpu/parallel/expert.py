@@ -53,12 +53,12 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from bigdl_tpu.nn import initialization as init
 from bigdl_tpu.nn.module import Module
-from bigdl_tpu.ops.remat import MOE_ROUTED_OUT
+from bigdl_tpu.ops.remat import (MOE_ROUTE_TABLES, MOE_ROUTED_OUT,
+                                 MOE_SHARED_HID, keep)
 from bigdl_tpu.parallel.mesh import EXPERT_AXIS
 
 
@@ -177,23 +177,21 @@ class MoE(Module):
             return jnp.square(jax.nn.relu(x))
         return jax.nn.relu(x)
 
-    def _hidden(self, w, x):
+    def _hidden(self, w, x, tag=lambda v: v):
         """An expert's activations on its rows, before its output matrix:
         ``w`` holds that expert's matrices under the stacked leaves' names
-        (``shared_`` stripped)."""
+        (``shared_`` stripped). ``tag`` is applied to the float32 first
+        products (the shared expert's are kept across block remat)."""
         f32 = jnp.float32
         cd = x.dtype
-        hid = jnp.dot(x, w["w1"].astype(cd), preferred_element_type=f32)
+        hid = tag(jnp.dot(x, w["w1"].astype(cd), preferred_element_type=f32))
         if "wg" in w:
-            gate = jnp.dot(x, w["wg"].astype(cd), preferred_element_type=f32)
+            gate = tag(jnp.dot(x, w["wg"].astype(cd),
+                               preferred_element_type=f32))
             return (jax.nn.silu(gate) * hid).astype(cd)
         if "b1" in w:
             hid = hid + w["b1"].astype(f32)
         return self._act(hid).astype(cd)
-
-    def _ffn(self, w, x):
-        """One expert on its rows."""
-        return _project(w, self._hidden(w, x))
 
     def _route(self, x):
         """The held layer's router: (expert ids (T, k), combine weights
@@ -206,7 +204,10 @@ class MoE(Module):
         _, picked = jax.lax.top_k(
             scores + jax.lax.stop_gradient(
                 self.select_bias.astype(jnp.float32)), self.k)
-        w = jnp.take_along_axis(scores, picked, axis=-1)
+        # kept across a block's rematerialisation (ops.remat), as float32
+        picked = keep(picked, MOE_ROUTE_TABLES)
+        w = keep(jnp.take_along_axis(scores, picked, axis=-1),
+                 MOE_ROUTE_TABLES)
         w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
         return picked, w * self.route_scale
 
@@ -225,24 +226,32 @@ class MoE(Module):
             local_of[list(self.held)] = np.arange(n, dtype=np.int32)
             lid = jnp.asarray(local_of)[picked.reshape(-1)]      # (T*k,)
             rows = t * min(k, n)            # the picks that CAN land here
-            order = jnp.argsort(lid, stable=True)[:rows]
-            counts = jnp.bincount(lid, length=n + 1)[:n].astype(jnp.int32)
+            # the routing's tables are kept across a block's
+            # rematerialisation (``_route`` keeps the picks and their
+            # scores): the top-k, the sort, the count and the gathers run
+            # once; the router's product and the weights' arithmetic twice
+            order = keep(jnp.argsort(lid, stable=True)[:rows],
+                         MOE_ROUTE_TABLES)
+            counts = keep(jnp.bincount(lid, length=n + 1)[:n]
+                          .astype(jnp.int32), MOE_ROUTE_TABLES)
             tok = (order // k).astype(jnp.int32)
-            gate = weight.reshape(-1)[order]
+            gate = keep(weight.reshape(-1)[order], MOE_ROUTE_TABLES)
         with jax.named_scope("moe_experts"):
             routed = {p: v for p, v in self._parameters.items()
                       if p in ("w1", "wg", "b1", "w2", "b2")}
             y = _grouped_rows(self._hidden, routed, x, tok, gate, counts)
             # kept across a block's rematerialisation
             # (ops.remat.block_remat_policy): the loop runs once forward
-            y = checkpoint_name(y.astype(input.dtype), MOE_ROUTED_OUT) \
+            y = keep(y.astype(input.dtype), MOE_ROUTED_OUT) \
                 .astype(jnp.float32)
         if self.shared_hidden:
             with jax.named_scope("moe_shared"):
                 shared = {p[len("shared_"):]: v
                           for p, v in self._parameters.items()
                           if p.startswith("shared_")}
-                y = y + self._ffn(shared, x).astype(jnp.float32)
+                hid = self._hidden(shared, x,
+                                   lambda v: keep(v, MOE_SHARED_HID))
+                y = y + _project(shared, hid).astype(jnp.float32)
         return y.astype(input.dtype).reshape(orig_shape)
 
     def update_output(self, input):

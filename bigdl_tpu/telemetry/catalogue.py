@@ -220,6 +220,13 @@ METRIC_SPECS: List[MetricSpec] = [
                "full, no window or one that reaches past the first key). "
                "Counted once per eager call / once per TRACE under jit, "
                "as bigdl_ssd_scan_total.", ("form",)),
+    MetricSpec("bigdl_remat_kept_total", "counter",
+               "Values tagged for block remat to keep (ops/remat.keep), "
+               "by the name on its save-list (name label: one of "
+               "ops/remat.BLOCK_SAVED_NAMES). Counted once per eager call / "
+               "once per TRACE under jit, as bigdl_ssd_scan_total: the "
+               "tags a compiled program met, inside a checkpointed block "
+               "or not.", ("name",)),
     MetricSpec("bigdl_int8_fallbacks_total", "counter",
                "int8_matmul decode-shaped calls that LOST the fused "
                "kernel because K is off the 128-lane quantum (XLA "

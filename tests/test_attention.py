@@ -735,13 +735,17 @@ class TestFlashBand:
          "5b7be01b84ace8e7"),
     ])
     def test_a_call_without_a_band_is_the_code_it_was(self, case, jaxpr,
-                                                      values):
+                                                      values, monkeypatch):
         """The jaxpr of the band-less forward and backward, and the bits
         they give, as the commit before the band gave them (digests taken
         there, git a75b99c, jax 0.9.0): masked, padded, full and
-        halved-diagonal calls."""
+        halved-diagonal calls. The forward rule's two tags for block remat
+        (``ops.remat.keep`` on ``o`` and ``lse``, identities that name a
+        value) are newer than the digests and are taken out of the text."""
         import hashlib
+        from bigdl_tpu.ops import flash_attention as fa
         from bigdl_tpu.ops.flash_attention import flash_attention_with_lse
+        monkeypatch.setattr(fa, "keep", lambda value, name: value)
         s, block, causal, dtype = case
         rng = np.random.RandomState(s + block + causal)
         q, k, v, g = (jnp.asarray(rng.randn(1, s, 2, 8), dtype)
