@@ -475,6 +475,107 @@ def afmoe_lm_kwargs(config: Dict[str, Any], held_experts=None,
     return kwargs
 
 
+def joyai_llm_flash_lm_kwargs(config: Dict[str, Any], held_experts=None,
+                             train_router: bool = True,
+                             mtp_loss_weight: float = 0.3,
+                             picks_by_token: bool = False) -> Dict[str, Any]:
+    """``models.hybrid.build_hybrid_lm`` kwargs for an HF
+    ``joyai_llm_flash`` ``config.json`` dict (JoyAI-LLM-Flash; every key is
+    DeepSeek-V3's): latent attention in every layer (``q_lora_rank``,
+    ``kv_lora_rank``, the three head sizes, rotation of the rotary parts
+    at ``rope_theta``), a dense SwiGLU feed-forward in the leading
+    ``first_k_dense_replace`` layers and after them sigmoid-routed SwiGLU
+    experts (``noaux_tc``: top ``num_experts_per_tok`` of score + bias,
+    the picked scores renormalised and scaled by
+    ``routed_scaling_factor``) beside one shared expert; one norm before
+    every mixer; ``num_nextn_predict_layers`` multi-token-prediction
+    modules (0 or 1) over the shared embedding and head. The pattern is
+    ``L-`` for a dense layer and ``LE`` for an expert layer.
+
+    ``held_experts`` lists the routed experts that live on this chip of an
+    expert-parallel deployment (default: all ``n_routed_experts``, which
+    is always the router's width); ``vocab_size`` may be a slice.
+    ``train_router=False`` is ``MoE(train_router=False)`` in every expert
+    layer, as ``afmoe_lm_kwargs``. ``mtp_loss_weight`` is the prediction
+    module's loss weight: a training setting, no key of the config (0.3,
+    the DeepSeek-V3 report's early value). ``picks_by_token`` is
+    ``MoE(pick_rows=vocab_size)`` in every expert layer: each token's
+    experts from a table by its id, for the builder to fill.
+
+    ``rope_interleave`` says how a CHECKPOINT's rotary columns are ordered
+    (pairs side by side); here they are paired by halves, which seeded
+    weights cannot tell apart and an import would permute. Refused rather
+    than guessed: a group-limited router, scores other than sigmoid,
+    ``norm_topk_prob`` false, rope scaling, a plain q projection
+    (``q_lora_rank`` null), a tied head, more than one shared expert,
+    expert layers that alternate with dense ones (``moe_layer_freq``), an
+    activation other than silu, a biased attention, more than one
+    prediction module."""
+    if int(config.get("n_group", 1)) != 1 \
+            or int(config.get("topk_group", 1)) != 1:
+        raise ValueError("group-limited expert routing (n_group > 1) is "
+                         "not mapped")
+    if config.get("scoring_func", "sigmoid") != "sigmoid" \
+            or not config.get("norm_topk_prob", True):
+        raise ValueError("routing other than sigmoid scores with "
+                         "norm_topk_prob is not mapped")
+    if config.get("hidden_act", "silu") != "silu":
+        raise ValueError(f"unsupported activation "
+                         f"{config.get('hidden_act')!r}")
+    if config.get("rope_scaling"):
+        raise ValueError("rope_scaling is not mapped for latent attention")
+    if config.get("q_lora_rank") is None:
+        raise ValueError("a plain q projection (q_lora_rank null) is not "
+                         "mapped")
+    if config.get("tie_word_embeddings", False):
+        raise ValueError("a head tied to the embedding is not mapped")
+    if int(config.get("n_shared_experts", 1)) not in (0, 1):
+        raise ValueError("more than one shared expert is not mapped")
+    if int(config.get("moe_layer_freq", 1)) != 1:
+        raise ValueError("moe_layer_freq other than 1 is not mapped")
+    if config.get("attention_bias", False):
+        raise ValueError("a biased latent attention is not mapped")
+    modules = int(config.get("num_nextn_predict_layers", 0))
+    if modules not in (0, 1):
+        raise ValueError("more than one multi-token-prediction module is "
+                         "not mapped")
+    layers = int(config["num_hidden_layers"])
+    dense = min(int(config.get("first_k_dense_replace", 0)), layers)
+    pattern = "L-" * dense + "LE" * (layers - dense)
+    if modules and not pattern.endswith("LE"):
+        raise ValueError("a prediction module over a stack with no expert "
+                         "layer is not mapped")
+    eps = float(config.get("rms_norm_eps", 1e-6))
+    width = int(config["moe_intermediate_size"])
+    kwargs = dict(
+        vocab_size=int(config["vocab_size"]),
+        embed_dim=int(config["hidden_size"]), pattern=pattern, norm_eps=eps,
+        latent_attention=dict(
+            num_heads=int(config["num_attention_heads"]),
+            q_lora_rank=int(config["q_lora_rank"]),
+            kv_lora_rank=int(config["kv_lora_rank"]),
+            qk_nope_head_dim=int(config["qk_nope_head_dim"]),
+            qk_rope_head_dim=int(config["qk_rope_head_dim"]),
+            v_head_dim=int(config["v_head_dim"]),
+            rope_theta=float(config.get("rope_theta", 1e4)), norm_eps=eps))
+    if dense:
+        kwargs["mlp"] = dict(hidden_size=int(config["intermediate_size"]))
+    if layers > dense:
+        kwargs["moe"] = dict(
+            hidden_size=width, n_experts=int(config["n_routed_experts"]),
+            k=int(config["num_experts_per_tok"]), activation="swiglu",
+            dispatch="held",
+            held=None if held_experts is None else tuple(held_experts),
+            bias=False,
+            shared_hidden=width * int(config.get("n_shared_experts", 1)),
+            route_scale=float(config.get("routed_scaling_factor", 1.0)),
+            train_router=train_router,
+            pick_rows=int(config["vocab_size"]) if picks_by_token else 0)
+    if modules:
+        kwargs["mtp"] = dict(loss_weight=float(mtp_loss_weight))
+    return kwargs
+
+
 # ------------------------------------------------------------------- export
 
 def export_gpt2_state_dict(model: Module) -> Dict[str, np.ndarray]:

@@ -1,7 +1,8 @@
 """Causal LM over a pattern-built decoder (``nn.HybridDecoder``): the hybrid
-Mamba-2 / attention / mixture-of-experts family, and the decoders that mix
+Mamba-2 / attention / mixture-of-experts family, the decoders that mix
 sliding-window with full attention layers over dense and expert
-feed-forwards. Same shape as ``models.transformer.build_lm`` — embedding,
+feed-forwards, and those of latent attention with a multi-token-prediction
+module. Same shape as ``models.transformer.build_lm`` — embedding,
 decoder, fused-CE head — with the per-layer pattern in place of one
 repeated block and no positional module (state-space layers carry order;
 an attention group that rotates says so itself, ``rope=True``).
@@ -14,25 +15,87 @@ from typing import Optional
 from bigdl_tpu import nn
 
 
+class _LM(nn.Sequential):
+    """The chain embedding -> decoder -> head of a stack whose expert
+    layers pick by token id (``MoE(pick_rows=)``): it runs under
+    ``parallel.expert.token_ids``."""
+
+    def update_output(self, input):
+        from bigdl_tpu.parallel.expert import token_ids
+        with token_ids(input):
+            return super().update_output(input)
+
+
+class _LMWithMTP(_LM):
+    """The chain embedding -> decoder -> head with a multi-token-prediction
+    module (``nn.MTPModule``, the child ``mtp``) beside it. In training the
+    fused-CE Table the head emits gains the module's stream and its loss
+    weight (``mtp``, ``mtp_weight``), for ``nn.FusedLMHeadCriterion`` to
+    put through the same head against the token after next; in eval the
+    module does not run and the output is the chain's. The main stack runs
+    under ``token_ids`` of the input, the module under those one position
+    on (the token whose embedding its position takes in; the last
+    position, which no loss reads, gets the first token's)."""
+
+    def update_output(self, input):
+        import jax.numpy as jnp
+        from bigdl_tpu.parallel.expert import token_ids
+        if not self.training:
+            return super().update_output(input)
+        *front, decoder, head = self._ordered
+        x = input
+        for m in front:
+            x = m.forward(x)
+        with token_ids(input):
+            stream = decoder.stream(x)
+        out = head.forward(decoder.final_norm.forward(stream))
+        with token_ids(jnp.roll(input, -1, axis=-1)):
+            out["mtp"] = self.mtp.forward((x, stream))
+        out["mtp_weight"] = self.mtp.loss_weight
+        return out
+
+
 def build_hybrid_lm(vocab_size: int, embed_dim: int, pattern: str,
                     mamba: Optional[dict] = None, moe: Optional[dict] = None,
                     attention: Optional[dict] = None,
                     norm_eps: float = 1e-5,
                     window_attention: Optional[dict] = None,
                     mlp: Optional[dict] = None, post_norm: bool = False,
-                    embed_scale: Optional[float] = None) -> nn.Sequential:
+                    embed_scale: Optional[float] = None,
+                    latent_attention: Optional[dict] = None,
+                    mtp: Optional[dict] = None) -> nn.Sequential:
     """1-based token ids (N, T) -> the fused-CE tail: train with
     ``nn.FusedLMHeadCriterion``; eval/predict see log-probs (N, T, vocab).
     ``vocab_size`` may be this chip's slice of a sharded vocabulary: the
     embedding, the head and the loss are then over the slice. The head is
-    its own matrix (the family does not tie it to the embedding).
+    its own matrix (the family does not tie it to the embedding). Expert
+    layers that pick by token id (``moe={"pick_rows": vocab_size, ...}``)
+    are told the ids of the stream.
     ``embed_scale`` multiplies the embedding's rows on their way into the
-    stack (``sqrt(embed_dim)`` in the families that scale it)."""
-    m = nn.Sequential().add(nn.LookupTable(vocab_size, embed_dim))
+    stack (``sqrt(embed_dim)`` in the families that scale it).
+
+    ``mtp`` (``{"loss_weight": w}``) adds ONE multi-token-prediction module
+    (``nn.MTPModule``): two norms, a (2E -> E) projection, one more layer of
+    the pattern's last two kinds (its mixer and its feed-forward, built
+    from the same keyword groups) and a norm in front of the head. It has
+    no embedding and no head of its own: it reads the model's lookup table
+    and its stream goes through the model's ``LMHead``. In training the
+    criterion returns ``L_main + w * L_mtp``; eval is the main model's."""
+    groups = dict(mamba=mamba, moe=moe, attention=attention,
+                  norm_eps=norm_eps, window_attention=window_attention,
+                  mlp=mlp, post_norm=post_norm,
+                  latent_attention=latent_attention)
+    if mtp is not None:
+        m = _LMWithMTP()
+    else:
+        m = _LM() if (moe or {}).get("pick_rows") else nn.Sequential()
+    m.add(nn.LookupTable(vocab_size, embed_dim))
     if embed_scale is not None:
         m.add(nn.MulConstant(float(embed_scale)))
-    m.add(nn.HybridDecoder(pattern, embed_dim, mamba=mamba, moe=moe,
-                           attention=attention, norm_eps=norm_eps,
-                           window_attention=window_attention, mlp=mlp,
-                           post_norm=post_norm))
-    return m.add(nn.LMHead(embed_dim, vocab_size, with_bias=False))
+    m.add(nn.HybridDecoder(pattern, embed_dim, **groups))
+    m.add(nn.LMHead(embed_dim, vocab_size, with_bias=False))
+    if mtp is not None:     # after the chain: modules() in forward order
+        m.mtp = nn.MTPModule(
+            embed_dim, nn.HybridDecoder(pattern[-2:], embed_dim, **groups),
+            norm_eps, **mtp)
+    return m

@@ -73,8 +73,10 @@ from bigdl_tpu.nn.recurrent import (
     BiRecurrent, TimeDistributed,
 )
 from bigdl_tpu.nn.attention import (
-    LayerNorm, RMSNorm, MultiHeadAttention, PositionalEncoding,
-    LearnedPositionalEncoding, TransformerEncoderLayer, TransformerEncoder,
+    LayerNorm, RMSNorm, MultiHeadAttention, LatentAttention,
+    PositionalEncoding, LearnedPositionalEncoding, TransformerEncoderLayer,
+    TransformerEncoder,
 )
 from bigdl_tpu.nn.mamba import Mamba2
-from bigdl_tpu.nn.hybrid import GatedMLP, HybridBlock, HybridDecoder
+from bigdl_tpu.nn.hybrid import (GatedMLP, HybridBlock, HybridDecoder,
+                                 MTPModule)

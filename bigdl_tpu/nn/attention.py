@@ -656,6 +656,113 @@ class MultiHeadAttention(Module):
                 f"{', causal' if self.causal else ''})")
 
 
+class LatentAttention(Module):
+    """Causal multi-head LATENT self-attention (MLA, DeepSeek-V2/V3): the
+    queries and the keys and values each pass through a narrow latent with
+    a norm on it, and a head's query and key are a content part beside a
+    rotary part whose KEY is one head shared by all.
+
+    Input (B, S, E). With ``n = num_heads``, ``dc = qk_nope_head_dim``,
+    ``dr = qk_rope_head_dim``, ``dv = v_head_dim``::
+
+        cq        = RMSNorm(x Wqa)                   (q_lora_rank)
+        [qc ; qr] = cq Wqb                           n heads of dc + dr
+        [ckv ; kr] = x Wkva                          kv_lora_rank + dr
+        [kc ; v]  = RMSNorm(ckv) Wkvb                n heads of dc + dv
+        q_h = [qc_h ; rot(qr_h)],  k_h = [kc_h ; rot(kr)]
+        o_h = softmax(q_h k_h^T / sqrt(dc + dr), causal) v_h
+        y   = concat(o_h) Wo
+
+    No bias anywhere. ``rot`` is ``rope_rotate`` (feature i paired with
+    i + dr/2; a checkpoint whose rotary columns are interleaved permutes
+    them on import). The weights are (out, in), named after the HF
+    DeepSeek-V3 projections: ``q_a`` / ``q_b`` (down, up), ``kv_a`` /
+    ``kv_b``, ``out_proj``. This is the EXPANDED path, the one training
+    takes: the latent is up-projected to per-head keys and values, which
+    attend through the flash kernels at a q/k head of dc + dr over a v
+    head of dv (``ops/flash_attention.py``: ``flash_mla_*``), nothing
+    padded; the XLA core below 1,024 tokens and off the TPU. There is no
+    decode mode (serving keeps the latent as the cache and absorbs Wkvb
+    into the query and the output: ROADMAP R1). Everything but the
+    attention core runs under the scope ``mla_proj``.
+    """
+
+    def __init__(self, embed_dim: int, num_heads: int, q_lora_rank: int,
+                 kv_lora_rank: int, qk_nope_head_dim: int,
+                 qk_rope_head_dim: int, v_head_dim: int,
+                 rope_theta: float = 10000.0, norm_eps: float = 1e-6):
+        super().__init__()
+        if qk_rope_head_dim % 2:
+            raise ValueError("rope needs an even qk_rope_head_dim")
+        self.embed_dim, self.num_heads = embed_dim, num_heads
+        self.kv_lora_rank = kv_lora_rank
+        self.qk_nope_head_dim = qk_nope_head_dim
+        self.qk_rope_head_dim = qk_rope_head_dim
+        self.v_head_dim = v_head_dim
+        self.rope_theta = rope_theta
+        # of the softmax's logits: the whole q/k head's, not the value's
+        self.softmax_scale = (qk_nope_head_dim + qk_rope_head_dim) ** -0.5
+        e_q = num_heads * (qk_nope_head_dim + qk_rope_head_dim)
+        e_kv = num_heads * (qk_nope_head_dim + v_head_dim)
+        e_o = num_heads * v_head_dim
+        for name, rows, cols in (
+                ("q_a_weight", q_lora_rank, embed_dim),
+                ("q_b_weight", e_q, q_lora_rank),
+                ("kv_a_weight", kv_lora_rank + qk_rope_head_dim, embed_dim),
+                ("kv_b_weight", e_kv, kv_lora_rank),
+                ("out_proj_weight", embed_dim, e_o)):
+            self.register_parameter(name, init.xavier((rows, cols), cols,
+                                                      rows))
+        self.q_a_norm = RMSNorm(q_lora_rank, eps=norm_eps)
+        self.kv_a_norm = RMSNorm(kv_lora_rank, eps=norm_eps)
+
+    @staticmethod
+    def _project(x, w):
+        return jnp.matmul(match_compute(x, w), w.T)
+
+    def update_output(self, input):
+        from bigdl_tpu.ops import attention_core, flash_attention
+        from bigdl_tpu.telemetry import get_registry, instruments
+        # trace-time count, as bigdl_ssd_scan_total
+        instruments(get_registry()).latent_attention_total.labels(
+            path="expanded").inc()
+        b, s, _ = input.shape
+        n, dc, dr = self.num_heads, self.qk_nope_head_dim, \
+            self.qk_rope_head_dim
+        with jax.named_scope("mla_proj"):
+            # none of the projections' outputs is tagged for block remat
+            # to keep (ops.remat): on the v5e at 8,192 tokens keeping the
+            # two up-projections' cost 0.95 GB at the step's peak and
+            # keeping all five 1.12 GB, for 0.5 ms of 368 at best (PERF.md
+            # section 6, PR 34); flash's o and lse are kept by the kernel
+            cq = self.q_a_norm.forward(self._project(input, self.q_a_weight))
+            q = self._project(cq, self.q_b_weight).reshape(b, s, n, dc + dr)
+            ckv = self._project(input, self.kv_a_weight)
+            kv = self._project(
+                self.kv_a_norm.forward(ckv[..., :self.kv_lora_rank]),
+                self.kv_b_weight).reshape(b, s, n, -1)
+            pos = jnp.arange(s)
+            qr = rope_rotate(q[..., dc:], pos, self.rope_theta)
+            kr = rope_rotate(ckv[:, :, None, self.kv_lora_rank:], pos,
+                             self.rope_theta)
+            q = jnp.concatenate([q[..., :dc], qr], -1)
+            k = jnp.concatenate(
+                [kv[..., :dc], jnp.broadcast_to(kr, (b, s, n, dr))], -1)
+            v = kv[..., dc:]
+        if flash_attention.use_flash(q, None):
+            ctx = flash_attention.flash_attention(
+                q, k, v, causal=True, scale=self.softmax_scale)
+        else:
+            ctx = attention_core.dot_product_attention(
+                q, k, v, causal=True, scale=self.softmax_scale)
+        with jax.named_scope("mla_proj"):
+            return self._project(ctx.reshape(b, s, -1), self.out_proj_weight)
+
+    def __repr__(self):
+        return (f"LatentAttention({self.embed_dim}, heads={self.num_heads}, "
+                f"latent={self.kv_lora_rank})")
+
+
 class _AddedPositionBase(TensorModule):
     """Shared machinery for additive position encodings: a (max_len, E)
     table added to (B, S, E) input, with the incremental-decode offset

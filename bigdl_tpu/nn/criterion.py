@@ -541,6 +541,14 @@ class FusedLMHeadCriterion(Criterion):
     ``TimeDistributedCriterion(ClassNLLCriterion())`` on the unfused tail
     (the inner NLL's size-average already spans the merged batch*time axis,
     i.e. the loss is the flat mean over every position).
+
+    Multi-token prediction: where the training Table also carries ``mtp``
+    (the stream of a ``nn.MTPModule``, whose position i predicts the token
+    after next) and ``mtp_weight``, the loss is ``L_main + mtp_weight *
+    L_mtp``: the second term is the same fused pass over the same head
+    against the targets shifted one to the left, the last position left
+    out (``ignore_index``, or 0 where none is set: no 1-based id), under
+    the scope ``mtp``.
     """
 
     def __init__(self, chunk: Optional[int] = None,
@@ -554,16 +562,27 @@ class FusedLMHeadCriterion(Criterion):
     def update_output(self, input, target):
         from bigdl_tpu.ops.lm_head_ce import fused_lm_head_ce
         if isinstance(input, (Table, tuple, list)):
+            nxt = None
             if isinstance(input, Table):
-                hidden, weight = input[1], input[2]
-                bias = input[3] if len(input) >= 3 else None
+                hidden, weight, bias = input[1], input[2], input.get(3)
+                nxt = input.get("mtp")
             else:
                 hidden, weight = input[0], input[1]
                 bias = input[2] if len(input) >= 3 else None
-            return fused_lm_head_ce(hidden, weight, bias, target,
+            loss = fused_lm_head_ce(hidden, weight, bias, target,
                                     chunk=self.chunk,
                                     size_average=self.size_average,
                                     ignore_index=self.ignore_index)
+            if nxt is None:
+                return loss
+            left_out = 0 if self.ignore_index is None else self.ignore_index
+            with jax.named_scope("mtp"):
+                after_next = jnp.concatenate(
+                    [target[:, 1:], jnp.full_like(target[:, :1], left_out)],
+                    axis=1)
+                return loss + input["mtp_weight"] * fused_lm_head_ce(
+                    nxt, weight, bias, after_next, chunk=self.chunk,
+                    size_average=self.size_average, ignore_index=left_out)
         # eval fallback: input already log-probs (B, S, V) or (N, V)
         logp = input
         tgt = target.astype(jnp.int32) - 1

@@ -9,18 +9,26 @@ character: ``M`` a Mamba-2 layer (``nn.Mamba2``), ``E`` a mixture of
 experts (``parallel.expert.MoE``), ``*`` causal self-attention
 (``nn.MultiHeadAttention``), ``W`` causal self-attention from a SECOND
 keyword group (the sliding-window layers of a model that mixes them with
-full ones: another window, rotation or head count), ``-`` a dense gated
-MLP (``GatedMLP``). The mixers are built from the keyword groups the
+full ones: another window, rotation or head count), ``L`` causal latent
+self-attention (``nn.LatentAttention``), ``-`` a dense gated MLP
+(``GatedMLP``). The mixers are built from the keyword groups the
 caller gives for each kind. With ``post_norm`` a block norms its mixer's
 output too, ``x <- x + RMSNorm(mixer(RMSNorm(x)))``, so a layer of an
 attention and a feed-forward block holds four norms.
+
+``MTPModule`` is a multi-token-prediction module over such a stack: a
+short second stack fed the main one's stream and the NEXT token's
+embedding, whose output goes through the model's own head to predict the
+token after next (``models.hybrid.build_hybrid_lm(mtp=...)``).
 """
 
 from __future__ import annotations
 
 import jax
+import jax.numpy as jnp
 
-from bigdl_tpu.nn.attention import MultiHeadAttention, RMSNorm
+from bigdl_tpu.nn.attention import (LatentAttention, MultiHeadAttention,
+                                    RMSNorm)
 from bigdl_tpu.nn.module import Module
 from bigdl_tpu.ops.remat import MLP_PROJ, block_remat_policy, keep
 
@@ -74,14 +82,15 @@ class HybridBlock(Module):
 class HybridDecoder(Module):
     """The stack a pattern string describes, with a final RMSNorm.
 
-    ``mamba``, ``moe``, ``attention``, ``window_attention`` and ``mlp``
-    are the keyword arguments of ``nn.Mamba2(embed_dim, ...)``,
-    ``MoE(embed_dim, ...)``, ``nn.MultiHeadAttention(embed_dim, ...,
-    causal=True)`` for the ``*`` and for the ``W`` blocks, and
+    ``mamba``, ``moe``, ``attention``, ``window_attention``,
+    ``latent_attention`` and ``mlp`` are the keyword arguments of
+    ``nn.Mamba2(embed_dim, ...)``, ``MoE(embed_dim, ...)``,
+    ``nn.MultiHeadAttention(embed_dim, ..., causal=True)`` for the ``*``
+    and for the ``W`` blocks, ``nn.LatentAttention(embed_dim, ...)`` and
     ``GatedMLP(embed_dim, ...)``; a kind the pattern does not use needs
     none."""
 
-    KINDS = "ME*W-"
+    KINDS = "ME*W-L"
 
     #: as ``TransformerEncoder.remat_blocks``: ``Optimizer.set_remat(
     #: "block")`` sets it, and each block then runs under ``jax.checkpoint``
@@ -89,7 +98,10 @@ class HybridDecoder(Module):
     #: ``ops.remat.BLOCK_SAVED_NAMES`` lists (the module docstring there has
     #: the table): an attention block's five projection outputs and flash's
     #: ``o`` and ``lse`` (30.8 KB a token at 32 / 4 heads of 128 with a gate
-    #: and a second norm, 17.5 KB at 32 / 2 with neither), a dense block's
+    #: and a second norm, 17.5 KB at 32 / 2 with neither), a latent
+    #: attention block's flash ``o`` and ``lse`` alone (8.3 KB at 32 heads
+    #: of 128 + 64 over 128: its five low-rank projections run again,
+    #: measured no dearer than holding them), a dense block's
     #: gate, up and down outputs (28.7 KB at hidden 6,144 over 2,048), a
     #: Mamba-2 block's in-projection output (20.6 KB at 10,304 wide), an
     #: expert block's routing tables, routed output and its shared
@@ -101,7 +113,8 @@ class HybridDecoder(Module):
 
     def __init__(self, pattern: str, embed_dim: int, mamba=None, moe=None,
                  attention=None, norm_eps: float = 1e-5,
-                 window_attention=None, mlp=None, post_norm: bool = False):
+                 window_attention=None, mlp=None, post_norm: bool = False,
+                 latent_attention=None):
         super().__init__()
         bad = set(pattern) - set(self.KINDS)
         if bad or not pattern:
@@ -118,6 +131,8 @@ class HybridDecoder(Module):
                 mixer = MoE(embed_dim, **moe)
             elif kind == "-":
                 mixer = GatedMLP(embed_dim, **mlp)
+            elif kind == "L":
+                mixer = LatentAttention(embed_dim, **latent_attention)
             else:
                 mixer = MultiHeadAttention(
                     embed_dim, causal=True,
@@ -126,7 +141,9 @@ class HybridDecoder(Module):
                                                      norm_eps, post_norm))
         self.final_norm = RMSNorm(embed_dim, eps=norm_eps)
 
-    def update_output(self, input):
+    def stream(self, input):
+        """The residual stream after the last block, before the final
+        norm (what a multi-token-prediction module reads)."""
         x = input
         ckpt = self.remat_blocks and self.training
         for i in range(self.num_layers):
@@ -137,7 +154,52 @@ class HybridDecoder(Module):
                                    policy=block_remat_policy())(x)
             else:
                 x = layer.forward(x)
-        return self.final_norm.forward(x)
+        return x
+
+    def update_output(self, input):
+        return self.final_norm.forward(self.stream(input))
 
     def __repr__(self):
         return f"HybridDecoder({self.pattern!r})"
+
+
+class MTPModule(Module):
+    """One multi-token-prediction module (DeepSeek-V3, depth 1). From the
+    embedded tokens ``e`` (B, T, E) and the main stack's stream ``h``
+    (B, T, E; its last block's output, before the final norm)::
+
+        z_i = [RMSNorm_e(e_{i+1}) ; RMSNorm_h(h_i)] W_eh      (2E -> E)
+
+    then ``stack`` (a short ``HybridDecoder``: blocks of the main kind and
+    a final norm, which is the norm in front of the SHARED head) gives the
+    stream whose position i predicts token i + 2. The next token's
+    embedding is the main embedding's own output one position on, so the
+    lookup table takes gradient from both uses; the last position has no
+    next token and gets zeros: it is the one the criterion leaves out
+    (``FusedLMHeadCriterion``), and under a causal mixer no other position
+    sees it. ``loss_weight`` rides with the stream to the criterion, which
+    returns ``L_main + loss_weight * L_mtp``. Everything here runs under
+    the scope ``mtp``."""
+
+    def __init__(self, embed_dim: int, stack: HybridDecoder, norm_eps: float,
+                 loss_weight: float):
+        super().__init__()
+        from bigdl_tpu.nn.linear import Linear
+        self.loss_weight = loss_weight
+        self.norm_embed = RMSNorm(embed_dim, eps=norm_eps)
+        self.norm_hidden = RMSNorm(embed_dim, eps=norm_eps)
+        self.proj = Linear(2 * embed_dim, embed_dim, with_bias=False)
+        self.stack = stack
+
+    def update_output(self, input):
+        from bigdl_tpu.telemetry import get_registry, instruments
+        # trace-time count, as bigdl_ssd_scan_total
+        instruments(get_registry()).mtp_modules_total.inc()
+        embedded, stream = input
+        with jax.named_scope("mtp"):
+            nxt = jnp.concatenate(
+                [embedded[:, 1:], jnp.zeros_like(embedded[:, :1])], axis=1)
+            z = self.proj.forward(jnp.concatenate(
+                [self.norm_embed.forward(nxt),
+                 self.norm_hidden.forward(stream)], axis=-1))
+            return self.stack.forward(z)

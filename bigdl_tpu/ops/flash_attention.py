@@ -54,9 +54,24 @@ window of 2,048 that is 5 of 16 key tiles a query tile, for 14.7M
 query-key pairs where the full call holds 33.6M. Such calls are named
 ``flash_band_fwd``, ``flash_band_bwd_dq``, ``flash_band_bwd_dkv``, so a
 trace tells them from full calls of the same operand shape, and
-``bigdl_flash_attention_total{form=band|full}`` counts each form once a
+``bigdl_flash_attention_total{form=band|full|mla}`` counts each form once a
 trace. A window that reaches past the first key is no band: the call is
 the full one, code and name.
+
+The value head may differ from the query/key head (latent attention:
+``q`` and ``k`` 192 wide, a 128-wide content part beside a 64-wide rotary
+part, over a 128-wide ``v``): the kernels read each operand's own width,
+the forward's accumulator, ``o``, ``dO`` and ``dV`` are as wide as ``v``
+and ``dQ`` and ``dK`` as wide as ``q``. A call with equal sizes is the
+code and the names it was; a call whose sizes differ is named
+``flash_mla_fwd``, ``flash_mla_bwd_dq``, ``flash_mla_bwd_dkv`` and counted
+``form=mla``. ``k`` is ONE operand, the shared rotary key broadcast to the
+heads by the caller: the two-product form (``qc kc^T + qr kr^T`` with the
+rotary key's index map ignoring the head) feeds the MXU the same two
+128-deep passes a 192-deep contraction takes; it would save the rotary
+part's 32 copies in K (33.5 MB of 100 a layer at 8,192 tokens, written
+once and read by three kernels) for a fourth operand in every kernel and a
+``dkr`` summed over the heads (PERF.md section 6, PR 34).
 
 The LSE output is a first-class differentiable output: its cotangent folds
 into the delta term (d lse_i / d logits_ij = p_ij, so delta_i becomes
@@ -172,10 +187,28 @@ def _to_lanes(col):
          for i in range(0, n, c)], axis=1)
 
 
-def _name(kernel: str, window: Optional[int]) -> str:
+def _name(kernel: str, window: Optional[int], latent: bool = False) -> str:
     """The call's name in the HLO and the device trace: a call with a band
-    is told from a full one of the same operand shape by it."""
+    is told from a full one of the same operand shape by it, and one whose
+    value head differs from its query/key head (``latent``) from both."""
+    if latent:
+        return f"flash_mla_{kernel}"
     return f"flash_band_{kernel}" if window is not None else f"flash_{kernel}"
+
+
+def _call_params(kernel: str, window: Optional[int], latent: bool) -> dict:
+    """``pallas_call``'s name and, for a latent call only, its VMEM limit.
+    A 192-wide operand lies in VMEM as 256 lanes, so at 8,192 tokens the
+    dK/dV kernel's whole q and dO (and the forward's whole K and V) are
+    12 MB double-buffered, which with the tiles passes the 16 MiB a call
+    gets by default (the v5e's compiler refuses it, PR 34); the chip has
+    128. A call with equal sizes names no limit, as before."""
+    params = {"name": _name(kernel, window, latent)}
+    if latent:
+        from jax.experimental.pallas import tpu as pltpu
+        params["compiler_params"] = pltpu.CompilerParams(
+            vmem_limit_bytes=_LATENT_VMEM)
+    return params
 
 
 # ------------------------------------------------------------------ forward
@@ -183,7 +216,8 @@ def _name(kernel: str, window: Optional[int]) -> str:
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, l_ref, *, block_k: int, sk: int,
                 causal: bool, scale: float, block_q: int, diagonal: bool,
                 window: Optional[int] = None):
-    # q_ref: (1, BQ, D); k_ref/v_ref: (1, Sk_pad, D); o_ref: (1, BQ, D);
+    # q_ref: (1, BQ, D); k_ref: (1, Sk_pad, D); v_ref: (1, Sk_pad, Dv);
+    # o_ref: (1, BQ, Dv), Dv = D but in a latent call;
     # l_ref: (1, 1, BQ) row logsumexp of the scaled, masked logits. The
     # LSE rides a (BH, 1, S) array so its block's penultimate dim equals
     # the array dim — the real TPU lowering rejects (1, BQ) blocks over a
@@ -191,7 +225,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, l_ref, *, block_k: int, sk: int,
     # not enforce it, which is how this shipped unverified in round 2).
     j = pl.program_id(1)
     q = q_ref[0]                                            # (BQ, D)
-    bq, d = q.shape
+    bq = q.shape[0]
     nkb = k_ref.shape[1] // block_k
     prescaled = _exact_scale(scale)
     if prescaled:
@@ -226,7 +260,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, l_ref, *, block_k: int, sk: int,
                          causal, window) if masked else None
         return update(carry, slice(None), kb * block_k, block_k, valid)
 
-    carry = (jnp.zeros((bq, d), jnp.float32), jnp.zeros((bq, 1), jnp.float32),
+    carry = (jnp.zeros((bq, v_ref.shape[-1]), jnp.float32),
+             jnp.zeros((bq, 1), jnp.float32),
              jnp.full((bq, 1), _NEG, jnp.float32))
     if diagonal:
         # key tiles [0, j) lie wholly below the diagonal; tile j is on it
@@ -263,18 +298,18 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, l_ref, *, block_k: int, sk: int,
 
 def _flash_fwd_lse(q, k, v, causal, scale, block_q, block_k, interpret,
                    window=None):
-    """Returns (o (B,Sq,N,D), lse (B,N,Sq) f32)."""
+    """Returns (o (B,Sq,N,Dv), lse (B,N,Sq) f32)."""
     b, sq, n, d = q.shape
-    sk = k.shape[1]
+    sk, dv = k.shape[1], v.shape[-1]
     # under a band every tile is masked, which is where the 1024-tile loses
-    default = _fwd_block(sq, sk, d, q.dtype.itemsize) if window is None \
+    default = _fwd_block(sq, sk, d, dv, q.dtype.itemsize) if window is None \
         else _BLOCK
     block_q = min(block_q or default, sq)
     block_k = min(block_k or default, sk)
     # BSND -> (B*N, S, D): one grid row per (batch, head).
     qt = q.transpose(0, 2, 1, 3).reshape(b * n, sq, d)
     kt = k.transpose(0, 2, 1, 3).reshape(b * n, sk, d)
-    vt = v.transpose(0, 2, 1, 3).reshape(b * n, sk, d)
+    vt = v.transpose(0, 2, 1, 3).reshape(b * n, sk, dv)
     pad_q = (-sq) % block_q
     pad_k = (-sk) % block_k
     if pad_q:
@@ -291,20 +326,20 @@ def _flash_fwd_lse(q, k, v, causal, scale, block_q, block_k, interpret,
                           diagonal=window is None and _halved_diagonal(
                               causal, sq, sk, block_q, block_k),
                           window=window),
-        out_shape=(jax.ShapeDtypeStruct((b * n, sq_p, d), q.dtype),
+        out_shape=(jax.ShapeDtypeStruct((b * n, sq_p, dv), q.dtype),
                    jax.ShapeDtypeStruct((b * n, 1, sq_p), jnp.float32)),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, sk_p, d), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, sk_p, d), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((1, sk_p, dv), lambda i, j: (i, 0, 0)),
         ],
-        out_specs=(pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
+        out_specs=(pl.BlockSpec((1, block_q, dv), lambda i, j: (i, j, 0)),
                    pl.BlockSpec((1, 1, block_q), lambda i, j: (i, 0, j))),
         interpret=interpret,
-        name=_name("fwd", window),
+        **_call_params("fwd", window, dv != d),
     )(qt, kt, vt)
-    out = out[:, :sq].reshape(b, n, sq, d).transpose(0, 2, 1, 3)
+    out = out[:, :sq].reshape(b, n, sq, dv).transpose(0, 2, 1, 3)
     lse = lse[:, 0, :sq].reshape(b, n, sq)
     return out, lse
 
@@ -318,7 +353,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, l_ref, d_ref, dq_ref, *,
     # Per query tile: stream key tiles, recompute p from the saved LSE.
     j = pl.program_id(1)
     q = q_ref[0]                                            # (BQ, D)
-    do = do_ref[0]                                          # (BQ, D)
+    do = do_ref[0]                                          # (BQ, Dv)
     # Kept as the lane-dense (BQ,) rows they are stored as and turned into
     # columns where a tile uses them: hoisted (BQ, 1) columns are 128 vregs
     # that live across the whole loop, and cost 15% of the kernel (PR 24).
@@ -386,7 +421,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, l_ref, d_ref,
     # dV and ds = 1 * (0 - 0) in dK.
     jkb = pl.program_id(1)
     k = k_ref[0]                                            # (BK, D)
-    v = v_ref[0]
+    v = v_ref[0]                                            # (BK, Dv)
     bk, d = k.shape
     nqb = q_ref.shape[1] // block_q
     prescaled = _exact_scale(scale)
@@ -419,7 +454,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, l_ref, d_ref,
         dk, dv = part(slice(None), qb * block_q, block_q, valid)
         return carry[0] + dk, carry[1] + dv
 
-    zeros = (jnp.zeros((bk, d), jnp.float32), jnp.zeros((bk, d), jnp.float32))
+    zeros = (jnp.zeros((bk, d), jnp.float32),
+             jnp.zeros((bk, v.shape[-1]), jnp.float32))
     if diagonal:
         # query tile jkb is on the diagonal: its first half sees only the
         # first half of the keys; query tiles after it see every key
@@ -450,14 +486,14 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, l_ref, d_ref,
 def _flash_bwd(q, k, v, o, lse, g_o, g_l, causal, scale, block_q, block_k,
                interpret, window=None):
     b, sq, n, d = q.shape
-    sk = k.shape[1]
+    sk, d_v = k.shape[1], v.shape[-1]
     block_q = min(block_q or _BLOCK, sq)
     block_k = min(block_k or _BLOCK, sk)
     qt = q.transpose(0, 2, 1, 3).reshape(b * n, sq, d)
     kt = k.transpose(0, 2, 1, 3).reshape(b * n, sk, d)
-    vt = v.transpose(0, 2, 1, 3).reshape(b * n, sk, d)
-    dot = g_o.transpose(0, 2, 1, 3).reshape(b * n, sq, d)
-    ot = o.transpose(0, 2, 1, 3).reshape(b * n, sq, d)
+    vt = v.transpose(0, 2, 1, 3).reshape(b * n, sk, d_v)
+    dot = g_o.transpose(0, 2, 1, 3).reshape(b * n, sq, d_v)
+    ot = o.transpose(0, 2, 1, 3).reshape(b * n, sq, d_v)
     # lse/delta ride (BH, 1, S) arrays (see _fwd_kernel: the TPU lowering
     # rejects (1, BQ) blocks over a (BH, S) array).
     lt = lse.reshape(b * n, 1, sq)
@@ -493,14 +529,14 @@ def _flash_bwd(q, k, v, o, lse, g_o, g_l, causal, scale, block_q, block_k,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, sk_p, d), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, sk_p, d), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((1, sk_p, d_v), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((1, block_q, d_v), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, 1, block_q), lambda i, j: (i, 0, j)),
             pl.BlockSpec((1, 1, block_q), lambda i, j: (i, 0, j)),
         ],
         out_specs=pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
         interpret=interpret,
-        name=_name("bwd_dq", window),
+        **_call_params("bwd_dq", window, d_v != d),
     )(qt, kt, vt, dot, lt, delta)
 
     dk, dv = pl.pallas_call(
@@ -508,25 +544,25 @@ def _flash_bwd(q, k, v, o, lse, g_o, g_l, causal, scale, block_q, block_k,
                           causal=causal, scale=scale, block_k=block_k,
                           diagonal=diagonal, window=window),
         out_shape=(jax.ShapeDtypeStruct((b * n, sk_p, d), k.dtype),
-                   jax.ShapeDtypeStruct((b * n, sk_p, d), v.dtype)),
+                   jax.ShapeDtypeStruct((b * n, sk_p, d_v), v.dtype)),
         grid=(b * n, sk_p // block_k),
         in_specs=[
             pl.BlockSpec((1, sq_p, d), lambda i, j: (i, 0, 0)),
             pl.BlockSpec((1, block_k, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, sq_p, d), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((1, block_k, d_v), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((1, sq_p, d_v), lambda i, j: (i, 0, 0)),
             pl.BlockSpec((1, 1, sq_p), lambda i, j: (i, 0, 0)),
             pl.BlockSpec((1, 1, sq_p), lambda i, j: (i, 0, 0)),
         ],
         out_specs=(pl.BlockSpec((1, block_k, d), lambda i, j: (i, j, 0)),
-                   pl.BlockSpec((1, block_k, d), lambda i, j: (i, j, 0))),
+                   pl.BlockSpec((1, block_k, d_v), lambda i, j: (i, j, 0))),
         interpret=interpret,
-        name=_name("bwd_dkv", window),
+        **_call_params("bwd_dkv", window, d_v != d),
     )(qt, kt, vt, dot, lt, delta)
 
     dq = dq[:, :sq].reshape(b, n, sq, d).transpose(0, 2, 1, 3)
     dk = dk[:, :sk].reshape(b, n, sk, d).transpose(0, 2, 1, 3)
-    dv = dv[:, :sk].reshape(b, n, sk, d).transpose(0, 2, 1, 3)
+    dv = dv[:, :sk].reshape(b, n, sk, d_v).transpose(0, 2, 1, 3)
     return dq, dk, dv
 
 
@@ -575,32 +611,41 @@ _flash_lse.defvjp(_flash_lse_vjp_fwd, _flash_lse_vjp_bwd)
 _FWD_BLOCK = 1024
 _BLOCK = 512
 _VMEM_BUDGET = 15 << 20
+_LATENT_VMEM = 32 << 20     # _call_params
 
 
-def _fwd_block(sq: int, sk: int, d: int, itemsize: int) -> int:
+def _fwd_block(sq: int, sk: int, d: int, dv: int, itemsize: int) -> int:
     """The forward's default tile, from what the call can see: 1024 where
     the sequence is a whole number of such tiles (so the causal diagonal is
     halved and nothing else is masked) and the call's VMEM stays under the
-    budget; else 512, the parent's. The estimate: K and V whole and the q
-    and o tiles, each twice (Mosaic double-buffers its operands), two
-    (BQ, BK) float32 intermediates and three (BQ, D) float32 accumulators.
-    Every shape it admits compiled for the v5e (bf16 and float32, head 64
-    to 256, 1024 to 16384 keys, causal and not), and it refuses every one
-    that did not at 1024 (bf16: head 128 from 8192 keys, head 256 from
-    2048; float32: head 128 from 4096) with a few that would have
-    (PERF.md section 6, PR 24)."""
+    budget; else 512, the parent's. The estimate, for a query/key head of
+    ``d`` and a value head of ``dv``: K (``d`` wide) and V (``dv``) whole
+    and the q (``d``) and o (``dv``) tiles, each twice (Mosaic
+    double-buffers its operands), two (BQ, BK) float32 intermediates and
+    three (BQ, dv) float32 accumulators. Every shape it admits compiled for
+    the v5e (bf16 and float32, head 64 to 256, 1024 to 16384 keys, causal
+    and not), and it refuses every one that did not at 1024 (bf16: head 128
+    from 8192 keys, head 256 from 2048; float32: head 128 from 4096) with a
+    few that would have (PERF.md section 6, PR 24). A call whose two head
+    sizes differ takes 512 whatever its length: none has been compiled at
+    1024 on the chip, and a head of 192 lies in VMEM as 256 lanes, which
+    the estimate does not know (a head that lies so, 256, failed at 1024
+    from 2,048 keys)."""
     big = _FWD_BLOCK
-    vmem = (4 * (sk + big) * d * itemsize + 2 * big * big * 4
-            + 3 * big * d * 4)
-    if sq == sk and sk % big == 0 and vmem <= _VMEM_BUDGET:
+    vmem = (2 * (sk + big) * (d + dv) * itemsize + 2 * big * big * 4
+            + 3 * big * dv * 4)
+    if d == dv and sq == sk and sk % big == 0 and vmem <= _VMEM_BUDGET:
         return big
     return _BLOCK
 
 
-def _band_of(window: Optional[int], causal: bool, sk: int) -> Optional[int]:
+def _band_of(window: Optional[int], causal: bool, sk: int,
+             latent: bool = False) -> Optional[int]:
     """The band the kernels are told of, counted by form. A window that
     reaches past the first key cuts nothing: the call is then the full
-    one, code and name."""
+    one, code and name. ``latent``: the value head differs from the
+    query/key head, which has its own names and form and takes no band
+    (a trace could not tell such a call, and nothing makes one)."""
     if window is not None:
         if not causal:
             raise ValueError("window (a banded causal mask) needs "
@@ -609,11 +654,14 @@ def _band_of(window: Optional[int], causal: bool, sk: int) -> Optional[int]:
             raise ValueError("window must be >= 1")
         if window >= sk:
             window = None
+    if latent and window is not None:
+        raise ValueError("a sliding window with a value head that differs "
+                         "from the query/key head is not supported")
     from bigdl_tpu.telemetry import get_registry, instruments
     # trace-time count, as bigdl_ssd_scan_total: which form a compiled
     # program holds, not per-step traffic
     instruments(get_registry()).flash_attention_total.labels(
-        form="full" if window is None else "band").inc()
+        form="mla" if latent else "full" if window is None else "band").inc()
     return window
 
 
@@ -623,7 +671,11 @@ def flash_attention(q, k, v, causal: bool = False,
                     block_k: Optional[int] = None,
                     interpret: Optional[bool] = None,
                     window: Optional[int] = None) -> jax.Array:
-    """Flash attention, shapes (B, S, N, D); differentiable (Pallas fwd+bwd).
+    """Flash attention, q and k (B, S, N, D), v and the result (B, S, N, Dv);
+    differentiable (Pallas fwd+bwd). ``scale`` defaults to ``1/sqrt(D)``,
+    the query/key head's. Where ``Dv`` is not ``D`` (latent attention: a
+    192-wide q/k of a content and a rotary part over a 128-wide v) the same
+    three kernels run under the names ``flash_mla_*``, with nothing padded.
 
     ``window`` (with ``causal``): query i sees the keys ``(i - window, i]``,
     the Mistral convention. The three kernels mask the band's lower edge
@@ -632,7 +684,8 @@ def flash_attention(q, k, v, causal: bool = False,
         scale = 1.0 / float(q.shape[-1]) ** 0.5
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    window = _band_of(window, causal, k.shape[1])
+    window = _band_of(window, causal, k.shape[1],
+                      v.shape[-1] != q.shape[-1])
     o, _ = _flash_lse(q, k, v, causal, scale, block_q, block_k, interpret,
                       window)
     return o
@@ -643,7 +696,7 @@ def flash_attention_with_lse(
         block_q: Optional[int] = None, block_k: Optional[int] = None,
         interpret: Optional[bool] = None, window: Optional[int] = None
         ) -> Tuple[jax.Array, jax.Array]:
-    """Flash attention returning ``(o (B,S,N,D), lse (B,N,S) f32)``.
+    """Flash attention returning ``(o (B,S,N,Dv), lse (B,N,S) f32)``.
 
     The LSE is differentiable (its cotangent folds into the softmax
     jacobian), which is what lets ring attention run this kernel per hop
@@ -654,7 +707,8 @@ def flash_attention_with_lse(
         scale = 1.0 / float(q.shape[-1]) ** 0.5
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    window = _band_of(window, causal, k.shape[1])
+    window = _band_of(window, causal, k.shape[1],
+                      v.shape[-1] != q.shape[-1])
     return _flash_lse(q, k, v, causal, scale, block_q, block_k, interpret,
                       window)
 
@@ -663,7 +717,10 @@ def use_flash(q, mask) -> bool:
     """Dispatch policy for MultiHeadAttention: Pallas kernel on real TPU for
     sequences without an arbitrary mask (those use the XLA cores, which take
     any additive bias). A causal mask and a sliding window are no ``mask``
-    here: both are arguments of the kernels.
+    here: both are arguments of the kernels. The head size tested is the
+    QUERY/KEY head's (``q``'s last axis, the one ``q k^T`` contracts); the
+    value head may differ and is not looked at (a latent call's 192 / 128
+    passes on its 192).
 
     The gate is the in-model crossover measured in round 3 on a v5e, with
     the kernels then fed float32 (ROADMAP S5 keeps those numbers): at seq
@@ -678,5 +735,5 @@ def use_flash(q, mask) -> bool:
         return False
     if jax.default_backend() != "tpu":
         return False
-    seq, d = q.shape[1], q.shape[-1]
-    return seq >= 1024 and d % 64 == 0
+    seq, d_qk = q.shape[1], q.shape[-1]
+    return seq >= 1024 and d_qk % 64 == 0

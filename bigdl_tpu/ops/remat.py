@@ -32,7 +32,10 @@ name                    value, producer                                  bytes a
 ``ATTN_PROJ``           the q, k, v and gate projections' outputs        ``2 * (2 * heads + 2 * kv_heads)
                         BEFORE q/k norm and rotation, and the            * head_dim + 2 E``
                         out-projection's output
-                        (``nn.MultiHeadAttention.update_output``)
+                        (``nn.MultiHeadAttention.update_output``;
+                        NOT ``nn.LatentAttention``'s: kept, its
+                        low-rank projections' outputs bought 0.5 ms
+                        of 368 for 1 GB at the peak, PR 34)
 ``MLP_PROJ``            ``nn.GatedMLP``'s gate, up and down outputs      ``4 * hidden + 2 E``
 ``MAMBA_IN_PROJ``       ``nn.Mamba2``'s in-projection output             ``2 * (2 * d_inner + 2 * groups * state
                         ``[z | xBC | dt]``                               + heads)``

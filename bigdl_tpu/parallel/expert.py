@@ -43,11 +43,18 @@ in for. The local picks are sorted by expert and the held experts run as
 one grouped product over row blocks (``_grouped_rows``): no capacity, no
 dropped token, and the work follows the rows that really landed here
 (rounded up to whole blocks an expert), not the static bound
-``T * min(k, len(held))``.
+``T * min(k, len(held))``. With ``pick_rows`` the held layer takes each
+token's k experts from a table by the token's ID (``pick_table``: a fixed
+hash layer, Roller et al., arXiv:2106.04426, filled by whoever builds the
+model) and only their combine weights from the live scores: its work a
+step is then a function of the batch's ids alone, however the stream
+moves as it trains. The model that owns the layer says which ids its
+stream carries (``token_ids``).
 """
 
 from __future__ import annotations
 
+import contextlib
 import functools
 
 import jax
@@ -60,6 +67,23 @@ from bigdl_tpu.nn.module import Module
 from bigdl_tpu.ops.remat import (MOE_ROUTE_TABLES, MOE_ROUTED_OUT,
                                  MOE_SHARED_HID, keep)
 from bigdl_tpu.parallel.mesh import EXPERT_AXIS
+
+
+#: the token ids of the stream being traced, innermost last (``token_ids``)
+_TOKEN_IDS = []
+
+
+@contextlib.contextmanager
+def token_ids(ids):
+    """While a model runs its stack: ``ids`` (any shape, 1-based, as the
+    lookup table takes them) are the tokens at the stream's positions, for
+    the expert layers that pick by table (``MoE(pick_rows=)``). Read at
+    trace time; a layer inside ``jax.checkpoint`` closes over them."""
+    _TOKEN_IDS.append(ids)
+    try:
+        yield
+    finally:
+        _TOKEN_IDS.pop()
 
 
 @jax.custom_vjp
@@ -94,7 +118,9 @@ class MoE(Module):
     experts and exchanges nothing sees only that share of the router's
     gradient, and applied alone it trains the router TOWARD the held
     experts; the router then gets no gradient (nor does the layer's input
-    through it) and keeps its picks.
+    through it) and keeps its picks. ``pick_rows`` (the vocabulary's size;
+    ``dispatch="held"``) adds the buffer ``pick_table`` (pick_rows, k) and
+    picks from it by token id (module docstring); it is zeros until filled.
     """
 
     def __init__(self, input_size: int, hidden_size: int, n_experts: int,
@@ -102,7 +128,7 @@ class MoE(Module):
                  activation: str = "gelu", aux_loss_weight: float = 1e-2,
                  dispatch: str = "sort", held=None, bias: bool = True,
                  shared_hidden: int = 0, route_scale: float = 1.0,
-                 train_router: bool = True):
+                 train_router: bool = True, pick_rows: int = 0):
         super().__init__()
         if dispatch not in ("sort", "scatter", "einsum", "held"):
             raise ValueError(f"dispatch must be 'sort', 'scatter', "
@@ -114,9 +140,9 @@ class MoE(Module):
                              "bias) belong to dispatch='held'")
         if dispatch != "held" and (held is not None or shared_hidden
                                    or route_scale != 1.0
-                                   or not train_router):
-            raise ValueError("held, shared_hidden, route_scale and "
-                             "train_router belong to dispatch='held' (the "
+                                   or not train_router or pick_rows):
+            raise ValueError("held, shared_hidden, route_scale, pick_rows "
+                             "and train_router belong to dispatch='held' (the "
                              "capacity paths route by softmax over experts "
                              "that are all here)")
         # ids of the experts whose weights live here (all of them unless
@@ -147,6 +173,11 @@ class MoE(Module):
             # selection bias: moves which experts are picked, never their
             # weights; a buffer (no gradient), set by a balancing rule
             self.register_buffer("select_bias", init.zeros((e,)))
+        self.pick_rows = pick_rows
+        if pick_rows:
+            # expert ids as float32 (exact to 2^24), like every buffer
+            self.register_buffer("pick_table",
+                                 init.zeros((pick_rows, self.k)))
         self.register_parameter(
             "w1", np.stack([init.xavier((d, h), d, h) for _ in range(n)]))
         if activation == "swiglu":
@@ -201,9 +232,17 @@ class MoE(Module):
                     preferred_element_type=jnp.float32))
         if not self.train_router:
             scores = jax.lax.stop_gradient(scores)
-        _, picked = jax.lax.top_k(
-            scores + jax.lax.stop_gradient(
-                self.select_bias.astype(jnp.float32)), self.k)
+        if self.pick_rows:
+            if not _TOKEN_IDS:
+                raise ValueError("MoE(pick_rows=) picks by token id: the "
+                                 "model runs its stack under token_ids(ids)")
+            ids = _TOKEN_IDS[-1].reshape(-1).astype(jnp.int32) - 1
+            picked = jax.lax.stop_gradient(
+                self.pick_table)[ids].astype(jnp.int32)
+        else:
+            _, picked = jax.lax.top_k(
+                scores + jax.lax.stop_gradient(
+                    self.select_bias.astype(jnp.float32)), self.k)
         # kept across a block's rematerialisation (ops.remat), as float32
         picked = keep(picked, MOE_ROUTE_TABLES)
         w = keep(jnp.take_along_axis(scores, picked, axis=-1),

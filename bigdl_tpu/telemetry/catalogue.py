@@ -217,9 +217,23 @@ METRIC_SPECS: List[MetricSpec] = [
     MetricSpec("bigdl_flash_attention_total", "counter",
                "Flash-attention calls by form (form label: band, the "
                "kernels told of a sliding window, named flash_band_*; "
-               "full, no window or one that reaches past the first key). "
+               "mla, a value head that differs from the query/key head, "
+               "named flash_mla_*; full, neither: no window or one that "
+               "reaches past the first key). "
                "Counted once per eager call / once per TRACE under jit, "
                "as bigdl_ssd_scan_total.", ("form",)),
+    MetricSpec("bigdl_latent_attention_total", "counter",
+               "Latent-attention (nn.LatentAttention) forwards by path "
+               "(path label: expanded, the latent up-projected to "
+               "per-head keys and values and attended as such, the "
+               "training path; the only one there is). Counted once per "
+               "eager call / once per TRACE under jit, as "
+               "bigdl_ssd_scan_total.", ("path",)),
+    MetricSpec("bigdl_mtp_modules_total", "counter",
+               "Multi-token-prediction modules (nn.MTPModule) run in "
+               "training, where each hands the criterion a second stream. "
+               "Counted once per eager call / once per TRACE under jit, "
+               "as bigdl_ssd_scan_total."),
     MetricSpec("bigdl_remat_kept_total", "counter",
                "Values tagged for block remat to keep (ops/remat.keep), "
                "by the name on its save-list (name label: one of "

@@ -200,6 +200,51 @@ class TestFlashKernel:
                                        rtol=2e-4, atol=2e-4,
                                        err_msg=f"d{name} mismatch")
 
+    @pytest.mark.parametrize("d,dv", [(64, 64), (96, 64), (192, 128)])
+    def test_value_head_may_differ_from_the_query_key_head(self, d, dv):
+        """q and k ``d`` wide over a v ``dv`` wide (latent attention's 192
+        over 128), causal, on a sequence that is no whole number of tiles:
+        output, LSE and all three gradients against the masked XLA core,
+        whose scale is the same 1/sqrt(d). Differing sizes carry their own
+        kernel names and form; equal ones the names they had."""
+        from bigdl_tpu.ops import flash_attention as fa
+        from bigdl_tpu.telemetry import get_registry, instruments
+        b, s, n = 1, 40, 2
+        q, k = (jnp.asarray(_rand(b, s, n, d)) for _ in range(2))
+        v, g = (jnp.asarray(_rand(b, s, n, dv)) for _ in range(2))
+        gl = jnp.asarray(_rand(b, n, s))
+
+        def kernel(q_, k_, v_):
+            return fa.flash_attention_with_lse(
+                q_, k_, v_, causal=True, block_q=16, block_k=16,
+                interpret=True)
+
+        def run(f):
+            out, vjp = jax.vjp(f, q, k, v)
+            return out + vjp((g, gl))
+
+        form = "mla" if d != dv else "full"
+        count = instruments(get_registry()).flash_attention_total.labels(
+            form=form)
+        before = count.value
+        got = run(kernel)
+        assert count.value == before + 1
+        assert got[0].shape == (b, s, n, dv) and got[4].shape == v.shape
+        for r, o, name in zip(run(lambda *t: _attention_and_lse(*t, True)),
+                              got, ("o", "lse", "dq", "dk", "dv")):
+            np.testing.assert_allclose(np.asarray(o), np.asarray(r),
+                                       rtol=2e-4, atol=2e-4,
+                                       err_msg=f"{name} mismatch")
+        names = [e.params["name"] for e in _eqns(jax.make_jaxpr(
+            lambda *t: run(kernel))().jaxpr)
+            if e.primitive.name == "pallas_call"]
+        stem = "flash_mla_" if d != dv else "flash_"
+        assert names == [stem + "fwd", stem + "bwd_dq", stem + "bwd_dkv"]
+        if d != dv:
+            with pytest.raises(ValueError, match="window"):
+                fa.flash_attention(q, k, v, causal=True, window=8,
+                                   interpret=True)
+
     def test_lse_value_and_cotangent(self):
         from bigdl_tpu.ops.flash_attention import flash_attention_with_lse
         b, s, n, d = 1, 24, 2, 8
@@ -299,13 +344,16 @@ def _eqns(jaxpr):
     (2048, 2048, 256, 2, 512),      # causal did not compile at 1024
     (3000, 3000, 64, 2, 512),       # padded: every tile masked, 11% slower
     (2048, 4096, 64, 2, 512),       # oblong: no halved diagonal
+    (8192, 8192, (192, 128), 2, 512),   # latent: q/k 192 over v 128
+    (2048, 2048, (192, 128), 2, 512),   # not compiled at 1024: not taken
 ])
 def test_forward_tile_follows_the_call(sq, sk, d, itemsize, want):
     """The forward's default tile is 1024 only where a v5e compile and the
     on-chip times say so (PERF.md section 6, PR 24); everything else keeps
     the 512 the backward kernels and the parent use."""
     from bigdl_tpu.ops import flash_attention as fa
-    assert fa._fwd_block(sq, sk, d, itemsize) == want
+    d, dv = d if isinstance(d, tuple) else (d, d)
+    assert fa._fwd_block(sq, sk, d, dv, itemsize) == want
 
 
 class TestFlashKernelDtypeContract:
