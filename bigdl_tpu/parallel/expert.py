@@ -40,10 +40,17 @@ experts whose ids ``held`` lists and computes the part of the result that
 those give, plus an always-on shared expert (``shared_hidden``); what the
 absent experts would add is the other chips' to compute and is not stood
 in for. The local picks are sorted by expert and the held experts run as
-one grouped product over row blocks (``_grouped_rows``): no capacity, no
-dropped token, and the work follows the rows that really landed here
-(rounded up to whole blocks an expert), not the static bound
-``T * min(k, len(held))``. With ``pick_rows`` the held layer takes each
+one grouped product over the sorted rows (``_grouped_rows``): no capacity,
+no dropped token, and the work follows the rows that really landed here,
+not the static bound ``T * min(k, len(held))``. On a TPU, for experts
+without biases whose widths are whole lane tiles, the products are the
+Mosaic kernels of ``ops/grouped_matmul.py`` (``takes_kernel``): row tiles
+that pad no run, an expert's matrices read once a pass, its float32
+gradient tile kept in VMEM over all its rows, the activation and the
+scatter-add to the tokens' rows as the kernels' epilogues; everywhere else
+XLA loops over row blocks of ``_BLOCK_ROWS`` (an expert's run rounded up to
+whole blocks). ``bigdl_moe_grouped_total{form}`` says which a compiled
+layer holds. With ``pick_rows`` the held layer takes each
 token's k experts from a table by the token's ID (``pick_table``: a fixed
 hash layer, Roller et al., arXiv:2106.04426, filled by whoever builds the
 model) and only their combine weights from the live scores: its work a
@@ -55,7 +62,9 @@ stream carries (``token_ids``).
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -64,6 +73,7 @@ from jax.sharding import PartitionSpec as P
 
 from bigdl_tpu.nn import initialization as init
 from bigdl_tpu.nn.module import Module
+from bigdl_tpu.ops import grouped_matmul
 from bigdl_tpu.ops.remat import (MOE_ROUTE_TABLES, MOE_ROUTED_OUT,
                                  MOE_SHARED_HID, keep)
 from bigdl_tpu.parallel.mesh import EXPERT_AXIS
@@ -103,6 +113,19 @@ def _inject_bwd(_, g):
 
 
 inject_loss.defvjp(_inject_fwd, _inject_bwd)
+
+
+def _relu2(hid):
+    return jnp.square(jax.nn.relu(hid))
+
+
+def _swiglu(hid, gate):
+    return jax.nn.silu(gate) * hid
+
+
+#: an expert's activation by name, on its float32 first products
+_ACTIVATIONS = {"gelu": jax.nn.gelu, "relu": jax.nn.relu, "relu2": _relu2,
+                "swiglu": _swiglu}
 
 
 class MoE(Module):
@@ -202,11 +225,16 @@ class MoE(Module):
                 self.register_parameter("shared_b2", init.zeros((d,)))
 
     def _act(self, x):
-        if self.activation == "gelu":
-            return jax.nn.gelu(x)
-        if self.activation == "relu2":
-            return jnp.square(jax.nn.relu(x))
-        return jax.nn.relu(x)
+        return _ACTIVATIONS[self.activation](x)
+
+    @property
+    def _activate(self):
+        """An expert's activation on its float32 first products, elementwise:
+        ``(hid, gate)`` from ``w1`` (its bias added) and ``wg`` for the
+        gated form, ``(hid,)`` otherwise. A module-level function, one an
+        activation's name: the grouped kernels run it as their epilogue and
+        the layers of a model share their compiled calls by it."""
+        return _ACTIVATIONS[self.activation]
 
     def _hidden(self, w, x, tag=lambda v: v):
         """An expert's activations on its rows, before its output matrix:
@@ -215,14 +243,11 @@ class MoE(Module):
         products (the shared expert's are kept across block remat)."""
         f32 = jnp.float32
         cd = x.dtype
-        hid = tag(jnp.dot(x, w["w1"].astype(cd), preferred_element_type=f32))
-        if "wg" in w:
-            gate = tag(jnp.dot(x, w["wg"].astype(cd),
-                               preferred_element_type=f32))
-            return (jax.nn.silu(gate) * hid).astype(cd)
+        first = [tag(jnp.dot(x, w[k].astype(cd), preferred_element_type=f32))
+                 for k in ("w1", "wg") if k in w]
         if "b1" in w:
-            hid = hid + w["b1"].astype(f32)
-        return self._act(hid).astype(cd)
+            first[0] = first[0] + w["b1"].astype(f32)
+        return self._activate(*first).astype(cd)
 
     def _route(self, x):
         """The held layer's router: (expert ids (T, k), combine weights
@@ -278,7 +303,8 @@ class MoE(Module):
         with jax.named_scope("moe_experts"):
             routed = {p: v for p, v in self._parameters.items()
                       if p in ("w1", "wg", "b1", "w2", "b2")}
-            y = _grouped_rows(self._hidden, routed, x, tok, gate, counts)
+            y = _grouped_rows(self._hidden, self._activate, routed, x, tok,
+                              gate, counts)
             # kept across a block's rematerialisation
             # (ops.remat.block_remat_policy): the loop runs once forward
             y = keep(y.astype(input.dtype), MOE_ROUTED_OUT) \
@@ -455,17 +481,30 @@ class MoE(Module):
                 f"experts={self.n_experts}{held}, k={self.k})")
 
 
-#: rows of one block of the grouped product. An expert's run of rows is cut
-#: into blocks of this many; its last block is padded past the run's end
-#: with rows whose pick weight is zero (they are multiplied like any row
-#: and add nothing). One block is one pass over that expert's matrices, one
-#: float32 read-modify-write of their gradients (125 us: as long as the
-#: block's products at 512 rows) and scatter-adds of its rows. Chosen by
-#: throughput on the v5e at 8,192 tokens, top-6 of 128, 8 held, about 384
-#: rows an expert (PERF.md section 6, PR 25): 512 rows 3.245 records/s,
-#: 1,024 rows 3.229, 2,048 rows 3.03. Telling the scatters that a block's
-#: token ids are sorted and unique cost 8%.
+#: rows of one block of the XLA form's loops (below), and of one trip of
+#: the kernel form's gather and scatter loops. An XLA ``while`` keeps
+#: nothing on chip between trips, so in the XLA form a block is one pass
+#: over its expert's matrices, one float32 read-modify-write of their
+#: gradients and scatter-adds of its rows, and an expert's last block is
+#: padded past its run's end with rows of pick weight zero. How 512 was
+#: found: PERF.md section 6, PRs 25 and 35.
 _BLOCK_ROWS = 512
+
+
+def takes_kernel(backend, weights, x) -> bool:
+    """The form rule, by what the layer can see: the Mosaic kernels of
+    ``ops/grouped_matmul.py`` on a TPU for experts without biases whose two
+    widths are whole 128-lane tiles in a dtype the MXU takes; the XLA loops
+    everywhere else (a CPU, the biased gelu / relu experts of the tests, a
+    width off the lanes: the kernels take 1,856 = 14.5 x 128 as
+    whole-dimension blocks and halve the scope's time there, but XLA then
+    lays the float32 leaves of that width out transposed and copies them
+    in and out of every step, which costs the step what the kernels gave:
+    PERF.md section 6, PR 35)."""
+    d, h = weights["w1"].shape[1:]
+    return (backend == "tpu" and "b1" not in weights and "b2" not in weights
+            and d % 128 == 0 and h % 128 == 0
+            and x.dtype in (jnp.bfloat16, jnp.float32))
 
 
 def _block_table(counts, block, n_blocks_max):
@@ -491,27 +530,60 @@ def _project(w, hid):
     return out.astype(hid.dtype)
 
 
-def _grouped_rows(hidden, weights, x, tok, gate, counts):
+def _grouped_rows(hidden, activate, weights, x, tok, gate, counts):
     """sum over the sorted picks r of ``gate[r] * expert_r(x[tok[r]])``
     scattered to row ``tok[r]`` -> (T, d) float32, an expert being
-    ``_project(w, hidden(w, x))``.
+    ``_project(w, hidden(w, x))`` and ``activate`` the elementwise part of
+    ``hidden`` on its float32 first products.
 
     ``tok``/``gate`` (R,) hold the picks sorted by held expert, expert e's
-    run ``counts[e]`` long; entries past the runs are ignored. A loop whose
-    trip count is the number of blocks that hold real rows (at most one
-    partial block an expert): the rows of a block past its run's end are
-    multiplied with the rest and weigh zero in the result. The backward is
-    a second such loop: it recomputes a block's activations, takes
-    ``jax.vjp`` of ``hidden`` on them (so any activation or bias
-    differentiates) and the linear
-    output stage by hand (one product gives both the pick weights'
-    gradient and the activations'); weight gradients accumulate in
-    float32."""
-    block = min(_BLOCK_ROWS, -(-tok.shape[0] // 8) * 8)
-    n_max = tok.shape[0] // block + counts.shape[0]
-    pad = n_max * block - tok.shape[0]
+    run ``counts[e]`` long; entries past the runs are ignored. Two forms of
+    the one sum (``takes_kernel``; counted a trace in
+    ``bigdl_moe_grouped_total``), both with work that follows the rows that
+    landed and not R, both with float32 accumulation, activations rounded
+    to the compute dtype before the output matrix and weight gradients
+    summed in float32 over all of an expert's rows:
+
+    - ``kernel``: the products run in the kernels of
+      ``ops/grouped_matmul.py`` over the sorted rows as they lie (row tiles
+      of ``grouped_matmul.ROW_TILE`` that pad no run), ``activate`` and its
+      derivative as their epilogues (``_grouped_kernel``,
+      ``_kernel_backward``), the pick weight and the scatter-add to the
+      tokens' rows as the output stages' (an expert's output and a row's
+      gradient reach their sums in float32, not rounded to the compute
+      dtype first, which is what XLA makes of the other form on the TPU:
+      it elides a rounding that is widened again at once); XLA ``while``
+      loops of a dynamic trip count only gather the landed rows.
+    - ``xla``: a loop whose trip count is the number of ``_BLOCK_ROWS``
+      blocks that hold real rows (at most one partial block an expert: the
+      rows of a block past its run's end are multiplied with the rest and
+      weigh zero). The backward is a second such loop: it recomputes a
+      block's activations, takes ``jax.vjp`` of ``hidden`` on them (so any
+      activation or bias differentiates) and the linear output stage by
+      hand (one product gives both the pick weights' gradient and the
+      activations')."""
+    from bigdl_tpu.telemetry import get_registry, instruments
+    kernel = takes_kernel(jax.default_backend(), weights, x)
+    # trace-time count, as bigdl_ssd_scan_total: which form a compiled
+    # held layer's grouped products took
+    instruments(get_registry()).moe_grouped_total.labels(
+        form="kernel" if kernel else "xla").inc()
+    rows = tok.shape[0]
+    if kernel:
+        tile = min(grouped_matmul.ROW_TILE, -(-rows // 8) * 8)
+        # a trip of the gather and scatter loops: whole row tiles
+        block = min(max(_BLOCK_ROWS // tile, 1), -(-rows // tile)) * tile
+        # the kernels read ``tok`` in index blocks of their own
+        whole = grouped_matmul.INDEX_BLOCK
+        pad = -rows % (math.lcm(block, whole) if rows > whole else block)
+    else:
+        block = min(_BLOCK_ROWS, -(-rows // 8) * 8)
+        n_max = rows // block + counts.shape[0]
+        pad = n_max * block - rows
     tok = jnp.pad(tok, (0, pad))
     gate = jnp.pad(gate, (0, pad))
+    if kernel:
+        return _grouped_kernel(activate, block, weights, x, gate, tok, counts)
     return _grouped(hidden, block, n_max, weights, x, gate, tok, counts)
 
 
@@ -601,6 +673,122 @@ def _grouped_bwd_loop(hidden, block, n_max, weights, x, gate, tok, counts,
 
 
 _grouped.defvjp(_grouped_fwd, _grouped_bwd)
+
+
+# ------------------------------------------------------- the kernel form
+
+def _landed_rows(src, tok, landed, block):
+    """``src[tok]`` over the first ``landed`` rows of ``tok`` (rounded up to
+    whole blocks) -> (R, C); what lies past them is not written."""
+    def body(i, buf):
+        t_b = jax.lax.dynamic_slice(tok, (i * block,), (block,))
+        return jax.lax.dynamic_update_slice(buf, src[t_b], (i * block, 0))
+
+    return jax.lax.fori_loop(
+        0, (landed + block - 1) // block, body,
+        jax.lax.empty((tok.shape[0], src.shape[1]), src.dtype))
+
+
+# The kernels' epilogues: equal by value, so that the expert blocks of a
+# model share one compiled call a stage (``grouped_matmul.grouped_products``)
+
+@dataclasses.dataclass(frozen=True)
+class _Activated:
+    """The forward's first stage: the activation of the first products."""
+    activate: object
+
+    def __call__(self, prods, cols):
+        return [self.activate(*prods)]
+
+
+def _weighted(prods, cols):
+    """The forward's output stage: pick weight x (hid @ w2), float32."""
+    return [cols[0] * prods[0]]
+
+
+@dataclasses.dataclass(frozen=True)
+class _Backward:
+    """The backward's fused stage, from the first products and ``dy @
+    w2^T``: the activations again (rounded to ``cd``), the first products'
+    gradients through the activation's derivative at pick weight x ``dy @
+    w2^T`` (rounded as the XLA form rounds them), and the rows whose sums
+    are the pick weights' gradient ``<dy @ w2^T, hid>``."""
+    activate: object
+    cd: object
+
+    def __call__(self, prods, cols):
+        f32 = jnp.float32
+        hid, vjp = jax.vjp(self.activate, *prods[:-1])
+        hid = hid.astype(self.cd)
+        d_first = vjp((cols[0] * prods[-1]).astype(self.cd).astype(f32))
+        return ([hid] + [v.astype(self.cd) for v in d_first]
+                + [prods[-1] * hid.astype(f32)])
+
+
+def _summed(prods, cols):
+    """The rows' gradient: the sum of the first matrices' shares."""
+    return [sum(prods)]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _grouped_kernel(activate, block, weights, x, gate, tok, counts):
+    """The kernel form: the landed rows gathered, a call of the first
+    products with ``activate`` as its epilogue, and a call of the output
+    product that weighs each row by its pick and adds it to its token's
+    row."""
+    cd = x.dtype
+    xs = _landed_rows(x, tok, jnp.sum(counts), block)
+    act, = grouped_matmul.grouped_products(
+        counts, [xs],
+        [(0, weights[k].astype(cd), False) for k in ("w1", "wg")
+         if k in weights],
+        _Activated(activate), [cd], name="moe_gmm_hidden")
+    return grouped_matmul.grouped_products(
+        counts, [act], [(0, weights["w2"].astype(cd), False)], _weighted, [],
+        cols=[gate.astype(jnp.float32)[:, None]],
+        add_to=(tok, x.shape[0]), name="moe_gmm_out")[0]
+
+
+def _grouped_kernel_fwd(activate, block, weights, x, gate, tok, counts):
+    return (_grouped_kernel(activate, block, weights, x, gate, tok, counts),
+            (weights, x, gate, tok, counts))
+
+
+@jax.named_scope("moe_experts")             # the forward's scope, by hand
+def _kernel_backward(activate, block, res, dout):
+    """One fused call recomputes the first products and the activation,
+    takes ``dy @ w2^T`` (which serves d(gate) = <dy @ w2^T, hid> and
+    d(hid) = gate * it, as the XLA form has it) and the activation's
+    derivative (``_Backward``); two calls of transposed products give the
+    weight gradients, a call of two products the rows' gradient, added to
+    the tokens' rows inside it."""
+    weights, x, gate, tok, counts = res
+    cd = x.dtype
+    first = [k for k in ("w1", "wg") if k in weights]
+    landed = jnp.sum(counts)
+    xs = _landed_rows(x, tok, landed, block)
+    # the cotangent has passed the layer's rounding to the compute dtype
+    dys = _landed_rows(dout.astype(cd), tok, landed, block)
+    g = gate.astype(jnp.float32)[:, None]
+    hid, *d_first, dgate = grouped_matmul.grouped_products(
+        counts, [xs, dys],
+        [(0, weights[k].astype(cd), False) for k in first]
+        + [(1, weights["w2"].astype(cd), True)],
+        _Backward(activate, jnp.dtype(cd)), [cd] * (1 + len(first)),
+        cols=[g], row_sum=True, name="moe_gmm_bwd")
+    dw = dict(zip(first, grouped_matmul.grouped_transposed(
+        counts, xs, d_first, weights["w1"].dtype, name="moe_gmm_t_hidden")))
+    dw["w2"], = grouped_matmul.grouped_transposed(
+        counts, hid, [dys], weights["w2"].dtype, scale=g, name="moe_gmm_t_out")
+    dx, = grouped_matmul.grouped_products(
+        counts, d_first,
+        [(i, weights[k].astype(cd), True) for i, k in enumerate(first)],
+        _summed, [], add_to=(tok, x.shape[0]), name="moe_gmm_dx")
+    dgate = jnp.where(jnp.arange(gate.shape[0]) < landed, dgate, 0.0)
+    return (dw, dx.astype(x.dtype), dgate.astype(gate.dtype), None, None)
+
+
+_grouped_kernel.defvjp(_grouped_kernel_fwd, _kernel_backward)
 
 
 def expert_param_specs(moe: MoE, axis: str = EXPERT_AXIS):

@@ -207,6 +207,16 @@ METRIC_SPECS: List[MetricSpec] = [
                "else). Counted once per eager call / once per TRACE under "
                "jit, as bigdl_moe_dispatch_total: which form each compiled "
                "program holds, not per-step traffic.", ("form",)),
+    MetricSpec("bigdl_moe_grouped_total", "counter",
+               "Grouped products of held expert layers (MoE(dispatch="
+               "'held')) by form (form label: kernel, the Mosaic kernels of "
+               "ops/grouped_matmul.py over the sorted rows, taken on a TPU "
+               "for experts without biases whose widths are whole 128-lane "
+               "tiles; xla, the loops over row blocks of "
+               "parallel/expert.py, everywhere else). Counted once per "
+               "eager call / once per TRACE under jit, as "
+               "bigdl_ssd_scan_total: which form each compiled expert "
+               "block holds.", ("form",)),
     MetricSpec("bigdl_lm_head_ce_total", "counter",
                "Fused LM-head cross-entropies by form (form label: "
                "one_pass, the loss and its three gradients from one scan "

@@ -68,14 +68,28 @@ def _moe_reference_params(p):
             "e.shared_experts.down_proj.weight": p["shared_w2"]}
 
 
+def _grouped_form(monkeypatch, form, rows):
+    """The held layer's grouped product in ``form`` whatever the backend,
+    its row block (the XLA loops') or row tile (the kernels') ``rows``."""
+    from bigdl_tpu.ops import grouped_matmul
+    monkeypatch.setattr(expert, "takes_kernel",
+                        lambda *a: form == "kernel")
+    monkeypatch.setattr(expert, "_BLOCK_ROWS", rows)
+    monkeypatch.setattr(grouped_matmul, "ROW_TILE", rows)
+    monkeypatch.setattr(grouped_matmul, "SUB_ROWS", 8)
+
+
+@pytest.mark.parametrize("form", ["xla", "kernel"])
 @pytest.mark.parametrize("block_rows", [8, 512])
 @pytest.mark.parametrize("held", [(0, 1), (2, 5, 7), tuple(range(8))])
 def test_gated_experts_forward_and_gradients_match_the_reference(
-        held, block_rows, monkeypatch):
+        held, block_rows, form, monkeypatch):
     """Sigmoid top-3 of 8, renormalised, x 2.826, SwiGLU experts of three
     matrices and a shared one; rows in blocks of 8 (several blocks an
-    expert, masked tails) and in one block."""
-    monkeypatch.setattr(expert, "_BLOCK_ROWS", block_rows)
+    expert, masked tails) and in one block, through the XLA loops and
+    through the grouped kernels (in Pallas' interpreter: row tiles of 8,
+    which their runs straddle, and one tile)."""
+    _grouped_form(monkeypatch, form, block_rows)
     m = _moe(held)
     assert sorted(m._parameters) == ["gate_weight", "shared_w1", "shared_w2",
                                      "shared_wg", "w1", "w2", "wg"]
@@ -554,6 +568,49 @@ def test_scopes_of_the_gated_expert_layer(cut):
     whiles = [n for n in timeline.scope_instructions(hlo, "moe_experts")
               if n.startswith("while")]
     assert len(whiles) >= 2     # the backward's loop is under the scope too
+
+
+@pytest.mark.parametrize("form", ["xla", "kernel"])
+def test_the_grouped_counter_reads_the_form_the_layer_took(form, monkeypatch):
+    """``bigdl_moe_grouped_total`` counts once a trace, under the form that
+    ``takes_kernel`` chose; both forms' backward stays under the forward's
+    scope."""
+    from bigdl_tpu.telemetry import get_registry, instruments
+    other = {"xla": "kernel", "kernel": "xla"}[form]
+    if form == "kernel":
+        _grouped_form(monkeypatch, form, 8)
+    fam = instruments(get_registry()).moe_grouped_total
+    before = {f: fam.labels(form=f).value for f in (form, other)}
+    m = _moe((2, 5, 7))
+    u = _normal(_rng(4), 2, 21, 32)
+    hlo = jax.jit(jax.grad(lambda p: jnp.sum(jnp.square(_apply(m, p, u))))) \
+        .lower(m.parameter_tree()).compile().as_text()
+    assert fam.labels(form=form).value == before[form] + 1
+    assert fam.labels(form=other).value == before[other]
+    whiles = [n for n in timeline.scope_instructions(hlo, "moe_experts")
+              if n.startswith("while")]
+    assert len(whiles) >= 2
+
+
+def test_the_form_rule_reads_the_backend_the_widths_and_the_biases():
+    """The kernels on a TPU for experts without biases whose widths are
+    whole lane tiles (the Trinity and JoyAI cells' (2048, 1024) and (2048,
+    768)); the XLA loops everywhere else, Nemotron's 1,856 among it."""
+    bf = jnp.bfloat16
+
+    def w(d, h, **more):
+        return dict({"w1": jax.ShapeDtypeStruct((16, d, h), bf),
+                     "w2": jax.ShapeDtypeStruct((16, h, d), bf)}, **more)
+
+    x = jax.ShapeDtypeStruct((8192, 2048), bf)
+    assert expert.takes_kernel("tpu", w(2048, 1024, wg=None), x)
+    assert expert.takes_kernel("tpu", w(2048, 768), x)
+    assert not expert.takes_kernel("cpu", w(2048, 1024), x)
+    assert not expert.takes_kernel("tpu", w(2048, 1024, b1=None), x)
+    assert not expert.takes_kernel("tpu", w(2688, 1856), x)
+    assert not expert.takes_kernel("tpu", w(2000, 1024), x)
+    assert not expert.takes_kernel(
+        "tpu", w(2048, 1024), jax.ShapeDtypeStruct((8, 2048), jnp.float16))
 
 
 def test_a_traced_step_counts_band_and_full_once_a_layer(monkeypatch):

@@ -339,14 +339,28 @@ def _moe_reference_params(m, tree):
             "e.shared_experts.down_proj.weight": tree["shared_w2"]}
 
 
+def _grouped_form(monkeypatch, form, rows):
+    """The held layer's grouped product in ``form`` whatever the backend,
+    its row block (the XLA loops') or row tile (the kernels') ``rows``."""
+    from bigdl_tpu.ops import grouped_matmul
+    monkeypatch.setattr(expert, "takes_kernel",
+                        lambda *a: form == "kernel")
+    monkeypatch.setattr(expert, "_BLOCK_ROWS", rows)
+    monkeypatch.setattr(grouped_matmul, "ROW_TILE", rows)
+    monkeypatch.setattr(grouped_matmul, "SUB_ROWS", 8)
+
+
+@pytest.mark.parametrize("form", ["xla", "kernel"])
 @pytest.mark.parametrize("block_rows", [8, 512])
 @pytest.mark.parametrize("held", [(0, 1), (2, 5, 7), tuple(range(8))])
 def test_held_experts_forward_and_gradients_match_the_reference(
-        held, block_rows, monkeypatch):
+        held, block_rows, form, monkeypatch):
     """Sigmoid top-3 of 8, renormalised, x 2.5, relu^2 experts, a shared
     expert; rows in blocks of 8 (several blocks an expert, masked tails)
-    and in one block."""
-    monkeypatch.setattr(expert, "_BLOCK_ROWS", block_rows)
+    and in one block, through the XLA loops and through the grouped
+    kernels (in Pallas' interpreter: row tiles of 8, which their runs
+    straddle, and one tile)."""
+    _grouped_form(monkeypatch, form, block_rows)
     m = _moe(held)
     cfg = dict(CFG, n_routed_experts=len(held))
     rng = _rng(2)
