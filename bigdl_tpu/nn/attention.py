@@ -556,6 +556,29 @@ class MultiHeadAttention(Module):
             query = key = value = input
 
         e = getattr(self, "_e_q", self.embed_dim)
+        # everything but the core runs under the scope ``attn_proj``, the
+        # core under ``attn_core`` (telemetry/catalogue.SCOPE_SPECS)
+        with jax.named_scope("attn_proj"):
+            q, k, v = self._heads_in(query, key, value)
+            if not self._decode:
+                k, v = self._expand_kv(k), self._expand_kv(v)
+        with jax.named_scope("attn_core"):
+            ctx = self._attend_decode(q, k, v) if self._decode \
+                else self._attend(q, k, v, mask)
+        with jax.named_scope("attn_proj"):
+            b, s, _, _ = ctx.shape
+            ctx = ctx.reshape(b, s, e)
+            if getattr(self, "gated", False):
+                gate = keep(self._project(query, self.gate_proj_weight, None),
+                            ATTN_PROJ)
+                ctx = ctx * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(
+                    ctx.dtype)
+            # read again only by a norm on this output
+            # (HybridBlock.norm_post)
+            return keep(self._out_projection(ctx), ATTN_PROJ)
+
+    def _heads_in(self, query, key, value):
+        """q, k and v per head: the in-projections, q/k norm, rotation."""
         # the projections' outputs are kept across a block's
         # rematerialisation (ops.remat) BEFORE norm and rotation: q/k norm's
         # backward reads the un-normed value, and both are element-wise
@@ -597,22 +620,7 @@ class MultiHeadAttention(Module):
             scaling = getattr(self, "rope_scaling", None)
             q = rope_rotate(q, pos, theta, scaling)
             k = rope_rotate(k, pos, theta, scaling)
-
-        if self._decode:
-            ctx = self._attend_decode(q, k, v)
-        else:
-            ctx = self._attend(q, self._expand_kv(k), self._expand_kv(v),
-                               mask)
-
-        b, s, _, _ = ctx.shape
-        ctx = ctx.reshape(b, s, e)
-        if getattr(self, "gated", False):
-            gate = keep(self._project(query, self.gate_proj_weight, None),
-                        ATTN_PROJ)
-            ctx = ctx * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(
-                ctx.dtype)
-        # read again only by a norm on this output (HybridBlock.norm_post)
-        return keep(self._out_projection(ctx), ATTN_PROJ)
+        return q, k, v
 
     def _attend(self, q, k, v, mask):
         from bigdl_tpu.ops import attention_core, flash_attention
@@ -684,7 +692,8 @@ class LatentAttention(Module):
     padded; the XLA core below 1,024 tokens and off the TPU. There is no
     decode mode (serving keeps the latent as the cache and absorbs Wkvb
     into the query and the output: ROADMAP R1). Everything but the
-    attention core runs under the scope ``mla_proj``.
+    attention core runs under the scope ``mla_proj``, the core under
+    ``attn_core``.
     """
 
     def __init__(self, embed_dim: int, num_heads: int, q_lora_rank: int,
@@ -749,12 +758,13 @@ class LatentAttention(Module):
             k = jnp.concatenate(
                 [kv[..., :dc], jnp.broadcast_to(kr, (b, s, n, dr))], -1)
             v = kv[..., dc:]
-        if flash_attention.use_flash(q, None):
-            ctx = flash_attention.flash_attention(
-                q, k, v, causal=True, scale=self.softmax_scale)
-        else:
-            ctx = attention_core.dot_product_attention(
-                q, k, v, causal=True, scale=self.softmax_scale)
+        with jax.named_scope("attn_core"):
+            if flash_attention.use_flash(q, None):
+                ctx = flash_attention.flash_attention(
+                    q, k, v, causal=True, scale=self.softmax_scale)
+            else:
+                ctx = attention_core.dot_product_attention(
+                    q, k, v, causal=True, scale=self.softmax_scale)
         with jax.named_scope("mla_proj"):
             return self._project(ctx.reshape(b, s, -1), self.out_proj_weight)
 
@@ -938,11 +948,12 @@ class TransformerEncoderLayer(Module):
     def _ffn(self, x):
         if self.moe_experts:
             return self.moe.forward(x)
-        if self.activation == "swiglu":
-            up = self.linear1.forward(x)
-            gate = self.linear_gate.forward(x)
-            return self.linear2.forward(jax.nn.silu(up) * gate)
-        return self.linear2.forward(self._act(self.linear1.forward(x)))
+        with jax.named_scope("mlp"):
+            if self.activation == "swiglu":
+                up = self.linear1.forward(x)
+                gate = self.linear_gate.forward(x)
+                return self.linear2.forward(jax.nn.silu(up) * gate)
+            return self.linear2.forward(self._act(self.linear1.forward(x)))
 
     def update_output(self, input):
         # Megatron sequence-parallel regions: when tagged by

@@ -55,9 +55,10 @@ class GatedMLP(Module):
         # the three products' outputs are kept across a block's
         # rematerialisation (ops.remat); the down one is read again only by
         # a norm on it (HybridBlock.norm_post)
-        gate = keep(self.gate.forward(input), MLP_PROJ)
-        up = keep(self.up.forward(input), MLP_PROJ)
-        return keep(self.down.forward(jax.nn.silu(gate) * up), MLP_PROJ)
+        with jax.named_scope("mlp"):
+            gate = keep(self.gate.forward(input), MLP_PROJ)
+            up = keep(self.up.forward(input), MLP_PROJ)
+            return keep(self.down.forward(jax.nn.silu(gate) * up), MLP_PROJ)
 
 
 class HybridBlock(Module):

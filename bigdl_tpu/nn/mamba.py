@@ -96,33 +96,41 @@ class Mamba2(TensorModule):
                       self.state_size)
         d_inner, conv_dim = self.d_inner, self.conv_dim
         bsz, length, _ = input.shape
-        w_in = self.in_proj_weight
-        # kept across a block's rematerialisation (ops.remat): the widest
-        # product of the block runs once; conv, softplus, gate and scan twice
-        zxbcdt = keep(jnp.matmul(match_compute(input, w_in), w_in.T),
-                      MAMBA_IN_PROJ)
-        z = zxbcdt[..., :d_inner]
-        xbc = self._conv(zxbcdt[..., d_inner:d_inner + conv_dim])
-        dt = zxbcdt[..., d_inner + conv_dim:]
-        x = xbc[..., :d_inner].reshape(bsz, length, h, p)
-        b = xbc[..., d_inner:d_inner + g * n].reshape(bsz, length, g, n)
-        c = xbc[..., d_inner + g * n:].reshape(bsz, length, g, n)
-        dt = jax.nn.softplus(dt.astype(jnp.float32)
-                             + self.dt_bias.astype(jnp.float32))
-        a = -jnp.exp(self.A_log.astype(jnp.float32))
+        # three leaf scopes beside the scan's own (``ssd_scan``): the two
+        # products under ``mamba_proj``, everything else under
+        # ``mamba_local`` (telemetry/catalogue.SCOPE_SPECS)
+        with jax.named_scope("mamba_proj"):
+            w_in = self.in_proj_weight
+            # kept across a block's rematerialisation (ops.remat): the
+            # widest product of the block runs once; conv, softplus, gate
+            # and scan twice
+            zxbcdt = keep(jnp.matmul(match_compute(input, w_in), w_in.T),
+                          MAMBA_IN_PROJ)
+        with jax.named_scope("mamba_local"):
+            z = zxbcdt[..., :d_inner]
+            xbc = self._conv(zxbcdt[..., d_inner:d_inner + conv_dim])
+            dt = zxbcdt[..., d_inner + conv_dim:]
+            x = xbc[..., :d_inner].reshape(bsz, length, h, p)
+            b = xbc[..., d_inner:d_inner + g * n].reshape(bsz, length, g, n)
+            c = xbc[..., d_inner + g * n:].reshape(bsz, length, g, n)
+            dt = jax.nn.softplus(dt.astype(jnp.float32)
+                                 + self.dt_bias.astype(jnp.float32))
+            a = -jnp.exp(self.A_log.astype(jnp.float32))
         y = ssd_scan(x, dt, a, b, c, self.chunk_size)
-        y = y.astype(jnp.float32) + (self.D.astype(jnp.float32)[:, None]
-                                     * x.astype(jnp.float32))
-        # gated RMSNorm over each of the G groups of the inner width
-        y = y.reshape(bsz, length, d_inner) \
-            * jax.nn.silu(z.astype(jnp.float32))
-        yg = y.reshape(bsz, length, g, d_inner // g)
-        yg = yg * jax.lax.rsqrt(jnp.mean(jnp.square(yg), axis=-1,
-                                         keepdims=True) + self.norm_eps)
-        y = yg.reshape(bsz, length, d_inner).astype(input.dtype) \
-            * self.norm_weight
-        w_out = self.out_proj_weight
-        return jnp.matmul(match_compute(y, w_out), w_out.T)
+        with jax.named_scope("mamba_local"):
+            y = y.astype(jnp.float32) + (self.D.astype(jnp.float32)[:, None]
+                                         * x.astype(jnp.float32))
+            # gated RMSNorm over each of the G groups of the inner width
+            y = y.reshape(bsz, length, d_inner) \
+                * jax.nn.silu(z.astype(jnp.float32))
+            yg = y.reshape(bsz, length, g, d_inner // g)
+            yg = yg * jax.lax.rsqrt(jnp.mean(jnp.square(yg), axis=-1,
+                                             keepdims=True) + self.norm_eps)
+            y = yg.reshape(bsz, length, d_inner).astype(input.dtype) \
+                * self.norm_weight
+        with jax.named_scope("mamba_proj"):
+            w_out = self.out_proj_weight
+            return jnp.matmul(match_compute(y, w_out), w_out.T)
 
     def __repr__(self):
         return (f"Mamba2({self.embed_dim}, heads={self.num_heads}x"

@@ -72,8 +72,9 @@ def current_rng() -> RngStream:
 # forwardTime/backwardTime on every call. Under jit that is meaningless (XLA
 # fuses the whole step), so the TPU build offers two complementary tools:
 #  - ``jax.named_scope(module.name)`` is ALWAYS applied around update_output,
-#    so HLO ops carry module names and a ``jax.profiler`` trace attributes
-#    device time to layers;
+#    so compiled instructions carry module names; ``Optimizer.set_profiling``
+#    reduces a profile of the step to device time by layer and pass
+#    (``step_partition.json``; ``telemetry/step_partition.py``);
 #  - opt-in EAGER timing (``enable_timing``): outside jit, each forward/
 #    backward blocks on its result and accumulates wall time, read back via
 #    ``get_times()`` exactly like the reference.
@@ -251,9 +252,9 @@ class Module:
         """Per-module (module, forward_s, backward_s), depth-first — the
         reference's ``getTimes`` (``AbstractModule.scala:134-145``;
         aggregated over containers ``Container.scala:88-95``). Populated only
-        while ``nn.module.enable_timing(True)`` and outside jit; inside jit
-        use a ``jax.profiler`` trace, where the always-on named_scope tags
-        attribute device time to these same module names."""
+        while ``nn.module.enable_timing(True)`` and outside jit: the EAGER
+        path only. Under jit, ``Optimizer.set_profiling`` writes the step's
+        device time by layer and pass (``step_partition.json``)."""
         times = [(self, getattr(self, "_forward_time", 0.0),
                   getattr(self, "_backward_time", 0.0))]
         for child in self._modules.values():
@@ -268,7 +269,8 @@ class Module:
             child.reset_times()
 
     def time_report(self) -> str:
-        """Human-readable get_times() table (debug aid)."""
+        """Human-readable get_times() table (debug aid; the eager path, as
+        ``get_times``)."""
         lines = ["module                                  fwd(s)    bwd(s)"]
         for m, f, b in self.get_times():
             lines.append(f"{type(m).__name__ + ' (' + m.name + ')':38s} "

@@ -94,6 +94,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 
 from bigdl_tpu.ops.remat import FLASH_OUT, keep
+from bigdl_tpu.ops.scopes import under_scope
 
 _NEG = float(jnp.finfo(jnp.float32).min)
 _NT = (((1,), (1,)), ((), ()))        # a (M, K) x b (N, K) -> (M, N): b as it lies
@@ -586,6 +587,7 @@ def _flash_lse_vjp_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
     return (o, lse), (q, k, v, o, lse)
 
 
+@under_scope("attn_core")
 def _flash_lse_vjp_bwd(causal, scale, block_q, block_k, interpret, window,
                        res, g):
     g_o, g_l = g

@@ -49,6 +49,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from bigdl_tpu.ops.scopes import under_scope
+
 _LANES = 128
 _NN = (((1,), (0,)), ((), ()))          # a (M, K) x b (K, N) -> (M, N)
 _NT = (((1,), (1,)), ((), ()))          # a (M, K) x b (N, K) -> (M, N)
@@ -415,6 +417,7 @@ def _first(prods, cols):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+@under_scope("moe_experts")
 def grouped_matmul(lhs, rhs, counts, transpose_rhs=False, interpret=None):
     """``lhs[rows of e] @ rhs[e]`` (``@ rhs[e]^T`` with ``transpose_rhs``,
     ``rhs`` then (E, N, K)) -> (R, N) in ``lhs``'s dtype, zeros past the
@@ -430,6 +433,7 @@ def _grouped_matmul_fwd(lhs, rhs, counts, transpose_rhs, interpret):
             (lhs, rhs, counts))
 
 
+@under_scope("moe_experts")
 def _grouped_matmul_bwd(transpose_rhs, interpret, res, dout):
     lhs, rhs, counts = res
     dout = dout.astype(lhs.dtype)

@@ -39,6 +39,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from bigdl_tpu.ops.scopes import under_scope
+
 # what the logits of one row tile may hold, reckoned in float32 (XLA keeps the
 # tile in the compute dtype: half of it under the bf16 policy): one tile at
 # T=4,096, V=151,936 (2.49 GB) and at T=8,192, V=16,384 (537 MB)
@@ -117,16 +119,16 @@ def _count(form):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-@jax.named_scope("lm_head_ce")
+@under_scope("lm_head_ce")
 def _lm_head_ce(h, w, b, valid, tgt0, rows):
     """CE summed over the valid rows; ``b`` may be None."""
     _count("forward_only")
     return _over_tiles(h, w, b, valid, tgt0, rows, grads=False)[0]
 
 
-# the scope names the head's operations in the trace (a custom_vjp rule is
-# traced outside the primal's name stack, so each carries the scope itself)
-@jax.named_scope("lm_head_ce")
+# the scope names the head's operations in the trace: the primal enters it
+# inside itself, so each rule carries it too (ops/scopes.py)
+@under_scope("lm_head_ce")
 def _lm_head_ce_fwd(h, w, b, valid, tgt0, rows):
     _count("one_pass")
     loss, grads = _over_tiles(h, w, b, valid, tgt0, rows, grads=True)
@@ -134,7 +136,7 @@ def _lm_head_ce_fwd(h, w, b, valid, tgt0, rows):
                                 (h, w, b)), valid, tgt0)
 
 
-@jax.named_scope("lm_head_ce")
+@under_scope("lm_head_ce")
 def _lm_head_ce_bwd(rows, res, g_sum):
     *grads, valid, tgt0 = res
     return (*jax.tree.map(lambda g: (g * g_sum).astype(g.dtype),

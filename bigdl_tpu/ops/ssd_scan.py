@@ -50,6 +50,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
+from bigdl_tpu.ops.scopes import under_scope
+
 _LANES = 128
 _NT = (((1,), (1,)), ((), ()))          # a (M, K) x b (N, K) -> (M, N)
 _TN = (((0,), (0,)), ((), ()))          # a (K, M) x b (K, N) -> (M, N)
@@ -532,7 +534,7 @@ def _chunks_fwd(x, rows, b, c, whole, hd, interpret):
             (x, rows, b, c, before, carry_back))
 
 
-@jax.named_scope("ssd_scan")
+@under_scope("ssd_scan")
 def _chunks_bwd(hd, interpret, res, dy):
     x, rows, b, c, before, carry_back = res
     dx, drows, db, dc, dbefore = _bwd_out_call(x, rows, b, c, before, dy, hd,

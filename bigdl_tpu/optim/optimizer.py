@@ -112,10 +112,14 @@ def make_training_loss_fn(model, criterion, policy, reg_pairs, remat,
     """The ONE training loss closure shared by every step builder (local,
     distributed allreduce, ZeRO-1 sharded): precision cast -> functional
     forward (optionally rematerialized via ``jax.checkpoint``) -> criterion
-    + regularizer, returning ``(loss, (new_buffers, raw_loss))``."""
+    + regularizer, returning ``(loss, (new_buffers, raw_loss))``. The cast
+    and the loss run under the scopes ``param_cast`` and ``criterion``: two
+    of the step's own stages in its partition
+    (``telemetry/step_partition.py``; ``clipped_update`` has the others)."""
     def forward(p, data):
         from bigdl_tpu.ops.precision import cast_tree
-        p_c = policy.cast_params_for_compute(p)
+        with jax.named_scope("param_cast"):
+            p_c = policy.cast_params_for_compute(p)
         out, new_buf = functional_apply(model, p_c, buffers, data,
                                         training=True, rng=rng)
         return out, cast_tree(new_buf, jnp.float32)
@@ -130,10 +134,22 @@ def make_training_loss_fn(model, criterion, policy, reg_pairs, remat,
 
     def loss_fn(p):
         out, new_buf = fwd(p, data)
-        loss = criterion.apply(out, labels).astype(jnp.float32)
-        return loss + _reg_loss(p, reg_pairs), (new_buf, loss)
+        with jax.named_scope("criterion"):
+            loss = criterion.apply(out, labels).astype(jnp.float32)
+            return loss + _reg_loss(p, reg_pairs), (new_buf, loss)
 
     return loss_fn
+
+
+def clipped_update(optim, clip, grads, opt_state, params, **clip_args):
+    """``optim.update`` on the clipped gradient, as every step builder ends:
+    the clipper (``make_grad_clipper``; ``clip_args`` are its ZeRO-1
+    arguments) under the scope ``grad_clip``, the update under
+    ``optim_update``."""
+    with jax.named_scope("grad_clip"):
+        grads = clip(grads, **clip_args)
+    with jax.named_scope("optim_update"):
+        return optim.update(grads, opt_state, params)
 
 
 class Optimizer:
@@ -398,14 +414,20 @@ class Optimizer:
     def set_profiling(self, log_dir: str, start_iteration: int = 5,
                       n_iterations: int = 5) -> "Optimizer":
         """Capture a ``jax.profiler`` trace of iterations
-        [start_iteration, start_iteration + n_iterations). The TPU-native
-        per-module breakdown (reference ``getTimes``,
-        ``AbstractModule.scala:134-145``): every module forward runs under
-        ``jax.named_scope(module.name)``, so the trace's HLO ops are
-        attributed to layers; open the dump with TensorBoard's profile
-        plugin or Perfetto. The span tracer (``telemetry/tracing.py``) is
-        on for the profiled window, so the loop's ``train.*`` spans are in
-        the same trace, on the thread that enqueues the step."""
+        [start_iteration, start_iteration + n_iterations) under
+        ``log_dir``, and reduce it to the reference's ``getTimes``
+        (``AbstractModule.scala:134-145``) under jit: when the profile
+        stops, ``<log_dir>/step_partition.json`` and a log line hold the
+        step's device time by layer and pass (forward, recompute, backward,
+        update), in ms a step and % of it. A layer is a scope of
+        ``telemetry/catalogue.SCOPE_SPECS`` (every module's forward runs
+        under ``jax.named_scope(module.name)``, the step's stages and the
+        mixers' parts under leaf scopes of their own);
+        ``telemetry/step_partition.py`` says how an operation finds its
+        cell. The dump itself opens in TensorBoard's profile plugin or
+        Perfetto. The span tracer (``telemetry/tracing.py``) is on for the
+        profiled window, so the loop's ``train.*`` spans are in the same
+        trace, on the thread that enqueues the step."""
         self._profile = (log_dir, int(start_iteration), int(n_iterations))
         return self
 
@@ -426,6 +448,36 @@ class Optimizer:
         self._profiling_active = False
         if self._profile_owns_tracer:
             tracing.disable()
+        self._write_step_partition(self._profile[0])
+
+    def _write_step_partition(self, log_dir: str) -> None:
+        """The profile just written, reduced to the step's device time by
+        layer and pass (``telemetry/step_partition.py``): logged, and kept
+        as ``<log_dir>/step_partition.json``. Once, after ``stop_trace``;
+        a profile it cannot read costs a warning, never the run."""
+        from bigdl_tpu.telemetry import step_partition
+        step = getattr(self.step_fn, "tracked", self.step_fn)
+        t0 = time.perf_counter()
+        try:
+            texts = step.compiled_texts()
+            report = step_partition.write_report(log_dir, texts[-1]) \
+                if texts else None
+            if texts and "optim_update" not in texts[-1]:
+                logger.warning(
+                    "[Profiler] the step's executable carries none of this "
+                    "checkout's scopes: a compile cache filled by an older "
+                    "checkout served it; clear it for a table by layer")
+        except Exception as e:      # noqa: BLE001 - a reader's fault
+            logger.warning("[Profiler] no step partition: %r", e)
+            return
+        if report is None:
+            logger.info("[Profiler] the profile holds no run of the step "
+                        "program: no step partition")
+        else:
+            logger.info("[Profiler] %s\n(%s, reduced in %.2f s)",
+                        step_partition.format_table(report),
+                        os.path.join(log_dir, "step_partition.json"),
+                        time.perf_counter() - t0)
 
     def _telemetry_mode(self) -> str:
         """Label value for the ``bigdl_train_*`` metric families
@@ -573,8 +625,8 @@ class LocalOptimizer(Optimizer):
                 model, criterion, policy, reg_pairs, remat,
                 buffers, rng, data, labels)
             grads, (new_buf, loss) = jax.grad(loss_fn, has_aux=True)(params)
-            new_params, new_opt_state = optim.update(clip(grads), opt_state,
-                                                     params)
+            new_params, new_opt_state = clipped_update(
+                optim, clip, grads, opt_state, params)
             return new_params, new_buf, new_opt_state, loss
 
         # compile flight recorder: counts/times every step compilation

@@ -53,7 +53,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from bigdl_tpu.nn.module import functional_apply
 from bigdl_tpu.optim.optimizer import (LocalOptimizer, Optimizer,
                                        _regularizer_pairs, _reg_loss,
-                                       make_grad_clipper,
+                                       clipped_update, make_grad_clipper,
                                        make_training_loss_fn)
 from bigdl_tpu.parallel.mesh import DATA_AXIS, TENSOR_AXIS, MeshTopology
 from bigdl_tpu.telemetry.profiling import tracked_jit
@@ -324,12 +324,14 @@ class DistriOptimizer(LocalOptimizer):
             grads, (new_buf, loss) = jax.grad(loss_fn, has_aux=True)(params)
             if compress:
                 # bf16 payload ≙ reference FP16CompressedTensor (truncated fp32)
-                grads = jax.tree_util.tree_map(
-                    lambda g: g.astype(jnp.bfloat16).astype(g.dtype), grads)
+                with jax.named_scope("grad_sync"):
+                    grads = jax.tree_util.tree_map(
+                        lambda g: g.astype(jnp.bfloat16).astype(g.dtype),
+                        grads)
             # clip the GLOBAL (GSPMD-allreduced) gradient, post-compression,
             # so the update sees the same clipped grad on every device
-            new_params, new_opt_state = optim.update(clip(grads), opt_state,
-                                                     params)
+            new_params, new_opt_state = clipped_update(
+                optim, clip, grads, opt_state, params)
             return new_params, new_buf, new_opt_state, loss
 
         rep, bat = self._replicated, self._batch_sharding
@@ -392,15 +394,17 @@ class DistriOptimizer(LocalOptimizer):
                 buffers, rng, data, labels)
 
             grads, (new_buf, loss) = jax.grad(loss_fn, has_aux=True)(params)
-            if compress:
-                grads = jax.tree_util.tree_map(
-                    lambda g: g.astype(jnp.bfloat16).astype(g.dtype), grads)
-            # constrain grads to the param shardings: the backward's psum
-            # lowers to reduce-scatter (each device keeps its shard) instead
-            # of all-reduce + slice
-            grads = jax.lax.with_sharding_constraint(grads, p_sh)
-            new_params, new_opt_state = optim.update(clip(grads), opt_state,
-                                                     params)
+            with jax.named_scope("grad_sync"):
+                if compress:
+                    grads = jax.tree_util.tree_map(
+                        lambda g: g.astype(jnp.bfloat16).astype(g.dtype),
+                        grads)
+                # constrain grads to the param shardings: the backward's
+                # psum lowers to reduce-scatter (each device keeps its
+                # shard) instead of all-reduce + slice
+                grads = jax.lax.with_sharding_constraint(grads, p_sh)
+            new_params, new_opt_state = clipped_update(
+                optim, clip, grads, opt_state, params)
             return new_params, new_buf, new_opt_state, loss
 
         rep, bat = self._replicated, self._batch_sharding
@@ -449,30 +453,39 @@ class DistriOptimizer(LocalOptimizer):
                 buffers, rng, data, labels)
 
             grads, (new_buf, loss) = jax.grad(loss_fn, has_aux=True)(params)
-            flat_grads, _ = ravel_pytree(grads)
-            flat_grads = jnp.pad(flat_grads, (0, pad))
-            if compress:
-                flat_grads = flat_grads.astype(jnp.bfloat16)
-            # reduce-scatter: each device reduces ONLY its own slice
-            # (≙ aggregrateGradientPartition, AllReduceParameter.scala:172-210)
-            grad_slice = jax.lax.psum_scatter(
-                flat_grads, DATA_AXIS, scatter_dimension=0, tiled=True) / n_dev
-            grad_slice = grad_slice.astype(jnp.float32)
+            with jax.named_scope("grad_sync"):
+                flat_grads, _ = ravel_pytree(grads)
+                flat_grads = jnp.pad(flat_grads, (0, pad))
+                if compress:
+                    flat_grads = flat_grads.astype(jnp.bfloat16)
+                # reduce-scatter: each device reduces ONLY its own slice (≙
+                # aggregrateGradientPartition,
+                # AllReduceParameter.scala:172-210)
+                grad_slice = jax.lax.psum_scatter(
+                    flat_grads, DATA_AXIS, scatter_dimension=0,
+                    tiled=True) / n_dev
+                grad_slice = grad_slice.astype(jnp.float32)
             rank = jax.lax.axis_index(DATA_AXIS)
             # clip on the slice: the global L2 norm psums the per-slice
             # squared norms (each device owns 1/P of the flat gradient);
             # the mask keeps PAD lanes at zero through the clamp so the
             # norm matches the allreduce path exactly
             lane = rank * chunk + jnp.arange(chunk)
-            grad_slice = clip(grad_slice, axis_name=DATA_AXIS,
-                              valid_mask=(lane < n).astype(jnp.float32))
-            param_slice = jax.lax.dynamic_slice(flat_params, (rank * chunk,), (chunk,))
-            new_slice, new_opt_state = optim.update(grad_slice, opt_state, param_slice)
+            with jax.named_scope("optim_update"):
+                param_slice = jax.lax.dynamic_slice(
+                    flat_params, (rank * chunk,), (chunk,))
+            new_slice, new_opt_state = clipped_update(
+                optim, clip, grad_slice, opt_state, param_slice,
+                axis_name=DATA_AXIS,
+                valid_mask=(lane < n).astype(jnp.float32))
             # republish slices (≙ sendWeightPartition + getWeights)
-            new_flat = jax.lax.all_gather(new_slice, DATA_AXIS, tiled=True)
-            new_buf = jax.tree_util.tree_map(
-                lambda b: jax.lax.pmean(b, DATA_AXIS), new_buf)
-            loss = jax.lax.pmean(loss, DATA_AXIS)
+            with jax.named_scope("optim_update"):
+                new_flat = jax.lax.all_gather(new_slice, DATA_AXIS,
+                                              tiled=True)
+            with jax.named_scope("grad_sync"):
+                new_buf = jax.tree_util.tree_map(
+                    lambda b: jax.lax.pmean(b, DATA_AXIS), new_buf)
+                loss = jax.lax.pmean(loss, DATA_AXIS)
             return new_flat, new_buf, new_opt_state, loss
 
         in_specs = (P(), P(), opt_specs, P(), P(DATA_AXIS), P(DATA_AXIS))

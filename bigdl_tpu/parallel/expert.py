@@ -74,6 +74,7 @@ from jax.sharding import PartitionSpec as P
 from bigdl_tpu.nn import initialization as init
 from bigdl_tpu.nn.module import Module
 from bigdl_tpu.ops import grouped_matmul
+from bigdl_tpu.ops.scopes import under_scope
 from bigdl_tpu.ops.remat import (MOE_ROUTE_TABLES, MOE_ROUTED_OUT,
                                  MOE_SHARED_HID, keep)
 from bigdl_tpu.parallel.mesh import EXPERT_AXIS
@@ -625,11 +626,11 @@ def _grouped_fwd(hidden, block, n_max, weights, x, gate, tok, counts):
     return out, (weights, x, gate, tok, counts)
 
 
+@under_scope("moe_experts")
 def _grouped_bwd(hidden, block, n_max, res, dout):
     weights, x, gate, tok, counts = res
-    with jax.named_scope("moe_experts"):    # the forward's scope, by hand
-        return _grouped_bwd_loop(hidden, block, n_max, weights, x, gate,
-                                 tok, counts, dout)
+    return _grouped_bwd_loop(hidden, block, n_max, weights, x, gate, tok,
+                             counts, dout)
 
 
 def _grouped_bwd_loop(hidden, block, n_max, weights, x, gate, tok, counts,
@@ -754,7 +755,7 @@ def _grouped_kernel_fwd(activate, block, weights, x, gate, tok, counts):
             (weights, x, gate, tok, counts))
 
 
-@jax.named_scope("moe_experts")             # the forward's scope, by hand
+@under_scope("moe_experts")
 def _kernel_backward(activate, block, res, dout):
     """One fused call recomputes the first products and the activation,
     takes ``dy @ w2^T`` (which serves d(gate) = <dy @ w2^T, hid> and

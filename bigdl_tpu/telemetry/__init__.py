@@ -36,8 +36,8 @@ from bigdl_tpu.telemetry.exposition import (PROMETHEUS_CONTENT_TYPE,
                                             render_json, render_prometheus)
 from bigdl_tpu.telemetry import profiling, scoreboard, tracing
 from bigdl_tpu.telemetry.tracing import span
-from bigdl_tpu.telemetry.catalogue import (METRIC_SPECS, SPAN_SPECS,
-                                           instruments)
+from bigdl_tpu.telemetry.catalogue import (METRIC_SPECS, SCOPE_SPECS,
+                                           SPAN_SPECS, instruments)
 from bigdl_tpu.telemetry.profiling import (CompileEvent, TrackedJit,
                                            peak_flops,
                                            sample_device_memory,
@@ -48,7 +48,8 @@ __all__ = [
     "CounterFamily", "GaugeFamily", "HistogramFamily",
     "DEFAULT_LATENCY_BUCKETS", "get_registry", "set_registry",
     "render_prometheus", "render_json", "PROMETHEUS_CONTENT_TYPE",
-    "tracing", "span", "METRIC_SPECS", "SPAN_SPECS", "instruments",
+    "tracing", "span", "METRIC_SPECS", "SPAN_SPECS", "SCOPE_SPECS",
+    "instruments",
     "profiling", "scoreboard", "tracked_jit", "TrackedJit",
     "CompileEvent", "peak_flops", "sample_device_memory",
 ]

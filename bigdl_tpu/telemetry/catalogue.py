@@ -13,12 +13,13 @@ two buckets matching the bucketed batcher's padding.
 
 from __future__ import annotations
 
-from typing import Dict, List, Tuple
+from typing import Dict, List, NamedTuple, Tuple
 
 from bigdl_tpu.telemetry.registry import (DEFAULT_LATENCY_BUCKETS,
                                           MetricSpec, MetricsRegistry)
 
-__all__ = ["METRIC_SPECS", "SPAN_SPECS", "instruments"]
+__all__ = ["METRIC_SPECS", "SPAN_SPECS", "SCOPE_SPECS", "ScopeSpec",
+           "instruments"]
 
 BATCH_SIZE_BUCKETS: Tuple[float, ...] = (1, 2, 4, 8, 16, 32, 64, 128)
 
@@ -370,6 +371,123 @@ SPAN_SPECS: List[Tuple[str, str]] = [
     ("profiling.compile", "One tracked_jit compilation of a new "
      "(site, signature) — trace+lower+compile wall time "
      "(telemetry/profiling.py)."),
+]
+
+
+class ScopeSpec(NamedTuple):
+    """One layer of the step's partition (``telemetry/step_partition.py``):
+    a ``jax.named_scope`` the program enters where the work is written, and
+    the names that stand for it where no scope is entered."""
+    name: str                       # the layer: a row of the table
+    entered: str                    # where the scope is entered
+    holds: str                      # the work under it
+    classes: Tuple[str, ...] = ()   # modules whose own scope (their class
+    #                                 name, ``Module.forward``) stands for it
+    kernels: Tuple[str, ...] = ()   # Mosaic kernel-name prefixes, the same
+    group: bool = False             # holds other layers: a class or kernel
+    #                                 under it still names its own layer
+    update: bool = False            # the step's stages after the gradient:
+    #                                 the pass ``update``
+
+
+#: The closed vocabulary of step layers. An instruction belongs to the
+#: INNERMOST of these in its ``op_name`` (an entered scope keeps the module
+#: classes under it, unless it is a ``group``), so a layer's time is its
+#: scope less the scopes inside it. ``tests/test_telemetry.py`` holds every
+#: ``named_scope`` / ``under_scope`` literal under ``bigdl_tpu/`` to this
+#: list and the list to the code.
+SCOPE_SPECS: List[ScopeSpec] = [
+    ScopeSpec("attn_proj", "nn/attention.py MultiHeadAttention",
+              "Everything of the mixer but the core: the q/k/v and out "
+              "products and biases, q/k norm, rotation, the output gate, "
+              "the repeat of grouped k/v heads."),
+    ScopeSpec("attn_core", "nn/attention.py MultiHeadAttention, "
+              "LatentAttention; ops/flash_attention.py (the backward rule)",
+              "The attention core as the module calls it: the flash "
+              "kernels with the (B,S,N,D) <-> (B*N,S,D) layout changes "
+              "inside their forward and backward, or the XLA cores below "
+              "use_flash; in decode mode the cache write too.",
+              kernels=("flash_",)),
+    ScopeSpec("mla_proj", "nn/attention.py LatentAttention",
+              "Latent attention but its core: the two down-projections, "
+              "the latents' norms, the two up-projections, rotation, the "
+              "assembly of q and k, the out-projection."),
+    ScopeSpec("mamba_proj", "nn/mamba.py Mamba2",
+              "The in- and out-projection products."),
+    ScopeSpec("mamba_local", "nn/mamba.py Mamba2",
+              "What is neither a projection nor the scan: the splits of "
+              "the in-projection's output, the causal convolution, "
+              "softplus / dt, the D skip, the gate, the group norm."),
+    ScopeSpec("ssd_scan", "ops/ssd_scan.py ssd_scan (and its backward "
+              "rule)", "The Mamba-2 state-space scan, kernel or chunked "
+              "form, with the carry between chunks.", kernels=("ssd_",)),
+    ScopeSpec("mlp", "nn/hybrid.py GatedMLP; nn/attention.py "
+              "TransformerEncoderLayer._ffn",
+              "A dense feed-forward: its two or three products and the "
+              "activation."),
+    ScopeSpec("moe_route", "parallel/expert.py MoE (held dispatch)",
+              "Router product, top-k, the sort and count of the local "
+              "picks, their gathers."),
+    ScopeSpec("moe_experts", "parallel/expert.py MoE (held dispatch, and "
+              "the backward rules of both forms); ops/grouped_matmul.py",
+              "The grouped product over the held experts' rows, XLA loop "
+              "or Mosaic kernels; by the class name, what `MoE` does "
+              "outside its three scopes (the token reshape in and out; "
+              "the whole of a capacity dispatch, which enters none).",
+              classes=("MoE",), kernels=("moe_gmm_",)),
+    ScopeSpec("moe_shared", "parallel/expert.py MoE (held dispatch)",
+              "The shared expert."),
+    ScopeSpec("mtp", "nn/hybrid.py MTPModule; nn/criterion.py "
+              "FusedLMHeadCriterion (the second loss)",
+              "The multi-token-prediction module; its own row is what no "
+              "layer inside it names (the 2E -> E projection, the "
+              "shifts).", group=True),
+    ScopeSpec("norm", "class scope", "A norm between blocks (a norm inside "
+              "attn_proj, mla_proj or mamba_local belongs to that layer).",
+              classes=("RMSNorm", "LayerNorm")),
+    ScopeSpec("embed", "class scope", "The embedding lookup and its "
+              "scatter-add backward.", classes=("LookupTable",)),
+    ScopeSpec("linear", "class scope", "A Linear outside any layer above "
+              "(a convnet's classifier, a head module).",
+              classes=("Linear", "LMHead", "TiedLMHead"),
+              kernels=("int8_matmul",)),
+    ScopeSpec("conv", "class scope", "Convolutions.",
+              classes=("SpatialConvolution", "SpatialShareConvolution",
+                       "SpaceToDepthConv7", "SpatialDilatedConvolution",
+                       "SpatialFullConvolution", "VolumetricConvolution")),
+    ScopeSpec("batchnorm", "class scope (ops/batch_norm.py runs under "
+              "it)", "Batch normalisation: statistics, normalise, its "
+              "custom backward.",
+              classes=("BatchNormalization", "SpatialBatchNormalization",
+                       "VolumetricBatchNormalization")),
+    ScopeSpec("pool", "class scope", "Pooling.",
+              classes=("SpatialMaxPooling", "SpatialAveragePooling",
+                       "VolumetricMaxPooling")),
+    ScopeSpec("activation", "class scope", "Activation modules and the "
+              "residual add of a convnet block.",
+              classes=("ReLU", "ReLU6", "PReLU", "LeakyReLU", "ELU",
+                       "Sigmoid", "Tanh", "SoftMax", "LogSoftMax",
+                       "SoftPlus", "HardTanh", "CAddTable", "Dropout")),
+    ScopeSpec("param_cast", "optim/optimizer.py make_training_loss_fn",
+              "The compute-dtype view of the master parameters, and the "
+              "gradient's way back through it.", update=True),
+    ScopeSpec("criterion", "optim/optimizer.py make_training_loss_fn",
+              "criterion.apply and the regulariser; its own row is what "
+              "lm_head_ce does not hold.", group=True),
+    ScopeSpec("lm_head_ce", "ops/lm_head_ce.py (the primal and both "
+              "rules)", "The fused LM-head cross-entropy: its loops over "
+              "row tiles."),
+    ScopeSpec("grad_sync", "parallel/distri_optimizer.py",
+              "The collective a step builder writes itself: ZeRO-1's "
+              "reduce-scatter and means, the bf16 payload cast, FSDP's "
+              "sharding constraint (GSPMD's own all-reduce carries the "
+              "metadata of the gradient it reduces).", update=True),
+    ScopeSpec("grad_clip", "optim/optimizer.py clipped_update",
+              "The clamp and the global-L2 rescale.", update=True),
+    ScopeSpec("optim_update", "optim/optimizer.py clipped_update; "
+              "parallel/distri_optimizer.py (ZeRO-1's slice and gather)",
+              "optim.update: the optimiser's arithmetic on parameters and "
+              "state.", update=True),
 ]
 
 

@@ -47,6 +47,7 @@ jax-free at import (the telemetry package contract): jax loads on first
 
 from __future__ import annotations
 
+import functools
 import time
 from collections import OrderedDict
 from typing import Any, Callable, Dict, Optional, Tuple
@@ -138,8 +139,26 @@ def _cost_number(analysis, key: str) -> Optional[float]:
     return total if seen else None
 
 
+def _named_after(fn: Callable, site: str) -> Callable:
+    """``fn`` under the name of its site (``train.step`` -> ``train_step``),
+    which jax gives the compiled program: ``HloModule jit_train_step``, the
+    profile's ``XLA Modules`` events, the ``jit(train_step)/`` every
+    ``op_name`` starts with. The name is also part of the persistent
+    compile cache's key, and ``op_name`` metadata is NOT (jax 0.9.0 strips
+    it before hashing): programs that differ only in their scopes share a
+    key, so a cache an older checkout filled would serve a step whose text
+    carries that checkout's scopes (``telemetry/step_partition.py`` reads
+    them). A site's name changes the key with the site."""
+    @functools.wraps(fn)
+    def program(*args, **kwargs):
+        return fn(*args, **kwargs)
+    program.__name__ = program.__qualname__ = site.replace(".", "_")
+    return program
+
+
 class TrackedJit:
     """``jax.jit`` with a compile flight recorder (see module docstring).
+    The compiled program carries its SITE's name (``_named_after``).
 
     NOT a drop-in for every jit feature: static_argnums/argnames are
     passed through to the underlying jit, but the signature key treats
@@ -157,7 +176,7 @@ class TrackedJit:
         from bigdl_tpu.telemetry.catalogue import instruments
         self.site = site
         self.cache_size = max(1, int(cache_size))
-        self._jitted = jax.jit(fn, **jit_kwargs)
+        self._jitted = jax.jit(_named_after(fn, site), **jit_kwargs)
         # explicit in_shardings fix the program's input layout, so the
         # arguments' own placement must not split programs: a mesh step's
         # first call sees fresh single-device state and every later call

@@ -109,7 +109,7 @@ reference-symbol mapping.
 
     # telemetry inventory, generated from the catalogue specs — the single
     # source of truth every instrumented subsystem builds its families from
-    from bigdl_tpu.telemetry import METRIC_SPECS, SPAN_SPECS
+    from bigdl_tpu.telemetry import METRIC_SPECS, SCOPE_SPECS, SPAN_SPECS
     out.write("## telemetry metric catalogue (bigdl_tpu.telemetry)\n\n")
     out.write("Scrape via ``GET /metrics`` on a serving process or "
               "``./scripts/bigdl-tpu.sh metrics <url>``; guide in "
@@ -129,6 +129,23 @@ reference-symbol mapping.
     out.write("| span | fires at |\n|------|----------|\n")
     for name, where in SPAN_SPECS:
         out.write(f"| `{name}` | {' '.join(where.split())} |\n")
+        total += 1
+    out.write("\n## step layer catalogue (bigdl_tpu.telemetry."
+              "step_partition)\n\n")
+    out.write("The closed vocabulary of ``jax.named_scope``s by which a "
+              "compiled step's device time is split into layer x pass "
+              "(``Optimizer.set_profiling`` -> ``step_partition.json``; "
+              "guide in ``docs/OBSERVABILITY.md``). An instruction belongs "
+              "to the innermost of these in its ``op_name``.\n\n")
+    out.write("| layer | entered at | holds | also named by |\n"
+              "|-------|------------|-------|---------------|\n")
+    for spec in SCOPE_SPECS:
+        also = ", ".join([f"`{c}`" for c in spec.classes]
+                         + [f"`{k}*` kernels" for k in spec.kernels]) or "—"
+        kind = " (group)" if spec.group else \
+            " (pass `update`)" if spec.update else ""
+        out.write(f"| `{spec.name}` | {' '.join(spec.entered.split())}"
+                  f"{kind} | {' '.join(spec.holds.split())} | {also} |\n")
         total += 1
     out.write("\n")
     path = os.path.join(os.path.dirname(os.path.dirname(

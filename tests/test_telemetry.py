@@ -461,6 +461,78 @@ class TestCatalogueDriftGate:
             f"{sorted(unused)}")
 
 
+class TestScopeVocabularyDriftGate:
+    """``catalogue.SCOPE_SPECS`` is the closed vocabulary of the step's
+    partition (``telemetry/step_partition.py``): every scope the package
+    enters by a literal is in it, and every entry of it is entered, or
+    stands for module classes and kernel names that exist."""
+
+    @staticmethod
+    def _package_sources():
+        root = os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "bigdl_tpu")
+        for dirpath, _dirs, files in os.walk(root):
+            for f in sorted(files):
+                if f.endswith(".py"):
+                    path = os.path.join(dirpath, f)
+                    with open(path) as fh:
+                        yield path, ast.parse(fh.read())
+
+    def _entered(self):
+        entered = set()
+        for _path, tree in self._package_sources():
+            for node in ast.walk(tree):
+                if not isinstance(node, ast.Call) or not node.args:
+                    continue
+                f = node.func
+                name = f.attr if isinstance(f, ast.Attribute) else \
+                    getattr(f, "id", None)
+                arg = node.args[0]
+                if name in ("named_scope", "under_scope") and isinstance(
+                        arg, ast.Constant) and isinstance(arg.value, str):
+                    entered.add(arg.value)
+        return entered
+
+    def test_every_entered_scope_is_in_the_vocabulary(self):
+        from bigdl_tpu.telemetry.catalogue import SCOPE_SPECS
+        names = [s.name for s in SCOPE_SPECS]
+        assert len(names) == len(set(names))
+        unknown = self._entered() - set(names)
+        assert not unknown, (
+            f"scopes entered under bigdl_tpu/ but missing from "
+            f"telemetry/catalogue.py SCOPE_SPECS: {sorted(unknown)}")
+
+    def test_every_spec_is_entered_or_stands_for_something(self):
+        from bigdl_tpu import nn
+        from bigdl_tpu.parallel import expert
+        from bigdl_tpu.telemetry.catalogue import SCOPE_SPECS
+        entered = self._entered()
+        literals = set()        # every string in the kernels' sources
+        for path, tree in self._package_sources():
+            if os.sep + "ops" + os.sep in path or path.endswith(
+                    os.path.join("parallel", "expert.py")):
+                literals |= {n.value for n in ast.walk(tree)
+                             if isinstance(n, ast.Constant)
+                             and isinstance(n.value, str)}
+        for spec in SCOPE_SPECS:
+            if not spec.classes:
+                assert spec.name in entered, (
+                    f"{spec.name}: in SCOPE_SPECS, entered nowhere")
+            for cls in spec.classes:
+                assert hasattr(nn, cls) or hasattr(expert, cls), \
+                    f"{spec.name}: no module class {cls}"
+            for prefix in spec.kernels:
+                assert any(v.startswith(prefix) for v in literals), (
+                    f"{spec.name}: no kernel is named {prefix}*")
+            assert spec.entered and spec.holds
+
+    def test_api_doc_holds_the_vocabulary(self):
+        from bigdl_tpu.telemetry.catalogue import SCOPE_SPECS
+        doc = open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "docs", "API.md")).read()
+        assert all(f"| `{s.name}` |" in doc for s in SCOPE_SPECS)
+
+
 # ------------------------------------------------------- overhead budget
 class TestDisabledOverhead:
     def _per_op(self, fn, n=20000):
