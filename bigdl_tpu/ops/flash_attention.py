@@ -2,7 +2,7 @@
 
 New capability (no reference analogue — the reference's hottest hand-written
 loops are im2col/col2im, ``nn/NNPrimitive.scala``; this is the TPU build's
-equivalent "hand kernel" for its hottest new op). Three kernels:
+equivalent "hand kernel" for its hottest new op). Two kernels:
 
 - forward: online-softmax attention tiled for VMEM. grid = (batch*heads,
   query blocks); each program holds one query tile resident and streams
@@ -11,12 +11,19 @@ equivalent "hand kernel" for its hottest new op). Three kernels:
   MXU; causal masking skips fully-masked key tiles. Emits the row
   logsumexp (LSE) alongside the output — the residual the backward needs,
   and the statistic ring attention folds across devices.
-- backward dQ: grid over query tiles; recomputes p = exp(logits - lse)
-  per key tile (no O(S^2) materialisation) and accumulates
-  dq += (p * (dO v^T - delta)) k * scale.
-- backward dK/dV: grid over key tiles; streams query tiles, accumulating
-  dv += p^T dO and dk += (p * (dO v^T - delta))^T q * scale on TRANSPOSED
-  (key, query) tiles. Causal runs start at the diagonal query tile.
+- backward, ONE call that returns dQ, dK and dV (PR 37; it keeps the name
+  of the dK/dV kernel it grew from, ``flash_bwd_dkv``): grid = (batch*heads,
+  key tiles), a row's key tiles in turn. A program holds its k and v tile
+  and streams the query tiles it can see, on TRANSPOSED (key, query) tiles:
+  it recomputes p = exp(logits - lse) (no O(S^2) materialisation) and
+  ds = p * (dO v^T - delta) ONCE a tile and feeds all three gradients from
+  them: dv += p^T dO and dk += ds^T q * scale into the program's own
+  accumulators, and dq[query tile] += ds k * scale into a float32 buffer of
+  the whole (batch, head) row that stays in VMEM over the row's key tiles
+  (zeroed at the first, scaled, cast and written to HBM at the last). Causal
+  runs start at the diagonal query tile. While dQ had a kernel of its own
+  (grid over query tiles) every tile's logits, exp, mask and dO v^T were
+  computed twice, 27-29% of the backward's MXU passes.
 
 What the MXU is fed: every matmul takes its operands in the dtype the
 caller's arrays have and accumulates in float32. The float32 intermediates
@@ -27,9 +34,13 @@ float32 callers get float32 operands. (On the v5e Mosaic rounds a float32
 operand to bf16 inside the MXU, one pass either way: bf16 and up-cast tiles
 measure the same and give the same bits; the operand dtype saves the casts,
 not MXU passes. PERF.md section 6, PR 24.) No operand is transposed in
-VMEM: ``q k^T`` and ``dO v^T`` contract the head dim of both operands
-(``dot_general``, NT), and dK/dV works on (key, query) tiles so that its
-other two products are plain.
+VMEM by this code: ``q k^T`` and ``dO v^T`` contract the head dim of both
+operands (``dot_general``, NT), and the backward works on (key, query)
+tiles so that dK's and dV's products are plain. dQ's is the one product
+whose left operand is contracted over its sublanes (``ds`` as it lies is
+(key, query)): Mosaic transposes the tile on the XLU, 64 transposes for a
+(512, 512) tile, which the v5e's schedule hides under the tile's 128 MXU
+passes (PERF.md section 6, PR 37).
 
 Where the time went, and what the loop bodies do about it: a kernel's tile
 loop runs within 5-20% of what its matmuls need with head 64 (half of the
@@ -43,17 +54,17 @@ through ``_to_lanes``, not Mosaic's sublane-to-lane relayout. Measured
 times and roofline shares: PERF.md section 5 (ledger, PR 24).
 
 A sliding window (``window``, with ``causal``: query i sees the keys
-``(i - window, i]``) is a static argument of the same three kernels. A call
+``(i - window, i]``) is a static argument of the same two kernels. A call
 that names none lowers to the code it lowered to before the band existed;
 a call that names one masks every tile it meets (the causal edge and the
 band's lower edge in one mask), runs no halved diagonal, and bounds its one
-loop on BOTH sides: the forward and dQ start at the key tile that holds the
-oldest key the query tile's first row can see, dK/dV ends at the query tile
-that holds the last query its last key reaches. At 8,192 tokens and a
-window of 2,048 that is 5 of 16 key tiles a query tile, for 14.7M
+loop on BOTH sides: the forward starts at the key tile that holds the
+oldest key the query tile's first row can see, the backward ends at the
+query tile that holds the last query its last key reaches. At 8,192 tokens
+and a window of 2,048 that is 5 of 16 key tiles a query tile, for 14.7M
 query-key pairs where the full call holds 33.6M. Such calls are named
-``flash_band_fwd``, ``flash_band_bwd_dq``, ``flash_band_bwd_dkv``, so a
-trace tells them from full calls of the same operand shape, and
+``flash_band_fwd`` and ``flash_band_bwd_dkv``, so a trace tells them from
+full calls of the same operand shape, and
 ``bigdl_flash_attention_total{form=band|full|mla}`` counts each form once a
 trace. A window that reaches past the first key is no band: the call is
 the full one, code and name.
@@ -64,13 +75,13 @@ part, over a 128-wide ``v``): the kernels read each operand's own width,
 the forward's accumulator, ``o``, ``dO`` and ``dV`` are as wide as ``v``
 and ``dQ`` and ``dK`` as wide as ``q``. A call with equal sizes is the
 code and the names it was; a call whose sizes differ is named
-``flash_mla_fwd``, ``flash_mla_bwd_dq``, ``flash_mla_bwd_dkv`` and counted
-``form=mla``. ``k`` is ONE operand, the shared rotary key broadcast to the
-heads by the caller: the two-product form (``qc kc^T + qr kr^T`` with the
+``flash_mla_fwd`` and ``flash_mla_bwd_dkv`` and counted ``form=mla``.
+``k`` is ONE operand, the shared rotary key broadcast to the heads by the
+caller: the two-product form (``qc kc^T + qr kr^T`` with the
 rotary key's index map ignoring the head) feeds the MXU the same two
 128-deep passes a 192-deep contraction takes; it would save the rotary
 part's 32 copies in K (33.5 MB of 100 a layer at 8,192 tokens, written
-once and read by three kernels) for a fourth operand in every kernel and a
+once and read by both kernels) for a fourth operand in every kernel and a
 ``dkr`` summed over the heads (PERF.md section 6, PR 34).
 
 The LSE output is a first-class differentiable output: its cotangent folds
@@ -92,12 +103,14 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from bigdl_tpu.ops.remat import FLASH_OUT, keep
 from bigdl_tpu.ops.scopes import under_scope
 
 _NEG = float(jnp.finfo(jnp.float32).min)
 _NT = (((1,), (1,)), ((), ()))        # a (M, K) x b (N, K) -> (M, N): b as it lies
+_TN = (((0,), (0,)), ((), ()))        # a (K, M) x b (K, N) -> (M, N): a^T b
 
 
 def _dot(a, b):
@@ -106,6 +119,10 @@ def _dot(a, b):
 
 def _dot_nt(a, b):
     return lax.dot_general(a, b, _NT, preferred_element_type=jnp.float32)
+
+
+def _dot_tn(a, b):
+    return lax.dot_general(a, b, _TN, preferred_element_type=jnp.float32)
 
 
 def _exact_scale(scale) -> bool:
@@ -197,19 +214,45 @@ def _name(kernel: str, window: Optional[int], latent: bool = False) -> str:
     return f"flash_band_{kernel}" if window is not None else f"flash_{kernel}"
 
 
-def _call_params(kernel: str, window: Optional[int], latent: bool) -> dict:
-    """``pallas_call``'s name and, for a latent call only, its VMEM limit.
-    A 192-wide operand lies in VMEM as 256 lanes, so at 8,192 tokens the
-    dK/dV kernel's whole q and dO (and the forward's whole K and V) are
-    12 MB double-buffered, which with the tiles passes the 16 MiB a call
-    gets by default (the v5e's compiler refuses it, PR 34); the chip has
-    128. A call with equal sizes names no limit, as before."""
+def _call_params(kernel: str, window: Optional[int], latent: bool,
+                 vmem: Optional[int] = None, carried: bool = False) -> dict:
+    """``pallas_call``'s name and what the call tells the compiler: its
+    VMEM limit (``vmem`` None: the 16 MiB a call gets by default) and, where
+    the grid's second axis carries an accumulator (``carried``), that a
+    row's programs run in turn."""
     params = {"name": _name(kernel, window, latent)}
-    if latent:
-        from jax.experimental.pallas import tpu as pltpu
+    if vmem is not None or carried:
         params["compiler_params"] = pltpu.CompilerParams(
-            vmem_limit_bytes=_LATENT_VMEM)
+            vmem_limit_bytes=vmem, dimension_semantics=(
+                ("parallel", "arbitrary") if carried else None))
     return params
+
+
+def _lanes(width: int) -> int:
+    """The lanes an operand's minor axis takes in VMEM: whole 128s (a head
+    of 192 lies as 256)."""
+    return -(-width // 128) * 128
+
+
+def _bwd_vmem(sq_p: int, d: int, dv: int, block_q: int, block_k: int,
+              itemsize: int) -> int:
+    """The backward call's VMEM limit, from the call's own shapes: q and dO
+    whole, the LSE and delta rows (a (1, S) float32 row lies on 8
+    sublanes) and the k, v, dk, dv tiles, each twice (Mosaic double-buffers
+    a call's operands); dQ's float32 accumulator and its block twice; the
+    float32 dK and dV accumulators and four (BK, BQ) float32 intermediates
+    of a tile (logits, p, dO v^T, ds); a quarter on top for what the
+    compiler spills. At 8,192 tokens and 512-tiles a latent call (192 over
+    128) reckons 35 MB (q and dO 12.6, dQ's accumulator 8.4 and block 8.4),
+    a head-128 call 20 MB, the LM cell's 28 x 2,048 x 64 under 4 MB; a
+    1024-tile adds 12.6 MB of intermediates. Never under the 16 MiB a call
+    gets that names none."""
+    ld, ldv = _lanes(d), _lanes(dv)
+    need = (2 * sq_p * (ld + ldv) * itemsize + 2 * 2 * 8 * sq_p * 4
+            + 4 * block_k * (ld + ldv) * itemsize
+            + sq_p * ld * (4 + 2 * itemsize)
+            + 2 * block_k * (ld + ldv) * 4 + 4 * block_k * block_q * 4)
+    return max(16 << 20, need * 5 // 4)
 
 
 # ------------------------------------------------------------------ forward
@@ -338,7 +381,12 @@ def _flash_fwd_lse(q, k, v, causal, scale, block_q, block_k, interpret,
         out_specs=(pl.BlockSpec((1, block_q, dv), lambda i, j: (i, j, 0)),
                    pl.BlockSpec((1, 1, block_q), lambda i, j: (i, 0, j))),
         interpret=interpret,
-        **_call_params("fwd", window, dv != d),
+        # a 192-wide operand lies in VMEM as 256 lanes, so at 8,192 tokens a
+        # latent call's whole K and V are 12 MB double-buffered, which with
+        # the tiles passes the default limit (the v5e's compiler refuses
+        # it, PR 34); a call with equal sizes names no limit, as before
+        **_call_params("fwd", window, dv != d,
+                       _LATENT_VMEM if dv != d else None),
     )(qt, kt, vt)
     out = out[:, :sq].reshape(b, n, sq, dv).transpose(0, 2, 1, 3)
     lse = lse[:, 0, :sq].reshape(b, n, sq)
@@ -347,79 +395,17 @@ def _flash_fwd_lse(q, k, v, causal, scale, block_q, block_k, interpret,
 
 # ----------------------------------------------------------------- backward
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, l_ref, d_ref, dq_ref, *,
-                   block_k: int, sk: int, causal: bool, scale: float,
-                   block_q: int, diagonal: bool,
-                   window: Optional[int] = None):
-    # Per query tile: stream key tiles, recompute p from the saved LSE.
-    j = pl.program_id(1)
-    q = q_ref[0]                                            # (BQ, D)
-    do = do_ref[0]                                          # (BQ, Dv)
-    # Kept as the lane-dense (BQ,) rows they are stored as and turned into
-    # columns where a tile uses them: hoisted (BQ, 1) columns are 128 vregs
-    # that live across the whole loop, and cost 15% of the kernel (PR 24).
-    lse = l_ref[0, 0]                                       # (BQ,) f32
-    delta = d_ref[0, 0]                                     # (BQ,) f32
-    bq, d = q.shape
-    nkb = k_ref.shape[1] // block_k
-    prescaled = _exact_scale(scale)
-    if prescaled:
-        q = q * jnp.asarray(scale, q.dtype)
-
-    def part(rows, k0, width, valid):
-        # sum(ds k) of the query rows `rows` over the keys [k0, k0 + width)
-        kblk = k_ref[0, pl.ds(k0, width), :]
-        vblk = v_ref[0, pl.ds(k0, width), :]
-        logits = _dot_nt(q[rows], kblk)                     # f32
-        if not prescaled:
-            logits = logits * scale
-        if valid is None:
-            p = jnp.exp(logits - lse[rows][:, None])
-        else:
-            # guard the exponent BEFORE exp (dead rows carry the _NEG
-            # sentinel; the raw exponent would overflow), then mask
-            expo = jnp.where(valid, logits - lse[rows][:, None], 0.0)
-            p = jnp.where(valid, jnp.exp(expo), 0.0)
-        ds = p * (_dot_nt(do[rows], vblk) - delta[rows][:, None])
-        return _dot(ds.astype(kblk.dtype), kblk)
-
-    def tile(kb, dq, masked):
-        valid = _visible((bq, block_k), 1, kb * block_k, j * block_q, sk,
-                         causal, window) if masked else None
-        return dq + part(slice(None), kb * block_k, block_k, valid)
-
-    dq = jnp.zeros((bq, d), jnp.float32)
-    if diagonal:
-        dq = lax.fori_loop(0, j, functools.partial(tile, masked=False), dq)
-        h = bq // 2
-        dq = dq + jnp.concatenate([
-            part(slice(0, h), j * block_k, h, _below(h, h, 0)),
-            part(slice(h, bq), j * block_k, block_k, _below(h, block_k, h))])
-    elif window is not None:
-        first, last = _band_tiles(j * block_q, bq, block_k, nkb, window,
-                                  behind=True)
-        dq = lax.fori_loop(first, last,
-                           functools.partial(tile, masked=True), dq)
-    else:
-        if causal:
-            nkb = lax.min(nkb, lax.div(j * block_q + bq - 1, block_k) + 1)
-        dq = lax.fori_loop(0, nkb, functools.partial(
-            tile, masked=_masked(causal, sk, block_k)), dq)
-    # dq = scale * sum(ds k): once on the float32 accumulator, not a tile
-    dq_ref[0] = (dq * scale).astype(dq_ref.dtype)
-
-
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, l_ref, d_ref,
-                    dk_ref, dv_ref, *, block_q: int, sk: int,
-                    causal: bool, scale: float, block_k: int,
-                    diagonal: bool, window: Optional[int] = None):
+def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, l_ref, d_ref,
+                dq_ref, dk_ref, dv_ref, dq_acc, *, block_q: int, sk: int,
+                causal: bool, scale: float, block_k: int,
+                diagonal: bool, window: Optional[int] = None):
     # Per key tile: stream query tiles, everything TRANSPOSED: the logits
-    # tile is (BK, BQ), so the four matmuls take their operands as they lie
-    # (k q^T and v dO^T contract the shared head dim, p^T dO and ds^T q are
-    # plain) and the LSE and delta rows broadcast from the lanes they are
-    # stored in. Padded query rows need no mask: _flash_bwd pads q, dO, the
-    # LSE and delta with zeros, so there p = exp(0 - 0) = 1 meets dO = 0 in
-    # dV and ds = 1 * (0 - 0) in dK.
+    # tile is (BK, BQ), so four of the five matmuls take their operands as
+    # they lie (k q^T and v dO^T contract the shared head dim, p^T dO and
+    # ds^T q are plain) and the LSE and delta rows broadcast from the lanes
+    # they are stored in. Padded query rows need no mask: _flash_bwd pads q,
+    # dO, the LSE and delta with zeros, so there p = exp(0 - 0) = 1 meets
+    # dO = 0 in dV and ds = 1 * (0 - 0) in dK and dQ.
     jkb = pl.program_id(1)
     k = k_ref[0]                                            # (BK, D)
     v = v_ref[0]                                            # (BK, Dv)
@@ -428,9 +414,13 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, l_ref, d_ref,
     prescaled = _exact_scale(scale)
     ks = k * jnp.asarray(scale, k.dtype) if prescaled else k
 
+    @pl.when(jkb == 0)
+    def _():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+
     def part(keys, q0, width, valid):
         # (sum(ds^T q), sum(p^T dO)) of the key rows `keys` over the
-        # queries [q0, q0 + width)
+        # queries [q0, q0 + width), whose dQ rows take ds k on the way
         qblk = q_ref[0, pl.ds(q0, width), :]
         doblk = do_ref[0, pl.ds(q0, width), :]
         lrow = l_ref[0, :, pl.ds(q0, width)]                # (1, width) f32
@@ -445,9 +435,9 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, l_ref, d_ref,
             # would overflow to inf and inf*0 -> NaN survives jnp.where
             expo = jnp.where(valid, logits - lrow, 0.0)
             p = jnp.where(valid, jnp.exp(expo), 0.0)
-        ds = p * (_dot_nt(v[keys], doblk) - drow)
-        return (_dot(ds.astype(qblk.dtype), qblk),
-                _dot(p.astype(doblk.dtype), doblk))
+        ds = (p * (_dot_nt(v[keys], doblk) - drow)).astype(qblk.dtype)
+        dq_acc[pl.ds(q0, width), :] += _dot_tn(ds, k[keys])
+        return _dot(ds, qblk), _dot(p.astype(doblk.dtype), doblk)
 
     def tile(qb, carry, masked):
         valid = _visible((bk, block_q), 0, jkb * block_k, qb * block_q, sk,
@@ -483,13 +473,19 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, l_ref, d_ref,
     dk_ref[0] = (dk * scale).astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
+    @pl.when(jkb == pl.num_programs(1) - 1)
+    def _():
+        # dq = scale * sum(ds k): once on the float32 accumulator
+        dq_ref[0] = (dq_acc[...] * scale).astype(dq_ref.dtype)
+
 
 def _flash_bwd(q, k, v, o, lse, g_o, g_l, causal, scale, block_q, block_k,
                interpret, window=None):
     b, sq, n, d = q.shape
     sk, d_v = k.shape[1], v.shape[-1]
-    block_q = min(block_q or _BLOCK, sq)
-    block_k = min(block_k or _BLOCK, sk)
+    default = _bwd_block(sq, sk, d, d_v, q.dtype.itemsize, causal, window)
+    block_q = min(block_q or default, sq)
+    block_k = min(block_k or default, sk)
     qt = q.transpose(0, 2, 1, 3).reshape(b * n, sq, d)
     kt = k.transpose(0, 2, 1, 3).reshape(b * n, sk, d)
     vt = v.transpose(0, 2, 1, 3).reshape(b * n, sk, d_v)
@@ -511,7 +507,7 @@ def _flash_bwd(q, k, v, o, lse, g_o, g_l, causal, scale, block_q, block_k,
         qt = jnp.pad(qt, ((0, 0), (0, pad_q), (0, 0)))
         dot = jnp.pad(dot, ((0, 0), (0, pad_q), (0, 0)))
         # zeros, so that padded query rows add nothing to dK and dV
-        # (p = exp(0 - 0) = 1 times dO = 0 and ds = 0): see _bwd_dkv_kernel
+        # (p = exp(0 - 0) = 1 times dO = 0 and ds = 0): see _bwd_kernel
         lt = jnp.pad(lt, ((0, 0), (0, 0), (0, pad_q)))
         delta = jnp.pad(delta, ((0, 0), (0, 0), (0, pad_q)))
     if pad_k:
@@ -521,30 +517,12 @@ def _flash_bwd(q, k, v, o, lse, g_o, g_l, causal, scale, block_q, block_k,
     diagonal = window is None and _halved_diagonal(causal, sq, sk, block_q,
                                                    block_k)
 
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, block_k=block_k, sk=sk,
-                          causal=causal, scale=scale, block_q=block_q,
-                          diagonal=diagonal, window=window),
-        out_shape=jax.ShapeDtypeStruct((b * n, sq_p, d), q.dtype),
-        grid=(b * n, sq_p // block_q),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, sk_p, d), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, sk_p, d_v), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, block_q, d_v), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda i, j: (i, 0, j)),
-            pl.BlockSpec((1, 1, block_q), lambda i, j: (i, 0, j)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
-        interpret=interpret,
-        **_call_params("bwd_dq", window, d_v != d),
-    )(qt, kt, vt, dot, lt, delta)
-
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, block_q=block_q, sk=sk,
+    dq, dk, dv = pl.pallas_call(
+        functools.partial(_bwd_kernel, block_q=block_q, sk=sk,
                           causal=causal, scale=scale, block_k=block_k,
                           diagonal=diagonal, window=window),
-        out_shape=(jax.ShapeDtypeStruct((b * n, sk_p, d), k.dtype),
+        out_shape=(jax.ShapeDtypeStruct((b * n, sq_p, d), q.dtype),
+                   jax.ShapeDtypeStruct((b * n, sk_p, d), k.dtype),
                    jax.ShapeDtypeStruct((b * n, sk_p, d_v), v.dtype)),
         grid=(b * n, sk_p // block_k),
         in_specs=[
@@ -555,10 +533,17 @@ def _flash_bwd(q, k, v, o, lse, g_o, g_l, causal, scale, block_q, block_k,
             pl.BlockSpec((1, 1, sq_p), lambda i, j: (i, 0, 0)),
             pl.BlockSpec((1, 1, sq_p), lambda i, j: (i, 0, 0)),
         ],
-        out_specs=(pl.BlockSpec((1, block_k, d), lambda i, j: (i, j, 0)),
+        # dQ's block is the whole row for every key tile of it: it stays in
+        # VMEM while they run and is written back once, when the row ends
+        out_specs=(pl.BlockSpec((1, sq_p, d), lambda i, j: (i, 0, 0)),
+                   pl.BlockSpec((1, block_k, d), lambda i, j: (i, j, 0)),
                    pl.BlockSpec((1, block_k, d_v), lambda i, j: (i, j, 0))),
+        scratch_shapes=[pltpu.VMEM((sq_p, d), jnp.float32)],
         interpret=interpret,
-        **_call_params("bwd_dkv", window, d_v != d),
+        **_call_params("bwd_dkv", window, d_v != d,
+                       min(_VMEM_MOST, _bwd_vmem(
+                           sq_p, d, d_v, block_q, block_k,
+                           q.dtype.itemsize)), carried=True),
     )(qt, kt, vt, dot, lt, delta)
 
     dq = dq[:, :sq].reshape(b, n, sq, d).transpose(0, 2, 1, 3)
@@ -603,17 +588,17 @@ _flash_lse.defvjp(_flash_lse_vjp_fwd, _flash_lse_vjp_bwd)
 
 # The square tile, when the caller names none. Measured op-level on the v5e,
 # bf16, at head 64 and 128, sequences 1024 to 8192, causal and not (PERF.md
-# section 6, PR 24). The backward kernels are fastest at 512 (at the LM
-# cell's shape dQ + dK/dV 0.36 + 0.46 ms a call against 0.43 + 0.47 at 1024).
+# section 6, PR 24). The backward's tile: _bwd_block.
 # The forward gains 8-32% at 1024 where no tile of the call is masked as a
 # whole (half as many programs share its fixed work), loses 11% where every
 # tile is (a padded causal sequence), and at 1024 its two (BQ, BK) float32
 # intermediates take 8 MiB of the 16 MiB of VMEM a call gets, beside K and V
 # which lie there whole: see _fwd_block.
-_FWD_BLOCK = 1024
+_BIG_BLOCK = 1024
 _BLOCK = 512
 _VMEM_BUDGET = 15 << 20
-_LATENT_VMEM = 32 << 20     # _call_params
+_VMEM_MOST = 100 << 20      # what the backward may name of the chip's 128 MiB
+_LATENT_VMEM = 32 << 20     # a latent forward's limit: _flash_fwd_lse
 
 
 def _fwd_block(sq: int, sk: int, d: int, dv: int, itemsize: int) -> int:
@@ -633,10 +618,36 @@ def _fwd_block(sq: int, sk: int, d: int, dv: int, itemsize: int) -> int:
     1024 on the chip, and a head of 192 lies in VMEM as 256 lanes, which
     the estimate does not know (a head that lies so, 256, failed at 1024
     from 2,048 keys)."""
-    big = _FWD_BLOCK
+    big = _BIG_BLOCK
     vmem = (2 * (sk + big) * (d + dv) * itemsize + 2 * big * big * 4
             + 3 * big * dv * 4)
     if d == dv and sq == sk and sk % big == 0 and vmem <= _VMEM_BUDGET:
+        return big
+    return _BLOCK
+
+
+def _bwd_block(sq: int, sk: int, d: int, dv: int, itemsize: int,
+               causal: bool, window: Optional[int]) -> int:
+    """The backward's default tile, from what the call can see: 1024 x 1024
+    where the causal diagonal is halved at that size (a square, unpadded
+    causal call of whole 1024-tiles, no band) and the call's VMEM
+    (``_bwd_vmem``) stays under what it may name; else 512 x 512. Where the
+    diagonal is halved the tiles below it run unmasked, and a tile of four
+    times the pairs shares its loads of k, v and the accumulators' carry
+    over four times the products. Measured on the v5e, bf16, ms a call
+    (PERF.md section 6, PR 37): 13.50 against 14.70 at 512 for a latent
+    call of 32 x 8,192 (192 over 128), 8.32 against 9.18 at head 128, 0.711
+    against 0.721 at 28 x 2,048 x 64; the schedule of the tile loop said so
+    before the chip did (3,698 bundles a (512, 512) of pairs against 4,135
+    latent, 2,363 against 2,532 at head 128). Oblong tiles lose the halved
+    diagonal and mask every tile (1024 x 512: +5% at head 128, +10% under
+    a band, -2% latent; 512 x 1024 +7 / +11 / +1%), smaller ones pay the
+    loop's fixed work more often (256 x 512 +8 to +23%, 256 x 256 +39 to
+    +62%), and a band of 2,048 meets 3-4 query tiles of 1024 a key tile
+    where it meets 5 of 512."""
+    big = _BIG_BLOCK
+    if (window is None and _halved_diagonal(causal, sq, sk, big, big)
+            and _bwd_vmem(sq, d, dv, big, big, itemsize) <= _VMEM_MOST):
         return big
     return _BLOCK
 
@@ -677,10 +688,10 @@ def flash_attention(q, k, v, causal: bool = False,
     differentiable (Pallas fwd+bwd). ``scale`` defaults to ``1/sqrt(D)``,
     the query/key head's. Where ``Dv`` is not ``D`` (latent attention: a
     192-wide q/k of a content and a rotary part over a 128-wide v) the same
-    three kernels run under the names ``flash_mla_*``, with nothing padded.
+    two kernels run under the names ``flash_mla_*``, with nothing padded.
 
     ``window`` (with ``causal``): query i sees the keys ``(i - window, i]``,
-    the Mistral convention. The three kernels mask the band's lower edge
+    the Mistral convention. The kernels mask the band's lower edge
     and skip the tiles that lie wholly below it."""
     if scale is None:
         scale = 1.0 / float(q.shape[-1]) ** 0.5

@@ -404,9 +404,11 @@ SCOPE_SPECS: List[ScopeSpec] = [
     ScopeSpec("attn_core", "nn/attention.py MultiHeadAttention, "
               "LatentAttention; ops/flash_attention.py (the backward rule)",
               "The attention core as the module calls it: the flash "
-              "kernels with the (B,S,N,D) <-> (B*N,S,D) layout changes "
-              "inside their forward and backward, or the XLA cores below "
-              "use_flash; in decode mode the cache write too.",
+              "kernels (a forward call and ONE backward call, *_bwd_dkv, "
+              "which returns dQ, dK and dV) with the (B,S,N,D) <-> "
+              "(B*N,S,D) layout changes inside their forward and backward, "
+              "or the XLA cores below use_flash; in decode mode the cache "
+              "write too.",
               kernels=("flash_",)),
     ScopeSpec("mla_proj", "nn/attention.py LatentAttention",
               "Latent attention but its core: the two down-projections, "

@@ -647,9 +647,10 @@ def test_a_traced_step_counts_band_and_full_once_a_layer(monkeypatch):
     assert rise == {"band": dec.pattern.count("W"),
                     "full": dec.pattern.count("*")}
     text = str(jaxpr)
-    for name in ("flash_band_fwd", "flash_band_bwd_dq", "flash_band_bwd_dkv",
-                 "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+    for name in ("flash_band_fwd", "flash_band_bwd_dkv",
+                 "flash_fwd", "flash_bwd_dkv"):
         assert f"name={name}" in text, name
+    assert "bwd_dq" not in text     # dQ leaves the dK/dV call
     # and no (T, T) mask or score tensor outside the kernels' own tiles
 
     def outside(jx):

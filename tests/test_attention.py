@@ -239,7 +239,8 @@ class TestFlashKernel:
             lambda *t: run(kernel))().jaxpr)
             if e.primitive.name == "pallas_call"]
         stem = "flash_mla_" if d != dv else "flash_"
-        assert names == [stem + "fwd", stem + "bwd_dq", stem + "bwd_dkv"]
+        # one backward call: the dK/dV kernel, which also returns dQ
+        assert names == [stem + "fwd", stem + "bwd_dkv"]
         if d != dv:
             with pytest.raises(ValueError, match="window"):
                 fa.flash_attention(q, k, v, causal=True, window=8,
@@ -356,6 +357,39 @@ def test_forward_tile_follows_the_call(sq, sk, d, itemsize, want):
     assert fa._fwd_block(sq, sk, d, dv, itemsize) == want
 
 
+@pytest.mark.parametrize("sq,sk,d,itemsize,causal,window,want", [
+    (2048, 2048, 64, 2, True, None, 1024),      # the LM cell
+    (1024, 1024, 64, 2, True, None, 1024),      # chip_smoke's 134M LM
+    (8192, 8192, 128, 2, True, None, 1024),     # the two hybrid cells
+    (8192, 8192, (192, 128), 2, True, None, 1024),   # latent: 64 MiB named
+    (8192, 8192, 128, 2, True, 2048, 512),      # a band meets more of 1024
+    (8192, 8192, 128, 2, False, None, 512),     # no diagonal to halve
+    (3000, 3000, 64, 2, True, None, 512),       # padded: every tile masked
+    (2048, 4096, 64, 2, True, None, 512),       # oblong
+    (1536, 1536, 64, 2, True, None, 512),       # no whole number of 1024s
+    (32768, 32768, 128, 2, True, None, 512),    # VMEM: the row's dQ, q, dO
+])
+def test_backward_tile_follows_the_call(sq, sk, d, itemsize, causal, window,
+                                        want):
+    """The backward's default tile is 1024 x 1024 only where the causal
+    diagonal is halved at that size and the call's VMEM estimate stays
+    under what it may name (PERF.md section 6, PR 37); else 512 x 512. The
+    limit the call names follows its shapes and is never under the 16 MiB
+    default."""
+    from bigdl_tpu.ops import flash_attention as fa
+    d, dv = d if isinstance(d, tuple) else (d, d)
+    assert fa._bwd_block(sq, sk, d, dv, itemsize, causal, window) == want
+    named = fa._bwd_vmem(sq, d, dv, want, want, itemsize)
+    assert named >= 16 << 20
+    if want == 1024:
+        assert named <= fa._VMEM_MOST
+    # over what lies whole in VMEM alone: q and dO twice, dQ's float32
+    # accumulator and its block twice (a head of 192 as 256 lanes)
+    whole = sq * (2 * (fa._lanes(d) + fa._lanes(dv)) * itemsize
+                  + fa._lanes(d) * (4 + 2 * itemsize))
+    assert named > min(whole, (16 << 20) - 1)
+
+
 class TestFlashKernelDtypeContract:
     """What the three kernels hand the MXU follows the caller's dtype:
     bfloat16 in, bfloat16 operands and float32 accumulators; float32 in,
@@ -428,8 +462,9 @@ class TestFlashKernelDtypeContract:
             x, x, x, x, jnp.zeros((b, n, s), jnp.float32)).jaxpr
         calls = {e.params["name"]: e.params["jaxpr"] for e in _eqns(jaxpr)
                  if e.primitive.name == "pallas_call"}
-        assert sorted(calls) == ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
-        dots = {"flash_fwd": 2, "flash_bwd_dq": 3, "flash_bwd_dkv": 4}
+        assert sorted(calls) == ["flash_bwd_dkv", "flash_fwd"]
+        # the one backward call: k q^T, v dO^T, ds^T k (dQ), ds q, p dO
+        dots = {"flash_fwd": 2, "flash_bwd_dkv": 5}
         for name, body in calls.items():
             eqns = list(_eqns(body))
             found = [e for e in eqns if e.primitive.name == "dot_general"]
@@ -729,7 +764,7 @@ class TestFlashBand:
                                        rtol=2e-4, atol=2e-4,
                                        err_msg=f"{name} mismatch")
         assert sorted(set(_pallas_calls(lambda *t: run(kernel), q))) == [
-            "flash_band_bwd_dkv", "flash_band_bwd_dq", "flash_band_fwd"]
+            "flash_band_bwd_dkv", "flash_band_fwd"]
 
     @pytest.mark.parametrize("window", [48, 49, 1000])
     def test_a_window_that_cuts_nothing_is_the_full_call(self, window):
@@ -775,21 +810,29 @@ class TestFlashBand:
         assert np.isfinite(np.asarray(dk[:, :16])).all()
         assert np.isfinite(np.asarray(dv[:, :16])).all()
 
-    @pytest.mark.parametrize("case,jaxpr,values", [
-        ((48, 16, True, "float32"), "168e6d0aabf1ef7c", "5b071ac1539792e0"),
-        ((40, 16, True, "float32"), "76d9d3ea67084614", "34a8898cf0fa3456"),
-        ((48, 16, False, "float32"), "b5cf025b4a423cf1", "8aeef2bca7ab1453"),
-        ((512, 256, True, "bfloat16"), "4877a2172aa4b935",
-         "5b7be01b84ace8e7"),
+    @pytest.mark.parametrize("case,jaxpr,kept,dq", [
+        ((48, 16, True, "float32"), "adb161f16fe780ce", "d45df316299c977b",
+         "e0ee15b507af613e"),
+        ((40, 16, True, "float32"), "914a5c73ee6ba65b", "d08b4a9443b08d56",
+         "b4413983a27f1098"),
+        ((48, 16, False, "float32"), "b17090be3bbadd8e", "62da69dffe8bd488",
+         "2c06ebd776ff16ec"),
+        ((512, 256, True, "bfloat16"), "49ecefa2bd54e948",
+         "758369b7ce09ae27", "733d1a73d24ed7a7"),
     ])
     def test_a_call_without_a_band_is_the_code_it_was(self, case, jaxpr,
-                                                      values, monkeypatch):
-        """The jaxpr of the band-less forward and backward, and the bits
-        they give, as the commit before the band gave them (digests taken
-        there, git a75b99c, jax 0.9.0): masked, padded, full and
-        halved-diagonal calls. The forward rule's two tags for block remat
-        (``ops.remat.keep`` on ``o`` and ``lse``, identities that name a
-        value) are newer than the digests and are taken out of the text."""
+                                                      kept, dq, monkeypatch):
+        """The jaxpr of the band-less forward and backward and the bits
+        they give: masked, padded, full and halved-diagonal calls. ``kept``
+        is the digest of o, the LSE, dK and dV, the bits the commit before
+        the band gave (git a75b99c, jax 0.9.0) and every commit since (read
+        again at 52d54b9, whose digest over all five was the one taken at
+        a75b99c). The text and dQ's bits were taken anew when the two
+        backward calls became one (PR 37: dQ leaves the dK/dV kernel, as
+        ds^T k on the transposed tile; the halved-diagonal case still
+        gives the dQ kernel's bits). The forward rule's two tags for block
+        remat (``ops.remat.keep`` on ``o`` and ``lse``, identities that
+        name a value) are taken out of the text."""
         import hashlib
         from bigdl_tpu.ops import flash_attention as fa
         from bigdl_tpu.ops.flash_attention import flash_attention_with_lse
@@ -808,10 +851,16 @@ class TestFlashBand:
 
         text = str(jax.make_jaxpr(run)(q, k, v, g, gl))
         assert hashlib.sha256(text.encode()).hexdigest()[:16] == jaxpr
-        h = hashlib.sha256()
-        for t in run(q, k, v, g, gl):
-            h.update(np.asarray(t, np.float32).tobytes())
-        assert h.hexdigest()[:16] == values
+        o, lse, g_q, g_k, g_v = run(q, k, v, g, gl)
+
+        def digest(*arrays):
+            h = hashlib.sha256()
+            for t in arrays:
+                h.update(np.asarray(t, np.float32).tobytes())
+            return h.hexdigest()[:16]
+
+        assert digest(o, lse, g_k, g_v) == kept
+        assert digest(g_q) == dq
 
     def test_band_needs_causal_and_a_positive_window(self):
         x = jnp.zeros((1, 16, 1, 8))
@@ -893,3 +942,118 @@ class TestGatedNormedAttention:
         m = nn.MultiHeadAttention(16, 4)
         assert sorted(m._parameters) == ["in_proj_bias", "in_proj_weight",
                                          "out_proj_bias", "out_proj_weight"]
+
+
+# ------------------------------------------- one backward call: dQ, dK, dV
+
+class TestOneBackwardCall:
+    """The backward is ONE call, the dK/dV kernel, which accumulates dQ in
+    a float32 VMEM buffer over a (batch, head) row's key tiles: zeroed at
+    the first, scaled, cast and written at the last. Every case has two
+    (batch, head) rows or more (a row's accumulator must not leak into the
+    next), a non-zero LSE cotangent, and all but ``one_key_tile`` more than
+    one key tile a row (the accumulator is carried)."""
+
+    # as TestFlashKernelDtypeContract: against the float32 oracle no
+    # further than 1.5x the bfloat16 XLA core, plus an ulp of the largest
+    FACTOR, FLOOR = 1.5, 2.0 ** -8
+
+    CASES = {
+        # name: (sq, sk, d, dv, causal, window, block)
+        "causal_halved_diagonal": (768, 768, 8, 8, True, None, 256),
+        "causal_masked_tiles": (64, 64, 8, 8, True, None, 16),
+        "non_causal": (48, 48, 8, 8, False, None, 16),
+        "non_causal_oblong": (32, 80, 8, 8, False, None, 16),
+        "band": (64, 64, 8, 8, True, 20, 16),
+        "latent": (48, 48, 24, 16, True, None, 16),
+        "padded_causal": (40, 40, 8, 8, True, None, 16),
+        "padded_oblong": (37, 53, 8, 8, False, None, 16),
+        "padded_band": (37, 37, 8, 8, True, 9, 16),
+        "one_key_tile": (16, 16, 8, 8, True, None, 16),
+    }
+
+    PARENT_DKV = {      # case: (float32, bfloat16)
+        "band": ("8f7f5f7f4bf1361d", "4707175de53ebc28"),
+        "causal_halved_diagonal": ("394d45422e21b798", "9c34b38bd374344c"),
+        "causal_masked_tiles": ("b45a1ee814e17f56", "3d7ad317a644fe01"),
+        "latent": ("794176a462a4e4b5", "17f68d74aab66ebe"),
+        "non_causal": ("648821f3251a662e", "794db281ddf4df6b"),
+        "non_causal_oblong": ("82cadc3e8eec54ba", "3aff8aefd8a236fe"),
+        "one_key_tile": ("7abfbc2a6c8ad353", "12c83b740ae9929e"),
+        "padded_band": ("d656059a8830c61f", "20f81d24c8f4165a"),
+        "padded_causal": ("bd689806add9bdb6", "1b12e5477230b0c1"),
+        "padded_oblong": ("6389d6157aa2226b", "b5d1c7fc3b9ffb4e"),
+    }
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                             ids=["f32", "bf16"])
+    @pytest.mark.parametrize("case", sorted(CASES))
+    def test_dq_dk_dv_of_the_one_call(self, case, dtype):
+        import hashlib
+        from bigdl_tpu.ops import flash_attention as fa
+        sq, sk, d, dv, causal, window, block = self.CASES[case]
+        rng = np.random.RandomState(sq + sk + d)
+        b, n = 2, 2
+        q = jnp.asarray(rng.randn(b, sq, n, d), dtype)
+        k = jnp.asarray(rng.randn(b, sk, n, d), dtype)
+        v = jnp.asarray(rng.randn(b, sk, n, dv), dtype)
+        g = jnp.asarray(rng.randn(b, sq, n, dv), dtype)
+        gl = jnp.asarray(rng.randn(b, n, sq), jnp.float32)
+        scale = 1.0 / d ** 0.5
+
+        def kernel(q_, k_, v_):
+            return fa.flash_attention_with_lse(
+                q_, k_, v_, causal=causal, block_q=block, block_k=block,
+                interpret=True, window=window)
+
+        def core(q_, k_, v_):
+            # the masked XLA core and its LSE, in the dtype of q
+            i = jnp.arange(sq)[:, None]
+            j = jnp.arange(sk)[None, :]
+            keep = jnp.ones((sq, sk), bool)
+            if causal:
+                keep = keep & (j <= i)
+            if window is not None:
+                keep = keep & (j > i - window)
+            o = ac.dot_product_attention(q_, k_, v_, mask=keep, causal=False)
+            logits = (jnp.einsum("bqnd,bknd->bnqk", q_, k_)
+                      * scale).astype(jnp.float32)
+            logits = jnp.where(keep, logits, jnp.finfo(jnp.float32).min)
+            return o, jax.nn.logsumexp(logits, axis=-1)
+
+        def grads(f, *xs):
+            (o, lse), vjp = jax.vjp(f, *xs)
+            return (o, lse) + tuple(vjp((g.astype(o.dtype), gl)))
+
+        got = grads(kernel, q, k, v)
+        assert _pallas_calls(lambda *t: grads(kernel, *t), q, k, v) == [
+            fa._name("fwd", window, d != dv),
+            fa._name("bwd_dkv", window, d != dv)]
+        oracle = grads(core, *(x.astype(jnp.float32) for x in (q, k, v)))
+        base = grads(core, q, k, v)
+        for name, a, x, ref in zip(("o", "lse", "dq", "dk", "dv"),
+                                   got, base, oracle):
+            assert a.dtype == x.dtype and a.shape == x.shape, name
+            a, x, ref = (np.asarray(t, np.float32) for t in (a, x, ref))
+            assert np.isfinite(a).all(), f"{name} has NaN/inf"
+            if dtype == jnp.float32:
+                np.testing.assert_allclose(a, ref, rtol=2e-4, atol=2e-4,
+                                           err_msg=f"{name} mismatch")
+                continue
+            top = np.abs(ref).max()
+            err, err_core = np.abs(a - ref).max(), np.abs(x - ref).max()
+            assert err <= self.FACTOR * err_core + self.FLOOR * top, (
+                f"{name}: kernel {err:.3e} against the XLA core's "
+                f"{err_core:.3e} (largest value {top:.3e})")
+        # dK and dV to the bit: their products and their order of summation
+        # are the two-call kernel's (digests of the parent's dK and dV on
+        # these inputs, git 52d54b9, jax 0.9.0, as TestFlashBand's digests
+        # are taken). dQ is not held so: its tile is now ds^T k on the
+        # transposed (key, query) tile where the dQ kernel took ds k on a
+        # (query, key) tile, and the inner order of a product's sum is the
+        # backend's to choose (equal bits in 10 of these 20 cases)
+        h = hashlib.sha256()
+        for t in got[3:]:
+            h.update(np.asarray(t, np.float32).tobytes())
+        assert h.hexdigest()[:16] == self.PARENT_DKV[case][
+            dtype == jnp.bfloat16], "dK, dV are not the two-call kernel's"

@@ -199,11 +199,11 @@ def test_the_differentiated_step_runs_kept_work_once_a_block(
     once = {"flash_band_fwd": n["W"], "flash_fwd": n["*"], "sort": n["E"],
             "top_k": n["E"], "mamba_in_proj": n["M"],
             # never kept, once a block either way
-            "flash_band_bwd_dq": n["W"], "flash_band_bwd_dkv": n["W"],
-            "flash_bwd_dq": n["*"], "flash_bwd_dkv": n["*"]}
+            "flash_band_bwd_dkv": n["W"], "flash_bwd_dkv": n["*"]}
     once = {k: v for k, v in once.items() if v}
     kept = _work(_step_jaxpr(cell, cfg, model), width)
     assert {k: kept[k] for k in once} == once
+    assert not [k for k in kept if k.endswith("bwd_dq")]  # one backward call
     assert {k[4:]: v for k, v in kept.items() if k.startswith("tag:")} \
         == MET[cell_name]
     monkeypatch.setattr(
