@@ -576,6 +576,122 @@ def joyai_llm_flash_lm_kwargs(config: Dict[str, Any], held_experts=None,
     return kwargs
 
 
+def smallthinker_pattern(rope_layout, sliding_window_layout) -> str:
+    """The ``HybridDecoder`` pattern of a SmallThinker stack: each layer is
+    an attention block (``W`` where ``sliding_window_layout`` is 1, ``*``
+    where it is 0) and an expert block whose router reads the LAYER'S
+    input, the stream that entered the attention block (``R``). The two
+    attention groups each rotate or do not: a window group or a full group
+    that mixes rotated and unrotated layers is refused."""
+    rope, window = list(rope_layout), list(sliding_window_layout)
+    if len(rope) != len(window):
+        raise ValueError(f"rope_layout has {len(rope)} entries, "
+                         f"sliding_window_layout {len(window)}")
+    bad = (set(rope) | set(window)) - {0, 1}
+    if bad:
+        raise ValueError(f"layout entries are 0 or 1, got {sorted(bad)}")
+    for w in (0, 1):
+        if len({r for r, x in zip(rope, window) if x == w}) > 1:
+            raise ValueError(
+                f"the layers with sliding_window_layout {w} mix rope_layout "
+                f"0 and 1: a third attention group is not mapped")
+    return "".join(("W" if w else "*") + "R" for w in window)
+
+
+#: the ``moe_*`` keys ``smallthinker_lm_kwargs`` reads
+_SMALLTHINKER_MOE_KEYS = ("moe_ffn_hidden_size",
+                          "moe_num_active_primary_experts",
+                          "moe_num_primary_experts",
+                          "moe_primary_router_apply_softmax")
+
+
+def smallthinker_lm_kwargs(config: Dict[str, Any], held_experts=None,
+                           train_router: bool = True,
+                           picks_by_token: bool = False) -> Dict[str, Any]:
+    """``models.hybrid.build_hybrid_lm`` kwargs for a SmallThinker
+    ``config.json`` dict (PowerInfer SmallThinker-21BA3B / 4BA0.6B): per
+    layer a GQA attention block, full and NOT rotated where
+    ``sliding_window_layout`` / ``rope_layout`` are 0, a window of
+    ``sliding_window_size`` and rotated at ``rope_theta`` where they are 1,
+    without bias, q/k norm or gate; then ``moe_num_primary_experts`` ReGLU
+    experts of width ``moe_ffn_hidden_size``, the top
+    ``moe_num_active_primary_experts`` of a router that reads the LAYER'S
+    INPUT (the stream ahead of the attention, not normed) with a softmax
+    over the picked logits as weights
+    (``moe_primary_router_apply_softmax`` with ``norm_topk_prob``); no
+    shared expert, no selection bias, no scale; one norm before each
+    mixer; an untied head.
+
+    ``held_experts`` lists the routed experts that live on this chip of an
+    expert-parallel deployment (default: all, which is always the
+    router's width); ``vocab_size`` may be a slice. ``train_router=False``
+    is ``MoE(train_router=False)`` in every expert layer, as
+    ``afmoe_lm_kwargs``; ``picks_by_token`` is ``MoE(pick_rows=vocab_size)``
+    in every expert layer, as ``joyai_llm_flash_lm_kwargs``: each token's
+    experts from a table by its id, for the builder to fill (the weights
+    stay the softmax over the live logits of those picks, read from the
+    layer's input).
+
+    Refused rather than guessed, each by its name: a router without the
+    softmax (``moe_primary_router_apply_softmax`` false: sigmoid scores),
+    ``norm_topk_prob`` false, any other ``moe_*`` key that is set
+    (secondary experts, shared experts), rope scaling, a tied head, a
+    biased attention."""
+    if not config.get("moe_primary_router_apply_softmax", False):
+        raise ValueError("moe_primary_router_apply_softmax false (sigmoid "
+                         "scores on the primary router) is not mapped")
+    if not config.get("norm_topk_prob", True):
+        raise ValueError("norm_topk_prob false (a softmax over all the "
+                         "router's outputs, not renormalised over the "
+                         "picks) is not mapped")
+    unknown = sorted(k for k, v in config.items() if k.startswith("moe_")
+                     and k not in _SMALLTHINKER_MOE_KEYS and v)
+    if unknown:
+        raise ValueError(f"unmapped SmallThinker keys {unknown}")
+    if config.get("rope_scaling"):
+        raise ValueError("SmallThinker rope_scaling is not mapped")
+    if config.get("tie_word_embeddings", False):
+        raise ValueError("a head tied to the embedding is not mapped")
+    if config.get("attention_bias", False):
+        raise ValueError("a biased attention is not mapped")
+    pattern = smallthinker_pattern(config["rope_layout"],
+                                   config["sliding_window_layout"])
+    if len(pattern) != 2 * int(config["num_hidden_layers"]):
+        raise ValueError(f"rope_layout has {len(pattern) // 2} entries, "
+                         f"num_hidden_layers says "
+                         f"{config['num_hidden_layers']}")
+    theta = float(config.get("rope_theta", 1e4))
+
+    def group(windowed):
+        # uniform within a group: smallthinker_pattern refused a mix
+        rope = any(r for r, w in zip(config["rope_layout"],
+                                     config["sliding_window_layout"])
+                   if bool(w) == windowed)
+        return dict(num_heads=int(config["num_attention_heads"]),
+                    num_kv_heads=int(config["num_key_value_heads"]),
+                    head_dim=int(config["head_dim"]), with_bias=False,
+                    rope=rope, rope_theta=theta,
+                    window=int(config["sliding_window_size"])
+                    if windowed else None)
+
+    kwargs = dict(vocab_size=int(config["vocab_size"]),
+                  embed_dim=int(config["hidden_size"]), pattern=pattern,
+                  norm_eps=float(config.get("rms_norm_eps", 1e-6)))
+    if "*" in pattern:
+        kwargs["attention"] = group(False)
+    if "W" in pattern:
+        kwargs["window_attention"] = group(True)
+    kwargs["moe"] = dict(
+        hidden_size=int(config["moe_ffn_hidden_size"]),
+        n_experts=int(config["moe_num_primary_experts"]),
+        k=int(config["moe_num_active_primary_experts"]),
+        activation="reglu", dispatch="held",
+        held=None if held_experts is None else tuple(held_experts),
+        bias=False, score="softmax_picked", train_router=train_router,
+        pick_rows=int(config["vocab_size"]) if picks_by_token else 0)
+    return kwargs
+
+
 # ------------------------------------------------------------------- export
 
 def export_gpt2_state_dict(model: Module) -> Dict[str, np.ndarray]:

@@ -1,10 +1,11 @@
 """Causal LM over a pattern-built decoder (``nn.HybridDecoder``): the hybrid
 Mamba-2 / attention / mixture-of-experts family, the decoders that mix
 sliding-window with full attention layers over dense and expert
-feed-forwards, and those of latent attention with a multi-token-prediction
-module. Same shape as ``models.transformer.build_lm`` — embedding,
-decoder, fused-CE head — with the per-layer pattern in place of one
-repeated block and no positional module (state-space layers carry order;
+feed-forwards (their experts routed from the block's own input, ``E``, or
+from the layer's, ahead of its attention, ``R``), and those of latent
+attention with a multi-token-prediction module. Same shape as
+``models.transformer.build_lm`` — embedding, decoder, fused-CE head —
+with the per-layer pattern in place of one repeated block and no positional module (state-space layers carry order;
 an attention group that rotates says so itself, ``rope=True``).
 """
 

@@ -11,8 +11,12 @@ experts (``parallel.expert.MoE``), ``*`` causal self-attention
 keyword group (the sliding-window layers of a model that mixes them with
 full ones: another window, rotation or head count), ``L`` causal latent
 self-attention (``nn.LatentAttention``), ``-`` a dense gated MLP
-(``GatedMLP``). The mixers are built from the keyword groups the
-caller gives for each kind. With ``post_norm`` a block norms its mixer's
+(``GatedMLP``), ``R`` a mixture of experts whose ROUTER reads the stream
+that entered the PRECEDING block (a layer that routes from its input,
+ahead of its attention: ``x <- x + experts(RMSNorm(x); routed by h)``, ``h``
+what the attention block before it was given, not normed). The mixers are
+built from the keyword groups the caller gives for each kind (``R`` from
+the ``E`` blocks' group). With ``post_norm`` a block norms its mixer's
 output too, ``x <- x + RMSNorm(mixer(RMSNorm(x)))``, so a layer of an
 attention and a feed-forward block holds four norms.
 
@@ -63,18 +67,26 @@ class GatedMLP(Module):
 
 class HybridBlock(Module):
     """``x + mixer(norm(x))``, or with ``post_norm``
-    ``x + norm_post(mixer(norm(x)))``."""
+    ``x + norm_post(mixer(norm(x)))``. With ``routed_ahead`` the input is
+    a pair ``(x, h)`` and the mixer (an expert layer with
+    ``router_input="given"``) is handed ``(norm(x), h)``: its router reads
+    ``h`` as it is."""
 
     def __init__(self, embed_dim: int, mixer: Module, norm_eps: float,
-                 post_norm: bool = False):
+                 post_norm: bool = False, routed_ahead: bool = False):
         super().__init__()
         self.norm = RMSNorm(embed_dim, eps=norm_eps)
         self.mixer = mixer
+        self.routed_ahead = routed_ahead
         if post_norm:
             self.norm_post = RMSNorm(embed_dim, eps=norm_eps)
 
     def update_output(self, input):
-        y = self.mixer.forward(self.norm.forward(input))
+        if self.routed_ahead:
+            input, entered = input
+            y = self.mixer.forward((self.norm.forward(input), entered))
+        else:
+            y = self.mixer.forward(self.norm.forward(input))
         if "norm_post" in self._modules:
             y = self.norm_post.forward(y)
         return input + y
@@ -91,7 +103,7 @@ class HybridDecoder(Module):
     ``GatedMLP(embed_dim, ...)``; a kind the pattern does not use needs
     none."""
 
-    KINDS = "ME*W-L"
+    KINDS = "ME*W-LR"
 
     #: as ``TransformerEncoder.remat_blocks``: ``Optimizer.set_remat(
     #: "block")`` sets it, and each block then runs under ``jax.checkpoint``
@@ -109,7 +121,9 @@ class HybridDecoder(Module):
     #: expert's float32 first products (128 B at top-8, 2 bytes a channel,
     #: 4 bytes a hidden unit or 8 for SwiGLU); norms, rotation, gates, the
     #: convolution, the scan, the router's product and the shared expert's
-    #: second product run a second time
+    #: second product run a second time. An ``R`` block's checkpoint takes
+    #: two values, the stream and the one its router reads, which is a
+    #: block boundary that is kept anyway
     remat_blocks = False
 
     def __init__(self, pattern: str, embed_dim: int, mamba=None, moe=None,
@@ -121,15 +135,20 @@ class HybridDecoder(Module):
         if bad or not pattern:
             raise ValueError(f"pattern {pattern!r}: blocks are named by "
                              f"the characters {self.KINDS!r}")
+        if pattern[0] == "R":
+            raise ValueError(f"pattern {pattern!r}: an 'R' block's router "
+                             f"reads the stream that entered the block "
+                             f"before it, so it cannot come first")
         self.pattern = pattern
         self.num_layers = len(pattern)
         for i, kind in enumerate(pattern):
             if kind == "M":
                 from bigdl_tpu.nn.mamba import Mamba2
                 mixer = Mamba2(embed_dim, **mamba)
-            elif kind == "E":
+            elif kind in "ER":
                 from bigdl_tpu.parallel.expert import MoE
-                mixer = MoE(embed_dim, **moe)
+                mixer = MoE(embed_dim, **moe, **(
+                    {"router_input": "given"} if kind == "R" else {}))
             elif kind == "-":
                 mixer = GatedMLP(embed_dim, **mlp)
             elif kind == "L":
@@ -138,23 +157,29 @@ class HybridDecoder(Module):
                 mixer = MultiHeadAttention(
                     embed_dim, causal=True,
                     **(window_attention if kind == "W" else attention))
-            self.add_module(f"layer{i}", HybridBlock(embed_dim, mixer,
-                                                     norm_eps, post_norm))
+            self.add_module(f"layer{i}", HybridBlock(
+                embed_dim, mixer, norm_eps, post_norm,
+                routed_ahead=kind == "R"))
         self.final_norm = RMSNorm(embed_dim, eps=norm_eps)
 
     def stream(self, input):
         """The residual stream after the last block, before the final
         norm (what a multi-token-prediction module reads)."""
         x = input
+        entered = None      # what the preceding block was given
         ckpt = self.remat_blocks and self.training
-        for i in range(self.num_layers):
+        for i, kind in enumerate(self.pattern):
             layer = self._modules[f"layer{i}"]
+            # an ``R`` block is given the stream and what its router reads
+            args = (x, entered) if kind == "R" else (x,)
+
+            def run(*a, _l=layer):
+                return _l.forward(a[0] if len(a) == 1 else a)
+
             if ckpt:
                 # kept by name, whatever the block is made of (ops.remat)
-                x = jax.checkpoint(lambda h, _l=layer: _l.forward(h),
-                                   policy=block_remat_policy())(x)
-            else:
-                x = layer.forward(x)
+                run = jax.checkpoint(run, policy=block_remat_policy())
+            entered, x = x, run(*args)
         return x
 
     def update_output(self, input):

@@ -255,6 +255,24 @@ def _bwd_vmem(sq_p: int, d: int, dv: int, block_q: int, block_k: int,
     return max(16 << 20, need * 5 // 4)
 
 
+def _fwd_vmem(sk_p: int, d: int, dv: int, block_q: int, block_k: int,
+              itemsize: int) -> Optional[int]:
+    """The forward call's VMEM limit, from the call's own shapes, or None
+    where the 16 MiB a call gets by default hold it (every call of up to
+    8,192 keys at head 128 in bf16, which so lowers to what it lowered to
+    before this rule). K and V lie whole in VMEM and the q and o tiles
+    beside them, each twice (Mosaic double-buffers a call's operands), with
+    two (BQ, BK) float32 intermediates and three (BQ, dv) float32
+    accumulators; a quarter on top for what the compiler spills. At 16,384
+    keys of head 128 in bf16 K and V alone are the 16 MiB (the v5e's
+    compiler refused the call while it named no limit) and the call
+    reckons 25 MB, at 32,768 keys 46 MB; the chip has 128 MiB."""
+    ld, ldv = _lanes(d), _lanes(dv)
+    need = (2 * (sk_p + block_q) * (ld + ldv) * itemsize
+            + 2 * block_q * block_k * 4 + 3 * block_q * ldv * 4)
+    return None if need <= 16 << 20 else min(_VMEM_MOST, need * 5 // 4)
+
+
 # ------------------------------------------------------------------ forward
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, l_ref, *, block_k: int, sk: int,
@@ -384,9 +402,12 @@ def _flash_fwd_lse(q, k, v, causal, scale, block_q, block_k, interpret,
         # a 192-wide operand lies in VMEM as 256 lanes, so at 8,192 tokens a
         # latent call's whole K and V are 12 MB double-buffered, which with
         # the tiles passes the default limit (the v5e's compiler refuses
-        # it, PR 34); a call with equal sizes names no limit, as before
+        # it, PR 34); a call with equal sizes names no limit where the
+        # default holds it, and from 16,384 keys of head 128 its own
         **_call_params("fwd", window, dv != d,
-                       _LATENT_VMEM if dv != d else None),
+                       _LATENT_VMEM if dv != d else _fwd_vmem(
+                           sk_p, d, dv, block_q, block_k,
+                           q.dtype.itemsize)),
     )(qt, kt, vt)
     out = out[:, :sq].reshape(b, n, sq, dv).transpose(0, 2, 1, 3)
     lse = lse[:, 0, :sq].reshape(b, n, sq)

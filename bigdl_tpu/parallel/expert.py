@@ -35,7 +35,14 @@ transformer block).
 deployment seen from ONE of its chips: the router scores all
 ``n_experts`` by a sigmoid, picks the top k of score + selection bias,
 renormalises the picked scores and scales them by ``route_scale`` (no
-auxiliary loss: ``aux_loss_weight`` is not read); the layer holds the
+auxiliary loss: ``aux_loss_weight`` is not read); with
+``score="softmax_picked"`` the scores are the router's logits and the
+weights a softmax over the k picked ones (the same as a softmax over all
+of them renormalised over the picks). With ``router_input="given"`` the
+layer's input is a pair ``(x, r)``: the experts read ``x`` and the router
+``r``, a stream of the same tokens that the model computed EARLIER (the
+layer's input, ahead of its attention), so the routing waits on nothing
+the experts' input waits on. The layer holds the
 experts whose ids ``held`` lists and computes the part of the result that
 those give, plus an always-on shared expert (``shared_hidden``); what the
 absent experts would add is the other chips' to compute and is not stood
@@ -124,9 +131,17 @@ def _swiglu(hid, gate):
     return jax.nn.silu(gate) * hid
 
 
+def _reglu(hid, gate):
+    return jax.nn.relu(gate) * hid
+
+
 #: an expert's activation by name, on its float32 first products
 _ACTIVATIONS = {"gelu": jax.nn.gelu, "relu": jax.nn.relu, "relu2": _relu2,
-                "swiglu": _swiglu}
+                "swiglu": _swiglu, "reglu": _reglu}
+#: those of the gated form: a gate matrix ``wg`` beside ``w1``
+_GATED = ("swiglu", "reglu")
+#: the held router's score rules (``MoE._route``)
+_SCORES = ("sigmoid", "softmax_picked")
 
 
 class MoE(Module):
@@ -134,11 +149,12 @@ class MoE(Module):
 
     Input (..., D) — leading axes are flattened into a token axis. Each
     expert is a two-layer FFN D -> H -> D, or with ``activation="swiglu"``
-    (``dispatch="held"`` only) the gated form of three matrices,
-    ``(silu(x W_g) * x W_1) W_2``, the shared expert alike. ``held``,
-    ``shared_hidden``, ``route_scale`` and ``train_router`` belong to
-    ``dispatch="held"`` (module docstring). ``train_router=False`` takes
-    the combine weights as constants: a chip that holds a share of the
+    / ``"reglu"`` (``dispatch="held"`` only) the gated form of three
+    matrices, ``(silu(x W_g) * x W_1) W_2`` / ``(relu(x W_g) * x W_1)
+    W_2``, the shared expert alike. ``held``, ``shared_hidden``,
+    ``route_scale``, ``train_router``, ``score`` and ``router_input``
+    belong to ``dispatch="held"`` (module docstring).
+    ``train_router=False`` takes the combine weights as constants: a chip that holds a share of the
     experts and exchanges nothing sees only that share of the router's
     gradient, and applied alone it trains the router TOWARD the held
     experts; the router then gets no gradient (nor does the layer's input
@@ -152,23 +168,32 @@ class MoE(Module):
                  activation: str = "gelu", aux_loss_weight: float = 1e-2,
                  dispatch: str = "sort", held=None, bias: bool = True,
                  shared_hidden: int = 0, route_scale: float = 1.0,
-                 train_router: bool = True, pick_rows: int = 0):
+                 train_router: bool = True, pick_rows: int = 0,
+                 score: str = "sigmoid", router_input: str = "own"):
         super().__init__()
         if dispatch not in ("sort", "scatter", "einsum", "held"):
             raise ValueError(f"dispatch must be 'sort', 'scatter', "
                              f"'einsum' or 'held', got {dispatch!r}")
-        if activation not in ("gelu", "relu", "relu2", "swiglu"):
+        if activation not in _ACTIVATIONS:
             raise ValueError(f"unknown expert activation {activation!r}")
-        if activation == "swiglu" and (dispatch != "held" or bias):
-            raise ValueError("swiglu experts (a gate matrix beside w1, no "
-                             "bias) belong to dispatch='held'")
+        if activation in _GATED and (dispatch != "held" or bias):
+            raise ValueError(f"{activation} experts (a gate matrix beside "
+                             f"w1, no bias) belong to dispatch='held'")
+        if score not in _SCORES:
+            raise ValueError(f"score must be one of {_SCORES}, got "
+                             f"{score!r}")
+        if router_input not in ("own", "given"):
+            raise ValueError(f"router_input must be 'own' or 'given', got "
+                             f"{router_input!r}")
         if dispatch != "held" and (held is not None or shared_hidden
                                    or route_scale != 1.0
-                                   or not train_router or pick_rows):
-            raise ValueError("held, shared_hidden, route_scale, pick_rows "
-                             "and train_router belong to dispatch='held' (the "
-                             "capacity paths route by softmax over experts "
-                             "that are all here)")
+                                   or not train_router or pick_rows
+                                   or score != "sigmoid"
+                                   or router_input != "own"):
+            raise ValueError("held, shared_hidden, route_scale, pick_rows, "
+                             "train_router, score and router_input belong "
+                             "to dispatch='held' (the capacity paths route "
+                             "by softmax over experts that are all here)")
         # ids of the experts whose weights live here (all of them unless
         # dispatch='held' names a share); the router is n_experts wide
         # either way
@@ -182,6 +207,8 @@ class MoE(Module):
         self.shared_hidden = shared_hidden
         self.route_scale = route_scale
         self.train_router = train_router
+        self.score = score
+        self.router_input = router_input
         self.input_size = input_size
         self.hidden_size = hidden_size
         self.n_experts = n_experts
@@ -204,7 +231,7 @@ class MoE(Module):
                                  init.zeros((pick_rows, self.k)))
         self.register_parameter(
             "w1", np.stack([init.xavier((d, h), d, h) for _ in range(n)]))
-        if activation == "swiglu":
+        if activation in _GATED:
             self.register_parameter(
                 "wg", np.stack([init.xavier((d, h), d, h) for _ in range(n)]))
         if bias:
@@ -217,7 +244,7 @@ class MoE(Module):
             # an always-on expert of its own width beside the routed ones
             hs = shared_hidden
             self.register_parameter("shared_w1", init.xavier((d, hs), d, hs))
-            if activation == "swiglu":
+            if activation in _GATED:
                 self.register_parameter("shared_wg",
                                         init.xavier((d, hs), d, hs))
             self.register_parameter("shared_w2", init.xavier((hs, d), hs, d))
@@ -252,10 +279,13 @@ class MoE(Module):
 
     def _route(self, x):
         """The held layer's router: (expert ids (T, k), combine weights
-        (T, k) float32) of every token over ALL n_experts."""
-        scores = jax.nn.sigmoid(
-            jnp.dot(x, self.gate_weight.astype(x.dtype),
-                    preferred_element_type=jnp.float32))
+        (T, k) float32) of every token over ALL n_experts. ``score``
+        ``"sigmoid"``: the picked sigmoid scores over their sum;
+        ``"softmax_picked"``: a softmax over the picked logits."""
+        scores = jnp.dot(x, self.gate_weight.astype(x.dtype),
+                         preferred_element_type=jnp.float32)
+        if self.score == "sigmoid":
+            scores = jax.nn.sigmoid(scores)
         if not self.train_router:
             scores = jax.lax.stop_gradient(scores)
         if self.pick_rows:
@@ -273,18 +303,30 @@ class MoE(Module):
         picked = keep(picked, MOE_ROUTE_TABLES)
         w = keep(jnp.take_along_axis(scores, picked, axis=-1),
                  MOE_ROUTE_TABLES)
-        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+        if self.score == "sigmoid":
+            w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+        else:
+            w = jax.nn.softmax(w, axis=-1)
         return picked, w * self.route_scale
 
     def _held_forward(self, input):
         """The dropless layer over the experts held here."""
+        routed_from = None
+        if self.router_input == "given":
+            input, routed_from = input
         orig_shape = input.shape
         d, e, k = self.input_size, self.n_experts, self.k
         x = input.reshape(-1, d)
         t = x.shape[0]
         n = len(self.held)
-        with jax.named_scope("moe_route"):
-            picked, weight = self._route(x)
+        # a router that reads a stream from ahead of the layer's mixer has
+        # a scope of its own: the step's partition charges its product,
+        # top-k, sort and count apart from a router that waits on its block
+        with jax.named_scope("moe_route_ahead") \
+                if self.router_input == "given" \
+                else jax.named_scope("moe_route"):
+            picked, weight = self._route(
+                x if routed_from is None else routed_from.reshape(-1, d))
             # local id of each pick: position of its expert in ``held``,
             # n where the expert lives on another chip
             local_of = np.full((e,), n, np.int32)
@@ -323,8 +365,11 @@ class MoE(Module):
     def update_output(self, input):
         if self.dispatch == "held":
             from bigdl_tpu.telemetry import get_registry, instruments
-            instruments(get_registry()).moe_dispatch_total.labels(
-                path="held").inc()
+            ins = instruments(get_registry())
+            ins.moe_dispatch_total.labels(path="held").inc()
+            # trace-time count, as bigdl_ssd_scan_total
+            ins.moe_router_total.labels(score=self.score,
+                                        input=self.router_input).inc()
             return self._held_forward(input)
         orig_shape = input.shape
         d, e, k = self.input_size, self.n_experts, self.k

@@ -218,6 +218,15 @@ METRIC_SPECS: List[MetricSpec] = [
                "eager call / once per TRACE under jit, as "
                "bigdl_ssd_scan_total: which form each compiled expert "
                "block holds.", ("form",)),
+    MetricSpec("bigdl_moe_router_total", "counter",
+               "Held expert layers (MoE(dispatch='held')) by their router "
+               "(score label: sigmoid, the picked sigmoid scores over "
+               "their sum; softmax_picked, a softmax over the picked "
+               "logits. input label: own, the router reads the experts' "
+               "input; given, it reads a stream the model hands it from "
+               "ahead of the layer's attention). Counted once per eager "
+               "call / once per TRACE under jit, as bigdl_ssd_scan_total.",
+               ("score", "input")),
     MetricSpec("bigdl_lm_head_ce_total", "counter",
                "Fused LM-head cross-entropies by form (form label: "
                "one_pass, the loss and its three gradients from one scan "
@@ -430,6 +439,12 @@ SCOPE_SPECS: List[ScopeSpec] = [
     ScopeSpec("moe_route", "parallel/expert.py MoE (held dispatch)",
               "Router product, top-k, the sort and count of the local "
               "picks, their gathers."),
+    ScopeSpec("moe_route_ahead", "parallel/expert.py MoE (held dispatch, "
+              "router_input='given')",
+              "The routing of a layer whose router reads the stream from "
+              "ahead of its attention: router product, top-k, the softmax "
+              "or renormalisation, the sort and count of the local picks, "
+              "their gathers. It waits on nothing the attention computes."),
     ScopeSpec("moe_experts", "parallel/expert.py MoE (held dispatch, and "
               "the backward rules of both forms); ops/grouped_matmul.py",
               "The grouped product over the held experts' rows, XLA loop "

@@ -1057,3 +1057,109 @@ class TestOneBackwardCall:
             h.update(np.asarray(t, np.float32).tobytes())
         assert h.hexdigest()[:16] == self.PARENT_DKV[case][
             dtype == jnp.bfloat16], "dK, dV are not the two-call kernel's"
+
+
+# ------------------------------- the forward from 16,384 keys of head 128
+
+class TestFlashForwardPast16kKeys:
+    """The forward holds K and V whole in VMEM. At 16,384 keys of head 128
+    in bf16 those alone are the 16 MiB a call gets by default, so such a
+    call names its limit from its own shapes (``_fwd_vmem``); every call
+    the older cells make (2,048 to 8,192 keys) names none, as before."""
+
+    def test_the_limit_is_named_from_the_calls_shapes(self):
+        from bigdl_tpu.ops import flash_attention as fa
+        # the accepted cells' calls: Qwen's 2,048 x 64 at 1024-tiles, the
+        # 8k cells' 8,192 x 128 at 512-tiles, and the float32 tests
+        assert fa._fwd_vmem(2048, 64, 64, 1024, 1024, 2) is None
+        assert fa._fwd_vmem(8192, 128, 128, 512, 512, 2) is None
+        assert fa._fwd_vmem(48, 8, 8, 16, 16, 4) is None
+        at16k = fa._fwd_vmem(16384, 128, 128, 512, 512, 2)
+        # K and V twice are 16 MiB; tiles, intermediates and a quarter on top
+        assert 24 << 20 < at16k < 32 << 20
+        at32k = fa._fwd_vmem(32768, 128, 128, 512, 512, 2)
+        assert 44 << 20 < at32k < 52 << 20
+        # never more than the call may name of the chip's 128 MiB
+        assert fa._fwd_vmem(1 << 18, 128, 128, 512, 512, 2) == fa._VMEM_MOST
+        # a head of 192 lies as 256 lanes
+        assert fa._fwd_vmem(16384, 192, 128, 512, 512, 2) > at16k
+
+    @pytest.mark.parametrize("window", [None, 132],
+                             ids=["full", "band"])
+    def test_more_key_tiles_than_the_old_limit_held(self, window):
+        """33 key tiles a row (the old limit was 32 tiles of 512): output
+        and LSE against the masked XLA core, and the gradients."""
+        from bigdl_tpu.ops.flash_attention import flash_attention_with_lse
+        s, block = 33 * 16, 16
+        rng = np.random.RandomState(7)
+        q, k, v, g = (jnp.asarray(rng.randn(1, s, 2, 8), jnp.float32)
+                      for _ in range(4))
+        gl = jnp.asarray(rng.randn(1, 2, s), jnp.float32)
+
+        def kernel(q_, k_, v_):
+            return flash_attention_with_lse(
+                q_, k_, v_, causal=True, block_q=block, block_k=block,
+                interpret=True, window=window)
+
+        def run(f):
+            out, vjp = jax.vjp(f, q, k, v)
+            return out + vjp((g, gl))
+
+        want = run(lambda *t: _banded_and_lse(*t, window or s))
+        for r, o, name in zip(want, run(kernel),
+                              ("o", "lse", "dq", "dk", "dv")):
+            np.testing.assert_allclose(np.asarray(o), np.asarray(r),
+                                       rtol=2e-4, atol=2e-4,
+                                       err_msg=f"{name} mismatch")
+        names = ["flash_band_fwd"] if window else ["flash_fwd"]
+        assert _pallas_calls(kernel, q, k, v) == names
+
+    @pytest.fixture(scope="class")
+    def one_chip(self):
+        """A described TPU v5e (compile-only), as
+        ``tests/test_grouped_matmul.py`` describes it."""
+        import os
+        os.environ.setdefault("TPU_LOG_DIR", "disabled")
+        from jax.experimental import topologies
+        from jax.sharding import SingleDeviceSharding
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:      # noqa: BLE001 - whatever keeps libtpu away
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        return SingleDeviceSharding(topo.devices[0])
+
+    @pytest.mark.parametrize("window", [None, 4096], ids=["full", "band"])
+    def test_16384_keys_of_head_128_compile_for_the_v5e(
+            self, window, one_chip, monkeypatch, request):
+        """Forward and backward of one SmallThinker attention core at the
+        cell's shape (28 heads x 16,384 x 128, bf16) through the chip's own
+        compiler, under the names the readers find them by; without the
+        named limit the forward is refused (16.25 MiB of 16)."""
+        import re
+        from bigdl_tpu.ops import flash_attention as fa
+        from jax.experimental.compilation_cache import compilation_cache
+        cached = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        request.addfinalizer(lambda: jax.config.update(
+            "jax_enable_compilation_cache", cached))
+        spec = jax.ShapeDtypeStruct((1, 16384, 28, 128), jnp.bfloat16,
+                                    sharding=one_chip)
+
+        def step(q, k, v):
+            def loss(q, k, v):
+                return jnp.sum(fa.flash_attention(
+                    q, k, v, causal=True, window=window,
+                    interpret=False).astype(jnp.float32))
+            return jax.value_and_grad(loss, (0, 1, 2))(q, k, v)
+
+        text = jax.jit(step).lower(spec, spec, spec).compile().as_text()
+        band = "band_" if window else ""
+        assert set(re.findall(r"flash_[a-z_]*", text)) >= {
+            f"flash_{band}fwd", f"flash_{band}bwd_dkv"}
+        monkeypatch.setattr(fa, "_fwd_vmem", lambda *a: None)
+        with pytest.raises(Exception, match="vmem"):
+            jax.jit(lambda q, k, v: fa.flash_attention(
+                q, k, v, causal=True, window=window, interpret=False)
+            ).lower(spec, spec, spec).compile()

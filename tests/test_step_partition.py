@@ -89,6 +89,12 @@ CASES = [
     ("jit(step)/jvp(Sequential)/HybridDecoder/HybridBlock/MoE/moe_route/"
      "reshape;jit(step)/jvp(Sequential)/HybridDecoder/HybridBlock/RMSNorm/"
      "mul", "moe_route", "forward"),
+    # a router that reads the stream from ahead of its layer's attention
+    (_BLOCK + "rematted_computation/HybridBlock/MoE/moe_route_ahead/"
+     "dot_general",
+     "moe_route_ahead", "recompute"),
+    ("jit(step)/jvp(Sequential)/HybridDecoder/HybridBlock/MoE/"
+     "moe_route_ahead/sort", "moe_route_ahead", "forward"),
 ]
 
 
@@ -118,10 +124,14 @@ CELLS = {
     "joyai-llm-flash-train-s8192":
         {"mla_proj", "attn_core", "mlp", "moe_route", "moe_experts",
          "moe_shared", "mtp", "norm", "embed", "lm_head_ce", "linear"},
+    "smallthinker-21b-a3b-train-s16384":
+        {"attn_proj", "attn_core", "moe_route_ahead", "moe_experts", "norm",
+         "embed", "lm_head_ce"},
 }
 UPDATE = {"param_cast", "grad_clip", "optim_update"}
 REMAT = {"nemotron-3-nano-30b-a3b-train-s8192", "trinity-mini-train-s8192",
-         "joyai-llm-flash-train-s8192"}
+         "joyai-llm-flash-train-s8192",
+         "smallthinker-21b-a3b-train-s16384"}
 
 
 def _step_text(cell_name):
