@@ -56,18 +56,33 @@ times and roofline shares: PERF.md section 5 (ledger, PR 24).
 A sliding window (``window``, with ``causal``: query i sees the keys
 ``(i - window, i]``) is a static argument of the same two kernels. A call
 that names none lowers to the code it lowered to before the band existed;
-a call that names one masks every tile it meets (the causal edge and the
-band's lower edge in one mask), runs no halved diagonal, and bounds its one
-loop on BOTH sides: the forward starts at the key tile that holds the
-oldest key the query tile's first row can see, the backward ends at the
-query tile that holds the last query its last key reaches. At 8,192 tokens
-and a window of 2,048 that is 5 of 16 key tiles a query tile, for 14.7M
-query-key pairs where the full call holds 33.6M. Such calls are named
-``flash_band_fwd`` and ``flash_band_bwd_dkv``, so a trace tells them from
-full calls of the same operand shape, and
+a call that names one bounds its one loop on BOTH sides: the forward starts
+at the key tile that holds the oldest key the query tile's first row can
+see, the backward ends at the query tile that holds the last query its last
+key reaches. At 8,192 tokens and a window of 2,048 that is 5 of 16 key
+tiles a query tile, for 14.7M query-key pairs where the full call holds
+33.6M. What the band's two edges cost follows the call's shapes
+(``_edge_strips``, PR 40). A square, unpadded call whose window is a whole
+number of its tiles (and whose tile halves are whole lane groups) pays for
+them only AT the edges: each edge then crosses ONE tile a program corner to
+corner, the diagonal's and the one ``window / tile`` tiles from it, its
+mirror image, and each runs as two half-height strips against only the keys
+(queries, in the backward) it can see, 3/4 of the tile's products, as the
+full call's diagonal does; the tiles between run UNMASKED in the one loop.
+The forward takes both edge tiles after the loop, in one online-softmax
+step a strip, and a query tile that reaches back to key 0 (no lower edge)
+runs that edge's strips on tile 0 with every pair masked: a branch on the
+program's index lengthened every program's schedule by more. The backward
+branches: a key tile the last query ends has no far edge and skips its
+strips. Every other banded call (an edge that cuts tiles at an angle,
+a padded or oblong call) keeps one loop that masks every tile it meets (the
+causal edge and the band's lower edge in one mask), code and bits. Banded
+calls are named ``flash_band_fwd`` and ``flash_band_bwd_dkv``, so a trace
+tells them from full calls of the same operand shape;
 ``bigdl_flash_attention_total{form=band|full|mla}`` counts each form once a
-trace. A window that reaches past the first key is no band: the call is
-the full one, code and name.
+trace and ``bigdl_flash_band_edges_total{edges=strips|masked}`` how a banded
+call's edges run. A window that reaches past the first key is no band: the
+call is the full one, code and name.
 
 The value head may differ from the query/key head (latent attention:
 ``q`` and ``k`` 192 wide, a 128-wide content part beside a 64-wide rotary
@@ -179,6 +194,19 @@ def _halved_diagonal(causal, sq, sk, block_q, block_k) -> bool:
     are whole lane groups; every other shape masks whole tiles."""
     return (causal and sq == sk and block_q == block_k
             and sk % block_k == 0 and block_k % 256 == 0)
+
+
+def _edge_strips(causal, sq, sk, block_q, block_k,
+                 window: Optional[int]) -> bool:
+    """Whether the tiles an edge of the visible region crosses corner to
+    corner run as two half-height strips and every tile between as it is,
+    unmasked: the causal diagonal of a band-less call (``_halved_diagonal``)
+    and, under a band, its lower edge as well, which needs a window of whole
+    tiles: the edge then crosses ONE tile a program, ``window / block``
+    tiles from the diagonal, as the diagonal's mirror image. A band whose
+    edge cuts tiles at an angle masks every tile it meets."""
+    return (_halved_diagonal(causal, sq, sk, block_q, block_k)
+            and (window is None or window % block_k == 0))
 
 
 def _masked(causal, sk, block_k) -> bool:
@@ -293,50 +321,101 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, l_ref, *, block_k: int, sk: int,
     if prescaled:
         q = q * jnp.asarray(scale, q.dtype)
 
-    def update(carry, rows, k0, width, valid):
+    def update(carry, rows, *pieces):
         # One online-softmax step of the query rows `rows` against the keys
-        # [k0, k0 + width). Without a band key 0 is visible to every row
-        # and is in the first tile a row meets, so no row is all-masked
-        # when its running maximum is first used. Under a band a row's
-        # first tiles can lie wholly below its window: they leave p = 1
-        # against a running maximum of _NEG, and the first tile that holds
-        # a visible key (the row's own, at the latest) wipes that with
-        # corr = exp(_NEG - max) = 0.
+        # of every piece (k0, width, valid): [k0, k0 + width) under the
+        # mask `valid`, one running maximum and one rescaling for them all.
+        # Without a band key 0 is visible to every row and is in the first
+        # tile a row meets, so no row is all-masked when its running
+        # maximum is first used. Under a band that masks every tile a
+        # row's first tiles can lie wholly below its window: they leave
+        # p = 1 against a running maximum of _NEG, and the first tile that
+        # holds a visible key (the row's own, at the latest) wipes that
+        # with corr = exp(_NEG - max) = 0. Where the band's edges run as
+        # strips the tile's LAST row sees nothing of the lower-edge tile
+        # (the columns past its own: there is none); that tile shares its
+        # step with the diagonal's, which holds the row's own key.
         acc, rsum, rmax = (x[rows] for x in carry)
-        kblk = k_ref[0, pl.ds(k0, width), :]
-        vblk = v_ref[0, pl.ds(k0, width), :]
-        logits = _dot_nt(q[rows], kblk)                     # f32
-        if not prescaled:
-            logits = logits * scale
-        if valid is not None:
-            logits = jnp.where(valid, logits, _NEG)
-        new_max = jnp.maximum(rmax, jnp.max(logits, axis=-1, keepdims=True))
-        p = jnp.exp(logits - new_max)
+        new_max, logits, values = rmax, [], []
+        for k0, width, valid in pieces:
+            kblk = k_ref[0, pl.ds(k0, width), :]
+            values.append(v_ref[0, pl.ds(k0, width), :])
+            logit = _dot_nt(q[rows], kblk)                  # f32
+            if not prescaled:
+                logit = logit * scale
+            if valid is not None:
+                logit = jnp.where(valid, logit, _NEG)
+            new_max = jnp.maximum(new_max,
+                                  jnp.max(logit, axis=-1, keepdims=True))
+            logits.append(logit)
+        ps = [jnp.exp(logit - new_max) for logit in logits]
         corr = jnp.exp(rmax - new_max)
-        new_sum = rsum * corr + jnp.sum(p, axis=-1, keepdims=True)
-        new_acc = acc * corr + _dot(p.astype(vblk.dtype), vblk)
+        new_sum = rsum * corr
+        for p in ps:
+            new_sum = new_sum + jnp.sum(p, axis=-1, keepdims=True)
+        new_acc = acc * corr
+        for p, vblk in zip(ps, values):
+            new_acc = new_acc + _dot(p.astype(vblk.dtype), vblk)
         return new_acc, new_sum, new_max
 
     def tile(kb, carry, masked):
         valid = _visible((bq, block_k), 1, kb * block_k, j * block_q, sk,
                          causal, window) if masked else None
-        return update(carry, slice(None), kb * block_k, block_k, valid)
+        return update(carry, slice(None), (kb * block_k, block_k, valid))
+
+    def strips(carry, *tiles):
+        # The key tiles an edge crosses corner to corner, each (kb,
+        # mirrored, live), as two half-height strips of the query rows,
+        # each against only the keys it can see and in ONE online-softmax
+        # step for all its tiles. On the diagonal a row sees the columns up
+        # to its own: the upper rows the first half of the keys, the lower
+        # rows all. `mirrored`, the band's lower edge: a row sees the
+        # columns PAST its own, the upper rows all the keys, the lower
+        # rows the second half. `live` (None: it is) masks a tile whole.
+        h = bq // 2
+
+        def pieces(lower):
+            for kb, mirrored, live in tiles:
+                k0 = kb * block_k
+                if mirrored:
+                    k0, width, valid = (
+                        (k0 + h, h, ~_below(h, h, 0)) if lower else
+                        (k0, block_k, ~_below(h, block_k, 0)))
+                else:
+                    k0, width, valid = (
+                        (k0, block_k, _below(h, block_k, h)) if lower else
+                        (k0, h, _below(h, h, 0)))
+                yield k0, width, valid if live is None else valid & live
+
+        upper = update(carry, slice(0, h), *pieces(False))
+        lower = update(carry, slice(h, bq), *pieces(True))
+        return tuple(jnp.concatenate(x) for x in zip(upper, lower))
 
     carry = (jnp.zeros((bq, v_ref.shape[-1]), jnp.float32),
              jnp.zeros((bq, 1), jnp.float32),
              jnp.full((bq, 1), _NEG, jnp.float32))
     if diagonal:
-        # key tiles [0, j) lie wholly below the diagonal; tile j is on it
-        carry = lax.fori_loop(0, j, functools.partial(tile, masked=False),
+        # key tiles [first, j) lie wholly below the diagonal and, under a
+        # band, wholly inside it (_edge_strips); tile j is on the diagonal
+        reach = None if window is None else window // block_k
+        first = 0 if reach is None else lax.max(j - reach + 1, 0)
+        carry = lax.fori_loop(first, j, functools.partial(tile, masked=False),
                               carry)
-        h = bq // 2
-        upper = update(carry, slice(0, h), j * block_k, h, _below(h, h, 0))
-        lower = update(carry, slice(h, bq), j * block_k, block_k,
-                       _below(h, block_k, h))
-        acc, rsum, rmax = (jnp.concatenate(x) for x in zip(upper, lower))
+        edges = ((j, False, None),)
+        if reach is not None:
+            # the band's lower edge crosses tile j - reach and shares the
+            # diagonal's step. The first `reach` query tiles reach back to
+            # key 0 and have none: their strips read tile 0 with every
+            # pair masked, p = 0 against the diagonal's maximum. A branch
+            # on j instead costs every program more than those dead strips
+            # cost the few: its schedule is 4% longer in the loop and 9%
+            # around it (PERF.md section 6, PR 40)
+            edges = ((lax.max(j - reach, 0), True, j >= reach),) + edges
+        acc, rsum, rmax = strips(carry, *edges)
     elif window is not None:
-        # The band: the key tiles from the one that holds the first row's
-        # oldest visible key to the one on the diagonal, every one masked.
+        # A band whose edge cuts tiles at an angle: the key tiles from the
+        # one that holds the first row's oldest visible key to the one on
+        # the diagonal, every one masked.
         first, last = _band_tiles(j * block_q, bq, block_k, nkb, window,
                                   behind=True)
         acc, rsum, rmax = lax.fori_loop(
@@ -363,11 +442,7 @@ def _flash_fwd_lse(q, k, v, causal, scale, block_q, block_k, interpret,
     """Returns (o (B,Sq,N,Dv), lse (B,N,Sq) f32)."""
     b, sq, n, d = q.shape
     sk, dv = k.shape[1], v.shape[-1]
-    # under a band every tile is masked, which is where the 1024-tile loses
-    default = _fwd_block(sq, sk, d, dv, q.dtype.itemsize) if window is None \
-        else _BLOCK
-    block_q = min(block_q or default, sq)
-    block_k = min(block_k or default, sk)
+    block_q, block_k = _fwd_tiles(q, k, v, block_q, block_k, causal, window)
     # BSND -> (B*N, S, D): one grid row per (batch, head).
     qt = q.transpose(0, 2, 1, 3).reshape(b * n, sq, d)
     kt = k.transpose(0, 2, 1, 3).reshape(b * n, sk, d)
@@ -385,8 +460,8 @@ def _flash_fwd_lse(q, k, v, causal, scale, block_q, block_k, interpret,
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, block_k=block_k, sk=sk,
                           causal=causal, scale=scale, block_q=block_q,
-                          diagonal=window is None and _halved_diagonal(
-                              causal, sq, sk, block_q, block_k),
+                          diagonal=_edge_strips(causal, sq, sk, block_q,
+                                                block_k, window),
                           window=window),
         out_shape=(jax.ShapeDtypeStruct((b * n, sq_p, dv), q.dtype),
                    jax.ShapeDtypeStruct((b * n, 1, sq_p), jnp.float32)),
@@ -468,19 +543,49 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, l_ref, d_ref,
 
     zeros = (jnp.zeros((bk, d), jnp.float32),
              jnp.zeros((bk, v.shape[-1]), jnp.float32))
-    if diagonal:
-        # query tile jkb is on the diagonal: its first half sees only the
-        # first half of the keys; query tiles after it see every key
+    def strips(qb, mirrored):
+        # The query tile qb, which an edge crosses corner to corner, as two
+        # half-height strips of the keys, each against only the queries
+        # that see it. On the diagonal a key is seen from its own position
+        # on: the first half of the keys by the whole tile, the second by
+        # its second half. `mirrored`, the band's far edge: a key is seen
+        # by the queries BEFORE its own position in the tile, the first
+        # half of the keys by the tile's first half, the second by all.
         h = bk // 2
-        upper = part(slice(0, h), jkb * block_q, block_q,
-                     ~_below(h, block_q, -1))
-        lower = part(slice(h, bk), jkb * block_q + h, h, ~_below(h, h, -1))
-        carry = tuple(jnp.concatenate(x) for x in zip(upper, lower))
-        dk, dv = lax.fori_loop(jkb + 1, nqb,
+        if mirrored:
+            upper = part(slice(0, h), qb * block_q, h, _below(h, h, -1))
+            lower = part(slice(h, bk), qb * block_q, block_q,
+                         _below(h, block_q, h - 1))
+        else:
+            upper = part(slice(0, h), qb * block_q, block_q,
+                         ~_below(h, block_q, -1))
+            lower = part(slice(h, bk), qb * block_q + h, h,
+                         ~_below(h, h, -1))
+        return tuple(jnp.concatenate(x) for x in zip(upper, lower))
+
+    if diagonal:
+        # query tile jkb is on the diagonal; the query tiles after it see
+        # every key and, under a band, up to the one on its far edge are
+        # seen by every key (_edge_strips)
+        carry = strips(jkb, mirrored=False)
+        last = nqb
+        if window is not None:
+            reach = window // block_q
+            last = lax.min(jkb + reach, nqb)
+        dk, dv = lax.fori_loop(jkb + 1, last,
                                functools.partial(tile, masked=False), carry)
+        if window is not None:
+            # the band's far edge crosses query tile jkb + reach; the last
+            # `reach` key tiles reach the last query and have none
+            dk, dv = lax.cond(
+                jkb + reach < nqb,
+                lambda c: tuple(a + b for a, b in zip(
+                    c, strips(jkb + reach, mirrored=True))),
+                lambda c: c, (dk, dv))
     elif window is not None:
-        # The band: from the query tile that holds this key tile's first
-        # row to the one that holds the last query its last key reaches.
+        # A band whose edge cuts tiles at an angle: from the query tile
+        # that holds this key tile's first row to the one that holds the
+        # last query its last key reaches, every one masked.
         first, last = _band_tiles(jkb * block_k, bk, block_q, nqb, window,
                                   behind=False)
         dk, dv = lax.fori_loop(first, last,
@@ -535,8 +640,7 @@ def _flash_bwd(q, k, v, o, lse, g_o, g_l, causal, scale, block_q, block_k,
         kt = jnp.pad(kt, ((0, 0), (0, pad_k), (0, 0)))
         vt = jnp.pad(vt, ((0, 0), (0, pad_k), (0, 0)))
     sq_p, sk_p = qt.shape[1], kt.shape[1]
-    diagonal = window is None and _halved_diagonal(causal, sq, sk, block_q,
-                                                   block_k)
+    diagonal = _edge_strips(causal, sq, sk, block_q, block_k, window)
 
     dq, dk, dv = pl.pallas_call(
         functools.partial(_bwd_kernel, block_q=block_q, sk=sk,
@@ -647,39 +751,78 @@ def _fwd_block(sq: int, sk: int, d: int, dv: int, itemsize: int) -> int:
     return _BLOCK
 
 
+def _fwd_tiles(q, k, v, block_q, block_k, causal,
+               window) -> Tuple[int, int]:
+    """The forward's (query, key) tile: the caller's, else the default of
+    the call's form, and never longer than the sequence. Without a band
+    the default is ``_fwd_block``'s. Under one it is 1024 where the
+    backward's is (``_bwd_block``: the edges run as strips at that size and
+    the window is at least two such tiles) and the limit the call would
+    name (``_fwd_vmem``) is one it may; else 512. ``_fwd_block``'s budget is
+    not asked: it is the 16 MiB of a call that names no limit, and refuses
+    1024 at head 128 from 8,192 keys, which is where the cells' bands are.
+    Measured on the v5e, bf16, ms a call (PERF.md section 6, PR 40; the
+    all-masked loop at 512 first): 28 x 16,384 x 128, window 4,096: 8.58,
+    8.26 with the edges as strips at 512, 7.94 at 1024; 32 x 8,192 x 128,
+    window 2,048: 3.01, 2.89, 2.74. The tile loop's schedule is no shorter
+    at 1024 (5,594 bundles for four times 1,393); half as many programs
+    pay for the strips and the epilogue."""
+    sq, sk = q.shape[1], k.shape[1]
+    d, dv, itemsize = q.shape[-1], v.shape[-1], q.dtype.itemsize
+    big = _BIG_BLOCK
+    if window is None:
+        default = _fwd_block(sq, sk, d, dv, itemsize)
+    elif (_edge_strips(causal, sq, sk, big, big, window)
+          and window >= 2 * big
+          and (_fwd_vmem(sk, d, dv, big, big, itemsize) or 0) < _VMEM_MOST):
+        default = big
+    else:
+        default = _BLOCK
+    return min(block_q or default, sq), min(block_k or default, sk)
+
+
 def _bwd_block(sq: int, sk: int, d: int, dv: int, itemsize: int,
                causal: bool, window: Optional[int]) -> int:
     """The backward's default tile, from what the call can see: 1024 x 1024
-    where the causal diagonal is halved at that size (a square, unpadded
-    causal call of whole 1024-tiles, no band) and the call's VMEM
-    (``_bwd_vmem``) stays under what it may name; else 512 x 512. Where the
-    diagonal is halved the tiles below it run unmasked, and a tile of four
-    times the pairs shares its loads of k, v and the accumulators' carry
-    over four times the products. Measured on the v5e, bf16, ms a call
-    (PERF.md section 6, PR 37): 13.50 against 14.70 at 512 for a latent
-    call of 32 x 8,192 (192 over 128), 8.32 against 9.18 at head 128, 0.711
-    against 0.721 at 28 x 2,048 x 64; the schedule of the tile loop said so
-    before the chip did (3,698 bundles a (512, 512) of pairs against 4,135
-    latent, 2,363 against 2,532 at head 128). Oblong tiles lose the halved
-    diagonal and mask every tile (1024 x 512: +5% at head 128, +10% under
-    a band, -2% latent; 512 x 1024 +7 / +11 / +1%), smaller ones pay the
-    loop's fixed work more often (256 x 512 +8 to +23%, 256 x 256 +39 to
-    +62%), and a band of 2,048 meets 3-4 query tiles of 1024 a key tile
-    where it meets 5 of 512."""
+    where the edges run as strips at that size (``_edge_strips``: a square,
+    unpadded causal call of whole 1024-tiles and, under a band, a window of
+    at least two of them) and the call's VMEM (``_bwd_vmem``) stays under
+    what it may name; else 512 x 512. Where the edges are strips the tiles
+    between them run unmasked, and a tile of four times the pairs shares
+    its loads of k, v and the accumulators' carry over four times the
+    products. Measured on the v5e, bf16, ms a call (PERF.md section 6, PR
+    37): 13.50 against 14.70 at 512 for a latent call of 32 x 8,192 (192
+    over 128), 8.32 against 9.18 at head 128, 0.711 against 0.721 at 28 x
+    2,048 x 64; the schedule of the tile loop said so before the chip did
+    (3,698 bundles a (512, 512) of pairs against 4,135 latent, 2,363
+    against 2,532 at head 128). Under a band with its edges as strips (PR
+    40; the all-masked loop at 512 in the same call first): 28 x 16,384 x
+    128, window 4,096: 16.26, 15.14 at 512, 14.07 at 1024; 32 x 8,192 x
+    128, window 2,048: 5.35, 4.90, 4.65. A 1024-tile computes half a tile
+    more past the band than two 512s do (4.5 tiles for 4 of window and 2.5
+    for 2, against 8.5 / 8 and 4.5 / 4) and still wins both; a window of
+    ONE 1024-tile (1.5 tiles computed for 1) is not measured and keeps 512.
+    Oblong tiles lose the strips and mask every tile (1024 x 512: +5% at
+    head 128, +10% under a band, -2% latent; 512 x 1024 +7 / +11 / +1%),
+    smaller ones pay the loop's fixed work more often (256 x 512 +8 to
+    +23%, 256 x 256 +39 to +62%)."""
     big = _BIG_BLOCK
-    if (window is None and _halved_diagonal(causal, sq, sk, big, big)
+    if (_edge_strips(causal, sq, sk, big, big, window)
+            and (window is None or window >= 2 * big)
             and _bwd_vmem(sq, d, dv, big, big, itemsize) <= _VMEM_MOST):
         return big
     return _BLOCK
 
 
-def _band_of(window: Optional[int], causal: bool, sk: int,
-             latent: bool = False) -> Optional[int]:
-    """The band the kernels are told of, counted by form. A window that
-    reaches past the first key cuts nothing: the call is then the full
-    one, code and name. ``latent``: the value head differs from the
-    query/key head, which has its own names and form and takes no band
-    (a trace could not tell such a call, and nothing makes one)."""
+def _band_of(window: Optional[int], causal: bool, q, k, v, block_q,
+             block_k) -> Optional[int]:
+    """The band the kernels are told of, counted by form and, where there
+    is one, by how its edges run. A window that reaches past the first key
+    cuts nothing: the call is then the full one, code and name. A latent
+    call (the value head differs from the query/key head) has its own names
+    and form and takes no band (a trace could not tell such a call, and
+    nothing makes one)."""
+    sk, latent = k.shape[1], v.shape[-1] != q.shape[-1]
     if window is not None:
         if not causal:
             raise ValueError("window (a banded causal mask) needs "
@@ -694,8 +837,16 @@ def _band_of(window: Optional[int], causal: bool, sk: int,
     from bigdl_tpu.telemetry import get_registry, instruments
     # trace-time count, as bigdl_ssd_scan_total: which form a compiled
     # program holds, not per-step traffic
-    instruments(get_registry()).flash_attention_total.labels(
+    ins = instruments(get_registry())
+    ins.flash_attention_total.labels(
         form="mla" if latent else "full" if window is None else "band").inc()
+    if window is not None:
+        # by the forward's tiles; the backward's default follows them
+        # (both take 1024 only where the edges are strips at it)
+        strips = _edge_strips(causal, q.shape[1], sk, *_fwd_tiles(
+            q, k, v, block_q, block_k, causal, window), window)
+        ins.flash_band_edges_total.labels(
+            edges="strips" if strips else "masked").inc()
     return window
 
 
@@ -712,14 +863,14 @@ def flash_attention(q, k, v, causal: bool = False,
     two kernels run under the names ``flash_mla_*``, with nothing padded.
 
     ``window`` (with ``causal``): query i sees the keys ``(i - window, i]``,
-    the Mistral convention. The kernels mask the band's lower edge
-    and skip the tiles that lie wholly below it."""
+    the Mistral convention. The kernels skip the tiles that lie wholly
+    below the band and mask its lower edge: in the one tile it crosses
+    where the window is a whole number of tiles, else in every tile."""
     if scale is None:
         scale = 1.0 / float(q.shape[-1]) ** 0.5
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    window = _band_of(window, causal, k.shape[1],
-                      v.shape[-1] != q.shape[-1])
+    window = _band_of(window, causal, q, k, v, block_q, block_k)
     o, _ = _flash_lse(q, k, v, causal, scale, block_q, block_k, interpret,
                       window)
     return o
@@ -741,8 +892,7 @@ def flash_attention_with_lse(
         scale = 1.0 / float(q.shape[-1]) ** 0.5
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    window = _band_of(window, causal, k.shape[1],
-                      v.shape[-1] != q.shape[-1])
+    window = _band_of(window, causal, q, k, v, block_q, block_k)
     return _flash_lse(q, k, v, causal, scale, block_q, block_k, interpret,
                       window)
 

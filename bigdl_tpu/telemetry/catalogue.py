@@ -242,6 +242,18 @@ METRIC_SPECS: List[MetricSpec] = [
                "reaches past the first key). "
                "Counted once per eager call / once per TRACE under jit, "
                "as bigdl_ssd_scan_total.", ("form",)),
+    MetricSpec("bigdl_flash_band_edges_total", "counter",
+               "Banded flash-attention calls (form=band of "
+               "bigdl_flash_attention_total) by how the band's two edges "
+               "run (edges label: strips, a square unpadded call whose "
+               "window is a whole number of its tiles: each edge tile as "
+               "two half-height strips against the keys it can see and "
+               "the tiles between unmasked; masked, every other banded "
+               "call: one loop that masks every tile it meets). Decided "
+               "from the call's shapes and the forward's tile, which the "
+               "backward's default follows. Counted once per eager call / "
+               "once per TRACE under jit, as bigdl_ssd_scan_total.",
+               ("edges",)),
     MetricSpec("bigdl_latent_attention_total", "counter",
                "Latent-attention (nn.LatentAttention) forwards by path "
                "(path label: expanded, the latent up-projected to "

@@ -636,6 +636,8 @@ def test_a_traced_step_counts_band_and_full_once_a_layer(monkeypatch):
     ins = instruments(get_registry())
     before = {f: ins.flash_attention_total.labels(form=f).value
               for f in ("band", "full")}
+    edges0 = {e: ins.flash_band_edges_total.labels(edges=e).value
+              for e in ("strips", "masked")}
 
     def f(p):
         return jnp.sum(functional_apply(dec, p, dec.buffer_tree(), x,
@@ -646,6 +648,12 @@ def test_a_traced_step_counts_band_and_full_once_a_layer(monkeypatch):
             for f in before}
     assert rise == {"band": dec.pattern.count("W"),
                     "full": dec.pattern.count("*")}
+    # at this size the window, 256, is half the default 512-tile, so each
+    # banded layer keeps the all-masked loop (the cell's 2,048 is four
+    # whole tiles and counts `strips`: tests/test_smallthinker_lm.py holds
+    # that form at a window of one whole tile)
+    assert {e: ins.flash_band_edges_total.labels(edges=e).value - edges0[e]
+            for e in edges0} == {"strips": 0, "masked": dec.pattern.count("W")}
     text = str(jaxpr)
     for name in ("flash_band_fwd", "flash_band_bwd_dkv",
                  "flash_fwd", "flash_bwd_dkv"):

@@ -362,7 +362,13 @@ def test_forward_tile_follows_the_call(sq, sk, d, itemsize, want):
     (1024, 1024, 64, 2, True, None, 1024),      # chip_smoke's 134M LM
     (8192, 8192, 128, 2, True, None, 1024),     # the two hybrid cells
     (8192, 8192, (192, 128), 2, True, None, 1024),   # latent: 64 MiB named
-    (8192, 8192, 128, 2, True, 2048, 512),      # a band meets more of 1024
+    (8192, 8192, 128, 2, True, 2048, 1024),     # Trinity's band: two tiles
+    (16384, 16384, 128, 2, True, 4096, 1024),   # SmallThinker's: four
+    (8192, 8192, 128, 2, True, 1024, 512),      # ONE 1024-tile: not measured
+    (8192, 8192, 128, 2, True, 2560, 512),      # whole 512s, no whole 1024s
+    (8192, 8192, 128, 2, True, 2000, 512),      # an edge at an angle: masked
+    (8200, 8200, 128, 2, True, 2048, 512),      # padded: masked
+    (65536, 65536, 128, 2, True, 4096, 512),    # VMEM, as without a band
     (8192, 8192, 128, 2, False, None, 512),     # no diagonal to halve
     (3000, 3000, 64, 2, True, None, 512),       # padded: every tile masked
     (2048, 4096, 64, 2, True, None, 512),       # oblong
@@ -371,11 +377,12 @@ def test_forward_tile_follows_the_call(sq, sk, d, itemsize, want):
 ])
 def test_backward_tile_follows_the_call(sq, sk, d, itemsize, causal, window,
                                         want):
-    """The backward's default tile is 1024 x 1024 only where the causal
-    diagonal is halved at that size and the call's VMEM estimate stays
-    under what it may name (PERF.md section 6, PR 37); else 512 x 512. The
-    limit the call names follows its shapes and is never under the 16 MiB
-    default."""
+    """The backward's default tile is 1024 x 1024 only where the edges run
+    as strips at that size (the causal diagonal and, under a band of at
+    least two such tiles, its far edge: ``_edge_strips``) and the call's
+    VMEM estimate stays under what it may name (PERF.md section 6, PR 37
+    and PR 40); else 512 x 512. The limit the call names follows its
+    shapes and is never under the 16 MiB default."""
     from bigdl_tpu.ops import flash_attention as fa
     d, dv = d if isinstance(d, tuple) else (d, d)
     assert fa._bwd_block(sq, sk, d, dv, itemsize, causal, window) == want
@@ -388,6 +395,30 @@ def test_backward_tile_follows_the_call(sq, sk, d, itemsize, causal, window,
     whole = sq * (2 * (fa._lanes(d) + fa._lanes(dv)) * itemsize
                   + fa._lanes(d) * (4 + 2 * itemsize))
     assert named > min(whole, (16 << 20) - 1)
+
+
+@pytest.mark.parametrize("s,d,window,want", [
+    (8192, 128, 2048, 1024),        # Trinity's band
+    (16384, 128, 4096, 1024),       # SmallThinker's: its limit is named
+    (8192, 128, 1024, 512),         # ONE 1024-tile: not measured
+    (8192, 128, 2560, 512),         # whole 512s, no whole 1024s
+    (8192, 128, 2000, 512),         # an edge at an angle
+    (8200, 128, 2048, 512),         # padded
+    (1024, 64, 512, 512),           # the rehearsal's size
+    (1 << 18, 128, 4096, 512),      # K and V whole would pass the limit
+])
+def test_banded_forward_tile_follows_the_backwards(s, d, window, want):
+    """Under a band the forward's default tile is the backward's: 1024
+    where the edges run as strips at it, the window is at least two such
+    tiles and the limit named from the shapes is one the call may name
+    (PERF.md section 6, PR 40); else 512. So one decision says how a
+    call's edges run in both kernels."""
+    from bigdl_tpu.ops import flash_attention as fa
+    x = jax.ShapeDtypeStruct((1, s, 2, d), jnp.bfloat16)
+    assert fa._fwd_tiles(x, x, x, None, None, True, window) == (want, want)
+    if s < 1 << 18:
+        assert fa._bwd_block(s, s, d, d, 2, True, window) == want
+    assert fa._fwd_tiles(x, x, x, 256, 256, True, window) == (256, 256)
 
 
 class TestFlashKernelDtypeContract:
@@ -740,9 +771,26 @@ class TestFlashBand:
         (48, 16, 47),       # one key short of the sequence
         (48, 16, 1),        # a query sees itself alone
         (512, 256, 300),    # the tiles that would halve the diagonal
+        # every case above keeps the all-masked loop (an edge that cuts
+        # tiles at an angle, a padded call or a tile of no whole lane
+        # groups); below, a window of whole lane-group tiles on a square
+        # unpadded call: both edge tiles as two strips, the tiles between
+        # unmasked. The first `window / block` query tiles have no lower
+        # edge and the last `window / block` key tiles no far one.
+        (1024, 256, 256),   # window == block: the two edge tiles are neighbours
+        (1024, 256, 512),   # one tile between them
+        (1536, 256, 1024),  # three; four of six key tiles have no far edge
+        (2048, 512, 1024),  # the cells' tile
     ])
     def test_band_matches_the_masked_core(self, s, block, window):
+        from bigdl_tpu.ops import flash_attention as fa
         from bigdl_tpu.ops.flash_attention import flash_attention_with_lse
+        from bigdl_tpu.telemetry import get_registry, instruments
+        strips = block % 256 == 0 and s % block == 0 and window % block == 0
+        assert fa._edge_strips(True, s, s, block, block, window) == strips
+        edges = instruments(get_registry()).flash_band_edges_total
+        before = {e: edges.labels(edges=e).value
+                  for e in ("strips", "masked")}
         rng = np.random.RandomState(s + window)
         q, k, v, g = (jnp.asarray(rng.randn(2, s, 2, 8), jnp.float32)
                       for _ in range(4))
@@ -758,8 +806,12 @@ class TestFlashBand:
             return out + vjp((g, gl))
 
         want = run(lambda *t: _banded_and_lse(*t, window))
-        for r, o, name in zip(want, run(kernel),
-                              ("o", "lse", "dq", "dk", "dv")):
+        got = run(kernel)
+        # one call, counted once by how its edges run
+        assert {e: edges.labels(edges=e).value - before[e]
+                for e in before} == {"strips": int(strips),
+                                     "masked": int(not strips)}
+        for r, o, name in zip(want, got, ("o", "lse", "dq", "dk", "dv")):
             np.testing.assert_allclose(np.asarray(o), np.asarray(r),
                                        rtol=2e-4, atol=2e-4,
                                        err_msg=f"{name} mismatch")
@@ -809,6 +861,165 @@ class TestFlashBand:
         _, dk, dv = vjp(g)
         assert np.isfinite(np.asarray(dk[:, :16])).all()
         assert np.isfinite(np.asarray(dv[:, :16])).all()
+
+    def test_strips_read_only_the_keys_they_can_see(self):
+        """The aligned twin: where the band's edges run as strips, a strip
+        reads neither the half of its edge tile that none of its rows can
+        see nor a tile outside the band. Query tile 4 of 256 under a
+        window of 512 meets key tile 2 (its lower edge), 3 and 4 (the
+        diagonal); key tile 1 meets query tile 1 (the diagonal), 2 and 3
+        (its far edge). The LAST row of a query tile sees nothing of its
+        lower-edge tile and still reads a strip of it: its p = 1 against
+        _NEG there is wiped by the next tile (``update``'s comment)."""
+        from bigdl_tpu.ops import flash_attention as fa
+        s, block, window = 1536, 256, 512
+        h = block // 2
+        assert fa._edge_strips(True, s, s, block, block, window)
+        rng = np.random.RandomState(1)
+        q, k, v, g = (jnp.asarray(rng.randn(1, s, 1, 8), jnp.float32)
+                      for _ in range(4))
+
+        def f(q_, k_, v_):
+            return flash_attention(q_, k_, v_, causal=True, block_q=block,
+                                   block_k=block, interpret=True,
+                                   window=window)
+
+        def poisoned(x, *spans):
+            for lo, hi in spans:
+                x = x.at[:, lo:hi].set(jnp.nan)
+            return x
+
+        want = f(q, k, v)
+        # query rows, and the keys they may not read: below the band and
+        # the blind half of the lower-edge tile, the blind half of the
+        # diagonal tile and every tile above it
+        for rows, spans in (
+                (slice(4 * block, 4 * block + h),       # the upper strips
+                 ((0, 2 * block), (4 * block + h, s))),
+                (slice(4 * block + h, 5 * block),       # the lower strips
+                 ((0, 2 * block + h), (5 * block, s)))):
+            out, vjp = jax.vjp(f, q, poisoned(k, *spans),
+                               poisoned(v, *spans))
+            np.testing.assert_array_equal(np.asarray(out[:, rows]),
+                                          np.asarray(want[:, rows]))
+            assert np.isfinite(np.asarray(vjp(g)[0][:, rows])).all()
+        # the last row of a query tile: all of its lower-edge strip is
+        # masked for it, and it is the masked core's row all the same
+        ref = _banded_and_lse(q, k, v, window)[0]
+        last = np.arange(block - 1, s, block)
+        np.testing.assert_allclose(np.asarray(want)[:, last],
+                                   np.asarray(ref)[:, last],
+                                   rtol=2e-4, atol=2e-4)
+        # key rows, and the queries they may not read
+        for rows, spans in (
+                (slice(block, block + h),               # the first half
+                 ((0, block), (3 * block + h, s))),
+                (slice(block + h, 2 * block),           # the second half
+                 ((0, block + h), (4 * block, s)))):
+            _, vjp = jax.vjp(f, poisoned(q, *spans), k, v)
+            _, dk, dv = vjp(poisoned(g, *spans))
+            assert np.isfinite(np.asarray(dk[:, rows])).all()
+            assert np.isfinite(np.asarray(dv[:, rows])).all()
+
+    @staticmethod
+    def _five(case, monkeypatch):
+        """(jaxpr text digest, digest of o, LSE, dK and dV, then o, LSE,
+        dQ, dK and dV in float32) of one banded call, the digests as
+        ``test_a_call_without_a_band_is_the_code_it_was`` takes them."""
+        import hashlib
+        from bigdl_tpu.ops import flash_attention as fa
+        monkeypatch.setattr(fa, "keep", lambda value, name: value)
+        sq, sk, block, window, dtype, d = case
+        rng = np.random.RandomState(sq + sk + block + window)
+        q, g = (jnp.asarray(rng.randn(1, sq, 2, d), dtype) for _ in range(2))
+        k, v = (jnp.asarray(rng.randn(1, sk, 2, d), dtype) for _ in range(2))
+        gl = jnp.asarray(rng.randn(1, 2, sq), jnp.float32)
+
+        def run(q, k, v, g, gl):
+            out, vjp = jax.vjp(lambda *t: fa.flash_attention_with_lse(
+                *t, causal=True, block_q=block, block_k=block,
+                interpret=True, window=window), q, k, v)
+            return out + vjp((g, gl))
+
+        def digest(*arrays):
+            h = hashlib.sha256()
+            for t in arrays:
+                h.update(np.asarray(t, np.float32).tobytes())
+            return h.hexdigest()[:16]
+
+        text = str(jax.make_jaxpr(run)(q, k, v, g, gl))
+        o, lse, g_q, g_k, g_v = run(q, k, v, g, gl)
+        return (hashlib.sha256(text.encode()).hexdigest()[:16],
+                digest(o, lse, g_k, g_v)) + tuple(
+                    np.asarray(t, np.float32) for t in (o, lse, g_q, g_k, g_v))
+
+    @pytest.mark.parametrize("case,jaxpr,kept,dq", [
+        ((512, 512, 256, 300, "float32", 8), "5ad3aa81803cacec",
+         "a178ef237bd6e8b8", "f11a568ff61bf1ea"),   # an edge at an angle
+        ((520, 520, 256, 256, "float32", 8), "5ab138c93aea41d6",
+         "47d65ef89c1a89b0", "34e26d7799c996f9"),   # padded
+        ((512, 768, 256, 256, "float32", 8), "37c7319af7497a4a",
+         "8c31f05d2c497a82", "8066149e3fc8fdbe"),   # more keys than queries
+        ((1024, 1024, 128, 256, "bfloat16", 8), "40201c526585cde3",
+         "bbcbf703a2267246", "8aabde311e5b9c4e"),   # half a lane group
+    ])
+    def test_a_band_off_the_strips_is_the_code_it_was(self, case, jaxpr, kept,
+                                                      dq, monkeypatch):
+        """A banded call whose window is no whole number of its tiles, a
+        padded one, one that is not square and one whose tile's halves are
+        no whole lane groups keep the all-masked loop: the jaxpr and the
+        bits of all five results are those taken at the parent commit (git
+        09f11b7, jax 0.9.0; (sq, sk, tile, window, dtype, head))."""
+        import hashlib
+        from bigdl_tpu.ops import flash_attention as fa
+        sq, sk, block, window = case[:4]
+        assert not fa._edge_strips(True, sq, sk, block, block, window)
+        text, four, _, _, g_q, _, _ = self._five(case, monkeypatch)
+        assert (text, four) == (jaxpr, kept)
+        assert hashlib.sha256(g_q.tobytes()).hexdigest()[:16] == dq
+
+    @pytest.mark.parametrize("case,jaxpr,kept,dq,within", [
+        ((1024, 1024, 256, 512, "float32", 16), "cbd18f744e717a03",
+         "7cfdf97c4d0be00a", "32dd7ae864af7c45", 2 ** -22),
+        ((1024, 1024, 512, 512, "bfloat16", 16), "4c035afd4c138b4a",
+         "bab1b853e49d3d4c", "a45988034a0f1b66", 2 ** -8),
+        ((1536, 1536, 256, 1024, "bfloat16", 16), "9166232fca41c193",
+         "bb74c149b640ab3d", "52f0e7490b9daebe", 2 ** -8),
+    ])
+    def test_an_aligned_band_is_within_a_rounding_of_what_it_gave(
+            self, case, jaxpr, kept, dq, within, monkeypatch):
+        """The digests are the PARENT's (git 09f11b7, the all-masked loop,
+        jax 0.9.0), and they are what this tree gives once the strips are
+        refused: text and all five results, checked first, so the
+        all-masked loop is still the parent's code for an aligned call too
+        and stands here for the parent. With the edges as strips every
+        result is within a rounding or two of its dtype of the parent's:
+        ``within`` times the array's largest value (float32 2^-22: read
+        2.7e-7 on a dQ of 1.7, 9.5e-7 on an LSE of 8; bf16 one place,
+        2^-8: read 3.9e-3 on a dQ of 1.3, 2.0e-3 on an o of 1.9). Where
+        the differences come from: the same visible pairs enter the
+        softmax, a masked pair gave p = 0 and is now not computed, but (1)
+        the forward takes the lower-edge tile LAST, in one online-softmax
+        step with the diagonal's, where the parent took it first and
+        alone: a row's maximum, sum and accumulator are rescaled in
+        another grouping, so o and the LSE move in the last place, and dK,
+        dV and dQ with the LSE they are computed from; (2) an edge tile's
+        ``ds k`` enters dQ's float32 accumulator as two sums over half the
+        keys each where it was one sum over the tile (alone it moves dQ by
+        2.5e-7 of 1.7 and leaves the other four the parent's bits)."""
+        import hashlib
+        from bigdl_tpu.ops import flash_attention as fa
+        sq, sk, block, window = case[:4]
+        assert fa._edge_strips(True, sq, sk, block, block, window)
+        got = self._five(case, monkeypatch)
+        assert got[0] != jaxpr
+        monkeypatch.setattr(fa, "_edge_strips", lambda *a: False)
+        was = self._five(case, monkeypatch)
+        assert was[:2] == (jaxpr, kept)
+        assert hashlib.sha256(was[4].tobytes()).hexdigest()[:16] == dq
+        for name, a, b in zip(("o", "lse", "dq", "dk", "dv"), got[2:],
+                              was[2:]):
+            assert np.max(np.abs(a - b)) <= within * np.max(np.abs(b)), name
 
     @pytest.mark.parametrize("case,jaxpr,kept,dq", [
         ((48, 16, True, "float32"), "adb161f16fe780ce", "d45df316299c977b",
@@ -886,9 +1097,13 @@ class TestFlashBand:
         x = jnp.asarray(_rand(1, s, e))
         ins = instruments(get_registry())
         band0 = ins.flash_attention_total.labels(form="band").value
+        masked0 = ins.flash_band_edges_total.labels(edges="masked").value
         jaxpr = jax.make_jaxpr(m.forward)(x).jaxpr
         assert ins.flash_attention_total.labels(form="band").value \
             == band0 + 1
+        # a window of 256 under the default 512-tile: the all-masked loop
+        assert ins.flash_band_edges_total.labels(edges="masked").value \
+            == masked0 + 1
         names = [e_.params["name"] for e_ in _eqns(jaxpr)
                  if e_.primitive.name == "pallas_call"]
         assert names == ["flash_band_fwd"]

@@ -731,7 +731,7 @@ def test_a_traced_step_counts_band_and_full_once_a_layer(monkeypatch):
     from bigdl_tpu.telemetry import get_registry, instruments
     _, cfg = harness.load_cell(CELL, rehearse=True)
     cfg = dict(builder.hf_config(cfg), head_dim=64, num_attention_heads=2,
-               num_key_value_heads=1, sliding_window_size=256)
+               num_key_value_heads=1, sliding_window_size=512)
     model = build_hybrid_lm(**smallthinker_lm_kwargs(cfg,
                                                      held_experts=(0, 1)))
     dec = builder.decoder_of(model)
@@ -745,6 +745,8 @@ def test_a_traced_step_counts_band_and_full_once_a_layer(monkeypatch):
     ins = instruments(get_registry())
     before = {f: ins.flash_attention_total.labels(form=f).value
               for f in ("band", "full")}
+    edges0 = {e: ins.flash_band_edges_total.labels(edges=e).value
+              for e in ("strips", "masked")}
 
     def f(p):
         return jnp.sum(functional_apply(dec, p, dec.buffer_tree(), x,
@@ -754,6 +756,11 @@ def test_a_traced_step_counts_band_and_full_once_a_layer(monkeypatch):
     rise = {f: ins.flash_attention_total.labels(form=f).value - before[f]
             for f in before}
     assert rise == {"band": 3, "full": 1}
+    # 1,024 tokens under the default 512-tile and a window of 512, one
+    # whole tile as the cell's 4,096 is eight: every banded layer runs its
+    # edges as strips, as the cell's do
+    assert {e: ins.flash_band_edges_total.labels(edges=e).value - edges0[e]
+            for e in edges0} == {"strips": 3, "masked": 0}
     for name in ("flash_band_fwd", "flash_band_bwd_dkv",
                  "flash_fwd", "flash_bwd_dkv"):
         assert f"name={name}" in text, name
