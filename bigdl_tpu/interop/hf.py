@@ -692,6 +692,95 @@ def smallthinker_lm_kwargs(config: Dict[str, Any], held_experts=None,
     return kwargs
 
 
+def lfm2_moe_pattern(layer_types, num_dense_layers: int) -> str:
+    """The ``HybridDecoder`` pattern of an ``lfm2_moe`` stack: each layer is
+    a mixer block (``C`` the gated short convolution, ``*`` full attention)
+    and a feed-forward block (``-`` dense in the leading
+    ``num_dense_layers``, ``E`` experts after them)."""
+    kinds = {"conv": "C", "full_attention": "*"}
+    bad = set(layer_types) - set(kinds)
+    if bad:
+        raise ValueError(f"unmapped layer_types {sorted(bad)} "
+                         f"({sorted(kinds)} are)")
+    return "".join(kinds[t] + ("-" if i < num_dense_layers else "E")
+                   for i, t in enumerate(layer_types))
+
+
+def lfm2_moe_lm_kwargs(config: Dict[str, Any], held_experts=None,
+                       train_router: bool = True,
+                       picks_by_token: bool = False) -> Dict[str, Any]:
+    """``models.hybrid.build_hybrid_lm`` kwargs for an HF ``lfm2_moe``
+    ``config.json`` dict (Liquid AI LFM2-8B-A1B / LFM2-24B-A2B): per
+    ``layer_types`` a double-gated short convolution of ``conv_L_cache``
+    taps (``nn.ShortConv``) or a full GQA attention layer with RMSNorm on
+    each head of q and k and rotation at ``rope_parameters.rope_theta``;
+    a dense SwiGLU feed-forward in the leading ``num_dense_layers`` and
+    after them sigmoid-routed SwiGLU experts (the top
+    ``num_experts_per_tok`` of score + bias, the picked scores over their
+    sum + 1e-6, times ``routed_scaling_factor``) with no shared expert; one
+    norm before every mixer at ``norm_eps``; the head TIED to the embedding
+    unless ``tie_word_embeddings`` says false.
+
+    ``held_experts``, ``train_router`` and ``picks_by_token`` as
+    ``joyai_llm_flash_lm_kwargs``; ``vocab_size`` may be a slice.
+
+    Not keys of the config but of the family's public model class, and so
+    fixed here: the order ``B, C, x`` of the in-projection's thirds, the q/k
+    norm ahead of the rotation, the head's tie. Refused rather than
+    guessed, each by its name: ``conv_bias`` true, ``norm_topk_prob``
+    false, a sliding window, rope scaling, a ``layer_types`` entry other
+    than ``conv`` / ``full_attention``."""
+    if config.get("conv_bias", False):
+        raise ValueError("conv_bias true (a bias on the short convolution "
+                         "and its projections) is not mapped")
+    if not config.get("norm_topk_prob", True):
+        raise ValueError("norm_topk_prob false (the picked scores not "
+                         "renormalised) is not mapped")
+    if config.get("sliding_window"):
+        raise ValueError("lfm2_moe sliding_window is not mapped")
+    rope = dict(config.get("rope_parameters") or {})
+    if config.get("rope_scaling") \
+            or rope.get("rope_type", "default") != "default":
+        raise ValueError("lfm2_moe rope scaling (rope_parameters.rope_type "
+                         "other than 'default') is not mapped")
+    types = list(config["layer_types"])
+    if len(types) != int(config["num_hidden_layers"]):
+        raise ValueError(f"layer_types has {len(types)} entries, "
+                         f"num_hidden_layers says "
+                         f"{config['num_hidden_layers']}")
+    dense = int(config.get("num_dense_layers", 0))
+    pattern = lfm2_moe_pattern(types, dense)
+    eps = float(config.get("norm_eps", 1e-5))
+    kwargs = dict(vocab_size=int(config["vocab_size"]),
+                  embed_dim=int(config["hidden_size"]), pattern=pattern,
+                  norm_eps=eps,
+                  tie_embeddings=bool(config.get("tie_word_embeddings",
+                                                 True)))
+    if "C" in pattern:
+        kwargs["short_conv"] = dict(kernel=int(config["conv_L_cache"]))
+    if "*" in pattern:
+        kwargs["attention"] = dict(
+            num_heads=int(config["num_attention_heads"]),
+            num_kv_heads=int(config["num_key_value_heads"]),
+            with_bias=False, qk_norm=True, qk_norm_eps=eps, rope=True,
+            rope_theta=float(rope.get("rope_theta",
+                                      config.get("rope_theta", 1e4))))
+    if "-" in pattern:
+        kwargs["mlp"] = dict(hidden_size=int(config["intermediate_size"]))
+    if "E" in pattern:
+        kwargs["moe"] = dict(
+            hidden_size=int(config["moe_intermediate_size"]),
+            n_experts=int(config["num_experts"]),
+            k=int(config["num_experts_per_tok"]), activation="swiglu",
+            dispatch="held",
+            held=None if held_experts is None else tuple(held_experts),
+            bias=False,
+            route_scale=float(config.get("routed_scaling_factor", 1.0)),
+            renorm_eps=1e-6, train_router=train_router,
+            pick_rows=int(config["vocab_size"]) if picks_by_token else 0)
+    return kwargs
+
+
 # ------------------------------------------------------------------- export
 
 def export_gpt2_state_dict(model: Module) -> Dict[str, np.ndarray]:

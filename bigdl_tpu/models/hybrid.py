@@ -64,12 +64,20 @@ def build_hybrid_lm(vocab_size: int, embed_dim: int, pattern: str,
                     mlp: Optional[dict] = None, post_norm: bool = False,
                     embed_scale: Optional[float] = None,
                     latent_attention: Optional[dict] = None,
-                    mtp: Optional[dict] = None) -> nn.Sequential:
-    """1-based token ids (N, T) -> the fused-CE tail: train with
-    ``nn.FusedLMHeadCriterion``; eval/predict see log-probs (N, T, vocab).
+                    mtp: Optional[dict] = None,
+                    short_conv: Optional[dict] = None,
+                    tie_embeddings: bool = False) -> nn.Sequential:
+    """Causal LM over ``nn.HybridDecoder``, head untied or tied
+    (``tie_embeddings``): 1-based token ids (N, T) -> the fused-CE tail.
+
+    Train with ``nn.FusedLMHeadCriterion``; eval/predict see log-probs
+    (N, T, vocab).
     ``vocab_size`` may be this chip's slice of a sharded vocabulary: the
     embedding, the head and the loss are then over the slice. The head is
-    its own matrix (the family does not tie it to the embedding). Expert
+    its own matrix unless ``tie_embeddings``: then ONE (vocab_size,
+    embed_dim) matrix serves the lookup and the fused CE
+    (``nn.TiedLMHead``, as ``models.transformer.build_lm`` ties it) and
+    its gradient is the sum of both uses. Expert
     layers that pick by token id (``moe={"pick_rows": vocab_size, ...}``)
     are told the ids of the stream.
     ``embed_scale`` multiplies the embedding's rows on their way into the
@@ -85,16 +93,18 @@ def build_hybrid_lm(vocab_size: int, embed_dim: int, pattern: str,
     groups = dict(mamba=mamba, moe=moe, attention=attention,
                   norm_eps=norm_eps, window_attention=window_attention,
                   mlp=mlp, post_norm=post_norm,
-                  latent_attention=latent_attention)
+                  latent_attention=latent_attention, short_conv=short_conv)
     if mtp is not None:
         m = _LMWithMTP()
     else:
         m = _LM() if (moe or {}).get("pick_rows") else nn.Sequential()
-    m.add(nn.LookupTable(vocab_size, embed_dim))
+    embed = nn.LookupTable(vocab_size, embed_dim)
+    m.add(embed)
     if embed_scale is not None:
         m.add(nn.MulConstant(float(embed_scale)))
     m.add(nn.HybridDecoder(pattern, embed_dim, **groups))
-    m.add(nn.LMHead(embed_dim, vocab_size, with_bias=False))
+    m.add(nn.TiedLMHead(embed) if tie_embeddings
+          else nn.LMHead(embed_dim, vocab_size, with_bias=False))
     if mtp is not None:     # after the chain: modules() in forward order
         m.mtp = nn.MTPModule(
             embed_dim, nn.HybridDecoder(pattern[-2:], embed_dim, **groups),

@@ -78,5 +78,6 @@ from bigdl_tpu.nn.attention import (
     TransformerEncoder,
 )
 from bigdl_tpu.nn.mamba import Mamba2
+from bigdl_tpu.nn.short_conv import ShortConv
 from bigdl_tpu.nn.hybrid import (GatedMLP, HybridBlock, HybridDecoder,
                                  MTPModule)

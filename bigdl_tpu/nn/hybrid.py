@@ -11,7 +11,8 @@ experts (``parallel.expert.MoE``), ``*`` causal self-attention
 keyword group (the sliding-window layers of a model that mixes them with
 full ones: another window, rotation or head count), ``L`` causal latent
 self-attention (``nn.LatentAttention``), ``-`` a dense gated MLP
-(``GatedMLP``), ``R`` a mixture of experts whose ROUTER reads the stream
+(``GatedMLP``), ``C`` a double-gated short convolution (``nn.ShortConv``),
+``R`` a mixture of experts whose ROUTER reads the stream
 that entered the PRECEDING block (a layer that routes from its input,
 ahead of its attention: ``x <- x + experts(RMSNorm(x); routed by h)``, ``h``
 what the attention block before it was given, not normed). The mixers are
@@ -93,17 +94,18 @@ class HybridBlock(Module):
 
 
 class HybridDecoder(Module):
-    """The stack a pattern string describes, with a final RMSNorm.
+    """The stack a pattern string describes (kinds ``ME*W-LRC``; ``C`` a
+    short convolution), with a final RMSNorm.
 
     ``mamba``, ``moe``, ``attention``, ``window_attention``,
-    ``latent_attention`` and ``mlp`` are the keyword arguments of
-    ``nn.Mamba2(embed_dim, ...)``, ``MoE(embed_dim, ...)``,
+    ``latent_attention``, ``mlp`` and ``short_conv`` are the keyword
+    arguments of ``nn.Mamba2(embed_dim, ...)``, ``MoE(embed_dim, ...)``,
     ``nn.MultiHeadAttention(embed_dim, ..., causal=True)`` for the ``*``
-    and for the ``W`` blocks, ``nn.LatentAttention(embed_dim, ...)`` and
-    ``GatedMLP(embed_dim, ...)``; a kind the pattern does not use needs
-    none."""
+    and for the ``W`` blocks, ``nn.LatentAttention(embed_dim, ...)``,
+    ``GatedMLP(embed_dim, ...)`` and ``nn.ShortConv(embed_dim, ...)``; a
+    kind the pattern does not use needs none."""
 
-    KINDS = "ME*W-LR"
+    KINDS = "ME*W-LRC"
 
     #: as ``TransformerEncoder.remat_blocks``: ``Optimizer.set_remat(
     #: "block")`` sets it, and each block then runs under ``jax.checkpoint``
@@ -116,7 +118,8 @@ class HybridDecoder(Module):
     #: of 128 + 64 over 128: its five low-rank projections run again,
     #: measured no dearer than holding them), a dense block's
     #: gate, up and down outputs (28.7 KB at hidden 6,144 over 2,048), a
-    #: Mamba-2 block's in-projection output (20.6 KB at 10,304 wide), an
+    #: Mamba-2 block's in-projection output (20.6 KB at 10,304 wide), a
+    #: convolution block's in-projection output (12.3 KB at 3 x 2,048), an
     #: expert block's routing tables, routed output and its shared
     #: expert's float32 first products (128 B at top-8, 2 bytes a channel,
     #: 4 bytes a hidden unit or 8 for SwiGLU); norms, rotation, gates, the
@@ -129,7 +132,7 @@ class HybridDecoder(Module):
     def __init__(self, pattern: str, embed_dim: int, mamba=None, moe=None,
                  attention=None, norm_eps: float = 1e-5,
                  window_attention=None, mlp=None, post_norm: bool = False,
-                 latent_attention=None):
+                 latent_attention=None, short_conv=None):
         super().__init__()
         bad = set(pattern) - set(self.KINDS)
         if bad or not pattern:
@@ -153,6 +156,9 @@ class HybridDecoder(Module):
                 mixer = GatedMLP(embed_dim, **mlp)
             elif kind == "L":
                 mixer = LatentAttention(embed_dim, **latent_attention)
+            elif kind == "C":
+                from bigdl_tpu.nn.short_conv import ShortConv
+                mixer = ShortConv(embed_dim, **(short_conv or {}))
             else:
                 mixer = MultiHeadAttention(
                     embed_dim, causal=True,

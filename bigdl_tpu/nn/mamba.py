@@ -28,6 +28,7 @@ import jax.numpy as jnp
 
 from bigdl_tpu.nn import initialization as init
 from bigdl_tpu.nn.module import TensorModule
+from bigdl_tpu.nn.short_conv import causal_depthwise_conv
 from bigdl_tpu.ops.precision import match_compute
 from bigdl_tpu.ops.remat import MAMBA_IN_PROJ, keep
 from bigdl_tpu.ops.ssd_scan import ssd_scan
@@ -81,14 +82,11 @@ class Mamba2(TensorModule):
                                                   d_inner))
 
     def _conv(self, xbc):
-        """Causal depthwise convolution along the sequence: position t
-        reads t-k+1 .. t, zeros before the start."""
-        k = self.conv_kernel
-        length = xbc.shape[1]
-        w = self.conv_weight.astype(jnp.float32)
-        padded = jnp.pad(xbc.astype(jnp.float32), [(0, 0), (k - 1, 0), (0, 0)])
-        out = sum(padded[:, j:j + length] * w[:, j] for j in range(k))
-        out = out + self.conv_bias.astype(jnp.float32)
+        """The causal depthwise convolution (``nn.short_conv``'s: position
+        t reads t-k+1 .. t, zeros before the start) under the family's bias
+        and SiLU."""
+        out = causal_depthwise_conv(xbc, self.conv_weight) \
+            + self.conv_bias.astype(jnp.float32)
         return jax.nn.silu(out).astype(xbc.dtype)
 
     def update_output(self, input):

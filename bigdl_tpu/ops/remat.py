@@ -39,6 +39,8 @@ name                    value, producer                                  bytes a
 ``MLP_PROJ``            ``nn.GatedMLP``'s gate, up and down outputs      ``4 * hidden + 2 E``
 ``MAMBA_IN_PROJ``       ``nn.Mamba2``'s in-projection output             ``2 * (2 * d_inner + 2 * groups * state
                         ``[z | xBC | dt]``                               + heads)``
+``SHORT_CONV_IN_PROJ``  ``nn.ShortConv``'s in-projection output          ``6 E``
+                        ``[B | C | x]``
 ``MOE_SHARED_HID``      the shared expert's float32 first products       ``4 * shared_hidden``, twice for
                         (``MoE._hidden``), before the activation         SwiGLU
 ======================  ===============================================  ==========================================
@@ -83,13 +85,15 @@ FLASH_OUT = "flash_out"
 ATTN_PROJ = "attn_proj"
 MLP_PROJ = "mlp_proj"
 MAMBA_IN_PROJ = "mamba_in_proj"
+SHORT_CONV_IN_PROJ = "short_conv_in_proj"
 MOE_SHARED_HID = "moe_shared_hid"
 
 #: what block remat keeps (module docstring), dearest to recompute a byte
 #: held first: the order to drop names in, from the end, on a chip that
 #: runs out
 BLOCK_SAVED_NAMES = (MOE_ROUTE_TABLES, MOE_ROUTED_OUT, FLASH_OUT, ATTN_PROJ,
-                     MLP_PROJ, MAMBA_IN_PROJ, MOE_SHARED_HID)
+                     MLP_PROJ, MAMBA_IN_PROJ, SHORT_CONV_IN_PROJ,
+                     MOE_SHARED_HID)
 
 
 def block_remat_policy():

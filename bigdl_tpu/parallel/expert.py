@@ -161,6 +161,8 @@ class MoE(Module):
     through it) and keeps its picks. ``pick_rows`` (the vocabulary's size;
     ``dispatch="held"``) adds the buffer ``pick_table`` (pick_rows, k) and
     picks from it by token id (module docstring); it is zeros until filled.
+    ``renorm_eps`` stands under the sum of the picked sigmoid scores
+    (1e-6 in the families whose class writes one).
     """
 
     def __init__(self, input_size: int, hidden_size: int, n_experts: int,
@@ -169,7 +171,8 @@ class MoE(Module):
                  dispatch: str = "sort", held=None, bias: bool = True,
                  shared_hidden: int = 0, route_scale: float = 1.0,
                  train_router: bool = True, pick_rows: int = 0,
-                 score: str = "sigmoid", router_input: str = "own"):
+                 score: str = "sigmoid", router_input: str = "own",
+                 renorm_eps: float = 1e-20):
         super().__init__()
         if dispatch not in ("sort", "scatter", "einsum", "held"):
             raise ValueError(f"dispatch must be 'sort', 'scatter', "
@@ -209,6 +212,7 @@ class MoE(Module):
         self.train_router = train_router
         self.score = score
         self.router_input = router_input
+        self.renorm_eps = renorm_eps
         self.input_size = input_size
         self.hidden_size = hidden_size
         self.n_experts = n_experts
@@ -304,7 +308,7 @@ class MoE(Module):
         w = keep(jnp.take_along_axis(scores, picked, axis=-1),
                  MOE_ROUTE_TABLES)
         if self.score == "sigmoid":
-            w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+            w = w / (jnp.sum(w, axis=-1, keepdims=True) + self.renorm_eps)
         else:
             w = jax.nn.softmax(w, axis=-1)
         return picked, w * self.route_scale

@@ -208,6 +208,13 @@ METRIC_SPECS: List[MetricSpec] = [
                "else). Counted once per eager call / once per TRACE under "
                "jit, as bigdl_moe_dispatch_total: which form each compiled "
                "program holds, not per-step traffic.", ("form",)),
+    MetricSpec("bigdl_short_conv_total", "counter",
+               "Double-gated short convolutions (nn.ShortConv) by the form "
+               "their local part took: the split, both gates and the "
+               "causal depthwise convolution (form label: xla, shifted "
+               "multiply-adds that XLA fuses; the only one there is). "
+               "Counted once per eager call / once per TRACE under jit, as "
+               "bigdl_ssd_scan_total.", ("form",)),
     MetricSpec("bigdl_moe_grouped_total", "counter",
                "Grouped products of held expert layers (MoE(dispatch="
                "'held')) by form (form label: kernel, the Mosaic kernels of "
@@ -444,6 +451,12 @@ SCOPE_SPECS: List[ScopeSpec] = [
     ScopeSpec("ssd_scan", "ops/ssd_scan.py ssd_scan (and its backward "
               "rule)", "The Mamba-2 state-space scan, kernel or chunked "
               "form, with the carry between chunks.", kernels=("ssd_",)),
+    ScopeSpec("short_conv_proj", "nn/short_conv.py ShortConv",
+              "The in- and out-projection products."),
+    ScopeSpec("short_conv_local", "nn/short_conv.py ShortConv",
+              "What is no projection: the split of the in-projection's "
+              "output, the two gates and the causal depthwise convolution "
+              "between them."),
     ScopeSpec("mlp", "nn/hybrid.py GatedMLP; nn/attention.py "
               "TransformerEncoderLayer._ffn",
               "A dense feed-forward: its two or three products and the "

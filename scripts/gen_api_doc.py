@@ -22,6 +22,7 @@ MODS = [
     ("bigdl_tpu.optim", "Optimizers, triggers, validation"),
     ("bigdl_tpu.parallel", "Distribution (mesh, DP/TP/PP/SP/EP)"),
     ("bigdl_tpu.models.transformer", "Causal-LM builder"),
+    ("bigdl_tpu.models.hybrid", "Causal-LM builder over a pattern decoder"),
     ("bigdl_tpu.models.generation", "Autoregressive decoding"),
     ("bigdl_tpu.ops.flash_attention", "Flash attention (Pallas)"),
     ("bigdl_tpu.ops.lm_head_ce", "Fused LM-head cross-entropy"),

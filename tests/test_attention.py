@@ -1378,3 +1378,45 @@ class TestFlashForwardPast16kKeys:
             jax.jit(lambda q, k, v: fa.flash_attention(
                 q, k, v, causal=True, window=window, interpret=False)
             ).lower(spec, spec, spec).compile()
+
+    def test_8192_keys_of_head_64_at_batch_4_compile_for_the_v5e(
+            self, one_chip, monkeypatch, request):
+        """Forward and backward of the LFM2 cell's attention core at its
+        shape (4 x 32 heads x 8,192 x 64, bf16: the kernels see (128, 8,192,
+        64)) through the chip's own compiler. Both run at 1024-tiles: the
+        forward names 23 MiB where Qwen's 2,048 keys of the same head name
+        none, the backward 46 of the 100 MiB it may; without the forward's
+        named limit the chip refuses it."""
+        import re
+        from bigdl_tpu.ops import flash_attention as fa
+        from jax.experimental.compilation_cache import compilation_cache
+        assert fa._fwd_block(8192, 8192, 64, 64, 2) == 1024
+        assert fa._bwd_block(8192, 8192, 64, 64, 2, True, None) == 1024
+        assert fa._fwd_vmem(2048, 64, 64, 1024, 1024, 2) is None
+        assert 20 << 20 < fa._fwd_vmem(8192, 64, 64, 1024, 1024, 2) < 32 << 20
+        assert 40 << 20 < fa._bwd_vmem(8192, 64, 64, 1024, 1024, 2) \
+            < fa._VMEM_MOST
+        cached = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        request.addfinalizer(lambda: jax.config.update(
+            "jax_enable_compilation_cache", cached))
+        spec = jax.ShapeDtypeStruct((4, 8192, 32, 64), jnp.bfloat16,
+                                    sharding=one_chip)
+
+        def step(q, k, v):
+            def loss(q, k, v):
+                return jnp.sum(fa.flash_attention(
+                    q, k, v, causal=True, interpret=False
+                ).astype(jnp.float32))
+            return jax.value_and_grad(loss, (0, 1, 2))(q, k, v)
+
+        text = jax.jit(step).lower(spec, spec, spec).compile().as_text()
+        assert set(re.findall(r"flash_[a-z_]*", text)) >= {
+            "flash_fwd", "flash_bwd_dkv"}
+        assert "bf16[128,8192,64]" in text
+        monkeypatch.setattr(fa, "_fwd_vmem", lambda *a: None)
+        with pytest.raises(Exception, match="vmem"):
+            jax.jit(lambda q, k, v: fa.flash_attention(
+                q, k, v, causal=True, interpret=False)
+            ).lower(spec, spec, spec).compile()

@@ -65,7 +65,8 @@ def flash_in_the_interpreter(monkeypatch):
 @pytest.mark.parametrize("kind,post_norm,flash", [
     ("M", False, False), ("E", False, False), ("E", True, False),
     ("*", False, False), ("*", True, True), ("W", True, False),
-    ("W", True, True), ("-", True, False), ("-", False, False)])
+    ("W", True, True), ("-", True, False), ("-", False, False),
+    ("C", False, False)])
 def test_keeping_changes_neither_the_loss_nor_a_gradient(
         kind, post_norm, flash, monkeypatch):
     """Two blocks of one kind, float32: the loss and every gradient leaf
@@ -175,7 +176,14 @@ MET = {"trinity-mini-train-s8192": {
        "nemotron-3-nano-30b-a3b-train-s8192": {
            remat.MAMBA_IN_PROJ: 1, remat.MOE_ROUTE_TABLES: 5,
            remat.MOE_ROUTED_OUT: 1, remat.MOE_SHARED_HID: 1,
-           remat.ATTN_PROJ: 4, remat.FLASH_OUT: 2}}
+           remat.ATTN_PROJ: 4, remat.FLASH_OUT: 2},
+       # four convolution mixers' in-projections, a dense block of three,
+       # one attention block (no gate), four expert blocks whose picks are
+       # a table's (four tables each, no top-k) and have no shared expert
+       "lfm2-24b-a2b-train-s8192": {
+           remat.SHORT_CONV_IN_PROJ: 4, remat.MLP_PROJ: 3,
+           remat.ATTN_PROJ: 4, remat.FLASH_OUT: 2,
+           remat.MOE_ROUTE_TABLES: 20, remat.MOE_ROUTED_OUT: 4}}
 
 
 CELLS = {"trinity-mini-train-s8192": "W-WE*E",
@@ -238,7 +246,7 @@ def _tags_and_counts(cell_name):
 def test_every_tag_is_on_the_list_and_every_listed_name_is_tagged(
         flash_in_the_interpreter):
     tagged = set()
-    for cell_name in CELLS:
+    for cell_name in MET:
         tagged |= _tags_and_counts(cell_name)[0]
     assert tagged == set(remat.BLOCK_SAVED_NAMES)
     with pytest.raises(ValueError):
@@ -247,7 +255,7 @@ def test_every_tag_is_on_the_list_and_every_listed_name_is_tagged(
         remat.keep(jnp.ones(()), remat.REMAT_SAVED_NAMES[0])
 
 
-@pytest.mark.parametrize("cell_name", sorted(CELLS))
+@pytest.mark.parametrize("cell_name", sorted(MET))
 def test_the_counter_reads_the_tags_a_trace_met(cell_name,
                                                 flash_in_the_interpreter):
     """``bigdl_remat_kept_total{name}`` rises once a tag a trace: the

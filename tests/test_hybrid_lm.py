@@ -530,6 +530,69 @@ def test_attention_with_its_own_head_dim_and_no_positional_term():
     _close(att.forward(u), reference.attention(p, "a.", u, CFG))
 
 
+def _conv_stack(tie, seed=13):
+    from bigdl_tpu.models.hybrid import build_hybrid_lm
+    manual_seed(seed)
+    np.random.seed(seed)
+    return build_hybrid_lm(
+        48, 32, "C-*ECE", tie_embeddings=tie, short_conv=dict(kernel=3),
+        mlp=dict(hidden_size=40),
+        attention=dict(num_heads=4, num_kv_heads=2, with_bias=False,
+                       qk_norm=True, rope=True),
+        moe=dict(hidden_size=16, n_experts=4, k=2, activation="swiglu",
+                 dispatch="held", bias=False))
+
+
+@pytest.mark.parametrize("tie", [False, True])
+def test_the_pattern_decoder_under_a_tied_or_an_untied_head(tie):
+    """``build_hybrid_lm(tie_embeddings=)``: ONE (V, E) matrix for the
+    lookup and the head, or two; either way eval gives log-probabilities
+    over the vocabulary and the decoder is the same stack."""
+    lm = _conv_stack(tie)
+    matrices = [leaf for leaf in jax.tree_util.tree_leaves(
+        lm.parameter_tree()) if leaf.shape == (48, 32)]
+    assert len(matrices) == (1 if tie else 2)
+    head = list(lm.modules())[-1]
+    assert isinstance(head, nn.TiedLMHead if tie else nn.LMHead)
+    if tie:
+        assert head.embed_ref is lm[0]
+    ids = jnp.asarray(_rng(1).integers(1, 49, (2, 11)), jnp.float32)
+    logp = lm.evaluate_mode().forward(ids)
+    assert logp.shape == (2, 11, 48)
+    np.testing.assert_allclose(np.asarray(jnp.exp(logp).sum(-1)), 1.0,
+                               rtol=1e-5)
+    assert _digest(_conv_stack(tie).parameter_tree()[
+        "1"]) == _digest(lm.parameter_tree()["1"])
+
+
+def test_a_convolution_block_is_a_kind_of_the_pattern_decoder():
+    """Kind ``C``: ``x + ShortConv(norm(x))`` from the ``short_conv``
+    group; a batch above one runs each record as it runs alone, through
+    every kind of block, with block remat on or off."""
+    assert "C" in nn.HybridDecoder.KINDS
+    dec = nn.HybridDecoder("C", 16, short_conv=dict(kernel=4))
+    assert isinstance(dec.layer0.mixer, nn.ShortConv)
+    assert dec.layer0.mixer.kernel == 4
+    assert nn.HybridDecoder("C", 16).layer0.mixer.kernel == 3
+    x = _normal(_rng(2), 1, 9, 16)
+    blk = dec.layer0
+    _close(dec.stream(x), x + blk.mixer.forward(blk.norm.forward(x)),
+           tol=1e-6)
+    with pytest.raises(ValueError):
+        nn.HybridDecoder("CX", 16)
+    stack = _conv_stack(True)[1]
+    x = _normal(_rng(3), 3, 10, 32)
+    params = stack.parameter_tree()
+    outs = {}
+    for remat in (False, True):
+        stack.remat_blocks = remat
+        outs[remat] = _apply(stack, params, x)
+        for i in range(3):
+            _close(outs[remat][i], _apply(stack, params, x[i:i + 1])[0],
+                   tol=1e-5)
+    _close(outs[True], outs[False], tol=1e-6)
+
+
 # ------------------------------------------------------------------ the model
 
 @pytest.fixture(scope="module")

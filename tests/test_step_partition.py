@@ -95,6 +95,18 @@ CASES = [
      "moe_route_ahead", "recompute"),
     ("jit(step)/jvp(Sequential)/HybridDecoder/HybridBlock/MoE/"
      "moe_route_ahead/sort", "moe_route_ahead", "forward"),
+    # the convolution mixer's two scopes; neither is the convnets' ``conv``
+    ("jit(step)/jvp(_LM)/HybridDecoder/HybridBlock/ShortConv/"
+     "short_conv_proj/dot_general", "short_conv_proj", "forward"),
+    (_BLOCK + "HybridBlock/ShortConv/short_conv_local/pad",
+     "short_conv_local", "backward"),
+    (_BLOCK + "rematted_computation/HybridBlock/ShortConv/short_conv_local/"
+     "mul", "short_conv_local", "recompute"),
+    (_BLOCK + "rematted_computation/HybridBlock/ShortConv/short_conv_proj/"
+     "dot_general", "short_conv_proj", "recompute"),
+    # ... and what the mixer does outside them is no layer's
+    ("jit(step)/jvp(_LM)/HybridDecoder/HybridBlock/ShortConv/mul",
+     "unattributed", "forward"),
 ]
 
 
@@ -127,11 +139,14 @@ CELLS = {
     "smallthinker-21b-a3b-train-s16384":
         {"attn_proj", "attn_core", "moe_route_ahead", "moe_experts", "norm",
          "embed", "lm_head_ce"},
+    "lfm2-24b-a2b-train-s8192":
+        {"short_conv_proj", "short_conv_local", "attn_proj", "attn_core",
+         "mlp", "moe_route", "moe_experts", "norm", "embed", "lm_head_ce"},
 }
 UPDATE = {"param_cast", "grad_clip", "optim_update"}
 REMAT = {"nemotron-3-nano-30b-a3b-train-s8192", "trinity-mini-train-s8192",
          "joyai-llm-flash-train-s8192",
-         "smallthinker-21b-a3b-train-s16384"}
+         "smallthinker-21b-a3b-train-s16384", "lfm2-24b-a2b-train-s8192"}
 
 
 def _step_text(cell_name):
