@@ -49,7 +49,12 @@ absent experts would add is the other chips' to compute and is not stood
 in for. The local picks are sorted by expert and the held experts run as
 one grouped product over the sorted rows (``_grouped_rows``): no capacity,
 no dropped token, and the work follows the rows that really landed here,
-not the static bound ``T * min(k, len(held))``. On a TPU, for experts
+not the static bound ``T * min(k, len(held))``. Between the picks and the
+tables that product reads there is no per-pick gather or scatter (on a TPU
+XLA runs those an element at a time, 9 ns each): the picked scores, each
+pick's local id and the rows an expert come from comparing and selecting,
+and ONE stable sort by local id carries each pick's index and its combine
+weight along as payloads (PERF.md section 6, PR 42). On a TPU, for experts
 without biases whose widths are whole lane tiles, the products are the
 Mosaic kernels of ``ops/grouped_matmul.py`` (``takes_kernel``): row tiles
 that pad no run, an expert's matrices read once a pass, its float32
@@ -121,6 +126,21 @@ def _inject_bwd(_, g):
 
 
 inject_loss.defvjp(_inject_fwd, _inject_bwd)
+
+
+@jax.custom_jvp
+def _in_order(weight, order, sorted_weight):
+    """``weight[order]`` where a sort has already carried ``weight`` along
+    as its payload (``sorted_weight``): the value costs no gather, and the
+    cotangent goes back to pick order through ``order``, a row once."""
+    return sorted_weight
+
+
+@_in_order.defjvp
+def _in_order_jvp(primals, tangents):
+    _, order, sorted_weight = primals
+    return sorted_weight, tangents[0].at[order].get(
+        unique_indices=True, mode="promise_in_bounds")
 
 
 def _relu2(hid):
@@ -303,10 +323,15 @@ class MoE(Module):
             _, picked = jax.lax.top_k(
                 scores + jax.lax.stop_gradient(
                     self.select_bias.astype(jnp.float32)), self.k)
-        # kept across a block's rematerialisation (ops.remat), as float32
+        # kept across a block's rematerialisation (ops.remat)
         picked = keep(picked, MOE_ROUTE_TABLES)
-        w = keep(jnp.take_along_axis(scores, picked, axis=-1),
-                 MOE_ROUTE_TABLES)
+        # each pick's score by compare-and-select over the experts: one
+        # pass over ``scores`` (its transpose a dense select), no gather
+        experts = jnp.arange(self.n_experts, dtype=jnp.int32)
+        w = keep(jnp.stack(
+            [jnp.sum(jnp.where(picked[:, j:j + 1] == experts, scores, 0.0),
+                     axis=-1) for j in range(self.k)], axis=-1),
+            MOE_ROUTE_TABLES)
         if self.score == "sigmoid":
             w = w / (jnp.sum(w, axis=-1, keepdims=True) + self.renorm_eps)
         else:
@@ -332,21 +357,33 @@ class MoE(Module):
             picked, weight = self._route(
                 x if routed_from is None else routed_from.reshape(-1, d))
             # local id of each pick: position of its expert in ``held``,
-            # n where the expert lives on another chip
-            local_of = np.full((e,), n, np.int32)
-            local_of[list(self.held)] = np.arange(n, dtype=np.int32)
-            lid = jnp.asarray(local_of)[picked.reshape(-1)]      # (T*k,)
+            # n where the expert lives on another chip; the counts an
+            # expert and the ids by comparing, as the picked scores
+            flat = picked.reshape(-1)                            # (T*k,)
+            if self.held == tuple(range(e)):
+                lid = flat
+            else:
+                lid = jnp.full(flat.shape, n, jnp.int32)
+                for j, h in enumerate(self.held):
+                    lid = jnp.where(flat == h, j, lid)
+            counts = keep(jnp.sum(
+                lid == jnp.arange(n, dtype=jnp.int32)[:, None], axis=1,
+                dtype=jnp.int32), MOE_ROUTE_TABLES)
             rows = t * min(k, n)            # the picks that CAN land here
-            # the routing's tables are kept across a block's
-            # rematerialisation (``_route`` keeps the picks and their
-            # scores): the top-k, the sort, the count and the gathers run
-            # once; the router's product and the weights' arithmetic twice
-            order = keep(jnp.argsort(lid, stable=True)[:rows],
-                         MOE_ROUTE_TABLES)
-            counts = keep(jnp.bincount(lid, length=n + 1)[:n]
-                          .astype(jnp.int32), MOE_ROUTE_TABLES)
-            tok = (order // k).astype(jnp.int32)
-            gate = keep(weight.reshape(-1)[order], MOE_ROUTE_TABLES)
+            # ONE stable sort by local id carries each pick's index and
+            # its combine weight along: the landed picks first, grouped by
+            # expert, in pick order. The routing's tables are kept across
+            # a block's rematerialisation (``_route`` keeps the picks and
+            # their scores): the top-k, the sort and the count run once;
+            # the router's product and the weights' arithmetic twice
+            weight = weight.reshape(-1)
+            _, order, gate = jax.lax.sort(
+                (lid, jnp.arange(t * k, dtype=jnp.int32),
+                 jax.lax.stop_gradient(weight)), num_keys=1)
+            order = keep(order[:rows], MOE_ROUTE_TABLES)
+            gate = keep(_in_order(weight, order, gate[:rows]),
+                        MOE_ROUTE_TABLES)
+            tok = order // k
         with jax.named_scope("moe_experts"):
             routed = {p: v for p, v in self._parameters.items()
                       if p in ("w1", "wg", "b1", "w2", "b2")}
