@@ -12,6 +12,23 @@ term, a per-head recurrent state of ``head_dim x state_size``.
     y = GroupRMSNorm_G(y * silu(z)) * w
     out = y W_out
 
+Two forms of what lies between the two products, chosen by what
+``update_output`` can see (``ops.mamba_local.takes_kernel``: backend, dtype,
+shapes) and by nothing else, counted by ``bigdl_mamba_local_total{form}``:
+
+- ``form="kernel"``: on a TPU, bf16, where ``G * N`` is whole 128-lane tiles
+  that divide ``d_inner``, a norm group is whole lane tiles and 128 divides
+  the length (the Nemotron cell: 4,096 / 1,024 / 512 over 8,192 tokens and
+  its reference check's 1,024). ``ops.mamba_local.around_scan``: the
+  convolution, bias, SiLU and split in one Mosaic call that reads its columns
+  of ``zxbcdt`` where they lie and writes x, B and C; the D skip, gate, group
+  norm and weight in another; their backwards in two more behind ONE
+  ``custom_vjp`` around the scan, so ``zxbcdt``'s cotangent is one buffer
+  written once.
+- ``form="xla"``: everything else, a CPU and the tier-1 sizes (an inner
+  width of 32) included: the ``jax.numpy`` lines of ``_around_scan``, which
+  XLA fuses.
+
 Parameter names and layouts follow the public modelling code
 (``in_proj_weight`` (d_in_proj, E) and ``out_proj_weight`` (E, d_inner) in
 Linear's (out, in) layout, ``conv_weight`` (conv_dim, k)). As in every
@@ -29,6 +46,7 @@ import jax.numpy as jnp
 from bigdl_tpu.nn import initialization as init
 from bigdl_tpu.nn.module import TensorModule
 from bigdl_tpu.nn.short_conv import causal_depthwise_conv
+from bigdl_tpu.ops import mamba_local
 from bigdl_tpu.ops.precision import match_compute
 from bigdl_tpu.ops.remat import MAMBA_IN_PROJ, keep
 from bigdl_tpu.ops.ssd_scan import ssd_scan
@@ -90,10 +108,8 @@ class Mamba2(TensorModule):
         return jax.nn.silu(out).astype(xbc.dtype)
 
     def update_output(self, input):
-        h, p, g, n = (self.num_heads, self.head_dim, self.n_groups,
-                      self.state_size)
-        d_inner, conv_dim = self.d_inner, self.conv_dim
-        bsz, length, _ = input.shape
+        from bigdl_tpu.telemetry import get_registry, instruments
+        p, g, n = self.head_dim, self.n_groups, self.state_size
         # three leaf scopes beside the scan's own (``ssd_scan``): the two
         # products under ``mamba_proj``, everything else under
         # ``mamba_local`` (telemetry/catalogue.SCOPE_SPECS)
@@ -104,6 +120,33 @@ class Mamba2(TensorModule):
             # and scan twice
             zxbcdt = keep(jnp.matmul(match_compute(input, w_in), w_in.T),
                           MAMBA_IN_PROJ)
+        # trace-time count, as bigdl_ssd_scan_total: the form the local
+        # part took (ops.mamba_local.takes_kernel; PERF.md section 6, PR 43)
+        kernel = mamba_local.takes_kernel(
+            jax.default_backend(), zxbcdt.dtype, input.shape[1],
+            self.d_inner, g * n, g, self.conv_kernel)
+        instruments(get_registry()).mamba_local_total.labels(
+            form="kernel" if kernel else "xla").inc()
+        if kernel:
+            with jax.named_scope("mamba_local"):
+                y = mamba_local.around_scan(
+                    zxbcdt, self.conv_weight, self.conv_bias, self.dt_bias,
+                    self.A_log, self.D, self.norm_weight, head_dim=p,
+                    n_groups=g, state_size=n, chunk=self.chunk_size,
+                    eps=self.norm_eps)
+        else:
+            y = self._around_scan(zxbcdt, input.dtype)
+        with jax.named_scope("mamba_proj"):
+            w_out = self.out_proj_weight
+            return jnp.matmul(match_compute(y, w_out), w_out.T)
+
+    def _around_scan(self, zxbcdt, dtype):
+        """The XLA form of what lies between the projections: these
+        ``jax.numpy`` lines, which XLA fuses, around ``ssd_scan``."""
+        h, p, g, n = (self.num_heads, self.head_dim, self.n_groups,
+                      self.state_size)
+        d_inner, conv_dim = self.d_inner, self.conv_dim
+        bsz, length, _ = zxbcdt.shape
         with jax.named_scope("mamba_local"):
             z = zxbcdt[..., :d_inner]
             xbc = self._conv(zxbcdt[..., d_inner:d_inner + conv_dim])
@@ -124,11 +167,8 @@ class Mamba2(TensorModule):
             yg = y.reshape(bsz, length, g, d_inner // g)
             yg = yg * jax.lax.rsqrt(jnp.mean(jnp.square(yg), axis=-1,
                                              keepdims=True) + self.norm_eps)
-            y = yg.reshape(bsz, length, d_inner).astype(input.dtype) \
+            return yg.reshape(bsz, length, d_inner).astype(dtype) \
                 * self.norm_weight
-        with jax.named_scope("mamba_proj"):
-            w_out = self.out_proj_weight
-            return jnp.matmul(match_compute(y, w_out), w_out.T)
 
     def __repr__(self):
         return (f"Mamba2({self.embed_dim}, heads={self.num_heads}x"

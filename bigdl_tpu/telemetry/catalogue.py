@@ -208,6 +208,19 @@ METRIC_SPECS: List[MetricSpec] = [
                "else). Counted once per eager call / once per TRACE under "
                "jit, as bigdl_moe_dispatch_total: which form each compiled "
                "program holds, not per-step traffic.", ("form",)),
+    MetricSpec("bigdl_mamba_local_total", "counter",
+               "Mamba-2 mixers (nn.Mamba2) by the form their local part "
+               "took, everything between the two projections but the scan: "
+               "the convolution with its bias, SiLU and split, the D skip, "
+               "the gate and the group norm (form label: kernel, the four "
+               "Mosaic calls of ops/mamba_local.py, mamba_local_conv / "
+               "_gate and their *_bwd, taken on a TPU for bf16 operands "
+               "where G * N is whole lane tiles that divide d_inner, a norm "
+               "group is whole lane tiles and 128 divides the length; xla, "
+               "the jax.numpy lines of nn/mamba.py, everywhere else, a CPU "
+               "and inner widths under a lane tile included). Counted once "
+               "per eager call / once per TRACE under jit, as "
+               "bigdl_ssd_scan_total.", ("form",)),
     MetricSpec("bigdl_short_conv_total", "counter",
                "Double-gated short convolutions (nn.ShortConv) by the form "
                "their local part took: the split, both gates and the "

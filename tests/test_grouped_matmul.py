@@ -294,3 +294,57 @@ def test_the_cells_expert_blocks_compile_for_the_v5e(cell, one_chip,
     assert set(re.findall(r"moe_gmm[a-z_]*", text)) >= {
         "moe_gmm_hidden", "moe_gmm_out", "moe_gmm_bwd", "moe_gmm_t_hidden",
         "moe_gmm_t_out", "moe_gmm_dx"}
+
+
+@pytest.mark.parametrize("length", [8192, 1024],
+                         ids=["the-cell", "its-reference-check"])
+def test_the_mamba_local_calls_compile_for_the_v5e(length, one_chip,
+                                                   monkeypatch, request):
+    """One Mamba-2 mixer at the Nemotron cell's widths, forward and backward
+    under ``jax.checkpoint``, through the chip's own compiler (here beside
+    the other real-width compiles: one file, one worker, one libtpu): the
+    four ``mamba_local_*`` Mosaic calls are in the program with the scan's,
+    what they hold in VMEM fits, ``zxbcdt``'s cotangent is ONE buffer (no
+    pad, no concatenate of its width), and no copy of that width stands
+    between the in-projection and the calls."""
+    import re
+    from bigdl_tpu import nn
+    from bigdl_tpu.nn.module import functional_apply
+    from bigdl_tpu.ops import mamba_local
+    from bigdl_tpu.utils.rng import manual_seed
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    from jax.experimental.compilation_cache import compilation_cache
+    cached = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    request.addfinalizer(lambda: jax.config.update(
+        "jax_enable_compilation_cache", cached))
+    manual_seed(1)
+    m = nn.Mamba2(2688, num_heads=64, head_dim=64, state_size=128,
+                  n_groups=8, conv_kernel=4, chunk_size=128)
+    bf = jnp.bfloat16
+    assert mamba_local.takes_kernel("tpu", bf, length, m.d_inner, 1024, 8, 4)
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, bf, sharding=one_chip),
+        m.parameter_tree())
+    u = jax.ShapeDtypeStruct((1, length, 2688), bf, sharding=one_chip)
+
+    def loss(p, u):
+        mixer = jax.checkpoint(lambda p, u: functional_apply(
+            m, p, m.buffer_tree(), u, training=True)[0])
+        return jnp.sum(mixer(p, mixer(p, u)).astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, (0, 1))).lower(params, u).compile(
+        ).as_text()
+    assert set(re.findall(r"mamba_local_[a-z_]*", text)) >= {
+        "mamba_local_conv", "mamba_local_gate", "mamba_local_gate_bwd",
+        "mamba_local_conv_bwd"}
+    assert "ssd_fwd_out" in text and "ssd_bwd_out" in text
+    wide = rf"bf16\[(?:1,)?{length},10304\]"
+    assert not re.findall(rf"= {wide}\S* (?:pad|concatenate|copy)\(", text)
+    # none carries the operand by which the flash readers know a flash call
+    # in that cell (``builders/nemotron_h.flash_shape``: 32 heads of 128)
+    calls = [line for line in text.splitlines()
+             if "tpu_custom_call" in line and "mamba_local_" in line]
+    assert len(calls) >= 8
+    assert not [c for c in calls if f"bf16[32,{length},128]" in c]
