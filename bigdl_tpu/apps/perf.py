@@ -24,14 +24,13 @@ _LSTM_VOCAB = 20_000
 _TRANSFORMER_VOCAB = 32_000
 
 
-def _build_model(name: str, fused_head: bool = True, moe_experts: int = 0,
-                 moe_dispatch: str = "scatter"):
+def _build_model(name: str, fused_head: bool = True, moe_experts: int = 0):
     """(model, feature_shape, n_classes, int_vocab, seq_labels) —
     ``int_vocab > 0`` marks integer token-index features (LSTM text
-    classification, BASELINE config 5); ``seq_labels`` marks per-timestep
-    targets scored with the fused LM-head criterion (default — measured
-    +23% on chip, PERF.md round 3) or TimeDistributedCriterion(ClassNLL)
-    with ``fused_head=False`` (the causal LM)."""
+    classification); ``seq_labels`` marks per-timestep targets scored
+    with the fused LM-head criterion (default) or
+    TimeDistributedCriterion(ClassNLL) with ``fused_head=False`` (the
+    causal LM)."""
     from bigdl_tpu.models import (inception, lenet, resnet, rnn, transformer,
                                   vgg, vit)
     builders = {
@@ -54,8 +53,7 @@ def _build_model(name: str, fused_head: bool = True, moe_experts: int = 0,
             fused_head=fused_head),
             (512,), _TRANSFORMER_VOCAB, _TRANSFORMER_VOCAB, True),
         # realistic-scale LMs (GPT-2-small / GPT-2-medium shaped): big
-        # matmuls put the MXU in charge — measured 59.7% (b=8) / 52.6%
-        # (b=4) MFU on a v5e chip (PERF.md round 3), past the north star
+        # matmuls put the MXU in charge
         "transformer_134m": lambda: (transformer.build_lm(
             _TRANSFORMER_VOCAB, 768, 12, 3072, num_layers=12, max_len=1024,
             fused_head=fused_head),
@@ -92,10 +90,6 @@ def _build_model(name: str, fused_head: bool = True, moe_experts: int = 0,
             out = builders[name]()
         finally:
             _t.build_lm = orig
-        from bigdl_tpu.parallel.expert import MoE
-        for m in out[0].modules():
-            if isinstance(m, MoE):
-                m.dispatch = moe_dispatch
         return out
     return builders[name]()
 
@@ -117,7 +111,7 @@ def main(argv=None) -> None:
                     default="fp32",
                     help="adamw only: moment storage dtype (bf16 halves "
                     "optimizer-state HBM; math stays fp32)")
-    ap.add_argument("--remat", choices=("none", "full", "conv", "block"),
+    ap.add_argument("--remat", choices=("none", "full", "block"),
                     default="none",
                     help="activation rematerialization policy "
                     "(block = per-transformer-block, the LM memory knob)")
@@ -127,10 +121,6 @@ def main(argv=None) -> None:
     ap.add_argument("--moeExperts", type=int, default=0,
                     help="transformer models: top-k routed MoE FFN with "
                     "this many experts (gelu models only)")
-    ap.add_argument("--moeDispatch", choices=("scatter", "einsum"),
-                    default="scatter",
-                    help="MoE token dispatch: ragged scatter (default) or "
-                    "dense GShard einsum masks")
     ap.add_argument("--no-fused-head", action="store_true",
                     help="LM only: unfused TimeDistributed(Linear)+LogSoftMax"
                     " tail + ClassNLL instead of LMHead+FusedLMHeadCriterion")
@@ -152,7 +142,7 @@ def main(argv=None) -> None:
     redirect_logs()
     model, shape, n_class, int_vocab, seq_labels = _build_model(
         args.model, fused_head=not args.no_fused_head,
-        moe_experts=args.moeExperts, moe_dispatch=args.moeDispatch)
+        moe_experts=args.moeExperts)
 
     rng = np.random.RandomState(0)
     n_records = args.batchSize * 2

@@ -2,7 +2,7 @@
 ``AbstractDataSet`` protocol.
 
 Drop-in for ``ShardFolder.stream(folder) >> decoder``: the optimizer,
-``DistriOptimizer``, the evaluator, and ``apps/ingest_bench.py`` consume
+``DistriOptimizer``, the evaluator, and ``apps/ingest_bench`` consume
 it through the same ``data()/size()/shuffle()`` surface with no call-site
 rewrites, but ``data(train=True)`` runs the staged threaded engine
 (``bigdl_tpu/dataset/ingest/engine.py``) instead of the serial chain.
@@ -52,7 +52,7 @@ class PrefetchingDataSet(AbstractDataSet):
     clones per worker (validated: deterministic per-record stages plus at
     most one trailing batcher). ``config.workers == 0`` selects the
     serial engine: identical ordering rule, no threads — the A/B
-    baseline ``apps/ingest_bench.py --engine serial`` measures.
+    baseline ``apps/ingest_bench --engine serial`` measures.
     """
 
     def __init__(self, paths: Sequence[str],

@@ -20,7 +20,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.ad_checkpoint import checkpoint_name
 
 from bigdl_tpu.nn import initialization as init
 from bigdl_tpu.nn.module import TensorModule
@@ -83,12 +82,6 @@ class SpatialConvolution(TensorModule):
             feature_group_count=self.n_group)
         if self.with_bias:
             out = out + self.bias
-        # Tag for remat policies (``set_remat("conv")``): save conv outputs,
-        # recompute the cheap elementwise tail (BN normalize, ReLU) in the
-        # backward instead of materializing those copies to HBM. A no-op
-        # unless the training loop wraps the forward in jax.checkpoint with
-        # a name-based policy.
-        out = checkpoint_name(out, "conv_out")
         return out[0] if squeeze else out
 
     def __repr__(self):
@@ -190,7 +183,6 @@ class SpaceToDepthConv7(TensorModule):
             dimension_numbers=_DN_2D)
         if self.with_bias:
             out = out + self.bias
-        out = checkpoint_name(out, "conv_out")
         return out[0] if squeeze else out
 
     def __repr__(self):

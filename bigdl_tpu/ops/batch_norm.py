@@ -31,7 +31,6 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax.ad_checkpoint import checkpoint_name
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(3,))
@@ -51,11 +50,6 @@ def _forward(x, gamma, beta, eps):
     meansq = jnp.mean(x32 * x32, axis=axes)
     var = jnp.maximum(meansq - mean * mean, 0.0)
     inv = jax.lax.rsqrt(var + eps)
-    # Tagged so name-based remat policies can SAVE the per-channel stats
-    # (tiny) while recomputing the normalize pass: recomputing the stats
-    # themselves would cost a full re-read of x in the backward.
-    mean = checkpoint_name(mean, "bn_stats")
-    inv = checkpoint_name(inv, "bn_stats")
     xhat = (x32 - mean) * inv
     out = (xhat * gamma.astype(jnp.float32)
            + beta.astype(jnp.float32)).astype(x.dtype)

@@ -17,9 +17,8 @@ keeps the weight int8 all the way into VMEM:
   dequantize-then-matmul.
 
 Decode (B=1) at real model sizes is weight-READ-bound (PERF.md round-4
-decode cost model), so halving resident bytes vs bf16 should approach 2x
-— the ``bench_int8`` harness in ``scripts/int8_decode_bench.py`` records
-the measured number.
+decode cost model), so halving resident bytes vs bf16 should approach 2x;
+no benchmark cell measures it yet (ROADMAP W1).
 
 Round 10 made the tiling FULL-COVERAGE: the grid rounds up and Pallas
 masks the partial final output tile, so any (O, K%128==0) shape takes the

@@ -57,27 +57,12 @@ block of the Trinity-Mini cell, 235 MB its dense block, 169 MB a Mamba-2
 block and 122 MB an expert block's shared expert of the Nemotron cell,
 and took 31 and 25 ms off steps of 286 and 287 ms for 0.77 and 0.30 GB
 more at the step's peak (PERF.md section 6, PR 31).
-
-**Conv remat** (``Optimizer.set_remat("conv")``): ``nn/conv.py`` tags conv
-outputs ``"conv_out"`` and ``ops/batch_norm.py`` the BN statistics
-``"bn_stats"``. Measured on a real v5e (PERF.md round 3): for ResNet-50
-this policy LOSES ~7% vs no remat — XLA's backward fusions already
-recompute the elementwise tail — so it is an explicit memory/HBM knob, not
-a default.
 """
 
 from __future__ import annotations
 
 import jax
 from jax.ad_checkpoint import checkpoint_name
-
-REMAT_SAVED_NAMES = ("conv_out", "bn_stats")
-
-
-def conv_remat_policy():
-    """Save conv outputs + BN statistics; recompute the elementwise tail."""
-    return jax.checkpoint_policies.save_only_these_names(*REMAT_SAVED_NAMES)
-
 
 MOE_ROUTE_TABLES = "moe_route_tables"
 MOE_ROUTED_OUT = "moe_routed_out"

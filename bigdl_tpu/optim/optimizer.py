@@ -124,13 +124,7 @@ def make_training_loss_fn(model, criterion, policy, reg_pairs, remat,
                                         training=True, rng=rng)
         return out, cast_tree(new_buf, jnp.float32)
 
-    if remat == "conv":
-        from bigdl_tpu.ops.remat import conv_remat_policy
-        fwd = jax.checkpoint(forward, policy=conv_remat_policy())
-    elif remat:
-        fwd = jax.checkpoint(forward)
-    else:
-        fwd = forward
+    fwd = jax.checkpoint(forward) if remat else forward
 
     def loss_fn(p):
         out, new_buf = fwd(p, data)
@@ -281,12 +275,6 @@ class Optimizer:
         ``True``: full remat — activation memory drops to O(1) forwards at
         ~1.3x FLOPs, the standard TPU recipe when a model does not fit HBM.
 
-        ``"conv"``: name-based policy for bandwidth-bound conv/BN models —
-        SAVE conv outputs and BN statistics (tagged via ``checkpoint_name``
-        in ``nn/conv.py`` / ``ops/batch_norm.py``), recompute the cheap
-        elementwise tail (BN normalize, ReLU) in the backward instead of
-        materializing those activation copies to HBM.
-
         ``"block"``: per-transformer-block checkpointing — every
         ``TransformerEncoder`` or ``HybridDecoder`` in the model recomputes
         inside each block during the backward. A ``TransformerEncoder``
@@ -312,10 +300,8 @@ class Optimizer:
         for enc in encs:  # reset; "block" re-enables below
             enc.remat_blocks = False
         if isinstance(enabled, str):
-            if enabled == "full":  # alias for True (matches the bench lever)
+            if enabled == "full":  # alias for True
                 self._remat = True
-            elif enabled == "conv":
-                self._remat = enabled
             elif enabled == "block":
                 if not encs:
                     raise ValueError("remat='block' needs a model with "
@@ -326,8 +312,7 @@ class Optimizer:
                 self._remat = False  # per-block checkpoints, no outer wrap
             else:
                 raise ValueError(f"unknown remat policy {enabled!r}; "
-                                 "expected True/False, 'full', 'conv' or "
-                                 "'block'")
+                                 "expected True/False, 'full' or 'block'")
         else:
             self._remat = bool(enabled)
         return self

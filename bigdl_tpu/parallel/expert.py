@@ -6,17 +6,14 @@ parallelism: ABSENT". ``MoE`` is its distributed descendant, built the
 GShard/Switch way for TPU:
 
 - top-k softmax gating with capacity limiting;
-- sort-based ragged dispatch (default, round 10): ONE stable argsort of
-  the round-major token→expert picks replaces the k× one-hot + cumsum +
-  scatter-add position bookkeeping — capacity slots fall out of segment
-  offsets (rank within the expert's sorted run), tokens GATHER into the
-  (expert, capacity, d) buffers, and the combine reads back through the
-  same indices. Static shapes, O(E·C·D) memory, and no (T, E)-wide
-  cumsum chains or scatter traffic on the hot path;
-- ``dispatch="scatter"`` keeps the round-5 scatter-add formulation and
-  ``dispatch="einsum"`` the dense GShard-paper (T, E, C) masks, both for
-  A/B comparison/debug — all three are bit-equivalent (same routing,
-  same drop semantics, same combine op order);
+- ``dispatch="sort"`` (the default), the capacity path: ONE stable
+  argsort of the round-major token→expert picks — capacity slots fall
+  out of segment offsets (rank within the expert's sorted run), tokens
+  GATHER into the (expert, capacity, d) buffers, and the combine reads
+  back through the same indices. Static shapes, O(E·C·D) memory, and no
+  (T, E)-wide cumsum chains or scatter traffic on the hot path. The dense
+  GShard (T, E, C) formulation of the same routing is the tests'
+  reference (``tests/test_expert_parallel.py``), not an option here;
 - expert FFN weights STACKED on a leading expert axis; under expert
   parallelism those leaves are sharded ``P('expert', ...)`` and GSPMD turns
   the dispatch einsums into all_to_alls over the mesh ``expert`` axis —
@@ -194,9 +191,9 @@ class MoE(Module):
                  score: str = "sigmoid", router_input: str = "own",
                  renorm_eps: float = 1e-20):
         super().__init__()
-        if dispatch not in ("sort", "scatter", "einsum", "held"):
-            raise ValueError(f"dispatch must be 'sort', 'scatter', "
-                             f"'einsum' or 'held', got {dispatch!r}")
+        if dispatch not in ("sort", "held"):
+            raise ValueError(f"dispatch must be 'sort' or 'held', got "
+                             f"{dispatch!r}")
         if activation not in _ACTIVATIONS:
             raise ValueError(f"unknown expert activation {activation!r}")
         if activation in _GATED and (dispatch != "held" or bias):
@@ -404,11 +401,12 @@ class MoE(Module):
         return y.astype(input.dtype).reshape(orig_shape)
 
     def update_output(self, input):
+        from bigdl_tpu.telemetry import get_registry, instruments
+        ins = instruments(get_registry())
+        # trace-time counts (as bigdl_ssd_scan_total): which path each
+        # compiled MoE forward uses
+        ins.moe_dispatch_total.labels(path=self.dispatch).inc()
         if self.dispatch == "held":
-            from bigdl_tpu.telemetry import get_registry, instruments
-            ins = instruments(get_registry())
-            ins.moe_dispatch_total.labels(path="held").inc()
-            # trace-time count, as bigdl_ssd_scan_total
             ins.moe_router_total.labels(score=self.score,
                                         input=self.router_input).inc()
             return self._held_forward(input)
@@ -419,113 +417,65 @@ class MoE(Module):
         capacity = max(1, int(np.ceil(t / e * self.capacity_factor * k)))
         capacity = min(capacity, t)
 
-        from bigdl_tpu.telemetry import get_registry, instruments
-        # trace-time count (like bigdl_int8_fallbacks_total): which
-        # dispatch formulation each compiled MoE forward uses
-        instruments(get_registry()).moe_dispatch_total.labels(
-            path=self.dispatch).inc()
-
         logits = x @ self.gate_weight                      # (T, E)
         probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
 
-        # Iterative top-k routing: the pick/gate loop is shared by all
-        # dispatch paths (identical argmax tie-breaking). Slot/keep
-        # bookkeeping differs: sort derives it from ONE stable argsort
-        # below; scatter/einsum keep the O(T·E) running-count cumsums.
-        use_sort = self.dispatch == "sort"
+        # Iterative top-k routing: round j picks each token's best expert
+        # among those it has not picked yet.
         masked = probs
-        fill = jnp.zeros((e,), jnp.int32)
         topk_mask = jnp.zeros_like(probs)
-        picks = []  # (expert (T,), slot (T,), keep, gate weight w/ drops 0)
+        experts, gates = [], []         # a round's pick (T,) and its gate
         for _ in range(k):
             pick = jnp.argmax(masked, axis=-1)             # (T,)
             onehot = jax.nn.one_hot(pick, e, dtype=jnp.float32)
             topk_mask = topk_mask + onehot
-            gate = jnp.sum(probs * onehot, axis=-1)        # (T,)
-            if use_sort:
-                picks.append((pick, None, None, gate))
-            else:
-                # Position of each token in its expert's capacity buffer:
-                # running count of earlier tokens routed to the same
-                # expert; slots used accumulate across the k picks.
-                pos = (jnp.cumsum(onehot, axis=0) - onehot) + fill[None, :]
-                pos_t = jnp.sum(pos * onehot, axis=-1).astype(jnp.int32)
-                keep = pos_t < capacity
-                w = gate * keep                            # (T,)
-                picks.append((pick, jnp.where(keep, pos_t, 0), keep, w))
-                fill = fill + jnp.sum(onehot * keep[:, None],
-                                      axis=0).astype(jnp.int32)
+            experts.append(pick)
+            gates.append(jnp.sum(probs * onehot, axis=-1))
             masked = masked * (1.0 - onehot)
 
-        if use_sort:
-            # Sort-based slot assignment: flatten the picks round-major
-            # (flat index j*T + t) and stable-argsort by expert. A pick's
-            # rank within its expert's sorted run IS its capacity slot —
-            # identical to the scatter bookkeeping, because positions
-            # within a round count all of that round's picks and an
-            # earlier-round drop implies the expert already saturated
-            # (so later rounds drop under both schemes).
-            kt = k * t
-            expert_flat = jnp.concatenate([p for p, _, _, _ in picks])
-            order = jnp.argsort(expert_flat, stable=True)   # (kT,)
-            counts = jnp.bincount(expert_flat, length=e)    # (E,)
-            offsets = (jnp.cumsum(counts) - counts).astype(jnp.int32)
-            # inverse permutation: sorted position of each flat pick
-            inv = jnp.zeros((kt,), jnp.int32).at[order].set(
-                jnp.arange(kt, dtype=jnp.int32))
-            slot_flat = inv - offsets[expert_flat]          # rank in expert
-            keep_flat = slot_flat < capacity
-            gate_flat = jnp.concatenate([g for _, _, _, g in picks])
-            w_flat = gate_flat * keep_flat
-            slot_flat = jnp.where(keep_flat, slot_flat, 0)
-            picks = [(picks[j][0], slot_flat[j * t:(j + 1) * t],
-                      keep_flat[j * t:(j + 1) * t],
-                      w_flat[j * t:(j + 1) * t]) for j in range(k)]
+        # Slot assignment: flatten the picks round-major (flat index
+        # j*T + t) and stable-argsort by expert. A pick's rank within its
+        # expert's sorted run IS its capacity slot: earlier rounds and,
+        # within a round, earlier tokens are served first, and a pick
+        # whose rank is past the capacity is dropped.
+        kt = k * t
+        expert_flat = jnp.concatenate(experts)
+        order = jnp.argsort(expert_flat, stable=True)       # (kT,)
+        counts = jnp.bincount(expert_flat, length=e)        # (E,)
+        offsets = (jnp.cumsum(counts) - counts).astype(jnp.int32)
+        # inverse permutation: sorted position of each flat pick
+        inv = jnp.zeros((kt,), jnp.int32).at[order].set(
+            jnp.arange(kt, dtype=jnp.int32))
+        slot_flat = inv - offsets[expert_flat]              # rank in expert
+        keep_flat = slot_flat < capacity
+        # each round's slots and gate weights, (k, T); drops weigh 0
+        slots = jnp.where(keep_flat, slot_flat, 0).reshape(k, t)
+        weights = (jnp.concatenate(gates) * keep_flat).reshape(k, t)
 
         # Renormalise the k kept gate weights to sum 1 per token, then
         # rescale by the FULL top-k probability mass (drops included) —
         # GShard combine semantics.
-        denom = sum(w for _, _, _, w in picks)             # (T,)
+        denom = jnp.sum(weights, axis=0)                   # (T,)
         scale = jnp.sum(probs * topk_mask, axis=-1)        # (T,)
         coef = scale / jnp.maximum(denom, 1e-9)
 
         # Dispatch + expert matmuls run in the COMPUTE dtype (bf16 under
-        # the training policy: the MXU's native rate; round-4's forced-f32
-        # dispatch was measured at 24.2% MFU — half the matmul rate was
-        # left on the table). Gating/combine coefficients stay f32.
+        # the training policy: the MXU's native rate). Gating/combine
+        # coefficients stay f32.
         cd = input.dtype
-        xc = x
-        if use_sort:
-            # Pure-gather dispatch: expert e's capacity row c holds the
-            # token of its c-th sorted pick (exactly the pick that got
-            # slot c), zero-masked past the expert's real count. No
-            # scatter traffic at all — XLA lowers this to gathers, and
-            # under EP sharding the gather feeding the sharded expert
-            # einsum still becomes the all_to_all over the expert axis.
-            token_flat = jnp.tile(jnp.arange(t, dtype=jnp.int32), k)
-            sorted_tokens = token_flat[order]               # (kT,)
-            src = offsets[:, None] + jnp.arange(capacity,
-                                                dtype=jnp.int32)[None, :]
-            valid = (jnp.arange(capacity, dtype=jnp.int32)[None, :]
-                     < jnp.minimum(counts, capacity)[:, None])  # (E, C)
-            gathered = sorted_tokens[jnp.clip(src, 0, kt - 1)]  # (E, C)
-            xe = jnp.where(valid[:, :, None], xc[gathered], 0).astype(cd)
-        elif self.dispatch == "scatter":
-            # Ragged dispatch: dropped picks have w=0 and slot clamped to 0,
-            # so their scatter contribution is zeroed and their gather-back
-            # is weighted out.
-            xe = jnp.zeros((e, capacity, d), cd)
-            for pick, slot, keep, _ in picks:
-                xe = xe.at[pick, slot].add(
-                    xc * keep[:, None].astype(cd))
-        else:
-            dispatch_t = jnp.zeros((t, e, capacity), cd)
-            for pick, slot, keep, _ in picks:
-                dc = (jax.nn.one_hot(pick, e, dtype=cd)[:, :, None]
-                      * jax.nn.one_hot(slot, capacity, dtype=cd)[:, None, :]
-                      * keep[:, None, None].astype(cd))
-                dispatch_t = dispatch_t + dc
-            xe = jnp.einsum("tec,td->ecd", dispatch_t, xc)  # (E, C, D)
+        # Pure-gather dispatch: expert e's capacity row c holds the token
+        # of its c-th sorted pick (exactly the pick that got slot c),
+        # zero-masked past the expert's real count. XLA lowers this to
+        # gathers, and under EP sharding the gather feeding the sharded
+        # expert einsum becomes the exchange over the expert axis.
+        token_flat = jnp.tile(jnp.arange(t, dtype=jnp.int32), k)
+        sorted_tokens = token_flat[order]                   # (kT,)
+        src = offsets[:, None] + jnp.arange(capacity,
+                                            dtype=jnp.int32)[None, :]
+        valid = (jnp.arange(capacity, dtype=jnp.int32)[None, :]
+                 < jnp.minimum(counts, capacity)[:, None])  # (E, C)
+        gathered = sorted_tokens[jnp.clip(src, 0, kt - 1)]  # (E, C)
+        xe = jnp.where(valid[:, :, None], x[gathered], 0).astype(cd)
 
         hdn = jnp.einsum("ecd,edh->ech", xe, self.w1.astype(cd))
         if self.bias:
@@ -535,23 +485,12 @@ class MoE(Module):
         if self.bias:
             ye = ye + self.b2.astype(cd)[:, None, :]
 
-        if self.dispatch in ("sort", "scatter"):
-            # combine by (expert, slot) gather-back — same op order on
-            # both paths, so sort is bit-equivalent to scatter
-            y = jnp.zeros((t, d), jnp.float32)
-            for pick, slot, _, w in picks:
-                y = y + (w * coef)[:, None] * ye[pick, slot].astype(
-                    jnp.float32)
-            y = y.astype(input.dtype)
-        else:
-            combine = jnp.zeros((t, e, capacity), jnp.float32)
-            for pick, slot, keep, w in picks:
-                dc = (jax.nn.one_hot(pick, e)[:, :, None]
-                      * jax.nn.one_hot(slot, capacity)[:, None, :]
-                      * keep[:, None, None])
-                combine = combine + dc * (w * coef)[:, None, None]
-            y = jnp.einsum("tec,ecd->td", combine,
-                           ye.astype(jnp.float32)).astype(input.dtype)
+        # combine by (expert, slot) gather-back
+        y = jnp.zeros((t, d), jnp.float32)
+        for pick, slot, w in zip(experts, slots, weights):
+            y = y + (w * coef)[:, None] * ye[pick, slot].astype(
+                jnp.float32)
+        y = y.astype(input.dtype)
 
         if self.aux_loss_weight and self.training:
             # Switch-style load balance: E * sum_e f_e * p_e.

@@ -18,7 +18,7 @@ backing up) instead of per-module ad-hoc counters:
 - ``scoreboard``: the automated serving scoreboard (seeded Zipf workload
   driver, /metrics scrape, markdown table, regression diff).
 
-jax-free by design: importable from the bench orchestrator, the CLI
+jax-free by design: importable from the CLI
 (``python -m bigdl_tpu.telemetry``) and the launcher subcommands
 (``scripts/bigdl-tpu.sh metrics|trace|scoreboard``) without touching a
 backend (``profiling``/``scoreboard`` lazy-import jax only when a

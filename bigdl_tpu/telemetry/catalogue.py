@@ -1,6 +1,6 @@
 """Well-known metric and span inventory — the single source of truth.
 
-Every instrumented subsystem (serving, training, eval, bench) creates its
+Every instrumented subsystem (serving, training, eval) creates its
 families FROM these specs, and ``scripts/gen_api_doc.py`` renders this
 table into ``docs/API.md`` — so the docs can never drift from what a
 scrape actually returns. Narrative guide: ``docs/OBSERVABILITY.md``.
@@ -192,15 +192,15 @@ METRIC_SPECS: List[MetricSpec] = [
                "(unknown = markerless legacy snapshot).", ("elastic",)),
     # ---- kernel dispatch (ops/int8_matmul.py, parallel/expert.py)
     MetricSpec("bigdl_moe_dispatch_total", "counter",
-               "MoE forwards by dispatch formulation (path label: "
-               "sort / scatter / einsum / held). Counted once per eager call / "
-               "once per TRACE under jit — the branch runs at trace "
-               "time, so this records which formulation each compiled "
-               "MoE program uses, not per-step traffic. 'sort' (the "
-               "round-10 default) replaces the k-fold one-hot+cumsum+"
-               "scatter-add chains with one stable argsort plus "
-               "gathers; 'held' is the dropless layer over the share of "
-               "the experts that lives on this chip.", ("path",)),
+               "MoE forwards by dispatch path (path label: sort / held). "
+               "Counted once per eager call / once per TRACE under jit — "
+               "the branch runs at trace time, so this records which "
+               "path each compiled MoE program uses, not per-step "
+               "traffic. 'sort' (the default) is the capacity path: one "
+               "stable argsort of the picks plus gathers, tokens over "
+               "an expert's capacity dropped; 'held' is the dropless "
+               "layer over the share of the experts that lives on this "
+               "chip.", ("path",)),
     MetricSpec("bigdl_ssd_scan_total", "counter",
                "Mamba-2 state-space scans by form (form label: kernel, "
                "the Mosaic kernels of ops/ssd_scan.py, taken on a TPU at "
@@ -344,11 +344,6 @@ METRIC_SPECS: List[MetricSpec] = [
                "Legacy optim.Metrics counters bridged onto the registry "
                "(scope = one Metrics instance, name = reference counter "
                "name).", ("scope", "name")),
-    # ---- bench harness (bench.py)
-    MetricSpec("bigdl_bench_step_seconds", "histogram",
-               "Benchmark timed-loop per-step wall-clock (chunk time / "
-               "steps; embedded in BENCH_*.json).",
-               (), DEFAULT_LATENCY_BUCKETS + (60.0, 120.0)),
 ]
 
 #: Span inventory (tracing.span names) with where they fire.
@@ -381,7 +376,7 @@ SPAN_SPECS: List[Tuple[str, str]] = [
      "transfer of one batch (overlaps the step consuming the previous "
      "one)."),
     ("ingest.step", "Consumer-side work between batch pops in "
-     "apps/ingest_bench.py's pipelined measurement (the lane the "
+     "apps/ingest_bench's pipelined measurement (the lane the "
      "read/decode/device_put spans overlap with)."),
     ("ingest.materialize", "DeviceCachedDataSet building its whole-epoch "
      "device cache on first use; the same wall time lands in "

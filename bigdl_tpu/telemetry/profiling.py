@@ -6,7 +6,7 @@ compiled, how long each compilation took, what FLOPs/HBM bytes a program
 accounts for, or what memory it holds. This module closes that gap with
 one primitive every jitted site adopts (``optim/optimizer.py``,
 ``parallel/distri_optimizer.py``, ``models/serving.py``,
-``models/generation.py``, ``optim/evaluator.py``, ``bench.py``):
+``models/generation.py``, ``optim/evaluator.py``):
 
     step = tracked_jit(step_fn, site="train.step", donate_argnums=(0, 1, 2))
 
@@ -316,8 +316,8 @@ def tracked_jit(fn: Callable, *, site: str,
 
 # The one peak table: bf16 peak FLOP/s of one chip by ``device_kind``
 # substring, first match wins (Google Cloud TPU documentation, per-chip
-# figures; a v5e reports itself as "TPU v5 lite"). bench.py, chip_smoke.py
-# and the live MFU gauge all read it; a kind that is not here has no MFU.
+# figures; a v5e reports itself as "TPU v5 lite"). chip_smoke.py and the
+# live MFU gauge read it; a kind that is not here has no MFU.
 _PEAK_BY_KIND = (
     ("v5 lite", 197e12), ("v5e", 197e12),
     ("v5p", 459e12),
@@ -337,7 +337,7 @@ def kind_peak_flops(device_kind: str) -> Optional[float]:
 
 
 def require_tpu():
-    """Gate of the measurement paths (``bench.py``, ``chip_smoke.py``):
+    """Gate of the measurement paths (``chip_smoke.py``, the benchmark):
     returns ``(device, peak_flops)`` for the first device, and raises
     unless it is a TPU whose kind the peak table knows — a number taken
     anywhere else is not a device metric."""

@@ -56,7 +56,7 @@ def compile_cache_dir() -> str:
     Where ``JAX_COMPILATION_CACHE_DIR`` is set, jax reads it itself and
     nothing is set in code. Where it is not, the cache goes to
     ``<checkout>/.jax_cache``. ``import bigdl_tpu`` calls this, so every
-    entry point (apps, ``bench.py``, ``chip_smoke.py``, scripts) follows
+    entry point (apps, ``chip_smoke.py``, the benchmark, scripts) follows
     the same rule and a second run of any of them starts warm."""
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:

@@ -161,6 +161,17 @@ class TestPerfHarness:
         with pytest.raises(SystemExit):
             perf.main(["--model", "alexnet9000"])
 
+    @pytest.mark.parametrize("flags", [["--remat", "conv"],
+                                       ["--moeDispatch", "einsum"]])
+    def test_the_retired_levers_are_refused(self, flags, capsys):
+        # a remat policy and an A/B flag that only the pre-benchmark
+        # scripts pulled (PR 44): argparse refuses both before any model
+        # is built
+        with pytest.raises(SystemExit) as exc:
+            perf.main(["--model", "lenet5", *flags])
+        assert exc.value.code == 2
+        assert flags[0] in capsys.readouterr().err
+
     @pytest.mark.slow  # ~32s: full cp train loop on the 1-core CPU box
     def test_transformer_lm_train_and_context_parallel(self, tmp_path):
         from bigdl_tpu.apps import transformer

@@ -251,8 +251,8 @@ def test_every_tag_is_on_the_list_and_every_listed_name_is_tagged(
     assert tagged == set(remat.BLOCK_SAVED_NAMES)
     with pytest.raises(ValueError):
         remat.keep(jnp.ones(()), "no_such_name")
-    with pytest.raises(ValueError):     # the conv policy's are not block's
-        remat.keep(jnp.ones(()), remat.REMAT_SAVED_NAMES[0])
+    with pytest.raises(ValueError):     # a producer's name, not block's
+        remat.keep(jnp.ones(()), "router_logits")
 
 
 @pytest.mark.parametrize("cell_name", sorted(MET))
@@ -282,3 +282,17 @@ def test_a_tag_outside_a_checkpoint_lowers_to_nothing():
         assert lowered() == tagged
     finally:
         attention.keep = real
+
+
+def test_a_convnet_carries_no_tag():
+    """A tag stands only where a policy lists it: the convolutions and the
+    training batch norm, which carried the names of a policy no cell
+    selected until PR 44, trace to no ``name`` equation."""
+    m = nn.Sequential().add(nn.SpaceToDepthConv7(3, 8)) \
+        .add(nn.SpatialBatchNormalization(8)).add(nn.ReLU()) \
+        .add(nn.SpatialConvolution(8, 8, 3, 3))
+    x = jnp.ones((2, 16, 16, 3))
+    jaxpr = jax.make_jaxpr(jax.grad(lambda p: jnp.sum(functional_apply(
+        m, p, m.buffer_tree(), x, training=True)[0])))(m.parameter_tree())
+    names = [eqn.primitive.name for eqn in _eqns(jaxpr.jaxpr)]
+    assert "conv_general_dilated" in names and "name" not in names

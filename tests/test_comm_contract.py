@@ -121,7 +121,7 @@ def test_dp_cp_ring_stays_in_coset_and_grads_all_reduce():
                 "sequence shards from different batch slices got mixed")
 
 
-@pytest.mark.parametrize("dispatch", ["sort", "scatter"])
+@pytest.mark.parametrize("dispatch", ["sort", "held"])
 def test_expert_parallel_step_routes_over_expert_axis(dispatch):
     """EP collective RECORD (round-5 VERDICT #8): expert parallelism is
     GSPMD-sharded (``expert_param_specs`` + jit), so WHICH collective
@@ -132,11 +132,10 @@ def test_expert_parallel_step_routes_over_expert_axis(dispatch):
     the data axis — on a (data=2, expert=4) mesh the expert cosets are
     {0..3}/{4..7}, distinct from the data-axis pairs {0,4}... A
     replicated-weights regression would sync grads over data only and
-    fail here. Pinned for BOTH ragged dispatch formulations — the
-    round-10 sort path's gathers must leave the expert-coset pattern
-    intact, not trade it for a replicate-everything fallback. (The
-    dense einsum A/B path shares scatter's GSPMD spec and combine
-    einsum; its numerics are pinned by test_expert_parallel.)"""
+    fail here. Pinned for both paths of the layer: the capacity path's
+    gathers and the held path's grouped product over sorted rows must
+    each leave the expert-coset pattern intact, not trade it for a
+    replicate-everything fallback."""
     import re
     from jax.sharding import NamedSharding, PartitionSpec as P
     from bigdl_tpu.nn.module import functional_apply

@@ -188,30 +188,13 @@ class TestRemat:
         for a, b in zip(run(False), run(True)):
             np.testing.assert_allclose(b, a, rtol=1e-6, atol=1e-7)
 
-    def test_remat_conv_policy_matches_plain(self):
-        # set_remat("conv") saves conv outputs + BN stats and recomputes
-        # the elementwise tail (the bandwidth lever for BN-bound conv
-        # models, PERF.md round 3); like full remat it must never change
-        # numerics. LeNet has convs (tagged "conv_out") in the path.
-        def run(remat):
-            bt.utils.manual_seed(23)
-            model = lenet.build(10)
-            opt = Optimizer(model, make_dataset(128, 64),
-                            nn.ClassNLLCriterion())
-            opt.set_optim_method(SGD(learningrate=0.05, momentum=0.9)) \
-               .set_end_when(Trigger.max_iteration(3)).set_remat(remat)
-            trained = opt.optimize()
-            import jax
-            return [np.asarray(x) for x in
-                    jax.tree_util.tree_leaves(trained.parameter_tree())]
-
-        for a, b in zip(run(False), run("conv")):
-            np.testing.assert_allclose(b, a, rtol=1e-6, atol=1e-7)
-
-    def test_remat_rejects_unknown_policy(self):
-        with pytest.raises(ValueError):
+    # "conv" was a policy until PR 44 (measured -7% on ResNet-50, selected
+    # by no cell): it is refused as every unknown name is
+    @pytest.mark.parametrize("policy", ["gibberish", "conv"])
+    def test_remat_rejects_unknown_policy(self, policy):
+        with pytest.raises(ValueError, match="unknown remat policy"):
             Optimizer(lenet.build(10), make_dataset(128, 64),
-                      nn.ClassNLLCriterion()).set_remat("gibberish")
+                      nn.ClassNLLCriterion()).set_remat(policy)
 
     @pytest.mark.parametrize("sync_mode", ["allreduce", "sharded"])
     def test_remat_distributed_matches_plain(self, sync_mode):
