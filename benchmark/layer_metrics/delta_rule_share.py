@@ -1,0 +1,16 @@
+"""model step: share of the step's device time in the layer ``delta_rule``
+(``ops/delta_rule.py``: the gated delta rule's chunked recurrence, all of
+it: the chunk's triangular system, the carry over chunk states, the
+read-outs), all passes. From the step's partition
+(``benchmark/step_partition.py``): operations that start inside whole runs
+of the step program, each in one (layer, pass) cell, over the table's
+total, mean over the cell's chips. A program without the scope (every
+commit before PR 45, and every family without a delta-rule mixer) reads
+nothing."""
+LAYER, UNIT = "model step", "%"
+
+from benchmark import step_partition
+
+
+def read(ctx):
+    return step_partition.share(ctx, layers=("delta_rule",)) or None

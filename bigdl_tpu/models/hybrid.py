@@ -66,7 +66,9 @@ def build_hybrid_lm(vocab_size: int, embed_dim: int, pattern: str,
                     latent_attention: Optional[dict] = None,
                     mtp: Optional[dict] = None,
                     short_conv: Optional[dict] = None,
-                    tie_embeddings: bool = False) -> nn.Sequential:
+                    tie_embeddings: bool = False,
+                    delta: Optional[dict] = None,
+                    pre_norm: bool = True) -> nn.Sequential:
     """Causal LM over ``nn.HybridDecoder``, head untied or tied
     (``tie_embeddings``): 1-based token ids (N, T) -> the fused-CE tail.
 
@@ -82,6 +84,9 @@ def build_hybrid_lm(vocab_size: int, embed_dim: int, pattern: str,
     are told the ids of the stream.
     ``embed_scale`` multiplies the embedding's rows on their way into the
     stack (``sqrt(embed_dim)`` in the families that scale it).
+    ``delta`` builds the ``D`` blocks (``nn.GatedDeltaNet``); ``post_norm``
+    with ``pre_norm=False`` gives the blocks that norm their mixer's
+    output alone (``nn/hybrid.py``).
 
     ``mtp`` (``{"loss_weight": w}``) adds ONE multi-token-prediction module
     (``nn.MTPModule``): two norms, a (2E -> E) projection, one more layer of
@@ -93,7 +98,8 @@ def build_hybrid_lm(vocab_size: int, embed_dim: int, pattern: str,
     groups = dict(mamba=mamba, moe=moe, attention=attention,
                   norm_eps=norm_eps, window_attention=window_attention,
                   mlp=mlp, post_norm=post_norm,
-                  latent_attention=latent_attention, short_conv=short_conv)
+                  latent_attention=latent_attention, short_conv=short_conv,
+                  delta=delta, pre_norm=pre_norm)
     if mtp is not None:
         m = _LMWithMTP()
     else:

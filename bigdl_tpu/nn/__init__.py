@@ -79,5 +79,6 @@ from bigdl_tpu.nn.attention import (
 )
 from bigdl_tpu.nn.mamba import Mamba2
 from bigdl_tpu.nn.short_conv import ShortConv
+from bigdl_tpu.nn.gated_delta_net import GatedDeltaNet
 from bigdl_tpu.nn.hybrid import (GatedMLP, HybridBlock, HybridDecoder,
                                  MTPModule)

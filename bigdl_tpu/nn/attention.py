@@ -214,9 +214,18 @@ class MultiHeadAttention(Module):
         if with_bias:
             self.register_parameter("out_proj_bias", init.zeros((embed_dim,)))
         # qk_norm: RMSNorm over each head of q and of k (one learned
-        # (head_dim,) gain each, shared by the heads), BEFORE the rotation
+        # (head_dim,) gain each, shared by the heads), BEFORE the rotation;
+        # "projection": over the WHOLE q and the whole k projection (a gain
+        # a column, one mean square over all the heads here: the OLMo 2
+        # family's), before the heads are split
+        if qk_norm not in (False, True, "projection"):
+            raise ValueError(f"qk_norm {qk_norm!r}: False, True (a head) "
+                             f"or 'projection'")
         self.qk_norm = qk_norm
-        if qk_norm:
+        if qk_norm == "projection":
+            self.q_norm = RMSNorm(e_q, eps=qk_norm_eps)
+            self.k_norm = RMSNorm(e_kv, eps=qk_norm_eps)
+        elif qk_norm:
             self.q_norm = RMSNorm(head_dim, eps=qk_norm_eps)
             self.k_norm = RMSNorm(head_dim, eps=qk_norm_eps)
         # gated: a fourth projection of the QUERY input, as wide as q; the
@@ -584,11 +593,14 @@ class MultiHeadAttention(Module):
         # backward reads the un-normed value, and both are element-wise
         pq, pk, pv = (keep(p, ATTN_PROJ)
                       for p in self._in_projections(query, key, value))
+        over = getattr(self, "qk_norm", False)
+        if over == "projection":
+            pq, pk = self.q_norm.forward(pq), self.k_norm.forward(pk)
         q = self._split_heads(pq)
         k = self._split_heads(pk)
         v = self._split_heads(pv)
 
-        if getattr(self, "qk_norm", False):
+        if over is True:
             q, k = self.q_norm.forward(q), self.k_norm.forward(k)
 
         if getattr(self, "rope", False):

@@ -3,15 +3,16 @@ state-space / attention / mixture-of-experts models (Nemotron-H,
 Granite-4-H, Jamba) interleave layers of different kinds, where
 ``TransformerEncoder`` repeats one block.
 
-Every block is pre-norm residual with ONE mixer, ``x <- x + mixer(
-RMSNorm(x))``, and the pattern string names each block's mixer by one
-character: ``M`` a Mamba-2 layer (``nn.Mamba2``), ``E`` a mixture of
+A block is residual with ONE mixer, pre-norm unless told otherwise, ``x <-
+x + mixer(RMSNorm(x))``, and the pattern string names each block's mixer by
+one character: ``M`` a Mamba-2 layer (``nn.Mamba2``), ``E`` a mixture of
 experts (``parallel.expert.MoE``), ``*`` causal self-attention
 (``nn.MultiHeadAttention``), ``W`` causal self-attention from a SECOND
 keyword group (the sliding-window layers of a model that mixes them with
 full ones: another window, rotation or head count), ``L`` causal latent
 self-attention (``nn.LatentAttention``), ``-`` a dense gated MLP
 (``GatedMLP``), ``C`` a double-gated short convolution (``nn.ShortConv``),
+``D`` gated delta-rule linear attention (``nn.GatedDeltaNet``),
 ``R`` a mixture of experts whose ROUTER reads the stream
 that entered the PRECEDING block (a layer that routes from its input,
 ahead of its attention: ``x <- x + experts(RMSNorm(x); routed by h)``, ``h``
@@ -19,7 +20,11 @@ what the attention block before it was given, not normed). The mixers are
 built from the keyword groups the caller gives for each kind (``R`` from
 the ``E`` blocks' group). With ``post_norm`` a block norms its mixer's
 output too, ``x <- x + RMSNorm(mixer(RMSNorm(x)))``, so a layer of an
-attention and a feed-forward block holds four norms.
+attention and a feed-forward block holds four norms (the Trinity-Mini
+configuration); with ``post_norm`` and ``pre_norm=False`` it norms the
+output ALONE, ``x <- x + RMSNorm(mixer(x))``, the block of the OLMo 2
+family (the Olmo-Hybrid configuration, kinds ``D*-``). Every other
+configuration's blocks are pre-norm.
 
 ``MTPModule`` is a multi-token-prediction module over such a stack: a
 short second stack fed the main one's stream and the NEXT token's
@@ -68,44 +73,51 @@ class GatedMLP(Module):
 
 class HybridBlock(Module):
     """``x + mixer(norm(x))``, or with ``post_norm``
-    ``x + norm_post(mixer(norm(x)))``. With ``routed_ahead`` the input is
-    a pair ``(x, h)`` and the mixer (an expert layer with
+    ``x + norm_post(mixer(norm(x)))``; without ``pre_norm`` there is no
+    ``norm`` and the mixer reads ``x`` itself. With ``routed_ahead`` the
+    input is a pair ``(x, h)`` and the mixer (an expert layer with
     ``router_input="given"``) is handed ``(norm(x), h)``: its router reads
     ``h`` as it is."""
 
     def __init__(self, embed_dim: int, mixer: Module, norm_eps: float,
-                 post_norm: bool = False, routed_ahead: bool = False):
+                 post_norm: bool = False, routed_ahead: bool = False,
+                 pre_norm: bool = True):
         super().__init__()
-        self.norm = RMSNorm(embed_dim, eps=norm_eps)
+        if not (pre_norm or post_norm):
+            raise ValueError("a block norms its mixer's input, its output "
+                             "or both")
+        if pre_norm:
+            self.norm = RMSNorm(embed_dim, eps=norm_eps)
         self.mixer = mixer
         self.routed_ahead = routed_ahead
         if post_norm:
             self.norm_post = RMSNorm(embed_dim, eps=norm_eps)
 
     def update_output(self, input):
+        entered = None
         if self.routed_ahead:
             input, entered = input
-            y = self.mixer.forward((self.norm.forward(input), entered))
-        else:
-            y = self.mixer.forward(self.norm.forward(input))
+        y = self.norm.forward(input) if "norm" in self._modules else input
+        y = self.mixer.forward((y, entered) if self.routed_ahead else y)
         if "norm_post" in self._modules:
             y = self.norm_post.forward(y)
         return input + y
 
 
 class HybridDecoder(Module):
-    """The stack a pattern string describes (kinds ``ME*W-LRC``; ``C`` a
-    short convolution), with a final RMSNorm.
+    """The stack a pattern string describes (kinds ``ME*W-LRCD``; ``C`` a
+    short convolution, ``D`` a gated delta rule), with a final RMSNorm.
 
     ``mamba``, ``moe``, ``attention``, ``window_attention``,
-    ``latent_attention``, ``mlp`` and ``short_conv`` are the keyword
-    arguments of ``nn.Mamba2(embed_dim, ...)``, ``MoE(embed_dim, ...)``,
-    ``nn.MultiHeadAttention(embed_dim, ..., causal=True)`` for the ``*``
-    and for the ``W`` blocks, ``nn.LatentAttention(embed_dim, ...)``,
-    ``GatedMLP(embed_dim, ...)`` and ``nn.ShortConv(embed_dim, ...)``; a
-    kind the pattern does not use needs none."""
+    ``latent_attention``, ``mlp``, ``short_conv`` and ``delta`` are the
+    keyword arguments of ``nn.Mamba2(embed_dim, ...)``, ``MoE(embed_dim,
+    ...)``, ``nn.MultiHeadAttention(embed_dim, ..., causal=True)`` for the
+    ``*`` and for the ``W`` blocks, ``nn.LatentAttention(embed_dim, ...)``,
+    ``GatedMLP(embed_dim, ...)``, ``nn.ShortConv(embed_dim, ...)`` and
+    ``nn.GatedDeltaNet(embed_dim, ...)``; a kind the pattern does not use
+    needs none."""
 
-    KINDS = "ME*W-LRC"
+    KINDS = "ME*W-LRCD"
 
     #: as ``TransformerEncoder.remat_blocks``: ``Optimizer.set_remat(
     #: "block")`` sets it, and each block then runs under ``jax.checkpoint``
@@ -119,20 +131,22 @@ class HybridDecoder(Module):
     #: measured no dearer than holding them), a dense block's
     #: gate, up and down outputs (28.7 KB at hidden 6,144 over 2,048), a
     #: Mamba-2 block's in-projection output (20.6 KB at 10,304 wide), a
-    #: convolution block's in-projection output (12.3 KB at 3 x 2,048), an
-    #: expert block's routing tables, routed output and its shared
+    #: convolution block's in-projection output (12.3 KB at 3 x 2,048), a
+    #: delta-rule block's in-projection output (17.3 KB at 15 heads of 96
+    #: and 192), an expert block's routing tables, routed output and its shared
     #: expert's float32 first products (128 B at top-8, 2 bytes a channel,
     #: 4 bytes a hidden unit or 8 for SwiGLU); norms, rotation, gates, the
-    #: convolution, the scan, the router's product and the shared expert's
-    #: second product run a second time. An ``R`` block's checkpoint takes
-    #: two values, the stream and the one its router reads, which is a
-    #: block boundary that is kept anyway
+    #: convolutions, the scan, the delta rule, the router's product and the
+    #: shared expert's second product run a second time. An ``R`` block's
+    #: checkpoint takes two values, the stream and the one its router
+    #: reads, which is a block boundary that is kept anyway
     remat_blocks = False
 
     def __init__(self, pattern: str, embed_dim: int, mamba=None, moe=None,
                  attention=None, norm_eps: float = 1e-5,
                  window_attention=None, mlp=None, post_norm: bool = False,
-                 latent_attention=None, short_conv=None):
+                 latent_attention=None, short_conv=None, delta=None,
+                 pre_norm: bool = True):
         super().__init__()
         bad = set(pattern) - set(self.KINDS)
         if bad or not pattern:
@@ -159,13 +173,16 @@ class HybridDecoder(Module):
             elif kind == "C":
                 from bigdl_tpu.nn.short_conv import ShortConv
                 mixer = ShortConv(embed_dim, **(short_conv or {}))
+            elif kind == "D":
+                from bigdl_tpu.nn.gated_delta_net import GatedDeltaNet
+                mixer = GatedDeltaNet(embed_dim, **delta)
             else:
                 mixer = MultiHeadAttention(
                     embed_dim, causal=True,
                     **(window_attention if kind == "W" else attention))
             self.add_module(f"layer{i}", HybridBlock(
                 embed_dim, mixer, norm_eps, post_norm,
-                routed_ahead=kind == "R"))
+                routed_ahead=kind == "R", pre_norm=pre_norm))
         self.final_norm = RMSNorm(embed_dim, eps=norm_eps)
 
     def stream(self, input):

@@ -53,6 +53,18 @@ from bigdl_tpu.ops.ssd_scan import ssd_scan
 from bigdl_tpu.utils.rng import RandomGenerator
 
 
+def decay_init(rng, num_heads: int, dt_min: float = 1e-3,
+               dt_max: float = 0.1, dt_floor: float = 1e-4):
+    """(``dt_bias``, ``A_log``) of ``num_heads`` heads as the family
+    initialises them: dt log-uniform in [dt_min, dt_max], floored, stored
+    through softplus' inverse; A uniform in [1, 16]. ``nn.GatedDeltaNet``
+    takes its decay's parameters from here too."""
+    dt = np.exp(rng.uniform(np.log(dt_min), np.log(dt_max), (num_heads,)))
+    dt = np.maximum(dt, dt_floor)
+    return ((dt + np.log(-np.expm1(-dt))).astype(np.float32),
+            np.log(rng.uniform(1.0, 16.0, (num_heads,))).astype(np.float32))
+
+
 class Mamba2(TensorModule):
     """Input (B, L, E) -> (B, L, E). Training/prefill form only: the whole
     sequence through the chunked scan from a zero state."""
@@ -83,16 +95,10 @@ class Mamba2(TensorModule):
                                                   conv_kernel))
         self.register_parameter("conv_bias",
                                 init.default_init((conv_dim,), conv_kernel))
-        # the family's own: dt log-uniform in [dt_min, dt_max], floored,
-        # stored through softplus' inverse; A uniform in [1, 16]; D = 1
-        dt = np.exp(rng.uniform(np.log(dt_min), np.log(dt_max),
-                                (num_heads,)))
-        dt = np.maximum(dt, dt_floor)
-        self.register_parameter(
-            "dt_bias", (dt + np.log(-np.expm1(-dt))).astype(np.float32))
-        self.register_parameter(
-            "A_log", np.log(rng.uniform(1.0, 16.0, (num_heads,))
-                            ).astype(np.float32))
+        # the family's own decay (``decay_init``); D = 1
+        dt_bias, a_log = decay_init(rng, num_heads, dt_min, dt_max, dt_floor)
+        self.register_parameter("dt_bias", dt_bias)
+        self.register_parameter("A_log", a_log)
         self.register_parameter("D", init.ones((num_heads,)))
         self.register_parameter("norm_weight", init.ones((d_inner,)))
         self.register_parameter("out_proj_weight",

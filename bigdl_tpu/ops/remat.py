@@ -41,6 +41,8 @@ name                    value, producer                                  bytes a
                         ``[z | xBC | dt]``                               + heads)``
 ``SHORT_CONV_IN_PROJ``  ``nn.ShortConv``'s in-projection output          ``6 E``
                         ``[B | C | x]``
+``DELTA_IN_PROJ``       ``nn.GatedDeltaNet``'s in-projection output     ``2 * (2 * heads * d_k + 2 * heads * d_v
+                        ``[q | k | v | z | b | a]``                      + 2 * heads)``
 ``MOE_SHARED_HID``      the shared expert's float32 first products       ``4 * shared_hidden``, twice for
                         (``MoE._hidden``), before the activation         SwiGLU
 ======================  ===============================================  ==========================================
@@ -48,8 +50,8 @@ name                    value, producer                                  bytes a
 A kept value that no backward reads (an out-projection's or the routed
 experts' output with no norm behind it) is pruned by ``jax.checkpoint``
 itself and costs nothing. What a block still runs twice: norms, rotation,
-gates, the convolution, the scan, the router's product, the shared
-expert's activation and second product. Keeping changes the jaxpr's
+gates, the convolutions, the scan, the delta rule's recurrence, the
+router's product, the shared expert's activation and second product. Keeping changes the jaxpr's
 arithmetic nowhere; XLA compiles the forward around what must reach HBM,
 so on the chip in bf16 a loss moves in its sixth digit (PERF.md section 6,
 PR 31). On the v5e at 1 x 8,192 tokens the list holds 253 MB an attention
@@ -71,6 +73,7 @@ ATTN_PROJ = "attn_proj"
 MLP_PROJ = "mlp_proj"
 MAMBA_IN_PROJ = "mamba_in_proj"
 SHORT_CONV_IN_PROJ = "short_conv_in_proj"
+DELTA_IN_PROJ = "delta_in_proj"
 MOE_SHARED_HID = "moe_shared_hid"
 
 #: what block remat keeps (module docstring), dearest to recompute a byte
@@ -78,7 +81,7 @@ MOE_SHARED_HID = "moe_shared_hid"
 #: runs out
 BLOCK_SAVED_NAMES = (MOE_ROUTE_TABLES, MOE_ROUTED_OUT, FLASH_OUT, ATTN_PROJ,
                      MLP_PROJ, MAMBA_IN_PROJ, SHORT_CONV_IN_PROJ,
-                     MOE_SHARED_HID)
+                     DELTA_IN_PROJ, MOE_SHARED_HID)
 
 
 def block_remat_policy():

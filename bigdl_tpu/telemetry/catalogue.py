@@ -228,6 +228,19 @@ METRIC_SPECS: List[MetricSpec] = [
                "multiply-adds that XLA fuses; the only one there is). "
                "Counted once per eager call / once per TRACE under jit, as "
                "bigdl_ssd_scan_total.", ("form",)),
+    MetricSpec("bigdl_gated_delta_net_total", "counter",
+               "Gated delta-rule linear-attention mixers (nn.GatedDeltaNet) "
+               "run: each one fused in-projection, a causal convolution "
+               "and SiLU on q, k and v, the recurrence of "
+               "ops/delta_rule.py and a gated norm. Counted once per eager "
+               "call / once per TRACE under jit, as bigdl_ssd_scan_total."),
+    MetricSpec("bigdl_delta_rule_total", "counter",
+               "Gated delta-rule recurrences (ops/delta_rule.py) by form "
+               "(form label: chunked, the WY form a chunk of 64 positions "
+               "at a time as XLA einsums with a scan over the chunk "
+               "states; the only one there is). Counted once per eager "
+               "call / once per TRACE under jit, as bigdl_ssd_scan_total.",
+               ("form",)),
     MetricSpec("bigdl_moe_grouped_total", "counter",
                "Grouped products of held expert layers (MoE(dispatch="
                "'held')) by form (form label: kernel, the Mosaic kernels of "
@@ -465,6 +478,19 @@ SCOPE_SPECS: List[ScopeSpec] = [
               "What is no projection: the split of the in-projection's "
               "output, the two gates and the causal depthwise convolution "
               "between them."),
+    ScopeSpec("delta_proj", "nn/gated_delta_net.py GatedDeltaNet",
+              "The fused in-projection (q, k, v, the output gate, beta and "
+              "the decay's input: six products as one) and the "
+              "out-projection."),
+    ScopeSpec("delta_local", "nn/gated_delta_net.py GatedDeltaNet",
+              "What is neither a projection nor the recurrence: the "
+              "causal convolution and SiLU on q, k and v, their two L2 "
+              "norms, beta and the log-decay, the gated RMSNorm of the "
+              "recurrence's output."),
+    ScopeSpec("delta_rule", "ops/delta_rule.py gated_delta_rule",
+              "The gated delta rule's chunked recurrence, all of it, both "
+              "passes: the chunk's triangular system, the carry over "
+              "chunk states, the read-outs."),
     ScopeSpec("mlp", "nn/hybrid.py GatedMLP; nn/attention.py "
               "TransformerEncoderLayer._ffn",
               "A dense feed-forward: its two or three products and the "

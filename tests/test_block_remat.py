@@ -183,7 +183,12 @@ MET = {"trinity-mini-train-s8192": {
        "lfm2-24b-a2b-train-s8192": {
            remat.SHORT_CONV_IN_PROJ: 4, remat.MLP_PROJ: 3,
            remat.ATTN_PROJ: 4, remat.FLASH_OUT: 2,
-           remat.MOE_ROUTE_TABLES: 20, remat.MOE_ROUTED_OUT: 4}}
+           remat.MOE_ROUTE_TABLES: 20, remat.MOE_ROUTED_OUT: 4},
+       # three delta-rule mixers' in-projections, four dense blocks of
+       # three, one attention block (no gate)
+       "olmo-hybrid-7b-train-s8192": {
+           remat.DELTA_IN_PROJ: 3, remat.MLP_PROJ: 12,
+           remat.ATTN_PROJ: 4, remat.FLASH_OUT: 2}}
 
 
 CELLS = {"trinity-mini-train-s8192": "W-WE*E",

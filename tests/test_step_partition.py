@@ -107,6 +107,16 @@ CASES = [
     # ... and what the mixer does outside them is no layer's
     ("jit(step)/jvp(_LM)/HybridDecoder/HybridBlock/ShortConv/mul",
      "unattributed", "forward"),
+    # the delta-rule mixer's three scopes; its recurrence's own backward
+    # rule (the triangular inverse's) enters ``delta_rule`` by hand
+    ("jit(step)/jvp(Sequential)/HybridDecoder/HybridBlock/GatedDeltaNet/"
+     "delta_proj/dot_general", "delta_proj", "forward"),
+    (_BLOCK + "rematted_computation/HybridBlock/GatedDeltaNet/delta_local/"
+     "logistic", "delta_local", "recompute"),
+    (_BLOCK + "HybridBlock/GatedDeltaNet/delta_rule/while/body/dot_general",
+     "delta_rule", "backward"),
+    (_BLOCK + "HybridBlock/GatedDeltaNet/delta_rule/dot_general",
+     "delta_rule", "backward"),
 ]
 
 
@@ -142,11 +152,15 @@ CELLS = {
     "lfm2-24b-a2b-train-s8192":
         {"short_conv_proj", "short_conv_local", "attn_proj", "attn_core",
          "mlp", "moe_route", "moe_experts", "norm", "embed", "lm_head_ce"},
+    "olmo-hybrid-7b-train-s8192":
+        {"delta_proj", "delta_local", "delta_rule", "attn_proj",
+         "attn_core", "mlp", "norm", "embed", "lm_head_ce"},
 }
 UPDATE = {"param_cast", "grad_clip", "optim_update"}
 REMAT = {"nemotron-3-nano-30b-a3b-train-s8192", "trinity-mini-train-s8192",
          "joyai-llm-flash-train-s8192",
-         "smallthinker-21b-a3b-train-s16384", "lfm2-24b-a2b-train-s8192"}
+         "smallthinker-21b-a3b-train-s16384", "lfm2-24b-a2b-train-s8192",
+         "olmo-hybrid-7b-train-s8192"}
 
 
 def _step_text(cell_name):
