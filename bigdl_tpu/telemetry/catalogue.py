@@ -236,10 +236,13 @@ METRIC_SPECS: List[MetricSpec] = [
                "call / once per TRACE under jit, as bigdl_ssd_scan_total."),
     MetricSpec("bigdl_delta_rule_total", "counter",
                "Gated delta-rule recurrences (ops/delta_rule.py) by form "
-               "(form label: chunked, the WY form a chunk of 64 positions "
-               "at a time as XLA einsums with a scan over the chunk "
-               "states; the only one there is). Counted once per eager "
-               "call / once per TRACE under jit, as bigdl_ssd_scan_total.",
+               "(form label: kernel, the Mosaic calls delta_rule_fwd / "
+               "delta_rule_bwd that carry the state in VMEM, on a TPU at "
+               "the shapes ops.delta_rule.takes_kernel admits; chunked, "
+               "the WY form a chunk at a time as XLA einsums with a scan "
+               "over the chunk states, everywhere else and while a "
+               "control has replaced _wy). Counted once per eager call / "
+               "once per TRACE under jit, as bigdl_ssd_scan_total.",
                ("form",)),
     MetricSpec("bigdl_moe_grouped_total", "counter",
                "Grouped products of held expert layers (MoE(dispatch="

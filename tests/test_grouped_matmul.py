@@ -348,3 +348,45 @@ def test_the_mamba_local_calls_compile_for_the_v5e(length, one_chip,
              if "tpu_custom_call" in line and "mamba_local_" in line]
     assert len(calls) >= 8
     assert not [c for c in calls if f"bf16[32,{length},128]" in c]
+
+
+@pytest.mark.parametrize("heads,length,d_k,d_v", [
+    (15, 8192, 96, 192), (30, 8192, 96, 192), (2, 1024, 32, 64),
+    (2, 1024, 128, 256)], ids=["the-cell", "every-head-held",
+                               "the-rules-least", "the-rules-most"])
+def test_the_delta_rule_calls_compile_for_the_v5e(heads, length, d_k, d_v,
+                                                  one_chip, monkeypatch,
+                                                  request):
+    """The gated delta rule as ``nn.GatedDeltaNet`` calls it at the
+    Olmo-Hybrid cell's shape (15 heads of 96 / 192 over 8,192 tokens), with
+    all 30 published heads held and at the least and the largest heads
+    ``ops.delta_rule.takes_kernel`` admits, forward and backward through
+    the chip's own compiler (here beside the other real-width compiles:
+    one file, one worker, one libtpu): ``delta_rule_fwd`` and
+    ``delta_rule_bwd`` are in the program, a head of 96 tiles, and what a
+    grid cell holds in VMEM fits."""
+    from bigdl_tpu.ops import delta_rule
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    from jax.experimental.compilation_cache import compilation_cache
+    cached = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    request.addfinalizer(lambda: jax.config.update(
+        "jax_enable_compilation_cache", cached))
+    bf, f32 = jnp.bfloat16, jnp.float32
+    assert delta_rule.takes_kernel("tpu", bf, f32, 64, d_k, d_v)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(q, k, v, g, beta):
+        return jnp.sum(delta_rule.gated_delta_rule(q, k, v, g, beta, 64)
+                       .astype(f32))
+
+    qk, gate = spec((1, length, heads, d_k), bf), spec((1, length, heads), f32)
+    text = jax.jit(jax.grad(loss, argnums=range(5))).lower(
+        qk, qk, spec((1, length, heads, d_v), bf), gate, gate).compile(
+        ).as_text()
+    assert "delta_rule_fwd" in text and "delta_rule_bwd" in text
+    # the one residual beside the inputs: each chunk's start state
+    assert f"f32[1,{heads},{length // 64},{d_k},{d_v}]" in text
