@@ -51,8 +51,13 @@ A kept value that no backward reads (an out-projection's or the routed
 experts' output with no norm behind it) is pruned by ``jax.checkpoint``
 itself and costs nothing. What a block still runs twice: norms, rotation,
 gates, the convolutions, the scan, the delta rule's recurrence, the
-router's product, the shared expert's activation and second product. Keeping changes the jaxpr's
-arithmetic nowhere; XLA compiles the forward around what must reach HBM,
+router's product, the shared expert's activation and second product; a
+mixer's local part in the form it took (``nn.Mamba2``'s and
+``nn.GatedDeltaNet``'s as the forward calls of ``ops/mamba_local.py`` and
+``ops/delta_local.py`` where their path rules say so: the calls keep no
+residual but the kept in-projection output and the recurrence's, and
+their backward calls make the pre-activation and the norms again).
+Keeping changes the jaxpr's arithmetic nowhere; XLA compiles the forward around what must reach HBM,
 so on the chip in bf16 a loss moves in its sixth digit (PERF.md section 6,
 PR 31). On the v5e at 1 x 8,192 tokens the list holds 253 MB an attention
 block of the Trinity-Mini cell, 235 MB its dense block, 169 MB a Mamba-2

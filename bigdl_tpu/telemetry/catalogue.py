@@ -244,6 +244,21 @@ METRIC_SPECS: List[MetricSpec] = [
                "control has replaced _wy). Counted once per eager call / "
                "once per TRACE under jit, as bigdl_ssd_scan_total.",
                ("form",)),
+    MetricSpec("bigdl_delta_local_total", "counter",
+               "Gated delta-rule mixers (nn.GatedDeltaNet) by the form "
+               "their local part took, everything between the two "
+               "projections but the recurrence: the convolution with its "
+               "SiLU, the heads' L2 norms and q's scale, and the gated "
+               "RMSNorm (form label: kernel, the four Mosaic calls of "
+               "ops/delta_local.py, delta_local_conv / _gate and their "
+               "*_bwd, taken on a TPU for bf16 operands where the "
+               "convolution's columns are whole lane tiles, q and k "
+               "together and the value width end on a half tile, at most "
+               "64 heads and 128 divides the length; xla, the jax.numpy "
+               "lines of nn/gated_delta_net.py, everywhere else, a CPU and "
+               "tier-1's heads of 8 / 16 included). Counted once per eager "
+               "call / once per TRACE under jit, as "
+               "bigdl_mamba_local_total.", ("form",)),
     MetricSpec("bigdl_moe_grouped_total", "counter",
                "Grouped products of held expert layers (MoE(dispatch="
                "'held')) by form (form label: kernel, the Mosaic kernels of "
@@ -485,11 +500,15 @@ SCOPE_SPECS: List[ScopeSpec] = [
               "The fused in-projection (q, k, v, the output gate, beta and "
               "the decay's input: six products as one) and the "
               "out-projection."),
-    ScopeSpec("delta_local", "nn/gated_delta_net.py GatedDeltaNet",
+    ScopeSpec("delta_local", "nn/gated_delta_net.py GatedDeltaNet (and the "
+              "backward rules of ops/delta_local.py)",
               "What is neither a projection nor the recurrence: the "
               "causal convolution and SiLU on q, k and v, their two L2 "
               "norms, beta and the log-decay, the gated RMSNorm of the "
-              "recurrence's output."),
+              "recurrence's output; as the Mosaic calls delta_local_conv "
+              "/ delta_local_gate and their *_bwd of ops/delta_local.py "
+              "where its path rule says so, with beta, the log-decay and "
+              "the sum of proj's cotangent XLA's beside them."),
     ScopeSpec("delta_rule", "ops/delta_rule.py gated_delta_rule",
               "The gated delta rule's chunked recurrence, all of it, both "
               "passes: the chunk's triangular system, the carry over "

@@ -390,3 +390,60 @@ def test_the_delta_rule_calls_compile_for_the_v5e(heads, length, d_k, d_v,
     assert "delta_rule_fwd" in text and "delta_rule_bwd" in text
     # the one residual beside the inputs: each chunk's start state
     assert f"f32[1,{heads},{length // 64},{d_k},{d_v}]" in text
+
+
+@pytest.mark.parametrize("heads", [15, 30],
+                         ids=["the-cell", "every-head-held"])
+def test_the_delta_local_calls_compile_for_the_v5e(heads, one_chip,
+                                                   monkeypatch, request):
+    """One gated delta-rule mixer at the Olmo-Hybrid cell's widths (15
+    heads of 96 / 192 over 8,192 tokens, and all 30 published), forward
+    and backward under ``jax.checkpoint``, through the chip's own compiler
+    (here beside the other real-width compiles: one file, one worker, one
+    libtpu): the four ``delta_local_*`` Mosaic calls are in the program
+    with the recurrence's two, what they hold in VMEM fits, and NOTHING is
+    copied between them: the calls write and read (B, H, L, d) and XLA
+    cancels the transposed views that carry the methods' (B, L, H, d)
+    against ``ops.delta_rule``'s own, so no array of a head-major shape is
+    the result of a copy, a transpose or a fusion."""
+    import re
+    from bigdl_tpu import nn
+    from bigdl_tpu.nn.module import functional_apply
+    from bigdl_tpu.ops import delta_local
+    from bigdl_tpu.utils.rng import manual_seed
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    from jax.experimental.compilation_cache import compilation_cache
+    cached = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    request.addfinalizer(lambda: jax.config.update(
+        "jax_enable_compilation_cache", cached))
+    manual_seed(1)
+    length, bf = 8192, jnp.bfloat16
+    m = nn.GatedDeltaNet(256, heads, 96, 192, conv_kernel=4,
+                         allow_neg_eigval=True, chunk_size=64)
+    assert delta_local.takes_kernel("tpu", bf, length, heads, 96, 192, 4)
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, bf, sharding=one_chip),
+        m.parameter_tree())
+    u = jax.ShapeDtypeStruct((1, length, 256), bf, sharding=one_chip)
+
+    def loss(p, u):
+        mixer = jax.checkpoint(lambda p, u: functional_apply(
+            m, p, m.buffer_tree(), u, training=True)[0])
+        return jnp.sum(mixer(p, u).astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, (0, 1))).lower(params, u).compile(
+        ).as_text()
+    assert set(re.findall(r"delta_local_[a-z_]*", text)) >= {
+        "delta_local_conv", "delta_local_gate", "delta_local_gate_bwd",
+        "delta_local_conv_bwd"}
+    assert "delta_rule_fwd" in text and "delta_rule_bwd" in text
+    entry = text[text.index("\nENTRY"):]
+    head_major = rf"bf16\[1,{heads},{length},(?:96|192)\]"
+    assert not re.findall(
+        rf"= {head_major}\S* (?:copy|transpose|fusion)\(", entry)
+    # nor is the cotangent of the in-projection's output put together in
+    # HBM: the backward products read its parts
+    wide = rf"bf16\[(?:1,)?{length},{m.conv_dim + m.d_value + 2 * heads}\]"
+    assert not re.findall(rf"= {wide}\S* (?:pad|concatenate|copy)\(", entry)
