@@ -781,6 +781,61 @@ def lfm2_moe_lm_kwargs(config: Dict[str, Any], held_experts=None,
     return kwargs
 
 
+def ouro_lm_kwargs(config: Dict[str, Any],
+                   exit_beta: float = 0.1) -> Dict[str, Any]:
+    """``models.hybrid.build_hybrid_lm`` kwargs for an HF ``ouro``
+    ``config.json`` dict (ByteDance Ouro, the looped language model of
+    arXiv:2510.25741): ``num_hidden_layers`` layers of a full-attention
+    block and a dense SwiGLU block, each normed on its input AND its output
+    (four norms a layer), MHA or GQA with rotation at ``rope_theta``, an
+    untied head; the whole stack run ``total_ut_steps`` times over the same
+    weights with the final norm between passes, and one exit gate
+    (``passes``, ``exit_gate``). ``exit_beta`` is the weight of the exit
+    distribution's entropy in the training loss (no key of the config: the
+    paper's first stage gives 0.05-0.1).
+
+    Not keys of the config but of the family's public model class, and so
+    fixed here: the four norms, the gate's bias, no bias elsewhere.
+    Refused rather than guessed: a layer type other than full attention, a
+    sliding window, rope scaling, a tied head, an activation other than
+    silu, an ``early_exit_threshold`` under 1 (a pass left early is a
+    serving path that is not built)."""
+    types = list(config.get("layer_types")
+                 or ["full_attention"] * int(config["num_hidden_layers"]))
+    if len(types) != int(config["num_hidden_layers"]):
+        raise ValueError(f"layer_types has {len(types)} entries, "
+                         f"num_hidden_layers says "
+                         f"{config['num_hidden_layers']}")
+    if set(types) != {"full_attention"} or config.get("use_sliding_window") \
+            or config.get("sliding_window"):
+        raise ValueError("ouro layers other than full attention (a "
+                         "sliding window) are not mapped")
+    if config.get("hidden_act", "silu") != "silu":
+        raise ValueError(f"unsupported ouro activation "
+                         f"{config.get('hidden_act')!r}")
+    if config.get("rope_scaling"):
+        raise ValueError("ouro rope_scaling is not mapped")
+    if config.get("tie_word_embeddings", False):
+        raise ValueError("a head tied to the embedding is not mapped")
+    if float(config.get("early_exit_threshold", 1.0)) < 1.0:
+        raise ValueError("early_exit_threshold under 1 (leaving a pass "
+                         "early) is not mapped")
+    e, heads = int(config["hidden_size"]), int(config["num_attention_heads"])
+    return dict(
+        vocab_size=int(config["vocab_size"]), embed_dim=e,
+        pattern="*-" * len(types),
+        attention=dict(num_heads=heads,
+                       num_kv_heads=int(config.get("num_key_value_heads",
+                                                   heads)),
+                       head_dim=int(config.get("head_dim", e // heads)),
+                       with_bias=False, rope=True,
+                       rope_theta=float(config.get("rope_theta", 1e4))),
+        mlp=dict(hidden_size=int(config["intermediate_size"])),
+        norm_eps=float(config.get("rms_norm_eps", 1e-6)), post_norm=True,
+        passes=int(config["total_ut_steps"]), exit_gate=True,
+        exit_beta=float(exit_beta))
+
+
 # ------------------------------------------------------------------- export
 
 def export_gpt2_state_dict(model: Module) -> Dict[str, np.ndarray]:

@@ -56,6 +56,41 @@ class _LMWithMTP(_LM):
         return out
 
 
+class _LoopedLM(nn.Sequential):
+    """The chain embedding -> looped decoder -> head
+    (``nn.HybridDecoder(passes=P)``) with a learned EXIT GATE beside it
+    (the child ``exit_gate``, ONE ``Linear(embed_dim, 1)`` with a bias
+    shared by the passes). In training the head is handed all P normed
+    streams, stacked (P, B, T, E), and the fused-CE Table it emits gains
+    the gate's logit of every pass and position (``exit_logits``, (P, B,
+    T), float32, under the scope ``loop_exit``) and the weight of the exit
+    distribution's entropy in the loss (``exit_beta``):
+    ``nn.FusedLMHeadCriterion`` forms the distribution and the loss from
+    them. In eval the gate does not run and the output is the chain's: the
+    LAST pass's log-probs (no pass is left early)."""
+
+    def update_output(self, input):
+        import jax
+        import jax.numpy as jnp
+        if not self.training:
+            return super().update_output(input)
+        *front, decoder, head = self._ordered
+        x = input
+        for m in front:
+            x = m.forward(x)
+        streams = decoder.pass_streams(x)
+        out = head.forward(streams)
+        gate = self.exit_gate
+        with jax.named_scope("loop_exit"):
+            # the gate's one row against every stream, summed in float32
+            out["exit_logits"] = jnp.einsum(
+                "...e,e->...", streams, gate.weight[0].astype(streams.dtype),
+                preferred_element_type=jnp.float32) \
+                + gate.bias[0].astype(jnp.float32)
+        out["exit_beta"] = self.exit_beta
+        return out
+
+
 def build_hybrid_lm(vocab_size: int, embed_dim: int, pattern: str,
                     mamba: Optional[dict] = None, moe: Optional[dict] = None,
                     attention: Optional[dict] = None,
@@ -68,7 +103,9 @@ def build_hybrid_lm(vocab_size: int, embed_dim: int, pattern: str,
                     short_conv: Optional[dict] = None,
                     tie_embeddings: bool = False,
                     delta: Optional[dict] = None,
-                    pre_norm: bool = True) -> nn.Sequential:
+                    pre_norm: bool = True, passes: int = 1,
+                    exit_gate: bool = False,
+                    exit_beta: float = 0.1) -> nn.Sequential:
     """Causal LM over ``nn.HybridDecoder``, head untied or tied
     (``tie_embeddings``): 1-based token ids (N, T) -> the fused-CE tail.
 
@@ -94,7 +131,23 @@ def build_hybrid_lm(vocab_size: int, embed_dim: int, pattern: str,
     from the same keyword groups) and a norm in front of the head. It has
     no embedding and no head of its own: it reads the model's lookup table
     and its stream goes through the model's ``LMHead``. In training the
-    criterion returns ``L_main + w * L_mtp``; eval is the main model's."""
+    criterion returns ``L_main + w * L_mtp``; eval is the main model's.
+
+    ``passes`` P > 1 LOOPS the stack (``nn.HybridDecoder(passes=)``: every
+    block P times over the same parameters, the final norm between passes,
+    ONE traced body under ``lax.scan``). Alone, the model trains and
+    predicts on the last pass. With ``exit_gate`` it gains ONE
+    ``Linear(embed_dim, 1)`` with a bias, shared by the passes, and trains
+    on every pass: with ``lam_t = sigmoid(gate(h_t))`` the exit
+    distribution of a token is ``p_t = lam_t prod_{j<t}(1 - lam_j)``, the
+    last pass taking what is left, and the
+    loss ``mean_i[sum_t p_{i,t} CE_{i,t} - exit_beta * H(p_i)]``, ONE fused
+    pass over the P x T rows of one head (``nn.FusedLMHeadCriterion``;
+    the looped language model of arXiv:2510.25741, its first-stage
+    objective). Eval is the last pass's log-probs."""
+    if mtp is not None and (passes > 1 or exit_gate):
+        raise ValueError("a multi-token-prediction module over a looped "
+                         "stack is not built")
     groups = dict(mamba=mamba, moe=moe, attention=attention,
                   norm_eps=norm_eps, window_attention=window_attention,
                   mlp=mlp, post_norm=post_norm,
@@ -102,17 +155,25 @@ def build_hybrid_lm(vocab_size: int, embed_dim: int, pattern: str,
                   delta=delta, pre_norm=pre_norm)
     if mtp is not None:
         m = _LMWithMTP()
+    elif exit_gate:
+        if (moe or {}).get("pick_rows"):
+            raise ValueError("experts that pick by token id under an exit "
+                             "gate are not built")
+        m = _LoopedLM()
     else:
         m = _LM() if (moe or {}).get("pick_rows") else nn.Sequential()
     embed = nn.LookupTable(vocab_size, embed_dim)
     m.add(embed)
     if embed_scale is not None:
         m.add(nn.MulConstant(float(embed_scale)))
-    m.add(nn.HybridDecoder(pattern, embed_dim, **groups))
+    m.add(nn.HybridDecoder(pattern, embed_dim, **groups, passes=passes))
     m.add(nn.TiedLMHead(embed) if tie_embeddings
           else nn.LMHead(embed_dim, vocab_size, with_bias=False))
     if mtp is not None:     # after the chain: modules() in forward order
         m.mtp = nn.MTPModule(
             embed_dim, nn.HybridDecoder(pattern[-2:], embed_dim, **groups),
             norm_eps, **mtp)
+    if exit_gate:           # as the module above: after the chain
+        m.exit_gate = nn.Linear(embed_dim, 1)
+        m.exit_beta = float(exit_beta)
     return m

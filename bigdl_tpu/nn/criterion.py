@@ -522,6 +522,22 @@ class TimeDistributedCriterion(Criterion):
         return loss / t if self.size_average else loss
 
 
+def exit_distribution(logits):
+    """(p, sum_t p log p) of a looped decoder's exit gate ``logits`` (P,
+    ...): the distribution over the P passes a position, ``p_t = lam_t
+    prod_{j<t}(1 - lam_j)`` with ``lam = sigmoid(logits)``, the last pass
+    taking what the others left, and its negative entropy; float32, from
+    log-sigmoids (``FusedLMHeadCriterion``)."""
+    g = logits.astype(jnp.float32)
+    stay = jax.nn.log_sigmoid(-g[:-1])              # log(1 - lam_j)
+    left = jnp.concatenate([jnp.zeros_like(g[:1]),
+                            jnp.cumsum(stay, axis=0)])
+    log_p = left + jnp.concatenate(
+        [jax.nn.log_sigmoid(g[:-1]), jnp.zeros_like(g[:1])])
+    p = jnp.exp(log_p)
+    return p, jnp.sum(p * log_p, axis=0)
+
+
 class FusedLMHeadCriterion(Criterion):
     """Row-tiled cross-entropy paired with ``nn.LMHead``.
 
@@ -549,6 +565,23 @@ class FusedLMHeadCriterion(Criterion):
     against the targets shifted one to the left, the last position left
     out (``ignore_index``, or 0 where none is set: no 1-based id), under
     the scope ``mtp``.
+
+    A looped decoder's exit gate
+    (``models.hybrid.build_hybrid_lm(passes=P, exit_gate=True)``): where
+    the Table carries ``exit_logits`` ((P, B, T) float32, the gate's logit
+    of every pass and position) and ``exit_beta``, ``hidden`` is the P
+    passes' streams stacked (P, B, T, E) and the loss is the expected
+    cross-entropy under the exit distribution less ``exit_beta`` times its
+    entropy, a mean over the tokens::
+
+        lam_t = sigmoid(g_t);  p_t = lam_t prod_{j<t}(1 - lam_j)  (t < P),
+        p_P = prod_{j<P}(1 - lam_j)
+        L = mean_i [ sum_t p_{i,t} CE_{i,t} + exit_beta sum_t p log p ]
+
+    ``lam``, ``p`` and the entropy are float32, from log-sigmoids, under
+    the scope ``loop_exit``; the P x B x T cross-entropies are ONE fused
+    pass with ``p`` as its weight a row (``fused_lm_head_ce(row_weight=)``),
+    whose gradient with respect to ``p`` is what trains the gate.
     """
 
     def __init__(self, chunk: Optional[int] = None,
@@ -566,6 +599,10 @@ class FusedLMHeadCriterion(Criterion):
             if isinstance(input, Table):
                 hidden, weight, bias = input[1], input[2], input.get(3)
                 nxt = input.get("mtp")
+                if "exit_logits" in input:
+                    return self._exit_loss(hidden, weight, bias, target,
+                                           input["exit_logits"],
+                                           input["exit_beta"])
             else:
                 hidden, weight = input[0], input[1]
                 bias = input[2] if len(input) >= 3 else None
@@ -595,3 +632,24 @@ class FusedLMHeadCriterion(Criterion):
                     jnp.float32)), 1.0)
             return total
         return -_reduce(picked, self.size_average)
+
+    def _exit_loss(self, hidden, weight, bias, target, logits, beta):
+        """The looped decoder's loss (class docstring) from the P streams,
+        the gate's logits and the entropy's weight."""
+        from bigdl_tpu.ops.lm_head_ce import fused_lm_head_ce
+        valid = None if self.ignore_index is None \
+            else target.astype(jnp.int32) != int(self.ignore_index)
+        with jax.named_scope("loop_exit"):
+            p, neg_entropy = exit_distribution(logits)
+            if valid is not None:
+                neg_entropy = jnp.where(valid, neg_entropy, 0.0)
+        ce = fused_lm_head_ce(
+            hidden, weight, bias, jnp.broadcast_to(target, logits.shape),
+            chunk=self.chunk, size_average=False,
+            ignore_index=self.ignore_index, row_weight=p)
+        with jax.named_scope("loop_exit"):
+            loss = ce + beta * jnp.sum(neg_entropy)
+            if not self.size_average:
+                return loss
+            return loss / (target.size if valid is None else jnp.maximum(
+                jnp.sum(valid.astype(jnp.float32)), 1.0))

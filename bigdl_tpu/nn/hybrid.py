@@ -26,6 +26,14 @@ output ALONE, ``x <- x + RMSNorm(mixer(x))``, the block of the OLMo 2
 family (the Olmo-Hybrid configuration, kinds ``D*-``). Every other
 configuration's blocks are pre-norm.
 
+With ``passes`` P > 1 the stack is LOOPED (a weight-shared,
+recurrent-depth decoder, the Ouro configuration): all its blocks run P
+times over the SAME parameters, the final norm closes every pass and the
+next pass reads the normed stream, ``h_t = norm(stack(h_{t-1}))``.
+``pass_streams`` hands on all P normed streams (each goes through the
+model's one head in training, ``models.hybrid.build_hybrid_lm(passes=)``);
+the module's output is the last.
+
 ``MTPModule`` is a multi-token-prediction module over such a stack: a
 short second stack fed the main one's stream and the NEXT token's
 embedding, whose output goes through the model's own head to predict the
@@ -115,7 +123,13 @@ class HybridDecoder(Module):
     ``*`` and for the ``W`` blocks, ``nn.LatentAttention(embed_dim, ...)``,
     ``GatedMLP(embed_dim, ...)``, ``nn.ShortConv(embed_dim, ...)`` and
     ``nn.GatedDeltaNet(embed_dim, ...)``; a kind the pattern does not use
-    needs none."""
+    needs none.
+
+    ``passes`` P > 1 runs the whole stack P times over the same parameters
+    with the final norm between passes (module docstring): the output is
+    the last pass's normed stream, ``pass_streams`` gives all P. The loop is
+    one ``lax.scan`` body; inside it a block may not write a buffer (none of
+    the kinds does in training)."""
 
     KINDS = "ME*W-LRCD"
 
@@ -142,12 +156,23 @@ class HybridDecoder(Module):
     #: reads, which is a block boundary that is kept anyway
     remat_blocks = False
 
+    #: the LAST name of ``ops.remat.BLOCK_SAVED_NAMES`` that block remat
+    #: keeps in this decoder (the tuple is in dropping order, so what is
+    #: kept is a leading part of it); None keeps the whole list. A looped
+    #: stack holds ``passes`` x the activations over one set of parameters
+    #: and may not have room for all of it
+    remat_keep_through = None
+
     def __init__(self, pattern: str, embed_dim: int, mamba=None, moe=None,
                  attention=None, norm_eps: float = 1e-5,
                  window_attention=None, mlp=None, post_norm: bool = False,
                  latent_attention=None, short_conv=None, delta=None,
-                 pre_norm: bool = True):
+                 pre_norm: bool = True, passes: int = 1):
         super().__init__()
+        if passes < 1:
+            raise ValueError(f"passes {passes}: the stack runs at least "
+                             f"once")
+        self.passes = passes
         bad = set(pattern) - set(self.KINDS)
         if bad or not pattern:
             raise ValueError(f"pattern {pattern!r}: blocks are named by "
@@ -201,12 +226,32 @@ class HybridDecoder(Module):
 
             if ckpt:
                 # kept by name, whatever the block is made of (ops.remat)
-                run = jax.checkpoint(run, policy=block_remat_policy())
+                run = jax.checkpoint(run, policy=block_remat_policy(
+                    self.remat_keep_through))
             entered, x = x, run(*args)
         return x
 
+    def pass_streams(self, input):
+        """The normed stream after each pass, stacked: (passes, B, T, E).
+        One pass is ``final_norm(stream(.))`` and the next reads its
+        output. The loop is ONE traced body under ``lax.scan``: the
+        program holds one copy of the stack and of its kernel calls, and
+        the cotangents of the shared parameters are summed over the passes
+        by the scan's transpose, in the parameters' own dtype."""
+        from bigdl_tpu.telemetry import get_registry, instruments
+        # trace-time count, as bigdl_ssd_scan_total
+        instruments(get_registry()).decoder_passes_total.inc()
+
+        def one_pass(h, _):
+            h = self.final_norm.forward(self.stream(h))
+            return h, h
+
+        return jax.lax.scan(one_pass, input, None, length=self.passes)[1]
+
     def update_output(self, input):
-        return self.final_norm.forward(self.stream(input))
+        if self.passes == 1:
+            return self.final_norm.forward(self.stream(input))
+        return self.pass_streams(input)[-1]
 
     def __repr__(self):
         return f"HybridDecoder({self.pattern!r})"

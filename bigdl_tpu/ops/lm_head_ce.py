@@ -19,6 +19,14 @@ so its logsumexp is final the moment the tile's product is done:
   cotangent; the backward rule scales them. (A frozen head, gradient wrt
   ``h`` alone, still pays for the ``dW`` it drops.)
 - not differentiated: the same tiles, the logits product alone.
+- with a weight a row (``row_weight``, float32, itself differentiable: an
+  exit distribution over the passes of a looped decoder,
+  ``nn.FusedLMHeadCriterion``): the loss is ``sum_r w_r l_r``, a unit
+  cotangent's gradients cannot be scaled by ONE number afterwards, so the
+  tile forms ``(softmax - onehot) * w_r`` while it is live and keeps the
+  per-row losses ``l_r``: they ARE ``dL/dw_r``. One call takes the rows of
+  every pass, so ``W`` is read and ``dW`` written once a tile, not once a
+  pass. Without a weight nothing of this is traced.
 
 Rows a tile come from the shapes (``rows_per_tile``): a tile's logits are
 live memory, and ``dW`` is read and written once a tile, in the open (on a
@@ -56,9 +64,10 @@ def rows_per_tile(n: int, v: int, chunk: Optional[int] = None) -> int:
     return -(-n // max(1, -(-4 * n * v // _TILE_BYTES)))
 
 
-def _tile(h, tgt0, valid, w, b, grads):
-    """One row tile: its loss sum and, with ``grads``, (dh, dW, db) of that
-    sum; ``w`` already in the compute dtype, ``b`` None or (V,)."""
+def _tile(h, tgt0, valid, wr, w, b, grads):
+    """One row tile: its loss sum (each row times ``wr`` where there is a
+    weight a row), its rows' losses and, with ``grads``, (dh, dW, db) of
+    that sum; ``w`` already in the compute dtype, ``b`` None or (V,)."""
     logits = jnp.matmul(h, w.T).astype(jnp.float32)
     if b is not None:
         logits = logits + b.astype(jnp.float32)
@@ -68,22 +77,26 @@ def _tile(h, tgt0, valid, w, b, grads):
     # where a gather would make XLA keep a float32 copy of the tile for it
     onehot = jnp.arange(w.shape[0])[None, :] == tgt0[:, None]
     zt = jnp.sum(jnp.where(onehot, logits, 0.0), axis=-1)
-    loss = jnp.sum(jnp.where(valid, lse - zt, 0.0))
+    per_row = jnp.where(valid, lse - zt, 0.0)
+    loss = jnp.sum(per_row if wr is None else per_row * wr)
     if not grads:
-        return loss, (None, None, None)
-    # d loss / d logits = (softmax - onehot) on valid rows
-    g = jnp.where(valid[:, None],
-                  jnp.exp(logits - lse[:, None]) - onehot, 0.0)
+        return loss, per_row, (None, None, None)
+    # d loss / d logits = (softmax - onehot) on valid rows, times the row's
+    # weight where there is one
+    on = valid[:, None]
+    g = jnp.exp(logits - lse[:, None]) - onehot
+    g = jnp.where(on, g if wr is None else g * wr[:, None], 0.0)
     gl = g.astype(h.dtype)
     dw = lax.dot_general(gl, h, (((0,), (0,)), ((), ())),
                          preferred_element_type=jnp.float32)
     db = None if b is None else jnp.sum(g, axis=0)
-    return loss, (jnp.matmul(gl, w), dw, db)
+    return loss, per_row, (jnp.matmul(gl, w), dw, db)
 
 
-def _over_tiles(h, w, b, valid, tgt0, rows, grads):
+def _over_tiles(h, w, b, valid, tgt0, rows, grads, row_weight=None):
     """Scan ``_tile`` over tiles of ``rows`` rows (the last one padded with
-    invalid rows): loss_sum and, with ``grads``, the (dh, dW, db) of it."""
+    invalid rows): loss_sum, with ``row_weight`` the rows' losses (else
+    None) and, with ``grads``, the (dh, dW, db) of the sum."""
     n, e = h.shape
     tiles = -(-n // rows)
     pad = tiles * rows - n
@@ -94,21 +107,26 @@ def _over_tiles(h, w, b, valid, tgt0, rows, grads):
         return x.reshape((tiles, rows) + x.shape[1:])
 
     wc = w.astype(h.dtype)      # once, at its own V
+    weighted = row_weight is not None
 
     def body(acc, x):
-        loss, (dh, dw, db) = _tile(*x, wc, b, grads)
-        return jax.tree.map(jnp.add, acc, (loss, dw, db)), dh
+        loss, per_row, (dh, dw, db) = _tile(*x, wc, b, grads)
+        return jax.tree.map(jnp.add, acc, (loss, dw, db)), \
+            (dh, per_row if weighted else None)
 
     def zeros(x):
         if grads and x is not None:
             return jnp.zeros(x.shape, jnp.float32)
 
-    (loss, dw, db), dh = lax.scan(
+    (loss, dw, db), (dh, per_row) = lax.scan(
         body, (jnp.zeros((), jnp.float32), zeros(w), zeros(b)),
-        (tiled(h), tiled(tgt0), tiled(valid)))
+        (tiled(h), tiled(tgt0), tiled(valid),
+         tiled(row_weight) if weighted else None))
     if grads:
         dh = dh.reshape(tiles * rows, e)[:n]
-    return loss, (dh, dw, db)
+    if weighted:
+        per_row = per_row.reshape(tiles * rows)[:n]
+    return loss, per_row, (dh, dw, db)
 
 
 def _count(form):
@@ -131,7 +149,7 @@ def _lm_head_ce(h, w, b, valid, tgt0, rows):
 @under_scope("lm_head_ce")
 def _lm_head_ce_fwd(h, w, b, valid, tgt0, rows):
     _count("one_pass")
-    loss, grads = _over_tiles(h, w, b, valid, tgt0, rows, grads=True)
+    loss, _, grads = _over_tiles(h, w, b, valid, tgt0, rows, grads=True)
     return loss, (*jax.tree.map(lambda g, x: g.astype(x.dtype), grads,
                                 (h, w, b)), valid, tgt0)
 
@@ -148,10 +166,49 @@ def _lm_head_ce_bwd(rows, res, g_sum):
 _lm_head_ce.defvjp(_lm_head_ce_fwd, _lm_head_ce_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+@under_scope("lm_head_ce")
+def _lm_head_ce_weighted(h, w, b, row_weight, valid, tgt0, rows):
+    """(``sum_r row_weight_r l_r`` over the valid rows, the rows' ``l_r``);
+    ``b`` may be None."""
+    _count("forward_only")
+    return _over_tiles(h, w, b, valid, tgt0, rows, False, row_weight)[:2]
+
+
+@under_scope("lm_head_ce")
+def _lm_head_ce_weighted_fwd(h, w, b, row_weight, valid, tgt0, rows):
+    _count("weighted_one_pass")
+    loss, per_row, grads = _over_tiles(h, w, b, valid, tgt0, rows, True,
+                                       row_weight)
+    return (loss, per_row), (
+        *jax.tree.map(lambda g, x: g.astype(x.dtype), grads, (h, w, b)),
+        per_row, valid, tgt0)
+
+
+@under_scope("lm_head_ce")
+def _lm_head_ce_weighted_bwd(rows, res, cotangents):
+    # the rows' own cotangent has no gradient formed for it (that would be
+    # a second pass over the logits): fused_lm_head_ce hands the rows out
+    # behind stop_gradient, so it is zero here
+    g_sum, _ = cotangents
+    *grads, per_row, valid, tgt0 = res
+    return (*jax.tree.map(lambda g: (g * g_sum).astype(g.dtype),
+                          tuple(grads)),
+            g_sum * per_row,
+            np.zeros(valid.shape, dtype=jax.dtypes.float0),
+            np.zeros(tgt0.shape, dtype=jax.dtypes.float0))
+
+
+_lm_head_ce_weighted.defvjp(_lm_head_ce_weighted_fwd,
+                            _lm_head_ce_weighted_bwd)
+
+
 def fused_lm_head_ce(hidden: jax.Array, weight: jax.Array,
                      bias: Optional[jax.Array], targets: jax.Array, *,
                      chunk: Optional[int] = None, size_average: bool = True,
-                     ignore_index: Optional[int] = None) -> jax.Array:
+                     ignore_index: Optional[int] = None,
+                     row_weight: Optional[jax.Array] = None,
+                     return_rows: bool = False):
     """Cross-entropy of ``hidden @ weight.T + bias`` against 1-based targets.
 
     ``hidden``: (..., E); ``weight``: (V, E); ``targets``: hidden's leading
@@ -160,6 +217,14 @@ def fused_lm_head_ce(hidden: jax.Array, weight: jax.Array,
     ``chunk`` is the rows a tile (default: from the shapes,
     ``rows_per_tile``). Numerically equal to ``ClassNLL(LogSoftMax(logits),
     targets)`` without ever materialising (N, V) logits.
+
+    ``row_weight`` (hidden's leading shape, float32) weighs each row's loss:
+    the result is ``sum_r w_r l_r`` (over the count of valid rows with
+    ``size_average``, not over the weights' sum), and its gradient with
+    respect to the weights is the rows' losses ``l_r``. ``return_rows``
+    (with a weight) also returns those ``l_r`` (hidden's leading shape,
+    float32, 0 on ignored rows) as a second value, behind ``stop_gradient``:
+    the gradient flows through the weighted sum alone.
     """
     e = hidden.shape[-1]
     h2 = hidden.reshape(-1, e)
@@ -169,8 +234,17 @@ def fused_lm_head_ce(hidden: jax.Array, weight: jax.Array,
     else:
         valid = jnp.ones(tgt.shape, bool)
     rows = rows_per_tile(h2.shape[0], weight.shape[0], chunk)
-    loss_sum = _lm_head_ce(h2, weight, bias, valid, tgt - 1, rows)
+    if row_weight is None:
+        if return_rows:
+            raise ValueError("the rows' losses come back with a row_weight")
+        loss_sum = _lm_head_ce(h2, weight, bias, valid, tgt - 1, rows)
+    else:
+        loss_sum, per_row = _lm_head_ce_weighted(
+            h2, weight, bias, row_weight.reshape(-1).astype(jnp.float32),
+            valid, tgt - 1, rows)
     if size_average:
-        return loss_sum / jnp.maximum(jnp.sum(valid.astype(jnp.float32)),
-                                      1.0)
+        loss_sum = loss_sum / jnp.maximum(
+            jnp.sum(valid.astype(jnp.float32)), 1.0)
+    if return_rows:
+        return loss_sum, lax.stop_gradient(per_row).reshape(targets.shape)
     return loss_sum

@@ -89,10 +89,23 @@ BLOCK_SAVED_NAMES = (MOE_ROUTE_TABLES, MOE_ROUTED_OUT, FLASH_OUT, ATTN_PROJ,
                      DELTA_IN_PROJ, MOE_SHARED_HID)
 
 
-def block_remat_policy():
+def _listed(name: str) -> int:
+    """``name``'s place on block remat's list."""
+    if name not in BLOCK_SAVED_NAMES:
+        raise ValueError(f"{name!r} is not on block remat's list "
+                         f"{BLOCK_SAVED_NAMES}")
+    return BLOCK_SAVED_NAMES.index(name)
+
+
+def block_remat_policy(through=None):
     """Per-block checkpointing of a decoder: recompute everything inside a
-    block but ``BLOCK_SAVED_NAMES``."""
-    return jax.checkpoint_policies.save_only_these_names(*BLOCK_SAVED_NAMES)
+    block but ``BLOCK_SAVED_NAMES``, or but the leading part of it that
+    ends with the name ``through`` (the tuple is in dropping order: a
+    decoder that has no room for all of it,
+    ``nn.HybridDecoder.remat_keep_through``, drops from the end)."""
+    kept = BLOCK_SAVED_NAMES if through is None \
+        else BLOCK_SAVED_NAMES[:_listed(through) + 1]
+    return jax.checkpoint_policies.save_only_these_names(*kept)
 
 
 def keep(value, name: str):
@@ -100,9 +113,7 @@ def keep(value, name: str):
     on its list. Counted at trace time (``bigdl_remat_kept_total{name}``:
     the tags a compiled program MET; whether a checkpoint honoured them is
     in the lowered program)."""
-    if name not in BLOCK_SAVED_NAMES:
-        raise ValueError(f"{name!r} is not on block remat's list "
-                         f"{BLOCK_SAVED_NAMES}")
+    _listed(name)
     from bigdl_tpu.telemetry import get_registry, instruments
     instruments(get_registry()).remat_kept_total.labels(name=name).inc()
     return checkpoint_name(value, name)

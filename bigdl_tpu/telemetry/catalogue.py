@@ -281,10 +281,21 @@ METRIC_SPECS: List[MetricSpec] = [
     MetricSpec("bigdl_lm_head_ce_total", "counter",
                "Fused LM-head cross-entropies by form (form label: "
                "one_pass, the loss and its three gradients from one scan "
-               "over row tiles, traced under grad; forward_only, the loss "
-               "alone, not differentiated). Counted once per eager call / "
-               "once per TRACE under jit, as bigdl_ssd_scan_total.",
+               "over row tiles, traced under grad; weighted_one_pass, the "
+               "same with a weight a row, fused_lm_head_ce(row_weight=): "
+               "the gradients formed with the weights while a tile is "
+               "live and the rows' losses kept, which are the weights' "
+               "gradient; forward_only, the loss alone, not "
+               "differentiated, weighted or not). Counted once per eager "
+               "call / once per TRACE under jit, as bigdl_ssd_scan_total.",
                ("form",)),
+    MetricSpec("bigdl_decoder_passes_total", "counter",
+               "Looped pattern decoders (nn.HybridDecoder(passes=P), "
+               "pass_streams: ONE traced body under lax.scan, so a "
+               "compiled program holds one copy of the stack and of its "
+               "kernel calls). A decoder of one pass that is asked for "
+               "its output counts nothing. Counted once per eager call / "
+               "once per TRACE under jit, as bigdl_ssd_scan_total."),
     MetricSpec("bigdl_flash_attention_total", "counter",
                "Flash-attention calls by form (form label: band, the "
                "kernels told of a sliding window, named flash_band_*; "
@@ -540,6 +551,14 @@ SCOPE_SPECS: List[ScopeSpec] = [
               "The multi-token-prediction module; its own row is what no "
               "layer inside it names (the 2E -> E projection, the "
               "shifts).", group=True),
+    ScopeSpec("loop_exit", "models/hybrid.py _LoopedLM; nn/criterion.py "
+              "FusedLMHeadCriterion._exit_loss",
+              "A looped decoder's exit gate: the gate's product with each "
+              "pass's stream, the log-sigmoids, the exit distribution "
+              "over the passes, its entropy and the loss's last sums, "
+              "forward and backward (plain jax.numpy: autodiff carries "
+              "the scope to the transpose). The passes' cross-entropies "
+              "are lm_head_ce's."),
     ScopeSpec("norm", "class scope", "A norm between blocks (a norm inside "
               "attn_proj, mla_proj or mamba_local belongs to that layer).",
               classes=("RMSNorm", "LayerNorm")),

@@ -221,7 +221,8 @@ def test_the_differentiated_step_runs_kept_work_once_a_block(
         == MET[cell_name]
     monkeypatch.setattr(
         hybrid, "block_remat_policy",
-        lambda: jax.checkpoint_policies.save_only_these_names("drifted"))
+        lambda through=None:
+        jax.checkpoint_policies.save_only_these_names("drifted"))
     twice = _work(_step_jaxpr(cell, cfg, model), width)
     for k, v in once.items():
         assert twice[k] == (v if "bwd" in k else 2 * v), (k, twice)

@@ -353,3 +353,153 @@ class TestTiedEmbeddings:
         opt.set_optim_method(SGD(learningrate=0.1))
         opt.set_end_when(Trigger.max_iteration(3))
         opt.optimize()
+
+
+# ------------------------------------------------- a weight a row (PR 48)
+
+def dense_weighted(h, w, b, tgt, row_weight, ignore_index=None):
+    """``sum_r w_r l_r`` and the rows' ``l_r`` by the dense formula."""
+    logits = h.astype(jnp.float32) @ w.astype(jnp.float32).T
+    if b is not None:
+        logits = logits + b.astype(jnp.float32)
+    logp = jax.nn.log_softmax(logits, axis=-1)
+    rows = -jnp.take_along_axis(
+        logp, (tgt.astype(jnp.int32) - 1)[:, None], axis=1)[:, 0]
+    if ignore_index is not None:
+        rows = jnp.where(tgt.astype(jnp.int32) != ignore_index, rows, 0.0)
+    return jnp.sum(rows * row_weight), rows
+
+
+class TestRowWeight:
+    """``fused_lm_head_ce(row_weight=)``: the weighted sum, its gradients
+    with respect to the hidden states, the head AND the weights (the rows'
+    losses), against the dense formula; without a weight the function is
+    the parent's, equation for equation."""
+
+    @pytest.mark.parametrize("chunk,bias,ignore", [
+        (7, True, None), (16, False, None), (64, True, 3), (None, False, 5)])
+    def test_loss_rows_and_every_gradient(self, chunk, bias, ignore):
+        h, w, b, tgt = make_inputs(4)
+        b = b if bias else None
+        rw = jnp.asarray(np.random.RandomState(5).rand(N).astype(np.float32))
+
+        def fused(h, w, b, rw):
+            return fused_lm_head_ce(h, w, b, tgt, chunk=chunk,
+                                    size_average=False, ignore_index=ignore,
+                                    row_weight=rw, return_rows=True)
+
+        def dense(h, w, b, rw):
+            return dense_weighted(h, w, b, tgt, rw, ignore)
+
+        (got, rows), grads = jax.value_and_grad(
+            fused, argnums=(0, 1, 2, 3) if bias else (0, 1, 3),
+            has_aux=True)(h, w, b, rw)
+        (want, want_rows), want_g = jax.value_and_grad(
+            dense, argnums=(0, 1, 2, 3) if bias else (0, 1, 3),
+            has_aux=True)(h, w, b, rw)
+        np.testing.assert_allclose(float(got), float(want), rtol=1e-5)
+        np.testing.assert_allclose(np.asarray(rows), np.asarray(want_rows),
+                                   rtol=1e-5, atol=1e-6)
+        for a, e in zip(grads, want_g):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(e),
+                                       atol=2e-5, rtol=1e-4)
+        # the weights' gradient IS the rows' losses
+        np.testing.assert_allclose(np.asarray(grads[-1]), np.asarray(rows),
+                                   rtol=1e-6)
+
+    def test_unit_weights_are_the_unweighted_loss(self):
+        h, w, b, tgt = make_inputs(6)
+        ones = jnp.ones((N,), jnp.float32)
+        for avg in (True, False):
+            got = fused_lm_head_ce(h, w, b, tgt, size_average=avg,
+                                   ignore_index=2, row_weight=ones)
+            want = fused_lm_head_ce(h, w, b, tgt, size_average=avg,
+                                    ignore_index=2)
+            np.testing.assert_allclose(float(got), float(want), rtol=1e-6)
+
+    def test_leading_shapes_and_a_scaled_cotangent(self):
+        """(P, B, T) weights over (P, B, T, E) hidden states, as the looped
+        decoder's criterion calls it; a cotangent other than 1 scales every
+        gradient, the weights' too."""
+        rng = np.random.RandomState(7)
+        h = jnp.asarray(rng.randn(2, 3, 4, E).astype(np.float32))
+        w = jnp.asarray(rng.randn(V, E).astype(np.float32) * 0.3)
+        tgt = jnp.asarray(rng.randint(1, V + 1, (2, 3, 4)).astype(np.float32))
+        rw = jnp.asarray(rng.rand(2, 3, 4).astype(np.float32))
+
+        def fused(h, w, rw):
+            return 2.5 * fused_lm_head_ce(h, w, None, tgt,
+                                          size_average=False, row_weight=rw)
+
+        def dense(h, w, rw):
+            return 2.5 * dense_weighted(h.reshape(-1, E), w, None,
+                                        tgt.reshape(-1), rw.reshape(-1))[0]
+
+        got, want = (jax.grad(f, argnums=(0, 1, 2))(h, w, rw)
+                     for f in (fused, dense))
+        for a, e in zip(got, want):
+            assert a.shape == e.shape
+            np.testing.assert_allclose(np.asarray(a), np.asarray(e),
+                                       atol=2e-5, rtol=1e-4)
+        rows = fused_lm_head_ce(h, w, None, tgt, size_average=False,
+                                row_weight=rw, return_rows=True)[1]
+        assert rows.shape == tgt.shape
+
+    def test_the_rows_carry_no_gradient_of_their_own(self):
+        """The rows come back behind ``stop_gradient``: a loss made of them
+        alone has no gradient, and they ask for a weight."""
+        h, w, b, tgt = make_inputs(8)
+        rw = jnp.ones((N,), jnp.float32)
+        g = jax.grad(lambda h: jnp.sum(fused_lm_head_ce(
+            h, w, b, tgt, row_weight=rw, return_rows=True)[1]))(h)
+        assert not np.asarray(g).any()
+        with pytest.raises(ValueError, match="row_weight"):
+            fused_lm_head_ce(h, w, b, tgt, return_rows=True)
+
+    def test_the_counter_says_which_form_was_traced(self):
+        from bigdl_tpu.telemetry import get_registry, instruments
+        fam = instruments(get_registry()).lm_head_ce_total
+        count = lambda: tuple(fam.labels(form=f).value for f in (
+            "weighted_one_pass", "one_pass", "forward_only"))
+        h, w, b, tgt = make_inputs(9)
+        rw = jnp.ones((N,), jnp.float32)
+        before = count()
+        jax.make_jaxpr(jax.grad(lambda h: fused_lm_head_ce(
+            h, w, b, tgt, row_weight=rw)))(h)
+        assert tuple(a - b for a, b in zip(count(), before)) == (1, 0, 0)
+        before = count()
+        jax.make_jaxpr(lambda h: fused_lm_head_ce(
+            h, w, b, tgt, row_weight=rw))(h)
+        assert tuple(a - b for a, b in zip(count(), before)) == (0, 0, 1)
+
+    def test_without_a_weight_the_jaxpr_is_the_parents(self):
+        """``row_weight=None`` is the call without the keyword: plain and
+        under ``grad``, with and without a bias, both trace to the same
+        text, no equation of it reads a weight, and the unweighted form is
+        what is counted."""
+        import re
+        from bigdl_tpu.telemetry import get_registry, instruments
+        fam = instruments(get_registry()).lm_head_ce_total
+        count = lambda: tuple(fam.labels(form=f).value for f in (
+            "weighted_one_pass", "one_pass"))
+        t = jnp.ones((2, 24))
+        h = jnp.zeros((2, 24, 16), jnp.bfloat16)
+        w, b = jnp.zeros((40, 16)), jnp.zeros((40,))
+
+        def text(fn, bias):
+            return re.sub(r"0x[0-9a-f]+", "", str(jax.make_jaxpr(fn)(
+                h, w, bias)))
+
+        def bare(h, w, b):
+            return fused_lm_head_ce(h, w, b, t, ignore_index=3)
+
+        def keyed(h, w, b):
+            return fused_lm_head_ce(h, w, b, t, ignore_index=3,
+                                    row_weight=None, return_rows=False)
+
+        before = count()
+        for bias in (None, b):
+            assert text(keyed, bias) == text(bare, bias)
+            assert text(jax.grad(keyed, argnums=(0, 1)), bias) \
+                == text(jax.grad(bare, argnums=(0, 1)), bias)
+        assert tuple(a - b for a, b in zip(count(), before)) == (0, 4)

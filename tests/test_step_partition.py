@@ -117,6 +117,19 @@ CASES = [
      "delta_rule", "backward"),
     (_BLOCK + "HybridBlock/GatedDeltaNet/delta_rule/dot_general",
      "delta_rule", "backward"),
+    # a looped decoder: the stack's layers keep their names inside the
+    # loop over the passes; the exit gate's product and distribution are
+    # ``loop_exit``, in the model and in the criterion, both ways
+    ("jit(step)/jvp(_LoopedLM)/HybridDecoder/while/body/HybridBlock/"
+     "GatedMLP/mlp/dot_general", "mlp", "forward"),
+    ("jit(step)/transpose(jvp(_LoopedLM))/HybridDecoder/while/body/"
+     "checkpoint/rematted_computation/HybridBlock/RMSNorm/mul", "norm",
+     "recompute"),
+    ("jit(step)/jvp(_LoopedLM)/loop_exit/dot_general", "loop_exit",
+     "forward"),
+    ("jit(step)/transpose(jvp(criterion))/loop_exit/logistic", "loop_exit",
+     "backward"),
+    ("jit(step)/jvp(criterion)/loop_exit/cumsum", "loop_exit", "forward"),
 ]
 
 
@@ -155,12 +168,15 @@ CELLS = {
     "olmo-hybrid-7b-train-s8192":
         {"delta_proj", "delta_local", "delta_rule", "attn_proj",
          "attn_core", "mlp", "norm", "embed", "lm_head_ce"},
+    "ouro-2.6b-train-s4096":
+        {"attn_proj", "attn_core", "mlp", "norm", "embed", "lm_head_ce",
+         "loop_exit"},
 }
 UPDATE = {"param_cast", "grad_clip", "optim_update"}
 REMAT = {"nemotron-3-nano-30b-a3b-train-s8192", "trinity-mini-train-s8192",
          "joyai-llm-flash-train-s8192",
          "smallthinker-21b-a3b-train-s16384", "lfm2-24b-a2b-train-s8192",
-         "olmo-hybrid-7b-train-s8192"}
+         "olmo-hybrid-7b-train-s8192", "ouro-2.6b-train-s4096"}
 
 
 def _step_text(cell_name):
